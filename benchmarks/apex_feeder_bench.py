@@ -1,11 +1,10 @@
 """Chip-side Ape-X service ceiling: in-RAM feeders, no emulator.
 
-VERDICT round-4 missing #1 / next #1: the end-to-end split bench
-(apex_split_bench.py) honestly measures this dev box's single CPU core
-running emulator + preprocessing + actors + service — the chip-side
-service idle-waits, so the number a v4-32 deployment actually plans
-around (how many records/s the TPU-side service can sustain when the
-host side is NOT starved) stayed unmeasured. This bench replaces the
+The end-to-end split bench (apex_split_bench.py) measures the host's
+CPU cores running emulator + preprocessing + actors + service — the
+chip-side service idle-waits, so how many records/s the TPU-side service
+can sustain when the host side is NOT starved stays unmeasured there.
+This bench replaces the
 rollout actors with ``actors/feeder.py`` processes that replay
 pre-generated, pre-encoded trajectory records through the PRODUCTION
 shm transport at maximum rate; everything downstream is the production
@@ -19,15 +18,14 @@ learner kept the configured inserts-per-grad ratio at that ingest rate
 — if not, trains-flat-out is the ceiling's meaning, standard Ape-X
 semantics).
 
-Honesty note: feeders and service still share this box's ONE core, so
-the feeder-side memcpy pump steals some service CPU — the measured
-ceiling is a LOWER bound on what the service does with a dedicated
-core. The emulator/preprocessing cost (the thing the split bench is
-bound by) is gone, which is the point.
+Honesty note: feeders and service share the host's cores, so the
+feeder-side memcpy pump can steal service CPU — the measured ceiling is
+a LOWER bound on what the service does with dedicated cores. The
+emulator/preprocessing cost (the thing the split bench is bound by) is
+gone, which is the point.
 
-Wedge discipline (verify skill): probe phase pays all compiles and
-measures the achievable rate; the measure phase's frame budget is
-derived from it, so the run cannot be oversized.
+Sizing: the probe phase pays all compiles and measures the achievable
+rate; the measure phase's frame budget is derived from it.
 
 Usage:  python benchmarks/apex_feeder_bench.py [--allow-cpu]
             [--variants pixel vector] [--measure-seconds 120]
@@ -45,7 +43,7 @@ from pathlib import Path
 sys.path.insert(0, str(Path(__file__).resolve().parent))
 sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
 
-from tpu_battery import gate_backend  # noqa: E402
+from dist_dqn_tpu.utils.backend import select_platform  # noqa: E402
 
 
 def _configs(variant: str, smoke: bool):
@@ -116,8 +114,7 @@ def _run(cfg, rt_kwargs, total: int, trace_path=None, **rt_extra):
 
 def _roundtrip_fields(summary) -> dict:
     """Device round-trip accounting (ISSUE 2): the service counts every
-    dispatched program by kind; per-ingest-pass ratios are the number a
-    remote-tunnel deployment plans around (~70 ms per round-trip)."""
+    dispatched program by kind, reported per ingest pass."""
     return {
         "device_calls": summary["device_calls"],
         "ingest_passes": summary["ingest_passes"],
@@ -515,16 +512,7 @@ def main() -> int:
                         "split — the ISSUE 2 before/after")
     args = p.parse_args()
 
-    if args.allow_cpu:
-        import jax
-
-        jax.config.update("jax_platforms", "cpu")
-        platforms = "cpu"
-    else:
-        platforms, gate_rc = gate_backend(allow_cpu=False,
-                                          tool="apex_feeder")
-        if gate_rc is not None:
-            return gate_rc
+    platforms = select_platform(args.allow_cpu)
 
     ok = True
     for variant in args.variants:

@@ -1,15 +1,12 @@
 """Full-game Pong learning AT CHIP RATE through the fused on-device loop.
 
 The two existing full-game proofs split along the dev box's constraint:
-the CPU leg learned fake-ALE Pong end-to-end through the REAL
-AtariPreprocessing path (744k frames, 49 min on one core —
-``ale_learning.py --calibrate-cpu``), and the chip leg of that same
-harness is host-bound (~36 frames/s: emulator + actors + service share
-one CPU core), so battery stage 8 cannot reach learning frames inside
-any window budget. This script closes the remaining gap from the other
-side: the FUSED on-device loop — the very program whose throughput is
-the headline bench (bench.py steps this exact env at ~600k
-env-steps/s/chip) — trained until it is WINNING whole games of the
+the CPU leg learns fake-ALE Pong end-to-end through the REAL
+AtariPreprocessing path (``ale_learning.py --calibrate-cpu``), and the
+chip leg of that same harness is host-bound (emulator + actors + service
+share the host's cores). This script closes the remaining gap from the
+other side: the FUSED on-device loop — the very program bench.py times
+on this exact env — trained until it is WINNING whole games of the
 device-native Pong (envs/pixel_pong.py: ±1 per point, first-to-5
 episodes, tracking opponent, spin). Same production stack as the atari
 config: Nature CNN bf16, uint8 84x84x4 frame stacks, n-step TD, uniform
@@ -21,12 +18,9 @@ window (epsilon ~1 -> the de-facto random baseline, ~-5 of the 5-point
 game) vs the BEST window; cleared iff best >= first + --margin
 (default +2.0 game points). Exit 0 iff cleared.
 
-Wedge discipline: sizes are the bench-proven ones (1024 lanes x batch
-512 x 32k ring — `docs/tpu_runs/20260801_0128_sweep/`), the pre-flight
-sizing gate (utils/sizing.py) refuses anything predicted to overrun
---budget-seconds, and a wall-clock stop_fn ends the run at the chunk
-boundary that crosses the post-compile budget, so the process always
-exits cleanly on its own.
+The run is WALL-bounded: a wall-clock stop_fn ends it at the chunk
+boundary that crosses the post-compile budget, so the worst case is
+compile + budget + one chunk, whatever the frame cap.
 
 Usage:  python benchmarks/pong_learning.py [--budget-seconds 300]
             [--smoke] [--seed N]
@@ -43,7 +37,7 @@ from pathlib import Path
 sys.path.insert(0, str(Path(__file__).resolve().parent))
 sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
 
-from tpu_battery import gate_backend  # noqa: E402
+from dist_dqn_tpu.utils.backend import select_platform  # noqa: E402
 
 
 def _apply_head(cfg, head: str):
@@ -97,8 +91,8 @@ def _apply_head(cfg, head: str):
 def _r2d2_cfg(args):
     """Recurrent variant: its own sizing (the feedforward lane/batch
     defaults do not transfer to sequence replay). Scaled between the
-    r2d2 preset and the PixelCatch chip run (17.6k steps/s at 32 lanes,
-    small torso): more lanes for frame rate, unroll 20 to span a few
+    r2d2 preset and the PixelCatch run (32 lanes, small torso): more
+    lanes for frame rate, unroll 20 to span a few
     ball crossings, small torso to keep the 20-step BPTT affordable."""
     import dataclasses as dc
 
@@ -172,7 +166,7 @@ def _base_cfg(args):
         # CPU harness check: tiny everything, bar not enforced — but the
         # SAME head family AND env as the chip run, so a head- or
         # env-specific config bug (e.g. the per-game C51 support) fails
-        # here instead of costing a window its compile time.
+        # here instead of costing a chip run its compile time.
         cfg = dataclasses.replace(
             cfg,
             env_name=args.env,
@@ -236,8 +230,7 @@ def main() -> int:
     p.add_argument("--frame-dedup", action="store_true",
                    help="replay.frame_dedup: store single frames, "
                         "rebuild stacks at sample time — 4x the "
-                        "affordable window (a >=1M-transition ring "
-                        "fits the v5e; VERDICT round-4 next #2/#4)")
+                        "affordable window")
     p.add_argument("--ring", type=int, default=131_072,
                help="4x the bench ring: at 1024 lanes the ring "
                     "holds 128 iterations of history — replay "
@@ -282,60 +275,12 @@ def main() -> int:
                           "--eps-end is ignored"}), flush=True)
 
     if args.smoke:
-        import jax
-
-        jax.config.update("jax_platforms", "cpu")
         args.total_env_steps = 16_000
         args.chunk_iters = 100
         args.budget_seconds = 120.0
-        platforms = "cpu"
-    else:
-        platforms, gate_rc = gate_backend(allow_cpu=False,
-                                          tool="pong_learning")
-        if gate_rc is not None:
-            return gate_rc
+    platforms = select_platform(allow_cpu=args.smoke)
 
     cfg = _cfg(args)
-
-    if not args.smoke:
-        from dist_dqn_tpu.utils import sizing
-
-        # Wedge-safety analysis. This run is WALL-bounded: the stop_fn
-        # exits cleanly at the first chunk boundary past the budget, so
-        # the worst case is compile + budget + one chunk of overshoot —
-        # independent of the frame cap. The envelope rules (measured
-        # proven-safe lanes/batch/ring) still apply; the gate's
-        # chunk-count cost model does not, because it would bound a
-        # quantity (total frames) that is not what bounds this run.
-        # Gate on the CONFIG's sizes, not the CLI args: _r2d2_cfg (and
-        # any future variant) overrides lanes/batch/ring, and the gate
-        # must describe the run that will actually execute. For r2d2
-        # the per-chunk time model is still the feedforward one — a
-        # permissive floor at its small sizes; the wall-clock stop_fn
-        # is the binding bound either way.
-        from dist_dqn_tpu.envs import make_jax_env as _mke
-        dedup_stack = (getattr(_mke(cfg.env_name), "frame_stack", 0)
-                       if cfg.replay.frame_dedup else 0)
-        envelope = sizing.check_envelope(
-            num_envs=cfg.actor.num_envs,
-            batch_size=cfg.learner.batch_size,
-            ring=cfg.replay.capacity,
-            frame_dedup_stack=dedup_stack)
-        if envelope is not None:
-            print(json.dumps({"sizing": envelope}), flush=True)
-            return 4
-        per_chunk_s = sizing.predict_fused_seconds(
-            num_envs=cfg.actor.num_envs,
-            batch_size=cfg.learner.batch_size,
-            train_every=cfg.train_every, chunk_iters=args.chunk_iters,
-            num_chunks=1, compile_s=0.0)
-        worst_case_s = (sizing.COMPILE_BUDGET_S + args.budget_seconds
-                        + per_chunk_s)
-        kill_budget = worst_case_s / sizing.BUDGET_FRACTION
-        print(json.dumps({"sizing": "ok",
-                          "sizing_predicted_s": round(worst_case_s, 1),
-                          "external_timeout_s": round(kill_budget, 0)}),
-              flush=True)
 
     from dist_dqn_tpu.train import train
 

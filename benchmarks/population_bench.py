@@ -1,7 +1,7 @@
 """Population training-plane microbenchmark (ISSUE 20).
 
-BENCH_r05 prices the solo fused learner at ~4% MFU — one policy's
-chunk program cannot fill the chip, and the per-dispatch constant
+One policy's chunk program cannot fill the chip (its utilisation is
+not measured on the current installation), and the per-dispatch constant
 (host step + launch overhead) is paid once per chunk no matter how
 much work rides inside. The population plane's bet is that M
 vmap-stacked members amortize that constant: M policies × M env
@@ -50,7 +50,7 @@ def _sweep_cfg():
     if jax.default_backend() == "cpu":
         # Shape chosen so per-op fixed overhead is the dominant cost of
         # a chunk body iteration (the regime the population plane
-        # targets on the chip, where BENCH_r05 measured 96% idle): ONE
+        # targets on the chip): ONE
         # cartpole lane against a one-layer MLP(8,) step at B=4 over a
         # 128-slot ring, training every step. At these shapes the
         # vmapped M=8 body measures 3.2-3.8x the solo aggregate rate
@@ -181,11 +181,12 @@ def main():
     p.add_argument("--chunk-iters", type=int, default=200)
     p.add_argument("--platform", default=None)
     args = p.parse_args()
-    from dist_dqn_tpu.utils.device_cleanup import install as _install
-
-    _install()  # SIGTERM'd bench must release its device grant
     if args.platform:
         jax.config.update("jax_platforms", args.platform)
+    else:
+        # No platform named: an accelerator, never a silent CPU run.
+        from dist_dqn_tpu.utils.backend import require_accelerator
+        require_accelerator()
     population_sweep(args.iters, sizes=tuple(args.sizes),
                      chunk_iters=args.chunk_iters)
 

@@ -1,10 +1,9 @@
 """R2D2 pixel-path LEARNING run — the on-chip leg of the evidence.
 
-The recurrent pixel path's frame budget exceeds the 1-core CPU box
-(BASELINE.md round-3: ~24 env-steps/s, returns still at the random
-baseline after 23 min), so its learning evidence on CPU stands on the
-CartPole SOLVE + pixel smoke only. This script is the missing run for
-real hardware: the tests/test_pixel_learning.py protocol (PixelCatch,
+The recurrent pixel path's frame budget exceeds what a CPU reaches in
+minutes, so its learning evidence on CPU stands on the CartPole SOLVE +
+pixel smoke only. This script is the run for real hardware: the
+tests/test_pixel_learning.py protocol (PixelCatch,
 random baseline ~-0.6, clear-margin bar +0.5) through the FULL R2D2
 machinery — sequence replay with burn-in, stored recurrent state, LSTM
 Q-net, value rescale.
@@ -41,11 +40,12 @@ def main() -> int:
 
     if args.platform:
         jax.config.update("jax_platforms", args.platform)
+    else:
+        # No platform named: an accelerator, never a silent CPU run.
+        from dist_dqn_tpu.utils.backend import require_accelerator
+        require_accelerator()
     from dist_dqn_tpu.config import CONFIGS
     from dist_dqn_tpu.train import train
-    from dist_dqn_tpu.utils.device_cleanup import install
-
-    install()  # SIGTERM'd run must release its device grant
 
     cfg = CONFIGS["r2d2"]
     cfg = dataclasses.replace(

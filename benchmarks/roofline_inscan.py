@@ -1,11 +1,9 @@
-"""Settle the roofline residual IN-SCAN (VERDICT round-4 weak #3 / next #3).
+"""The learner step's cost IN-SCAN, against its HBM roofline.
 
-The standalone learner bench measures each config's donated-state train
-step at 2.5-4.4x its HBM roofline, and docs/performance.md attributes
-the gap to per-call dispatch pipelining with "the fused loop is the
-harvest" — but that attribution was an inference: the cost of the SAME
-learner step running inside the fused ``lax.scan`` (where there is no
-per-step dispatch at all) had never been isolated.
+The standalone learner bench times each config's donated-state train
+step with one dispatch per step; the cost of the SAME learner step
+running inside the fused ``lax.scan`` (where there is no per-step
+dispatch at all) is what the fused loop actually pays.
 
 This bench isolates it by DIFFERENCING fused-loop chunks at
 ``train_every`` in {1, 2, never}: the train branch lives under a
@@ -22,8 +20,7 @@ must agree — that consistency check rides along in the row.
 
 Each config row also re-times the STANDALONE step (the learner_bench
 program) in the same process and carries the roofline census, so the
-output is exactly the table the verdict asked for: per config,
-standalone gap vs in-scan gap.
+output is one table: per config, standalone gap vs in-scan gap.
 
 Usage: python benchmarks/roofline_inscan.py [--configs atari qrdqn ...]
            [--allow-cpu] [--chunks 6] [--chunk-iters 200]
@@ -40,7 +37,7 @@ from pathlib import Path
 sys.path.insert(0, str(Path(__file__).resolve().parent))
 sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
 
-from tpu_battery import gate_backend  # noqa: E402
+from dist_dqn_tpu.utils.backend import select_platform  # noqa: E402
 
 FEEDFORWARD = ["atari", "apex", "rainbow", "qrdqn", "iqn", "mdqn"]
 NEVER = 1 << 30  # iteration % NEVER == 0 only at iter 0, where min_fill gates
@@ -114,20 +111,14 @@ def main() -> int:
     p.add_argument("--standalone-iters", type=int, default=200)
     args = p.parse_args()
 
+    select_platform(args.allow_cpu)
     if args.allow_cpu:
-        import jax
-
-        jax.config.update("jax_platforms", "cpu")
         # CPU smoke: shrink to harness-validation sizes.
         args.num_envs = min(args.num_envs, 8)
         args.chunk_iters = min(args.chunk_iters, 20)
         args.chunks = min(args.chunks, 2)
         args.ring = min(args.ring, 2_048)
         args.standalone_iters = min(args.standalone_iters, 3)
-    else:
-        _, gate_rc = gate_backend(allow_cpu=False, tool="roofline_inscan")
-        if gate_rc is not None:
-            return gate_rc
 
     from learner_bench import bench_config
 

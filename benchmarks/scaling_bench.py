@@ -7,10 +7,10 @@ discipline):
 * **host-replay dp leg** — the same tiny run at ``dp=1`` and ``dp=N``
   (``run_host_replay --mesh-devices``): aggregate and PER-CHIP
   env-steps/sec and grad-steps/sec, so the row answers "what did the
-  extra chips buy" instead of hiding the division. On the 2-core dev
-  box the virtual CPU mesh shares those cores, so dpN/dp1 near 1.0 is
-  the honest expectation there — the row records the mechanism works
-  and what it costs; the chip battery records the real scaling.
+  extra chips buy" instead of hiding the division. A virtual CPU mesh
+  shares the host's cores, so dpN/dp1 near 1.0 is the honest
+  expectation there — that row records that the mechanism works; only
+  a run on real chips records scaling.
 * **apex ingest-shard leg** — a real 4-actor fleet into a 2-shard
   store: ``records_by_shard`` / ``replay_added_by_shard`` prove the
   sticky crc32 spread end to end (skippable with --skip-apex; actor
@@ -23,8 +23,8 @@ Usage:
 
 ``--force-host-devices N`` must be honored BEFORE jax initializes, so
 pass it on the command line (not via an env var set after import).
-Wired as a tpu_battery stage; tests/test_chip_benches.py smokes the
-CPU path so the harness cannot bit-rot.
+tests/test_chip_benches.py smokes the CPU path so the harness cannot
+bit-rot.
 """
 from __future__ import annotations
 
@@ -150,7 +150,8 @@ def main() -> int:
                 f"{args.force_host_devices}").strip()
 
     from bench import ContractEmitter
-    from tpu_battery import gate_backend
+
+    from dist_dqn_tpu.utils.backend import select_platform
 
     contract = ContractEmitter(
         "dp_scaling",
@@ -158,12 +159,10 @@ def main() -> int:
         "dp mesh (host-replay runtime), with the apex sticky-shard "
         "ingest spread")
 
-    platforms, gate_rc = gate_backend(args.allow_cpu, "scaling_bench")
-    if gate_rc is not None:
-        return gate_rc
-
     try:
         import jax
+
+        platforms = select_platform(args.allow_cpu)
 
         from dist_dqn_tpu.config import CONFIGS
 
@@ -176,8 +175,8 @@ def main() -> int:
         lanes = args.lanes - args.lanes % dp or dp
         # The train batch must divide over the mesh too (each shard
         # draws an equal row block): round UP to a multiple of dp so a
-        # 32-device slice widens the batch instead of killing the
-        # battery stage on the divisibility gate.
+        # 32-device slice widens the batch instead of failing the
+        # divisibility gate.
         batch = -(-args.batch_size // dp) * dp
         cfg = CONFIGS["cartpole"]
         cfg = dataclasses.replace(

@@ -356,6 +356,7 @@ def main() -> int:
 
     from dist_dqn_tpu import telemetry
     from dist_dqn_tpu.config import CONFIGS, apply_overrides
+    from dist_dqn_tpu.utils.backend import device_summary
 
     cfg = apply_overrides(CONFIGS[args.config], args.overrides)
     tmp = None
@@ -390,6 +391,7 @@ def main() -> int:
                    "p99_ms": headline["p99_ms"],
                    "mean_fanin_rows": headline["mean_fanin_rows"],
                    "requests_shed": headline["requests_shed"],
+                   "device": device_summary(),
                    "manifest": telemetry.build_manifest(cfg),
                    "telemetry": telemetry.snapshot(
                        telemetry.get_registry())}

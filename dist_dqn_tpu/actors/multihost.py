@@ -32,7 +32,6 @@ from typing import Tuple
 
 import numpy as np
 
-from dist_dqn_tpu.utils import compat
 
 
 class MultihostLearner:
@@ -94,7 +93,7 @@ class MultihostLearner:
                                       is_leaf=lambda x: x is None)
             # mesh-axis: data_specs/metric_specs name the dp axis
             # (parallel/learner.py train_step_specs).
-            body = compat.shard_map(
+            body = jax.shard_map(
                 train_step, mesh=mesh,
                 in_specs=(state_spec,) + data_specs,
                 out_specs=(state_spec, metric_specs), check_vma=False)
@@ -165,7 +164,7 @@ class MultihostLearner:
         if self._agree is None:
             # donation: few-element counter psum, nothing worth donating
             # (caller reuses its input); devtime: out of census scope.
-            self._agree = jax.jit(compat.shard_map(
+            self._agree = jax.jit(jax.shard_map(
                 lambda x: jax.lax.psum(x, "dp"), mesh=self.mesh,
                 in_specs=P("dp"), out_specs=P(), check_vma=False))
         ints = np.asarray(values, np.int64)

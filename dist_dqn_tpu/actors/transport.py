@@ -3,7 +3,8 @@
 Three layers:
   * build/bind the C++ shared-memory primitives (ShmRing, ShmMailbox) —
     the intra-host hot path between actor processes and the learner service
-    (actors/_native/transport.cc; built on demand with g++, cached);
+    (actors/_native/transport.cc; built on demand with g++, keyed on the
+    source's content);
   * a zero-copy-ish numpy array codec (tiny JSON header + raw buffers) so
     trajectory batches cross process boundaries without pickle overhead;
   * TcpRecordTransport — the same length-prefixed record stream over a
@@ -13,6 +14,7 @@ Three layers:
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import json
 import os
 import socket
@@ -32,22 +34,38 @@ from dist_dqn_tpu.telemetry.collectors import (TRANSPORT_CORRUPT,
                                                TRANSPORT_SHED)
 
 _NATIVE_DIR = Path(__file__).parent / "_native"
-_LIB_PATH = _NATIVE_DIR / "libdqntransport.so"
 _lib = None
 _lib_lock = threading.Lock()
 
 
 def build_native_lib(src_name: str, lib_name: str,
                      directory: Optional[Path] = None) -> Path:
-    """Compile one _native/*.cc into a shared lib on demand (mtime-cached)."""
+    """Compile one _native/*.cc into a shared lib on demand.
+
+    The library's file name carries a digest of the source and the
+    compile flags, so a library found on disk was built from exactly
+    this source: a stale ``.so`` (the build products are ignored by git
+    and may be copied around with arbitrary mtimes) has another name and
+    can never shadow an edited ``.cc``. The compiler writes to a
+    per-process name that is renamed into place, so concurrent builders
+    (spawned actors) never load a half-written file.
+    """
     native_dir = directory or _NATIVE_DIR
     src = native_dir / src_name
-    out = native_dir / lib_name
-    if out.exists() and out.stat().st_mtime >= src.stat().st_mtime:
+    flags = ["-O2", "-std=c++17", "-shared", "-fPIC", "-pthread"]
+    digest = hashlib.sha256(
+        src.read_bytes() + " ".join(flags).encode()).hexdigest()[:16]
+    stem, suffix = os.path.splitext(lib_name)
+    out = native_dir / f"{stem}.{digest}{suffix}"
+    if out.exists():
         return out
-    cmd = ["g++", "-O2", "-std=c++17", "-shared", "-fPIC", "-pthread",
-           str(src), "-o", str(out)]
-    subprocess.run(cmd, check=True, capture_output=True)
+    tmp = native_dir / f"{stem}.{digest}.{os.getpid()}.tmp"
+    try:
+        subprocess.run(["g++", *flags, str(src), "-o", str(tmp)],
+                       check=True, capture_output=True)
+        os.replace(tmp, out)
+    finally:
+        tmp.unlink(missing_ok=True)
     return out
 
 

@@ -1,19 +1,12 @@
-"""Check ``mesh-axis``: mesh-parallel call sites resolve through
-utils/compat.py and name their mesh axis (or carry a rationale).
+"""Check ``mesh-axis``: every mesh-parallel call site names its mesh axis
+(or carries a rationale).
 
-Migrated from scripts/check_mesh_axis.py (ISSUE 13). Two rules, both
-born from the ISSUE 10 scale-out:
-
-1. No direct ``jax.shard_map`` / ``jax.experimental.shard_map`` outside
-   ``dist_dqn_tpu/utils/compat.py`` — JAX moved the API between 0.4.x
-   and 0.5 (and renamed ``check_rep`` to ``check_vma``), and a direct
-   spelling import-errors on the other side. The compat resolver is the
-   one place allowed to touch either spelling.
-2. Every ``shard_map``/``pjit`` call site names its axis: a literal
-   ``P("dp")``-style spec or an ``axis``/``axis_name`` keyword in the
-   call text, or a ``# mesh-axis:`` comment within three lines above
-   stating where the axis lives — so a reader at the call site can
-   always answer "which leaves live on which axis".
+Migrated from scripts/check_mesh_axis.py (ISSUE 13). Every
+``shard_map``/``pjit`` call site names its axis: a literal
+``P("dp")``-style spec or an ``axis``/``axis_name`` keyword in the call
+text, or a ``# mesh-axis:`` comment within three lines above stating
+where the axis lives — so a reader at the call site can always answer
+"which leaves live on which axis".
 """
 from __future__ import annotations
 
@@ -26,13 +19,7 @@ from dist_dqn_tpu.analysis.core import AnalysisContext, Check, Finding
 from dist_dqn_tpu.analysis.registry import register
 
 SCAN_ROOTS = ("dist_dqn_tpu", "benchmarks", "bench.py", "__graft_entry__.py")
-COMPAT_MODULE = "dist_dqn_tpu/utils/compat.py"
-
-#: Direct spellings rule 1 forbids outside the compat module.
-DIRECT = re.compile(
-    r"jax\.shard_map|jax\.experimental\.shard_map|"
-    r"from\s+jax\.experimental\.shard_map\s+import")
-#: What satisfies rule 2 inside the call text.
+#: What names the axis inside the call text.
 AXIS_IN_CALL = re.compile(r"""P\(\s*['"]|axis_name|axis\s*=""")
 #: Rationale escape hatch for spec-variable call sites.
 RATIONALE = re.compile(r"#.*mesh-axis:")
@@ -64,18 +51,6 @@ def scan(repo_root: Path, ctx: AnalysisContext = None
             continue  # the lint layer DEFINES the patterns it hunts
         src = ctx.source(rel)
         lines = src.splitlines()
-        if rel == COMPAT_MODULE:
-            # The resolver itself forwards to whichever spelling
-            # exists; its axis comes from the caller's specs —
-            # rule 2 applies at call sites, not here.
-            continue
-        for i, ln in enumerate(lines, 1):
-            if DIRECT.search(ln):
-                failures.append(
-                    (rel, i,
-                     "direct jax.shard_map spelling — resolve "
-                     "through dist_dqn_tpu.utils.compat."
-                     "shard_map (version-adaptive)"))
         try:
             tree = ctx.tree(rel)
         except SyntaxError as e:
@@ -105,8 +80,7 @@ def scan(repo_root: Path, ctx: AnalysisContext = None
 
 class MeshAxisCheck(Check):
     name = "mesh-axis"
-    description = ("shard_map resolves through utils/compat.py and "
-                   "every shard_map/pjit call site names its mesh axis "
+    description = ("every shard_map/pjit call site names its mesh axis "
                    "or carries a '# mesh-axis:' rationale")
     rationale_tag = "mesh-axis:"
 

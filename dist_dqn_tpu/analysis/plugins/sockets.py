@@ -4,8 +4,7 @@ rationale comment.
 
 Migrated from scripts/check_sockets.py (ISSUE 13). ISSUE 8: the chaos
 harness's whole disconnect/partition fault class turns into a silent
-process wedge the moment one socket blocks forever (the round-1 tunnel
-incident was exactly an unbounded wait nobody knew existed). Wherever a
+process wedge the moment one socket blocks forever. Wherever a
 socket is CREATED or ACCEPTED (``socket.socket(``,
 ``socket.create_connection(``, ``.accept()``), one of the following
 must hold within ``CONTEXT_LINES`` lines of the call: a ``settimeout(``

@@ -176,6 +176,9 @@ def main():
     if args.platform:
         import jax
         jax.config.update("jax_platforms", args.platform)
+    from dist_dqn_tpu.utils import backend
+    backend.enable_compile_cache()
+    backend.log_device()
     if not args.checkpoint_root:
         parser.error(f"--mode {args.mode} requires --checkpoint-root")
     games = tuple(ATARI_57 if args.games is None else args.games)

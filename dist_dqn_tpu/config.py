@@ -78,13 +78,11 @@ class ReplayConfig:
     store_final_obs: "bool | None" = None
     # Store multi-dim obs FLAT in the device ring ([slots, B, prod]).
     # XLA tiles multi-dim u8 ring buffers at (8,128) on the minor dims,
-    # padding an 84x84 ring to ~1.6x its logical bytes — but the tiled
-    # layout also gathers ~3% faster (v5e, 2026-08-01: 619k vs 602k
-    # env-steps/s at a 16k ring). None = auto: flat only when the ring's
-    # logical bytes exceed ~2 GB, where the padding waste dwarfs the
-    # throughput cost (the atari config's 200k-slot ring compiles at
-    # 5.26G flat vs 8.39G tiled — the difference between fitting a v5e
-    # beside the training program and OOM).
+    # padding an 84x84 ring to ~1.6x its logical bytes (88x128 tiles).
+    # None = auto: flat only when the ring's logical bytes exceed ~2 GB,
+    # where the padding decides whether the ring fits beside the training
+    # program (the atari config's 200k-slot ring is 5.3 GB flat). Which
+    # layout gathers faster is not measured on the current installation.
     flat_storage: "bool | None" = None
     # Frame-dedup storage for rolling-stack pixel obs (fused loop only):
     # store each step's NEWEST frame instead of the whole stack and
@@ -275,15 +273,12 @@ R2D2 = ExperimentConfig(
     network=NetworkConfig(torso="nature", hidden=512, dueling=True,
                           lstm_size=512, compute_dtype="bfloat16",
                           # Throughput knobs, numerics pinned by
-                          # tests/test_recurrent_knobs.py. Defaults are the
-                          # round-3 TPU sweep winner (v5e, learner_bench
-                          # --r2d2-sweep, docs/tpu_runs/20260731_0100):
-                          # no-remat + bf16 gates + unroll 8 = 58.8
-                          # grad-steps/s vs 53.4 for remat+f32+unroll 1
-                          # (+10%; +24% over the round-1 47.4/s). The
-                          # 120-step x B=64 pixel unroll fits v5e HBM
-                          # without remat; set remat_torso=True on
-                          # HBM-constrained configs (models/recurrent.py).
+                          # tests/test_recurrent_knobs.py: no remat,
+                          # bf16 gates, unroll 8 (learner_bench
+                          # --r2d2-sweep is the sweep; not measured on
+                          # the current installation). Set
+                          # remat_torso=True on HBM-constrained configs
+                          # (models/recurrent.py).
                           remat_torso=False,
                           lstm_dtype="bfloat16", lstm_unroll=8),
     replay=ReplayConfig(capacity=100_000, prioritized=True,

@@ -271,7 +271,7 @@ def _apply_risk_eta(cfg: ExperimentConfig, eta) -> ExperimentConfig:
         cfg, network=dataclasses.replace(cfg.network, risk_cvar_eta=eta))
 
 
-def main():
+def main(argv=None):
     parser = argparse.ArgumentParser(description=__doc__)
     parser.add_argument("--config", choices=sorted(CONFIGS), required=True)
     parser.add_argument("--checkpoint-dir", required=True)
@@ -340,7 +340,7 @@ def main():
                              "announce this eval's telemetry endpoint "
                              "to the run's aggregator; defaults to "
                              "$DQN_FLEET_DIR")
-    args = parser.parse_args()
+    args = parser.parse_args(argv)
     if args.export_params and (args.all_steps or args.host_env):
         parser.error("--export-params applies to the single-point JAX-env "
                      "surface (not --all-steps or --host-env)")
@@ -366,6 +366,9 @@ def main():
                                  host=args.telemetry_host)
     if args.platform:
         jax.config.update("jax_platforms", args.platform)
+    from dist_dqn_tpu.utils import backend
+    backend.enable_compile_cache()
+    backend.log_device()
     try:
         cfg = apply_overrides(CONFIGS[args.config], args.overrides)
     except ValueError as e:

@@ -99,20 +99,18 @@ def resolve_flat_storage(rcfg, obs_shape, obs_dtype, num_slots: int, B: int,
     XLA lays out multi-dim u8 ring buffers with (8,128) tiling on
     whichever dims it puts minormost, padding 84x84 to ~1.6x its logical
     bytes — and a [slots, B, flat] 3-D form to 2.0x (lanes transposed
-    minormost and padded 64->128; both measured in the 2026-08-01 v5e
-    compile OOMs). A 2-D merged-row buffer pads <1% but gathers ~3%
-    slower at small rings (619k vs 602k env-steps/s at 16k slots). Auto
-    rule (``replay.flat_storage=None``): flat only when the ring's
-    logical bytes exceed FLAT_AUTO_BYTES, where memory dominates.
+    minormost and padded 64->128). A 2-D merged-row buffer pads <1%.
+    Auto rule (``replay.flat_storage=None``): flat only when the ring's
+    logical bytes exceed FLAT_AUTO_BYTES, where memory dominates; the
+    gather speed of the two layouts is not measured on the current
+    installation (PERF.md §7: a mesh shard falls under the threshold).
     Shared by both fused loops so the rule cannot diverge.
     """
     if rcfg.flat_storage is None:
         if prefer_flat and len(obs_shape) >= 2:
             # Frame-dedup rings store [.., H, W, 1] slices whose TILED
-            # layout pads the size-1 minor dim catastrophically —
-            # measured on v5e (2026-08-01): 208k env-steps/s tiled vs
-            # 395k flat at the same 131k dedup ring. Flat is the dedup
-            # default at any size.
+            # layout pads the size-1 minor dim to a whole 128-lane tile.
+            # Flat is the dedup default at any size.
             return True
         obs_bytes = num_slots * B * int(jnp.dtype(obs_dtype).itemsize)
         for d in obs_shape:

@@ -26,7 +26,6 @@ import jax
 from jax.sharding import Mesh, PartitionSpec as P
 
 from dist_dqn_tpu.telemetry import get_registry
-from dist_dqn_tpu.utils import compat
 
 from dist_dqn_tpu.agents.dqn import LearnerState
 from dist_dqn_tpu.config import ExperimentConfig
@@ -98,13 +97,13 @@ def _mesh_wrap(mesh: Mesh, specs, init_local, run_local):
     # donation: PRNG-key-only init (run() donates the carry); devtime:
     # one-shot, not hot-path. mesh-axis: dp specs via _carry_specs.
     init = jax.jit(
-        compat.shard_map(init_local, mesh=mesh, in_specs=P(),
+        jax.shard_map(init_local, mesh=mesh, in_specs=P(),
                          out_specs=specs, check_vma=False))
 
     @partial(jax.jit, static_argnums=1, donate_argnums=0)
     def run(carry, num_iters: int):
         # mesh-axis: specs name the dp axis (see _carry_specs).
-        body = compat.shard_map(
+        body = jax.shard_map(
             lambda c: run_local(c, num_iters), mesh=mesh,
             in_specs=(specs,), out_specs=(specs, P()), check_vma=False)
         return body(carry)
@@ -127,6 +126,10 @@ def _mesh_wrap(mesh: Mesh, specs, init_local, run_local):
         c_chunks.inc()
         return out
 
+    # The chip-time census (train.py attach_cost) lowers the program it
+    # dispatches; without this the wrapper has no ``lower`` and the mesh
+    # chunk's census silently stays empty.
+    run_instrumented.lower = run.lower
     return init, run_instrumented
 
 
@@ -233,7 +236,7 @@ def make_sharded_train_step(train_step, mesh: Mesh, data_specs,
                                   is_leaf=lambda x: x is None)
         # mesh-axis: data_specs/metric_specs name the axis
         # (train_step_specs / scan_train_step_specs).
-        body = compat.shard_map(
+        body = jax.shard_map(
             train_step, mesh=mesh,
             in_specs=(state_spec,) + tuple(data_specs),
             out_specs=(state_spec, metric_specs), check_vma=False)

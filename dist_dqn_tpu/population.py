@@ -1,8 +1,8 @@
 """Population training plane (ISSUE 20): M vmap-stacked policies, one
 program.
 
-BENCH_r05 prices the fused learner at 96% chip-idle — one cartpole/atari
-policy cannot fill a TPU. ROADMAP item 6's answer (after Podracer's
+One cartpole/atari policy cannot fill a TPU (its utilisation is not
+measured on the current installation). ROADMAP item 6's answer (after Podracer's
 "one program, many policies", PAPERS.md, on the commodity-scale terms of
 arXiv:2111.01264) is to train M policies — distinct seeds and
 hyperparameter variants — as ONE jitted program: every carry leaf

@@ -23,6 +23,7 @@
 //
 // Built on demand with g++ via actors/transport.build_native_lib, loaded
 // with ctypes — no pybind11 in this image.
+#include <cstddef>
 #include <cstdint>
 #include <vector>
 

@@ -53,13 +53,12 @@ def time_ring_init(num_slots: int, num_envs: int, obs_example: PyTree,
     instead of ``[num_slots, num_envs, ...]``. Same records, same order —
     slot ``t`` of env ``b`` lives at row ``t * num_envs + b`` — but a 2-D
     buffer is immune to XLA layout assignment putting a small dim (the
-    lanes) minormost and tile-padding it: measured on v5e (2026-08-01),
-    the atari config's 200k-slot flat ring compiled at 10.51G as
-    ``[3125, 64, 28224]`` (lanes padded 64->128, 2.0x) vs its 5.26G
-    logical size as ``[200000, 28224]``. Callers pass the same flag to
-    add/gather/sample. Only obs/final_obs merge; the small per-step
-    fields keep ``[T, B]`` (their padding is irrelevant and the n-step
-    window math wants the time axis explicit).
+    lanes) minormost and tile-padding it: the atari config's 200k-slot
+    ring as ``[3125, 64, 28224]`` has its lanes padded 64->128 (2.0x)
+    against its 5.3 GB logical size as ``[200000, 28224]``. Callers pass
+    the same flag to add/gather/sample. Only obs/final_obs merge; the
+    small per-step fields keep ``[T, B]`` (their padding is irrelevant
+    and the n-step window math wants the time axis explicit).
     """
     def zeros(x):
         if merge_obs_rows:

@@ -11,10 +11,11 @@ Two interchangeable tree backends implement the priority mass:
     learner service: delta-propagation writes, per-query descent sampling,
     periodic exact rebuild. This is the native-runtime equivalent of the
     reference family's CUDA/host sum-trees (BASELINE.json:5).
-  * SumTree — vectorized numpy fallback (no Python-per-item loops: batched
+  * SumTree — vectorized numpy twin (no Python-per-item loops: batched
     leaf writes propagate level-by-level over *unique* parents; sampling
-    descends all queries in lockstep). Used where the toolchain can't build
-    the native lib, and as the correctness cross-check in tests.
+    descends all queries in lockstep). Selected only by an explicit
+    ``native=False``: the correctness cross-check in tests. A native build
+    that fails is an error, not a switch to this tree.
 
 The device-side sampler (replay/prioritized_device.py) is the fused-loop
 equivalent; both implement the same P(i) ~ p_i^alpha contract, tested against
@@ -35,7 +36,6 @@ from dist_dqn_tpu.telemetry import collectors as tm
 _NATIVE_DIR = Path(__file__).parent / "_native"
 _tree_lib = None
 _tree_lib_lock = threading.Lock()
-_fallback_warned = False
 
 
 def pad_pow2(n: int) -> int:
@@ -172,23 +172,12 @@ class NativeSumTree:
 
 
 def make_sum_tree(capacity: int, native: Optional[bool] = None):
-    """Pick the tree backend: native C++ if buildable (default), numpy else."""
-    global _fallback_warned
-    if native is None or native:
-        try:
-            return NativeSumTree(capacity)
-        except Exception as e:
-            if native:
-                raise
-            if not _fallback_warned:
-                _fallback_warned = True
-                # warnings (not print): multi-host / JSON-consuming runs
-                # must not get a bare stdout line from every process.
-                import warnings
-
-                warnings.warn(f"native sum-tree unavailable ({e!r}); "
-                              "using numpy tree", RuntimeWarning)
-    return SumTree(capacity)
+    """The native C++ tree, unless ``native=False`` asks for the numpy
+    twin (the tests' reference). A native build that fails raises: the
+    run does not carry on with a substitute."""
+    if native is False:
+        return SumTree(capacity)
+    return NativeSumTree(capacity)
 
 
 class SumTree:

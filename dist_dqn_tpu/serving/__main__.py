@@ -130,6 +130,9 @@ def main():
     if args.platform:
         import jax
         jax.config.update("jax_platforms", args.platform)
+    from dist_dqn_tpu.utils import backend
+    backend.enable_compile_cache()
+    backend.log_device()
     try:
         cfg = apply_overrides(CONFIGS[args.config], args.overrides)
         policies = _parse_kv(args.policy, "--policy")

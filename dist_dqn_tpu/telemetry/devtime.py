@@ -1,8 +1,8 @@
 """Chip-time attribution plane (ISSUE 19): per-program device-time
 ledger, utilization decomposition, on-demand profiling, HBM telemetry.
 
-ROADMAP item 1 says the chip is ~96% idle but nothing in the repo can
-say *why*: ``dqn_learner_mfu`` was hand-wired per runtime and no metric
+Nothing else in the repo can say how busy the chip is or *why* it is
+idle: ``dqn_learner_mfu`` was hand-wired per runtime and no metric
 attributed chunk wall-time to device-busy vs host-blocked causes. This
 module is the shared substrate:
 
@@ -13,9 +13,10 @@ module is the shared substrate:
     the XLA cost analysis (``utils/flops.py``), dispatch counts, and
     device-seconds sampled at fences the loops ALREADY hold — no new
     synchronization on the hot path. Cost is harvested lazily via
-    ``jitted.lower(*args).cost_analysis()`` at the first dispatch site
-    (trace-only; never forces a second XLA compile and never perturbs
-    the jit cache).
+    ``jitted.lower(*args)`` at the first dispatch site: its own
+    ``cost_analysis()`` on the CPU (trace-only), the compiled
+    executable's on a TPU (``_cost_from``; the dispatch reuses that
+    executable, so no program is compiled twice).
 
 ``UtilizationLedger``
     Decomposes each chunk's wall-time into device-busy plus the named
@@ -63,12 +64,24 @@ def _cost_from(obj: Any) -> Dict[str, Optional[float]]:
     """FLOPs/bytes for one execution of ``obj`` — a Compiled, a Lowered,
     or a zero-arg callable returning either. Any failure (CPU backends
     without a cost model, interpreter mode, tracing errors) degrades to
-    ``{"flops": None, "bytes": None}``."""
+    ``{"flops": None, "bytes": None}``.
+
+    A Lowered has a cost analysis on the CPU backend only; for a TPU it
+    returns None and the census needs the compiled executable. Compiling
+    the Lowered here is the compilation the first dispatch would do:
+    the jit call that follows reuses the executable from JAX's
+    in-memory cache (observed on the chip: a cold first chunk of 0.18 s
+    with no persistent-cache hit), so the compile moves ahead of the
+    first dispatch, it is not paid twice."""
     try:
         if callable(obj) and not hasattr(obj, "cost_analysis"):
             obj = obj()
         flops = flops_util.compiled_flops(obj)
         nbytes = flops_util.compiled_bytes(obj)
+        if flops is None and nbytes is None and hasattr(obj, "compile"):
+            obj = obj.compile()
+            flops = flops_util.compiled_flops(obj)
+            nbytes = flops_util.compiled_bytes(obj)
     except Exception:
         flops = nbytes = None
     return {"flops": flops, "bytes": nbytes}

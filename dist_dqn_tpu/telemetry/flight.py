@@ -27,7 +27,7 @@ Design constraints, same order as the registry's:
 
 Events are tuples in the ring and dicts on the way out (``tail()``):
 ``{"t": unix_time, "thread": name, "kind": ..., "name": ..., **args}``.
-``kind`` is a coarse taxonomy ("span", "instant", "counter", "chunk",
+``kind`` is a coarse classification ("span", "instant", "counter", "chunk",
 "queue", "fence", "train", "watchdog", "divergence") so a bundle reader
 can filter without knowing every event name.
 """

@@ -10,12 +10,10 @@ there. Every telemetry sink now registers its flush here exactly once:
     (``DQN_TELEMETRY_SNAPSHOT=<path>`` does the same from the
     environment — how spawned actor/feeder processes opt in).
 
-The SIGTERM handler CHAINS any pre-existing handler (device_cleanup.py
-installs one in accelerator entry points; order of installation does not
-matter — whichever runs first calls the other), and callbacks run at
-most once per process so the atexit leg after a handled signal cannot
-double-flush. Same honest limit as device_cleanup: a handler only runs
-while the main thread executes Python bytecode — SIGKILL, or a SIGTERM
+The SIGTERM handler CHAINS any pre-existing handler, and callbacks run
+at most once per process so the atexit leg after a handled signal cannot
+double-flush. Honest limit: a handler only runs while the main thread
+executes Python bytecode — SIGKILL, or a SIGTERM
 landing inside an uninterruptible syscall, still loses the tail.
 """
 from __future__ import annotations
@@ -74,7 +72,7 @@ def _install() -> None:
     try:
         signal.signal(signal.SIGTERM, on_term)
     except ValueError:
-        pass  # not the main thread: atexit-only (same as device_cleanup)
+        pass  # not the main thread: atexit-only
 
 
 def on_exit(fn: Callable[[], None]) -> None:

@@ -7,17 +7,13 @@ moments, step counters — is the recovery point. By DEFAULT checkpoints
 hold the learner pytree plus the host-side training cursor (env
 frames), not the replay ring.
 
-The replay trade-off, quantified (VERDICT round-3 next #7): a 65k-slot
-84x84x4 pixel ring is ~1.8 GB vs ~7 MB of Nature-CNN learner state —
-~260x the checkpoint bytes. Refill on resume costs
-``min_fill / steady-rate`` env steps of training delay: at the fused
-loop's measured 569k steps/s/chip that is 4096/569k ~= **7 ms**; even
-a full 65k-slot ring re-reaches capacity in ~0.12 s (the apex host
-shard's 20k min_fill at the 1-core dev box's ~13k steps/s host rate:
-~1.5 s; at a pod's per-host rates, sub-second). What refill does NOT
-recover is the ring's *contents* — a resumed run trains on freshly
-generated experience, so it is statistically equivalent, not
-bit-equal. Runs that need bit-exact resume (debugging, preemption-
+The replay trade-off: a 65k-slot 84x84x4 pixel ring is ~1.8 GB vs
+~7 MB of Nature-CNN learner state — ~260x the checkpoint bytes. Refill
+on resume costs ``min_fill / steady-rate`` of training delay (the
+rates are not measured on the current installation beyond PERF.md §5).
+What refill does NOT recover is the ring's *contents* — a resumed run
+trains on freshly generated experience, so it is statistically
+equivalent, not bit-equal. Runs that need bit-exact resume (debugging, preemption-
 heavy pods where distribution continuity matters) opt into
 ``train(..., checkpoint_replay=True)`` / ``--checkpoint-replay``,
 which checkpoints the WHOLE fused carry (ring + env states + rng) at

@@ -14,8 +14,7 @@ from pathlib import Path
 sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
 
 from dist_dqn_tpu.analysis.plugins.mesh_axis import (AXIS_IN_CALL,  # noqa: F401,E402
-                                                     COMPAT_MODULE,
-                                                     DIRECT, RATIONALE,
+                                                     RATIONALE,
                                                      SCAN_ROOTS, scan)
 from dist_dqn_tpu.analysis.runner import legacy_main  # noqa: E402
 

@@ -162,6 +162,7 @@ def test_evaluate_all_steps_walks_the_learning_curve(tmp_path, capsys):
         main()
     rows = [json.loads(line) for line in
             capsys.readouterr().out.splitlines() if line.startswith("{")]
+    assert "device" in rows.pop(0)   # the CLI's first line names the device
     assert [r["frames"] for r in rows] == list(steps)
     assert all(1.0 <= r["eval_return"] <= 500.0 for r in rows)
 
@@ -529,6 +530,7 @@ def test_host_all_steps_skips_only_missing_checkpoints(tmp_path, capsys):
         ev.main()
     rows = [json.loads(line) for line in
             capsys.readouterr().out.splitlines() if line.startswith("{")]
+    assert "device" in rows.pop(0)   # the CLI's first line names the device
     assert rows[0]["frames"] == 100 and "skipped" in rows[0]
     assert rows[1]["frames"] == 200 and rows[1]["eval_return"] == 1.0
 

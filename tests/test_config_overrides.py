@@ -86,10 +86,13 @@ def test_train_cli_honors_set(tmp_path, capsys):
     rows = [json.loads(line) for line in
             capsys.readouterr().out.splitlines()
             if line.startswith("{")]
-    # The CLI's first JSON line is the run manifest (ISSUE 4) — and it
+    # The CLI's first JSON line names the device as JAX reports it;
+    # the run manifest (ISSUE 4) follows, carries the same block, and
     # must fingerprint the OVERRIDDEN config, not the preset.
-    assert rows and rows[0]["manifest"]["config"]["actor"][
-        "num_envs"] == 4
+    assert rows[0]["device"]["platform"] == "cpu"
+    assert set(rows[0]["device"]) == {"platform", "kind", "count"}
+    assert rows[1]["manifest"]["device"] == rows[0]["device"]
+    assert rows[1]["manifest"]["config"]["actor"]["num_envs"] == 4
     # 4 env lanes (not the preset's 16): 150-iter chunks advance 600
     # frames each.
     metric_rows = [r for r in rows if "env_frames" in r]

@@ -115,6 +115,27 @@ def test_attach_cost_is_one_shot_and_failures_degrade():
     assert rec2.flops == 7.0
 
 
+def test_lowered_without_cost_analysis_is_compiled_for_the_census():
+    """On a TPU ``Lowered.cost_analysis()`` returns None — only the
+    compiled executable has a census there. A source that has none but
+    can be compiled is compiled, so the census is not silently absent
+    on the one platform it exists for."""
+    class _TpuLowered:
+        compiles = 0
+
+        def cost_analysis(self):
+            return None
+
+        def compile(self):
+            _TpuLowered.compiles += 1
+            return _Cost(11.0, 4.0)
+
+    reg = devtime.ProgramRegistry(metrics=Registry())
+    rec = reg.register("p", loop="l", cost=lambda: _TpuLowered())
+    assert (rec.flops, rec.bytes) == (11.0, 4.0)
+    assert _TpuLowered.compiles == 1
+
+
 def test_learner_mfu_registry_derived_and_absent_on_cpu():
     metrics = Registry()
     reg = devtime.reset_program_registry(metrics)

@@ -20,7 +20,6 @@ from dist_dqn_tpu.parallel import make_mesh, make_mesh_fused_train
 from dist_dqn_tpu.envs import make_jax_env
 from dist_dqn_tpu.models import build_network
 from dist_dqn_tpu.types import Transition
-from dist_dqn_tpu.utils import compat
 
 
 @pytest.fixture(scope="module")
@@ -75,7 +74,7 @@ def test_sharded_train_step_matches_single_device(mesh, head):
                               is_leaf=lambda x: x is None)
     metric_specs = {"loss": P(), "raw_loss": P(), "priorities": P("dp"),
                     "grad_norm": P(), "mean_q_target_gap": P()}
-    dist = jax.jit(compat.shard_map(
+    dist = jax.jit(jax.shard_map(
         step_d, mesh=mesh,
         in_specs=(state_spec, jax.tree.map(lambda _: P("dp"), batch)),
         out_specs=(state_spec, metric_specs), check_vma=False))

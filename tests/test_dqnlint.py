@@ -537,34 +537,22 @@ def test_sockets_bites_and_accepts_evidence(tmp_path):
     assert [f for f in sockets.scan(tmp_path) if "fine.py" in f] == []
 
 
-def test_mesh_axis_bites_on_direct_spelling_and_axisless_call(tmp_path):
+def test_mesh_axis_bites_on_axisless_call(tmp_path):
     pkg = tmp_path / "dist_dqn_tpu"
     pkg.mkdir()
     (pkg / "rogue.py").write_text(
         "import jax\n"
-        "body = jax.shard_map(lambda x: x, mesh=None,\n"
-        "                     in_specs=None, out_specs=None)\n")
-    failures = mesh_axis.scan(tmp_path)
-    assert any("direct jax.shard_map" in msg for _, _, msg in failures)
-    (pkg / "rogue.py").write_text(
-        "from dist_dqn_tpu.utils import compat\n"
         "specs = object()\n"
-        "bad = compat.shard_map(lambda x: x, mesh=None,\n"
-        "                       in_specs=specs, out_specs=specs)\n"
+        "bad = jax.shard_map(lambda x: x, mesh=None,\n"
+        "                    in_specs=specs, out_specs=specs)\n"
         "# mesh-axis: specs built by train_step_specs name dp\n"
-        "excused = compat.shard_map(lambda x: x, mesh=None,\n"
-        "                           in_specs=specs, out_specs=specs)\n"
-        "named = compat.shard_map(lambda x: x, mesh=None,\n"
-        "                         in_specs=P('dp'), out_specs=P())\n")
+        "excused = jax.shard_map(lambda x: x, mesh=None,\n"
+        "                        in_specs=specs, out_specs=specs)\n"
+        "named = jax.shard_map(lambda x: x, mesh=None,\n"
+        "                      in_specs=P('dp'), out_specs=P())\n")
     failures = mesh_axis.scan(tmp_path)
     assert [(rel, line) for rel, line, _ in failures] == [
         ("dist_dqn_tpu/rogue.py", 3)], failures
-
-
-def test_mesh_axis_compat_module_stays_exempt():
-    failures = [f for f in mesh_axis.scan(REPO)
-                if f[0] == mesh_axis.COMPAT_MODULE]
-    assert failures == [], failures
 
 
 def test_wire_bites_on_header_drift(monkeypatch):

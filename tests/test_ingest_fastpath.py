@@ -132,8 +132,7 @@ def test_fused_ingest_dispatch_budget():
     assembles 512 transitions (> _PRIO_CHUNK, < _PRIO_MAX_ROWS), and the
     fused path must serve act AND bootstrap in EXACTLY ONE device call
     per ingest pass; the split reference pays >= 2x that on the same
-    stream. A third dispatch creeping into the fast path fails here
-    before it costs a remote-tunnel deployment its feeder ceiling."""
+    stream. A third dispatch creeping into the fast path fails here."""
     assert 32 * 16 > _PRIO_CHUNK and 32 * 16 < _PRIO_MAX_ROWS
     service, stream, ring = _build_service(fused=True)
     try:

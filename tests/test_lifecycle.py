@@ -92,9 +92,8 @@ def test_sigterm_flushes_once_then_exits_128_plus_signum(tmp_path):
 
 
 def test_sigterm_chains_preexisting_handler_after_flush(tmp_path):
-    """A SIGTERM handler installed BEFORE the lifecycle (device_cleanup
-    does this in accelerator entry points) still runs — after the flush
-    callbacks, and the flush still happens exactly once."""
+    """A SIGTERM handler installed BEFORE the lifecycle still runs — after
+    the flush callbacks, and the flush still happens exactly once."""
     out = tmp_path / "order.txt"
     code = (
         "import os, signal, sys, time\n"

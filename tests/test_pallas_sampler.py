@@ -6,6 +6,7 @@ import dataclasses
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from dist_dqn_tpu.config import CONFIGS
 from dist_dqn_tpu.ops.pallas_sampler import pallas_stratified_sample
@@ -28,12 +29,11 @@ def test_kernel_matches_numpy_reference():
 
     flat = w.reshape(-1)
     cdf = np.cumsum(flat)
-    # The kernel shrinks targets by 1e-5 to keep the top stratum strictly
-    # inside the CDF (see pallas_sampler.py); mirror it in the reference.
-    # Near-total agreement, not exact: the kernel's chunked matmul prefix
-    # sums and numpy's sequential cumsum can disagree by an ulp at a
-    # stratum boundary.
-    ref = np.searchsorted(cdf, u * tot * (1.0 - 1e-5), side="right")
+    # The first cell whose inclusive CDF reaches the target. Near-total
+    # agreement, not exact: the kernel's chunked matmul prefix sums and
+    # numpy's sequential cumsum can disagree by an ulp at a stratum
+    # boundary.
+    ref = np.searchsorted(cdf, u * tot, side="left")
     assert np.mean((t * B + b) == ref) >= 0.98
     np.testing.assert_allclose(p, w[t, b], rtol=1e-6)
     np.testing.assert_allclose(tot, cdf[-1], rtol=1e-5)
@@ -49,6 +49,28 @@ def test_kernel_never_selects_zero_mass():
     assert (p > 0).all()
     assert (w[t, b] > 0).all()
     assert (t < T).all()                    # padded rows never selected
+
+
+@pytest.mark.parametrize("T,B", [(700, 8), (9, 512), (2100, 128)])
+def test_top_of_cdf_stops_at_the_last_row_with_mass(T, B):
+    """The kernel carries no margin on its targets: its total is the last
+    entry of the CDF it searches, so draws at and just below u = 1 land
+    on the last row that holds mass — never on the empty rows after it —
+    through the same [rows, 512] view whatever the plane's width."""
+    rng = np.random.default_rng(T)
+    w = _mass(rng, T, B, zero_frac=0.6) ** 3
+    live = T // 3
+    w[live:] = 0.0
+    w[live - 1, B // 2] = 1e-3              # the last cell with mass
+    w[live - 1, B // 2 + 1:] = 0.0
+    one = np.float32(1.0)
+    u = np.asarray([0.25, 1.0 - 1e-6, np.nextafter(one, np.float32(0)),
+                    one], np.float32)
+    t, b, p, _ = map(np.asarray, pallas_stratified_sample(
+        jnp.asarray(w), jnp.asarray(u), interpret=True))
+    assert (w[t, b] > 0).all() and (p > 0).all()
+    assert t.max() == live - 1
+    assert (t[-1], b[-1]) == (live - 1, B // 2)
 
 
 def test_kernel_distribution_tracks_mass():
@@ -120,3 +142,48 @@ def test_fused_loop_with_pallas_sampler_runs(monkeypatch):
     carry, metrics = run(carry, 40)
     assert float(metrics["grad_steps_in_chunk"]) > 0
     assert np.isfinite(float(metrics["loss"]))
+
+
+def test_routing_never_interprets_on_tpu(monkeypatch):
+    """On a TPU backend the kernel is compiled by Mosaic or not used:
+    neither the routing nor a direct call runs the interpreter there,
+    whatever the environment says. Off the chip the config flag alone
+    hands back the XLA sampler; the interpreter needs the explicit
+    DIST_DQN_PALLAS_INTERPRET=1."""
+    from dist_dqn_tpu.loop_common import pallas_routing
+
+    monkeypatch.delenv("DIST_DQN_PALLAS_INTERPRET", raising=False)
+    assert pallas_routing(True) == (False, False)
+    monkeypatch.setenv("DIST_DQN_PALLAS_INTERPRET", "1")
+    assert pallas_routing(True) == (True, True)
+    assert pallas_routing(False) == (False, True)
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert pallas_routing(True) == (True, False)
+    assert pallas_routing(False) == (False, False)
+    with pytest.raises(ValueError, match="never interpreted"):
+        pallas_stratified_sample(jnp.ones((8, 128)), jnp.full((4,), 0.5),
+                                 interpret=True)
+
+
+def test_narrow_plane_draws_match_lane_dense_plane():
+    """The kernel sees a narrow [T, 16] plane through a lane-dense view
+    of the same row-major cells: its draws are those of the XLA sampler
+    on the original plane (the apex preset's shape, cut short)."""
+    from dist_dqn_tpu.ops.pallas_sampler import stratified_sample_at
+
+    rng = np.random.default_rng(4)
+    T, B, S = 1000, 16, 128             # T*B not a multiple of 512
+    w = jnp.asarray(_mass(rng, T, B))
+    u = jnp.asarray(((np.arange(S) + rng.uniform(size=S)) / S)
+                    .astype(np.float32))
+    tk, bk, pk, tot = map(np.asarray, stratified_sample_at(
+        w, u, use_pallas=True, interpret=True))
+    tx, bx, _, _ = map(np.asarray, stratified_sample_at(w, u))
+    assert tk.max() < T and bk.max() < B
+    assert np.mean((tk == tx) & (bk == bx)) >= 0.95  # fp boundary jitter
+    # Same float64 reference as the dense test.
+    cdf = np.cumsum(np.asarray(w, np.float64).reshape(-1))
+    ref = np.searchsorted(cdf, np.asarray(u) * tot, side="left")
+    assert np.mean((tk * B + bk) == ref) >= 0.98
+    np.testing.assert_allclose(pk, np.asarray(w)[tk, bk], rtol=1e-6)

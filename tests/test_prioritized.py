@@ -204,6 +204,24 @@ def test_make_sum_tree_backend_selection():
     assert isinstance(PrioritizedHostReplay(8).tree, NativeSumTree)
 
 
+def test_make_sum_tree_default_raises_when_native_build_fails(monkeypatch):
+    """A native library that cannot be built is an error on the default
+    path — never a quiet switch to the numpy tree; ``native=False`` stays
+    the explicit way to get that tree."""
+    from dist_dqn_tpu.replay import host
+
+    def broken_build():
+        raise OSError("g++ failed")
+
+    monkeypatch.setattr(host, "_native_tree_lib", broken_build)
+    for native in (None, True):
+        with pytest.raises(OSError, match="g\\+\\+ failed"):
+            make_sum_tree(8, native=native)
+    with pytest.raises(OSError):
+        PrioritizedHostReplay(8)
+    assert isinstance(make_sum_tree(8, native=False), SumTree)
+
+
 # ---------------------------------------------------------------------------
 # Device stratified-CDF sampler
 # ---------------------------------------------------------------------------

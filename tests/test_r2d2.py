@@ -12,7 +12,6 @@ from dist_dqn_tpu.config import CONFIGS, LearnerConfig, ReplayConfig
 from dist_dqn_tpu.models.recurrent import RecurrentQNetwork
 from dist_dqn_tpu.replay import sequence_device as sring
 from dist_dqn_tpu.types import SequenceSample
-from dist_dqn_tpu.utils import compat
 
 import pytest
 
@@ -296,7 +295,7 @@ def test_r2d2_sharded_train_step_matches_single_device():
         t_idx=P("dp"), b_idx=P("dp"))
     metric_specs = {"loss": P(), "raw_loss": P(), "priorities": P("dp"),
                     "grad_norm": P()}
-    dist = jax.jit(compat.shard_map(
+    dist = jax.jit(jax.shard_map(
         step_d, mesh=mesh, in_specs=(state_spec, sample_spec),
         out_specs=(state_spec, metric_specs), check_vma=False))
 

@@ -254,3 +254,32 @@ def test_shed_bookkeeping_is_threadsafe(capsys):
         server._shed_alarmed = False
     server._shed(0)
     assert "transport_shedding" in capsys.readouterr().out
+
+
+def test_stale_native_lib_never_shadows_edited_source(tmp_path):
+    """The build is keyed on the source's CONTENT: a library left on
+    disk by an older source (the .so files are ignored by git and may be
+    copied around with any mtime) has another name, so an edited .cc is
+    always rebuilt and an unchanged one is reused."""
+    import ctypes
+    import time
+
+    from dist_dqn_tpu.actors.transport import build_native_lib
+
+    src = tmp_path / "answer.cc"
+    src.write_text('extern "C" int answer() { return 1; }\n')
+    first = build_native_lib("answer.cc", "libanswer.so", directory=tmp_path)
+    assert ctypes.CDLL(str(first)).answer() == 1
+    assert build_native_lib("answer.cc", "libanswer.so",
+                            directory=tmp_path) == first
+
+    src.write_text('extern "C" int answer() { return 2; }\n')
+    # Make the stale library look NEWER than the edited source — the
+    # case an mtime comparison gets wrong.
+    future = time.time() + 3600
+    os.utime(first, (future, future))
+    second = build_native_lib("answer.cc", "libanswer.so",
+                              directory=tmp_path)
+    assert second != first
+    assert ctypes.CDLL(str(second)).answer() == 2
+    assert not list(tmp_path.glob("*.tmp"))
