@@ -321,33 +321,40 @@ def make_learner(net: nn.Module, cfg: LearnerConfig,
                    ) -> Tuple[LearnerState, dict]:
         if weights is None:
             weights = jnp.ones_like(batch.reward)
-        rng, k_loss = jax.random.split(state.rng)
-        (loss, (priorities, raw_loss)), grads = jax.value_and_grad(
-            loss_fn, has_aux=True)(state.params, state.target_params, batch,
-                                   weights, k_loss)
+        # Stage names (telemetry/stages.py STAGES): trace metadata only;
+        # the backward ops keep theirs through transpose(jvp(...)).
+        with jax.named_scope("loss_grad"):
+            rng, k_loss = jax.random.split(state.rng)
+            (loss, (priorities, raw_loss)), grads = jax.value_and_grad(
+                loss_fn, has_aux=True)(state.params, state.target_params,
+                                       batch, weights, k_loss)
         if axis_name is not None:
             # Gradient allreduce over the learner mesh axis (ICI collective).
-            grads = jax.lax.pmean(grads, axis_name)
-            loss = jax.lax.pmean(loss, axis_name)
-            raw_loss = jax.lax.pmean(raw_loss, axis_name)
-            mean_gap = jax.lax.pmean(jnp.mean(priorities), axis_name)
+            with jax.named_scope("allreduce"):
+                grads = jax.lax.pmean(grads, axis_name)
+                loss = jax.lax.pmean(loss, axis_name)
+                raw_loss = jax.lax.pmean(raw_loss, axis_name)
+                mean_gap = jax.lax.pmean(jnp.mean(priorities), axis_name)
         else:
             mean_gap = jnp.mean(priorities)
-        updates, opt_state = tx.update(grads, state.opt_state, state.params)
-        params = optax.apply_updates(state.params, updates)
+        with jax.named_scope("optimizer"):
+            updates, opt_state = tx.update(grads, state.opt_state,
+                                           state.params)
+            params = optax.apply_updates(state.params, updates)
         steps = state.steps + 1
 
-        if cfg.target_tau > 0.0:
-            # Soft Polyak sync every step (BASELINE.json:5).
-            target_params = jax.tree.map(
-                lambda t, p: t + cfg.target_tau * (p - t),
-                state.target_params, params)
-        else:
-            # Periodic hard copy, branch-free under jit.
-            do_sync = (steps % cfg.target_update_period) == 0
-            target_params = jax.tree.map(
-                lambda t, p: jnp.where(do_sync, p, t),
-                state.target_params, params)
+        with jax.named_scope("target_sync"):
+            if cfg.target_tau > 0.0:
+                # Soft Polyak sync every step (BASELINE.json:5).
+                target_params = jax.tree.map(
+                    lambda t, p: t + cfg.target_tau * (p - t),
+                    state.target_params, params)
+            else:
+                # Periodic hard copy, branch-free under jit.
+                do_sync = (steps % cfg.target_update_period) == 0
+                target_params = jax.tree.map(
+                    lambda t, p: jnp.where(do_sync, p, t),
+                    state.target_params, params)
 
         new_state = LearnerState(params=params, target_params=target_params,
                                  opt_state=opt_state, steps=steps, rng=rng)

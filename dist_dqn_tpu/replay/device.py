@@ -248,6 +248,14 @@ def gather_transitions(state: TimeRingState, t_idx: Array, b_idx: Array,
             "frame_stack rebuild is undefined for rings with final_obs "
             "(the final-obs buffer is not a rolling frame stream) — "
             "build the ring with store_final_obs=False for frame dedup")
+    with jax.named_scope("gather"):
+        return _gather_transitions(state, t_idx, b_idx, n_step, gamma,
+                                   merge_obs_rows, frame_stack, frame_shape)
+
+
+def _gather_transitions(state: TimeRingState, t_idx: Array, b_idx: Array,
+                        n_step: int, gamma: float, merge_obs_rows: bool,
+                        frame_stack: int, frame_shape) -> Transition:
     num_slots, num_envs = state.action.shape
     reward_w = _gather_window(state.reward, t_idx, b_idx, n_step, num_slots)
     term_w = _gather_window(state.terminated, t_idx, b_idx, n_step, num_slots)
@@ -309,11 +317,13 @@ def time_ring_sample(state: TimeRingState, rng: Array, batch_size: int,
     """
     num_slots, num_envs = state.action.shape
     extra = max(frame_stack - 1, 0)
-    k_t, k_b = jax.random.split(rng)
-    num_valid = state.size - n_step - extra  # traced; gated by can_sample
-    u = jax.random.randint(k_t, (batch_size,), 0, jnp.maximum(num_valid, 1))
-    t_idx = (state.pos - state.size + extra + u) % num_slots
-    b_idx = jax.random.randint(k_b, (batch_size,), 0, num_envs)
+    with jax.named_scope("sample"):
+        k_t, k_b = jax.random.split(rng)
+        num_valid = state.size - n_step - extra  # traced; gated by can_sample
+        u = jax.random.randint(k_t, (batch_size,), 0,
+                               jnp.maximum(num_valid, 1))
+        t_idx = (state.pos - state.size + extra + u) % num_slots
+        b_idx = jax.random.randint(k_b, (batch_size,), 0, num_envs)
     return gather_transitions(state, t_idx, b_idx, n_step, gamma,
                               merge_obs_rows=merge_obs_rows,
                               frame_stack=frame_stack,

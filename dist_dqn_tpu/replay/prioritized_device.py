@@ -101,13 +101,14 @@ def prioritized_ring_sample(state: PrioritizedRingState, rng: Array,
                                                  stratified_sample)
 
     num_slots, num_envs = state.priorities.shape
-    mask = _valid_start_mask(state.ring, n_step, frame_stack)     # [T]
-    w = jnp.where(mask[:, None], state.priorities ** alpha, 0.0)  # [T, B]
-    n_valid = (jnp.sum(mask.astype(jnp.float32)) * num_envs)
-    t_idx, b_idx, mass_sel, total = stratified_sample(
-        w, rng, batch_size, use_pallas=use_pallas,
-        interpret=pallas_interpret)
-    weights = importance_weights(mass_sel, total, n_valid, beta)
+    with jax.named_scope("sample"):
+        mask = _valid_start_mask(state.ring, n_step, frame_stack)     # [T]
+        w = jnp.where(mask[:, None], state.priorities ** alpha, 0.0)  # [T, B]
+        n_valid = (jnp.sum(mask.astype(jnp.float32)) * num_envs)
+        t_idx, b_idx, mass_sel, total = stratified_sample(
+            w, rng, batch_size, use_pallas=use_pallas,
+            interpret=pallas_interpret)
+        weights = importance_weights(mass_sel, total, n_valid, beta)
 
     batch = ring.gather_transitions(state.ring, t_idx, b_idx, n_step, gamma,
                                     merge_obs_rows=merge_obs_rows,
@@ -121,11 +122,12 @@ def prioritized_ring_update(state: PrioritizedRingState, t_idx: Array,
                             b_idx: Array, new_priorities: Array,
                             eps: float = 1e-6) -> PrioritizedRingState:
     """Write back learner TD magnitudes for the sampled transitions."""
-    p = jnp.abs(new_priorities) + eps
-    priorities = state.priorities.at[t_idx, b_idx].set(p)
-    return PrioritizedRingState(
-        ring=state.ring, priorities=priorities,
-        max_priority=jnp.maximum(state.max_priority, jnp.max(p)))
+    with jax.named_scope("writeback"):
+        p = jnp.abs(new_priorities) + eps
+        priorities = state.priorities.at[t_idx, b_idx].set(p)
+        max_priority = jnp.maximum(state.max_priority, jnp.max(p))
+    return PrioritizedRingState(ring=state.ring, priorities=priorities,
+                                max_priority=max_priority)
 
 
 def prioritized_ring_update_batched(state: PrioritizedRingState,
@@ -144,11 +146,13 @@ def prioritized_ring_update_batched(state: PrioritizedRingState,
     chronology, so ``last_write_wins_scatter``'s election is exact.
     """
     T, B = state.priorities.shape
-    t_flat = t_idx.reshape(-1)
-    b_flat = b_idx.reshape(-1)
-    p = jnp.abs(new_priorities.reshape(-1)) + eps
-    flat = ring.last_write_wins_scatter(
-        state.priorities.reshape(-1), t_flat * B + b_flat, p)
-    return PrioritizedRingState(
-        ring=state.ring, priorities=flat.reshape(T, B),
-        max_priority=jnp.maximum(state.max_priority, jnp.max(p)))
+    with jax.named_scope("writeback"):
+        t_flat = t_idx.reshape(-1)
+        b_flat = b_idx.reshape(-1)
+        p = jnp.abs(new_priorities.reshape(-1)) + eps
+        flat = ring.last_write_wins_scatter(
+            state.priorities.reshape(-1), t_flat * B + b_flat, p)
+        max_priority = jnp.maximum(state.max_priority, jnp.max(p))
+    return PrioritizedRingState(ring=state.ring,
+                                priorities=flat.reshape(T, B),
+                                max_priority=max_priority)

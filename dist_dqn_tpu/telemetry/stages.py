@@ -1,0 +1,182 @@
+"""Stage names of the fused chunk program, and the table that joins a
+device trace to them.
+
+One vocabulary, :data:`STAGES`, each name entered as a ``jax.named_scope``
+where the work happens (train_loop.py, replay/device.py,
+replay/prioritized_device.py, agents/dqn.py). A scope is metadata: it lands
+in every HLO instruction's ``op_name`` and leaves the optimized program as
+it was.
+
+A device trace names each op by its HLO instruction (``fusion.584``), so the
+join back to a stage goes through the executable that actually ran:
+``train.train`` hands :func:`keep` the executable it compiles for
+``attach_cost`` and dispatches, and :func:`table` — called by a reader
+AFTER a traced run, never by the trainer — takes that executable's text and
+maps every instruction to its stage. Until somebody calls it nothing is
+printed, walked or written.
+
+Stdlib only, like the rest of the package.
+"""
+from __future__ import annotations
+
+import re
+import time
+from collections import Counter
+from typing import Dict, Iterable, Iterator, List, Optional, Tuple
+
+STAGES = ("act", "env", "insert", "sample", "gather", "loss_grad",
+          "allreduce", "optimizer", "target_sync", "writeback")
+#: A fusion whose instructions come from more than one stage.
+MIXED = "mixed"
+
+_STAGE_SET = frozenset(STAGES)
+# ``%fusion.584 = u8[..]{..} fusion(%a, %b), kind=kLoop,
+#   calls=%fused_computation.3, metadata={op_name="jit(run_chunk)/.."}``
+_INSTRUCTION = re.compile(
+    r"^\s*(?:ROOT )?%?(?P<inst>[\w.\-]+) = .*? (?P<op>[a-z][a-z\-]*)\("
+    r"(?P<operands>[^)]*)\)")
+_COMPUTATION = re.compile(r"^(?:ENTRY )?%?([\w.\-]+) \(.*\) -> .* \{\s*$")
+_NAME = re.compile(r"%([\w.\-]+)")
+_OP_NAME = re.compile(r'op_name="([^"]*)"')
+_CALLS = re.compile(r"\bcalls=%?([\w.\-]+)")
+# Inside a fusion these do no work of their own and do not vote (a shared
+# constant carries the op_name of whichever stage traced it first).
+_NO_VOTE = frozenset(("parameter", "constant", "broadcast", "iota", "bitcast",
+                      "tuple", "get-tuple-element"))
+# The op a fusion is built around; where one is fused in, it alone decides.
+_HEROES = frozenset(("convolution", "dot", "scatter", "gather", "sort",
+                     "dynamic-update-slice", "reduce-window",
+                     "select-and-scatter"))
+# Without heroes, the stage that holds this share of a fusion's votes.
+_MAJORITY = 0.75
+# Data movement the compiler inserts (layout changes, prefetches into fast
+# memory) carries no op_name: it takes the stage of what consumes it, else
+# of what produced its operand.
+_MOVES = frozenset(("copy", "copy-start", "copy-done", "bitcast", "transpose",
+                    "reshape", "get-tuple-element"))
+
+_program = None                     # the chunk program's Compiled
+_table: Optional[Dict[str, str]] = None
+_table_seconds: Optional[float] = None
+
+
+def keep(compiled) -> None:
+    """Remember the chunk program (a reference; nothing is computed)."""
+    global _program, _table, _table_seconds
+    _program, _table, _table_seconds = compiled, None, None
+
+
+def table_built() -> bool:
+    return _table is not None
+
+
+def table_seconds() -> Optional[float]:
+    """Seconds the one build of the table took; None before it."""
+    return _table_seconds
+
+
+def table() -> Dict[str, str]:
+    """``{instruction name: stage}`` of the kept executable, built on the
+    first call: its text and one walk over it. Empty where no program was
+    kept."""
+    global _table, _table_seconds
+    if _table is None and _program is not None:
+        t0 = time.perf_counter()
+        _table = table_from_text(_program.as_text())
+        _table_seconds = time.perf_counter() - t0
+    return _table or {}
+
+
+def stage_of(op_name: str) -> Optional[str]:
+    """The innermost stage on an ``op_name`` path
+    (``jit(run_chunk)/while/body/.../gather/...``), or None."""
+    for part in reversed(op_name.split("/")):
+        if part in _STAGE_SET:
+            return part
+    return None
+
+
+def _agree(stages: Iterable[Optional[str]]) -> Optional[str]:
+    found = {s for s in stages if s is not None}
+    if not found:
+        return None
+    return found.pop() if len(found) == 1 else MIXED
+
+
+def _fusion_stage(votes: List[Tuple[str, Optional[str]]]) -> Optional[str]:
+    """The stage of a fused computation from its ``(opcode, stage)`` pairs:
+    its heroes' if it has any, else the stage nearly all of its working
+    instructions share, else :data:`MIXED`."""
+    staged = [(op, s) for op, s in votes
+              if s is not None and op not in _NO_VOTE]
+    heroes = [s for op, s in staged if op in _HEROES]
+    if heroes:
+        return _agree(heroes)
+    if not staged:
+        return None
+    stage, count = Counter(s for _, s in staged).most_common(1)[0]
+    return stage if count >= _MAJORITY * len(staged) else MIXED
+
+
+def instructions(hlo_text: str) -> Iterator[Tuple[str, "re.Match", str]]:
+    """``(computation name, match, line)`` for every instruction line of an
+    HLO module's text; the match has ``inst``, ``op`` and ``operands``."""
+    current = None
+    for line in hlo_text.splitlines():
+        if current is None:
+            m = _COMPUTATION.match(line)
+            if m:
+                current = m.group(1)
+        elif line.startswith("}"):
+            current = None
+        else:
+            m = _INSTRUCTION.match(line)
+            if m:
+                yield current, m, line
+
+
+def table_from_text(hlo_text: str) -> Dict[str, str]:
+    """Map the instructions of an optimized HLO module to stages.
+
+    An instruction takes the stage its own ``op_name`` carries. One that
+    calls a computation (a fusion above all) takes the stage of the
+    instructions inside it (:func:`_fusion_stage`). Compiler-inserted data
+    movement under no stage takes its consumers' stage, else its
+    producer's. Instructions still under no stage are left out."""
+    opcode: Dict[str, str] = {}
+    own: Dict[str, Optional[str]] = {}      # instruction -> its own stage
+    operands: Dict[str, List[str]] = {}
+    calls: Dict[str, str] = {}              # instruction -> computation
+    members: Dict[str, List[str]] = {}      # computation -> instructions
+    for computation, m, line in instructions(hlo_text):
+        inst = m.group("inst")
+        members.setdefault(computation, []).append(inst)
+        opcode[inst] = m.group("op")
+        operands[inst] = _NAME.findall(m.group("operands"))
+        name = _OP_NAME.search(line)
+        own[inst] = stage_of(name.group(1)) if name else None
+        called = _CALLS.search(line)
+        if called:
+            calls[inst] = called.group(1)
+    stage = dict(own)
+    for inst, computation in calls.items():
+        inner = _fusion_stage([(opcode[i], own[i])
+                               for i in members.get(computation, ())])
+        stage[inst] = inner or own[inst]
+    users: Dict[str, List[str]] = {}
+    for inst, ops in operands.items():
+        for o in ops:
+            users.setdefault(o, []).append(inst)
+    moves = [i for i, op in opcode.items()
+             if op in _MOVES and stage[i] is None]
+    for neighbours in (users, operands):    # consumers first, then producers
+        changed = True
+        while changed:
+            changed = False
+            for inst in moves:
+                if stage[inst] is None:
+                    found = _agree(stage.get(n)
+                                   for n in neighbours.get(inst, ()))
+                    if found is not None:
+                        stage[inst], changed = found, True
+    return {inst: s for inst, s in stage.items() if s is not None}

@@ -46,7 +46,7 @@ def train(cfg: ExperimentConfig, total_env_steps: int = 0, seed: int = None,
           checkpoint_dir: str = None, save_every_frames: int = 0,
           profile_dir: str = None, num_devices: int = 1, stop_fn=None,
           checkpoint_replay: bool = False, telemetry_port: int = None,
-          telemetry_host: str = "127.0.0.1"):
+          telemetry_host: str = "127.0.0.1", trace_path: str = None):
     """Run training; returns (final_carry, history list of metric dicts).
 
     With ``checkpoint_replay`` the checkpoint holds the WHOLE fused
@@ -63,8 +63,12 @@ def train(cfg: ExperimentConfig, total_env_steps: int = 0, seed: int = None,
     ``save_every_frames`` env frames (default: every eval period) and the
     newest checkpoint is restored on startup — actors/replay are stateless
     and refill, per the failure model in SURVEY.md §5. With ``profile_dir``
-    set, the second chunk (first post-compile) is captured as a
-    ``jax.profiler`` trace for TensorBoard/xprof (SURVEY.md §5).
+    set, one steady chunk (the first after a chunk that took the full
+    cadence's grad steps; a run that ends before one writes no trace) is
+    captured as a ``jax.profiler`` trace for TensorBoard/xprof; with
+    ``trace_path``, the host loop's spans (``fused.dispatch`` /
+    ``fused.fence`` / ``fused.bookkeeping``) are also written as a Chrome
+    trace-event file (utils/trace.py).
 
     ``num_devices != 1`` selects the mesh trainers (parallel/learner.py):
     env lanes + the replay shard spread over a ``dp`` mesh of that many
@@ -202,9 +206,8 @@ def train(cfg: ExperimentConfig, total_env_steps: int = 0, seed: int = None,
     # it registers with role="train" and execs_per_dispatch=1 (the XLA
     # cost census already spans the whole chunk body, scan-once caveat
     # noted in telemetry/devtime.py). Cost is harvested before the first
-    # dispatch below via run.lower(...): trace-only on the CPU; on a TPU
-    # the census needs the executable, so devtime compiles that Lowered
-    # and the dispatch reuses it — one compile either way.
+    # dispatch below from the executable train compiles there
+    # (_compile_chunk); the dispatch reuses it — one compile either way.
     _prog_chunk = telemetry.register_program(
         "fused.chunk", loop="fused", role="train")
     _ledger = telemetry.UtilizationLedger("fused", _reg)
@@ -302,113 +305,146 @@ def train(cfg: ExperimentConfig, total_env_steps: int = 0, seed: int = None,
     next_eval = frames if cfg.eval_every_steps else float("inf")
     chunk_index = 0
     _t_prev_fence = None  # previous chunk's fence, for the ledger wall
-    # Trace the second chunk (the first is compile+warmup noise) — unless
-    # the whole run fits in one chunk, then trace that one rather than none.
-    profile_chunk = 1 if total > frames + chunk_iters * B else 0
+    # --profile-dir traces a STEADY chunk: the first one after a chunk that
+    # reported the full cadence's grad steps (past min_fill, every train
+    # event taken), so the trace shows the stages and spans as they repeat.
+    full_grad_steps = (chunk_iters // cfg.train_every * cfg.updates_per_train
+                       * (1 if cfg.network.lstm_size
+                          else _lc.resolve_replay_ratio(cfg)))
+    steady = profiled = False
+    # Host spans (utils/trace.py): durations to the flight ring (and, with
+    # --trace-path, the Chrome trace + dqn_host_span_seconds); each span is
+    # also a profiler TraceAnnotation, so in any device trace a gap between
+    # two chunk programs lies under the host span that caused it.
+    from dist_dqn_tpu.telemetry import stages as _stages
+    from dist_dqn_tpu.utils import backend
+    from dist_dqn_tpu.utils.trace import make_tracer
+    tracer = make_tracer(trace_path, process_name="fused-learner")
+
+    def _compile_chunk():
+        # The chunk program's ONE compilation, ahead of its first dispatch
+        # (which reuses the executable from JAX's in-memory cache), with
+        # its stage names in the persistent-cache key so that a cache from
+        # before a name existed cannot serve it. The stage table
+        # (telemetry/stages.py) is built from this executable on demand,
+        # after the run; here only a reference is kept.
+        with backend.names_in_cache_key():
+            compiled = run.lower(carry, chunk_iters).compile()
+        _stages.keep(compiled)
+        return compiled
+
     try:
         while frames < total:
-            profiling = (profile_dir is not None
-                         and chunk_index == profile_chunk)
+            profiling = profile_dir is not None and steady and not profiled
             if profiling:
                 jax.profiler.start_trace(profile_dir)
             if not _prog_chunk.cost_attached:
-                # Lowering against the live args. On a TPU attach_cost
-                # also compiles it (a Lowered has no census there) and
-                # the dispatch below reuses that executable from JAX's
-                # in-memory cache, so the first chunk's wall and rate
-                # hold no compile on the chip; on the CPU they do.
-                _c, _ci = carry, chunk_iters
-                _prog_chunk.attach_cost(lambda: run.lower(_c, _ci))
-            t0 = time.perf_counter()
-            carry, metrics = run(carry, chunk_iters)
-            metrics = jax.tree.map(np.asarray, jax.device_get(metrics))
-            dt = time.perf_counter() - t0
-            _prog_chunk.count_dispatch()
-            # The device_get above IS the chunk fence: dt bounds the
-            # program's device time (one fused program fills the chunk).
-            _prog_chunk.add_device_seconds(dt)
+                # Compiled against the live args; the dispatch below
+                # reuses the executable, so the first chunk's wall and
+                # rate hold no compile.
+                _prog_chunk.attach_cost(_compile_chunk)
+            with jax.profiler.StepTraceAnnotation("fused.chunk",
+                                                  step_num=chunk_index):
+                t0 = time.perf_counter()
+                with tracer.span("fused.dispatch"):
+                    carry, metrics = run(carry, chunk_iters)
+                with tracer.span("fused.fence"):
+                    metrics = jax.tree.map(np.asarray,
+                                           jax.device_get(metrics))
+                dt = time.perf_counter() - t0
+            # Everything from the fence to the next dispatch.
+            with tracer.span("fused.bookkeeping"):
+                _prog_chunk.count_dispatch()
+                # The device_get above IS the chunk fence: dt bounds
+                # the program's device time (one fused program fills
+                # the chunk).
+                _prog_chunk.add_device_seconds(dt)
+                chunk_index += 1
+                prev_frames = frames
+                frames = frame_offset + int(metrics["env_frames"])
+                grad_steps_chunk = float(metrics["grad_steps_in_chunk"])
+                steady = 0 < full_grad_steps <= grad_steps_chunk
+                frames_delta = max(frames - prev_frames, 0)
+                _tm["env_steps"].inc(frames_delta)
+                _tm["env_rate"].set(frames_delta / dt)
+                _tm["grad_steps"].inc(grad_steps_chunk)
+                _tm["chunk"].observe(dt)
+                # Host-visible params refresh once per chunk boundary, so the
+                # chunk wall bounds their staleness; grad-step latency is the
+                # per-step share of the fused chunk (the steps run inside one
+                # XLA program — there is no finer host-observable boundary).
+                _tm["staleness"].observe(dt)
+                if grad_steps_chunk:
+                    _tm["grad_latency"].observe(dt / grad_steps_chunk)
+                _tm["grad_rate"].set(grad_steps_chunk / dt)
+                _hb_chunk.beat()
+                _loss = float(metrics["loss"])
+                _flight.record("chunk", "fused.chunk", frames=frames,
+                               loss=_loss, wall_s=round(dt, 4))
+                tm_watchdog.observe_divergence(loss=_loss, step=frames)
+                _tm["loss"].set(_loss)
+                _tm["episodes"].inc(max(float(metrics["episodes"]), 0.0))
+                if float(metrics["episodes"]):
+                    _tm["ep_return"].set(float(metrics["episode_return"]))
+                _, ring_slots = tmc.observe_device_ring(carry.replay)
+                # Experience lineage (ISSUE 16): the fused loop stamps at
+                # collect — one (birth, version) row per chunk, aged over
+                # the live ring window into the same families the apex and
+                # host-replay runtimes observe per sampled record.
+                _lineage.on_chunk(_tm["grad_steps"].value,
+                                  max(1, ring_slots // chunk_iters))
+                # Utilization ledger (ISSUE 19): the fused loop's wall is
+                # the dispatch-to-fence dt (device busy, one program) plus
+                # whatever host bookkeeping separated it from the previous
+                # fence — no sample/evac/prefetch seams here, so the host
+                # share lands in the derived `other` bucket.
+                _t_now = time.perf_counter()
+                _ledger.observe_chunk(
+                    _t_now - (_t_prev_fence if _t_prev_fence is not None
+                              else t0), dt)
+                _t_prev_fence = _t_now
+                telemetry.set_learner_mfu("fused", reg=_reg)
+                telemetry.sweep_device_memory(_reg)
+                row = {
+                    "env_frames": frames,
+                    "episode_return": float(metrics["episode_return"]),
+                    # Disambiguates episode_return's no-episodes sentinel
+                    # (0.0 with episodes == 0) from a genuine 0.0 average
+                    # return.
+                    "episodes": float(metrics["episodes"]),
+                    "loss": float(metrics["loss"]),
+                    "env_steps_per_sec": chunk_iters * B / dt,
+                    "grad_steps_in_chunk": grad_steps_chunk,
+                    "grad_steps_per_sec": grad_steps_chunk / dt,
+                }
+                if frames >= next_eval:
+                    # Every process consumes k_eval so rng streams stay in
+                    # lockstep even where run_eval is None (non-logging
+                    # processes).
+                    rng, k_eval = jax.random.split(rng)
+                    if run_eval is not None:
+                        row["eval_return"] = run_eval(carry.learner.params,
+                                                      k_eval)
+                    next_eval = frames + cfg.eval_every_steps
+                history.append(row)
+                log_fn(json.dumps({k: round(v, 3) if isinstance(v, float)
+                                   else v for k, v in row.items()}))
+                _emerg["frames"], _emerg["carry"] = frames, carry
+                if ckpt is not None:
+                    ckpt.maybe_save(frames, carry if checkpoint_replay
+                                    else carry.learner)
+                # Early stop (single-process only: a data-dependent exit
+                # would desync multi-process lockstep): stop_fn sees each
+                # metric row — solve-detection for tests, target-return
+                # stops for users.
+                stop = (stop_fn is not None and jax.process_count() == 1
+                        and stop_fn(row))
             if profiling:
+                # After the span closed, so the trace holds all three.
                 jax.profiler.stop_trace()
+                profiled = True
                 log_fn(json.dumps({"profile_trace": profile_dir}))
-            chunk_index += 1
-            prev_frames = frames
-            frames = frame_offset + int(metrics["env_frames"])
-            grad_steps_chunk = float(metrics["grad_steps_in_chunk"])
-            frames_delta = max(frames - prev_frames, 0)
-            _tm["env_steps"].inc(frames_delta)
-            # Global frames over wall time — under a mesh the chunk covers
-            # num_shards * chunk_iters * B frames, so chunk_iters * B / dt
-            # (the per-process log row) would under-report by the shard count.
-            _tm["env_rate"].set(frames_delta / dt)
-            _tm["grad_steps"].inc(grad_steps_chunk)
-            _tm["chunk"].observe(dt)
-            # Host-visible params refresh once per chunk boundary, so the
-            # chunk wall bounds their staleness; grad-step latency is the
-            # per-step share of the fused chunk (the steps run inside one
-            # XLA program — there is no finer host-observable boundary).
-            _tm["staleness"].observe(dt)
-            if grad_steps_chunk:
-                _tm["grad_latency"].observe(dt / grad_steps_chunk)
-            _tm["grad_rate"].set(grad_steps_chunk / dt)
-            _hb_chunk.beat()
-            _loss = float(metrics["loss"])
-            _flight.record("chunk", "fused.chunk", frames=frames,
-                           loss=_loss, wall_s=round(dt, 4))
-            tm_watchdog.observe_divergence(loss=_loss, step=frames)
-            _tm["loss"].set(_loss)
-            _tm["episodes"].inc(max(float(metrics["episodes"]), 0.0))
-            if float(metrics["episodes"]):
-                _tm["ep_return"].set(float(metrics["episode_return"]))
-            _, ring_slots = tmc.observe_device_ring(carry.replay)
-            # Experience lineage (ISSUE 16): the fused loop stamps at
-            # collect — one (birth, version) row per chunk, aged over
-            # the live ring window into the same families the apex and
-            # host-replay runtimes observe per sampled record.
-            _lineage.on_chunk(_tm["grad_steps"].value,
-                              max(1, ring_slots // chunk_iters))
-            # Utilization ledger (ISSUE 19): the fused loop's wall is
-            # the dispatch-to-fence dt (device busy, one program) plus
-            # whatever host bookkeeping separated it from the previous
-            # fence — no sample/evac/prefetch seams here, so the host
-            # share lands in the derived `other` bucket.
-            _t_now = time.perf_counter()
-            _ledger.observe_chunk(
-                _t_now - (_t_prev_fence if _t_prev_fence is not None
-                          else t0), dt)
-            _t_prev_fence = _t_now
-            telemetry.set_learner_mfu("fused", reg=_reg)
-            telemetry.sweep_device_memory(_reg)
-            row = {
-                "env_frames": frames,
-                "episode_return": float(metrics["episode_return"]),
-                # Disambiguates episode_return's no-episodes sentinel (0.0
-                # with episodes == 0) from a genuine 0.0 average return.
-                "episodes": float(metrics["episodes"]),
-                "loss": float(metrics["loss"]),
-                "env_steps_per_sec": chunk_iters * B / dt,
-                "grad_steps_in_chunk": float(metrics["grad_steps_in_chunk"]),
-                "grad_steps_per_sec":
-                    float(metrics["grad_steps_in_chunk"]) / dt,
-            }
-            if frames >= next_eval:
-                # Every process consumes k_eval so rng streams stay in
-                # lockstep even where run_eval is None (non-logging processes).
-                rng, k_eval = jax.random.split(rng)
-                if run_eval is not None:
-                    row["eval_return"] = run_eval(carry.learner.params, k_eval)
-                next_eval = frames + cfg.eval_every_steps
-            history.append(row)
-            log_fn(json.dumps({k: round(v, 3) if isinstance(v, float) else v
-                               for k, v in row.items()}))
-            _emerg["frames"], _emerg["carry"] = frames, carry
-            if ckpt is not None:
-                ckpt.maybe_save(frames,
-                                carry if checkpoint_replay else carry.learner)
-            # Early stop (single-process only: a data-dependent exit would
-            # desync multi-process lockstep): stop_fn sees each metric row —
-            # solve-detection for tests, target-return stops for users.
-            if stop_fn is not None and jax.process_count() == 1 \
-                    and stop_fn(row):
+            if stop:
                 break
     finally:
         # Deregistered even when the loop raises: a leaked
@@ -416,6 +452,7 @@ def train(cfg: ExperimentConfig, total_env_steps: int = 0, seed: int = None,
         # process that caught the exception and lived on.
         _hb_chunk.close()
         tm_watchdog.unregister_emergency_hook("fused.checkpoint")
+        tracer.close()
     if ckpt is not None:
         ckpt.save(frames, carry if checkpoint_replay else carry.learner)
         ckpt.close()
@@ -854,18 +891,23 @@ def main(argv=None):
                              "apex runtime (its eval steps host envs "
                              "synchronously and stalls the service loop)")
     parser.add_argument("--profile-dir", default=None,
-                        help="capture a jax.profiler trace of the first "
-                             "post-warmup chunk into this directory "
-                             "(view with TensorBoard / xprof). All three "
-                             "runtimes. For a window at an arbitrary "
+                        help="capture a jax.profiler trace of one chunk "
+                             "into this directory (view with TensorBoard "
+                             "/ xprof). All three runtimes; the fused "
+                             "runtime traces a STEADY chunk (the first "
+                             "after one that took the full cadence's grad "
+                             "steps), the others their first post-warmup "
+                             "chunk. For a window at an arbitrary "
                              "point of a LIVE run, use the telemetry "
                              "server's /debug/profile?seconds=N endpoint "
                              "(or /fleet/profile on the aggregator) "
                              "instead — no restart needed")
     parser.add_argument("--trace-path", default=None,
-                        help="apex runtime: write a Chrome trace-event "
-                             "file of the host loop (ingest/sample/train "
-                             "spans; open in Perfetto) to this path")
+                        help="apex and fused runtimes: write a Chrome "
+                             "trace-event file of the host loop (apex: "
+                             "ingest/sample/train spans; fused: "
+                             "fused.dispatch/fence/bookkeeping per chunk; "
+                             "open in Perfetto) to this path")
     parser.add_argument("--telemetry-port", type=int, default=None,
                         help="serve the process telemetry registry's "
                              "/metrics endpoint (Prometheus text format) "
@@ -1312,7 +1354,7 @@ def main(argv=None):
           profile_dir=args.profile_dir, num_devices=args.mesh_devices,
           stop_fn=stop_fn, checkpoint_replay=args.checkpoint_replay,
           telemetry_port=args.telemetry_port,
-          telemetry_host=args.telemetry_host)
+          telemetry_host=args.telemetry_host, trace_path=args.trace_path)
 
 
 if __name__ == "__main__":

@@ -216,17 +216,21 @@ def make_fused_train(cfg: ExperimentConfig, env: JaxEnv, net,
         # the live fp32 learner params, exactly the pre-split program.
         acting_params = (actor_params if actor_params is not None
                          else carry.learner.params)
-        actions = act(acting_params, carry.obs, k_act, eps)
-        env_state, out = env.v_step(carry.env_state, actions)
+        # Stage names (telemetry/stages.py STAGES): trace metadata only.
+        with jax.named_scope("act"):
+            actions = act(acting_params, carry.obs, k_act, eps)
+        with jax.named_scope("env"):
+            env_state, out = env.v_step(carry.env_state, actions)
         add = (pring.prioritized_ring_add if prioritized
                else ring.time_ring_add)
-        replay = add(carry.replay,
-                     _flatten_batched(jax.tree.map(_slice_newest,
-                                                   carry.obs)),
-                     actions, out.reward, out.terminated, out.truncated,
-                     final_obs=_flatten_batched(out.next_obs)
-                     if store_final else None,
-                     merge_obs_rows=flat_storage)
+        with jax.named_scope("insert"):
+            replay = add(carry.replay,
+                         _flatten_batched(jax.tree.map(_slice_newest,
+                                                       carry.obs)),
+                         actions, out.reward, out.terminated, out.truncated,
+                         final_obs=_flatten_batched(out.next_obs)
+                         if store_final else None,
+                         merge_obs_rows=flat_storage)
         beta = beta_at(carry.iteration)
 
         def do_train(operand):
@@ -242,9 +246,10 @@ def make_fused_train(cfg: ExperimentConfig, env: JaxEnv, net,
                         pallas_interpret=pallas_interpret,
                         merge_obs_rows=flat_storage,
                         frame_stack=stack, frame_shape=_frame_shape)
-                    batch = s.batch._replace(
-                        obs=_decode_batch_obs(s.batch.obs),
-                        next_obs=_decode_batch_obs(s.batch.next_obs))
+                    with jax.named_scope("gather"):
+                        batch = s.batch._replace(
+                            obs=_decode_batch_obs(s.batch.obs),
+                            next_obs=_decode_batch_obs(s.batch.next_obs))
                     l, metrics = train_step(l, batch, s.weights)
                     if defer_writeback:
                         # Replay-ratio scan: stack this sub-step's draw
@@ -262,13 +267,15 @@ def make_fused_train(cfg: ExperimentConfig, env: JaxEnv, net,
                                                   merge_obs_rows=flat_storage,
                                                   frame_stack=stack,
                                                   frame_shape=_frame_shape)
-                    batch = batch._replace(
-                        obs=_decode_batch_obs(batch.obs),
-                        next_obs=_decode_batch_obs(batch.next_obs))
+                    with jax.named_scope("gather"):
+                        batch = batch._replace(
+                            obs=_decode_batch_obs(batch.obs),
+                            next_obs=_decode_batch_obs(batch.next_obs))
                     l, metrics = train_step(l, batch)
                 return (l, rep), (metrics["loss"],)
 
-            keys = jax.random.split(k_sample, updates)
+            with jax.named_scope("sample"):
+                keys = jax.random.split(k_sample, updates)
             (learner, rep), ys = jax.lax.scan(one_update,
                                               (learner, rep), keys)
             if defer_writeback:

@@ -4,7 +4,8 @@ Every entry point (train, evaluate, serving, atari57, bench.py,
 chip_smoke.py, the benchmark scripts) goes through here for the three
 things that depend on the machine rather than on the config:
 
-* where JAX keeps its persistent compilation cache;
+* where JAX keeps its persistent compilation cache, and which programs'
+  cache keys hold their stage names;
 * the ``{"platform", "kind", "count"}`` block a run logs first, so no log
   can be read as a chip run when it was not one;
 * the refusal to take a device measurement on a CPU backend.
@@ -14,6 +15,7 @@ use the device, never in a probe child.
 """
 from __future__ import annotations
 
+import contextlib
 import json
 import os
 from pathlib import Path
@@ -39,6 +41,28 @@ def enable_compile_cache() -> str:
     path = str(_CHECKOUT / ".jax_cache")
     jax.config.update("jax_compilation_cache_dir", path)
     return path
+
+
+@contextlib.contextmanager
+def names_in_cache_key():
+    """Compile inside this and the program's HLO metadata — scope names,
+    source lines — is part of its persistent-cache key.
+
+    JAX leaves metadata out of the key, so an executable compiled before a
+    ``jax.named_scope`` existed, or before it was renamed, is served for
+    the program that has it, with its OLD op_names: a stage table read from
+    it (telemetry/stages.py) would be stale or empty. The chunk program is
+    compiled inside this; every other program keeps JAX's key, so nothing
+    else compiles again. The price: after an edit that shifts the traced
+    code's source lines the first run compiles the chunk program even where
+    its HLO did not change; warm runs are untouched."""
+    flag = "jax_compilation_cache_include_metadata_in_key"
+    before = getattr(jax.config, flag)
+    jax.config.update(flag, True)
+    try:
+        yield
+    finally:
+        jax.config.update(flag, before)
 
 
 def device_summary() -> Dict:

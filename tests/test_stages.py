@@ -1,0 +1,321 @@
+"""Stage names in the fused chunk program, the table built from them on
+demand, the compile-cache flag that keeps them true, and the host spans of
+``train.train`` (telemetry/stages.py, utils/trace.py, utils/backend.py)."""
+import dataclasses
+import os
+import re
+import subprocess
+import sys
+from pathlib import Path
+
+import jax
+import numpy as np
+import pytest
+
+from dist_dqn_tpu.config import CONFIGS, apply_overrides
+from dist_dqn_tpu.envs import make_jax_env
+from dist_dqn_tpu.models import build_network
+from dist_dqn_tpu.telemetry import flight as tm_flight
+from dist_dqn_tpu.telemetry import stages
+from dist_dqn_tpu.train import train
+from dist_dqn_tpu.train_loop import make_fused_train
+
+CHECKOUT = Path(__file__).resolve().parents[1]
+TOY = ["network.mlp_features=(32,)", "replay.capacity=4096",
+       "replay.min_fill=64", "learner.batch_size=16", "actor.num_envs=8"]
+# Stages every fused program holds; a prioritized one adds the write-back.
+# ``allreduce`` exists only under a mesh (its case is below).
+COMMON = {"act", "env", "insert", "sample", "gather", "loss_grad",
+          "optimizer", "target_sync"}
+
+
+def _toy_cfg(*extra):
+    cfg = apply_overrides(CONFIGS["cartpole"], TOY + list(extra))
+    return dataclasses.replace(cfg, eval_every_steps=0)
+
+
+def _chunk_text(cfg, num_devices=1):
+    env = make_jax_env(cfg.env_name)
+    net = build_network(cfg.network, env.num_actions)
+    key = np.asarray(jax.random.PRNGKey(0))
+    if num_devices == 1:
+        init, run_chunk = make_fused_train(cfg, env, net)
+        run = jax.jit(run_chunk, static_argnums=1, donate_argnums=0)
+    else:
+        from dist_dqn_tpu.parallel import make_mesh, make_mesh_fused_train
+        mesh = make_mesh(devices=jax.devices()[:num_devices])
+        init, run = make_mesh_fused_train(cfg, env, net, mesh)
+    return run.lower(init(key), 10).compile().as_text()
+
+
+# -- the vocabulary --------------------------------------------------------
+def test_every_scope_in_the_package_is_a_stage_and_every_stage_is_entered():
+    entered = set()
+    for path in (CHECKOUT / "dist_dqn_tpu").rglob("*.py"):
+        entered.update(re.findall(r'named_scope\("([^"]+)"\)',
+                                  path.read_text()))
+    assert entered == set(stages.STAGES)
+
+
+@pytest.mark.parametrize("op_name,stage", [
+    ("jit(run_chunk)/while/body/closed_call/act/dot_general", "act"),
+    ("jit(run_chunk)/while/body/cond/branch_1_fun/while/body/loss_grad/"
+     "transpose(jvp(QNetwork))/Dense_0/dot_general", "loss_grad"),
+    ("jit(run_chunk)/while/body/insert/gather/x", "gather"),  # innermost
+    ("jit(run_chunk)/while/body/closed_call/add", None),
+    ("jit(run_chunk)/while/body/actor/transact", None),  # whole parts only
+])
+def test_stage_of_reads_the_innermost_whole_path_part(op_name, stage):
+    assert stages.stage_of(op_name) == stage
+
+
+# -- the table, from the executable's text -----------------------------------
+_SKIP = {"parameter", "get-tuple-element", "tuple", "constant", "bitcast"}
+
+
+def _loop_body_instructions(text):
+    """The instructions of the iteration loop: those of the outermost
+    ``while``'s body and of every computation it reaches — conditions,
+    branches, inner loops and the insides of fusions."""
+    comps = {}
+    for computation, m, line in stages.instructions(text):
+        comps.setdefault(computation, []).append((m, line))
+    (entry_name,) = re.findall(r"^ENTRY %?([\w.\-]+) ", text, re.M)
+    control = re.compile(r"\b(?:body|condition|true_computation|"
+                         r"false_computation|calls)=%?([\w.\-]+)")
+    branches = re.compile(r"branch_computations=\{([^}]*)\}")
+
+    def reached(line):
+        found = control.findall(line)
+        for group in branches.findall(line):
+            found += re.findall(r"%?([\w.\-]+)", group)
+        return found
+
+    todo = [c for m, line in comps[entry_name] if m.group("op") == "while"
+            for c in reached(line)]
+    seen, out = set(), []
+    while todo:
+        comp = todo.pop()
+        if comp in seen:
+            continue
+        seen.add(comp)
+        for m, line in comps.get(comp, ()):
+            if m.group("op") not in _SKIP:
+                out.append(m.group("inst"))
+            todo += reached(line)
+    return out
+
+
+@pytest.mark.parametrize("overrides,expected", [
+    ((), COMMON),
+    (("replay.prioritized=true",), COMMON | {"writeback"}),
+    (("replay.prioritized=true", "replay.updates_per_chunk=2"),
+     COMMON | {"writeback"}),
+], ids=["uniform", "prioritized", "prioritized_ratio2"])
+def test_table_holds_every_stage_and_covers_the_loop(overrides, expected):
+    text = _chunk_text(_toy_cfg(*overrides))
+    table = stages.table_from_text(text)
+    assert expected <= set(table.values()) <= set(stages.STAGES) | {
+        stages.MIXED}
+    # By count, on the CPU backend: 85-87% here. The rest is the loop's own
+    # bookkeeping (episode statistics, counters, the train predicate), the
+    # backend's carry copies, and the key splits — JAX lowers
+    # ``_threefry_split`` once as a shared sub-computation, which keeps no
+    # caller's scope. The chip's measure is by time
+    # (``stage_unattributed_share``, perf/tests/test_perf_stage_metrics.py).
+    body = _loop_body_instructions(text)
+    assert len(body) > 1000
+    staged = [i for i in body if table.get(i) in stages.STAGES]
+    assert len(staged) >= 0.8 * len(body), (len(staged), len(body))
+
+
+def test_the_mesh_program_names_its_allreduce():
+    cfg = _toy_cfg("actor.num_envs=16", "learner.batch_size=32")
+    table = stages.table_from_text(_chunk_text(cfg, num_devices=2))
+    assert COMMON | {"allreduce"} <= set(table.values())
+
+
+HLO = """HloModule toy
+
+%fused_computation (p: f32[8]) -> f32[8] {
+  %p = f32[8]{0} parameter(0)
+  %c = f32[] constant(1), metadata={op_name="jit(f)/while/body/env/mul"}
+  ROOT %t = f32[8]{0} tanh(%p), metadata={op_name="jit(f)/while/body/act/tanh"}
+}
+
+%fused_computation.1 (p.1: f32[8]) -> (f32[], f32[4,4]) {
+  %p.1 = f32[8]{0} parameter(0)
+  %conv = f32[4,4]{1,0} convolution(%p.1, %p.1), metadata={op_name="jit(f)/while/body/loss_grad/transpose(jvp(Net))/conv"}
+  %sq = f32[4,4]{1,0} multiply(%conv, %conv), metadata={op_name="jit(f)/while/body/optimizer/mul"}
+  %n = f32[] reduce(%sq), metadata={op_name="jit(f)/while/body/optimizer/reduce"}
+  ROOT %out = (f32[], f32[4,4]{1,0}) tuple(%n, %conv)
+}
+
+%fused_computation.2 (p.2: f32[8]) -> f32[8] {
+  %p.2 = f32[8]{0} parameter(0)
+  %a = f32[8]{0} add(%p.2, %p.2), metadata={op_name="jit(f)/while/body/act/add"}
+  ROOT %b = f32[8]{0} multiply(%a, %a), metadata={op_name="jit(f)/while/body/env/mul"}
+}
+
+%body (arg: (f32[8], f32[8])) -> (f32[8], f32[8]) {
+  %arg = (f32[8]{0}, f32[8]{0}) parameter(0)
+  %gte = f32[8]{0} get-tuple-element(%arg), index=0
+  %copy.1 = f32[8]{0} copy(%gte)
+  %bitcast.1 = f32[8]{0} bitcast(%copy.1)
+  %fusion.1 = f32[8]{0} fusion(%bitcast.1), kind=kLoop, calls=%fused_computation, metadata={op_name="jit(f)/while/body/sub"}
+  %fusion.2 = (f32[], f32[4,4]{1,0}) fusion(%fusion.1), kind=kOutput, calls=%fused_computation.1
+  %fusion.3 = f32[8]{0} fusion(%fusion.1), kind=kLoop, calls=%fused_computation.2
+  %copy.2 = f32[8]{0} copy(%fusion.3)
+  %add.9 = f32[8]{0} add(%gte, %gte), metadata={op_name="jit(f)/while/body/add"}
+  ROOT %tuple.1 = (f32[8]{0}, f32[8]{0}) tuple(%copy.2, %add.9)
+}
+
+ENTRY %main (x: f32[8]) -> f32[8] {
+  %x = f32[8]{0} parameter(0)
+  ROOT %while.1 = (f32[8]{0}, f32[8]{0}) while(%x), condition=%cond, body=%body
+}
+"""
+
+
+def test_table_rules_on_a_hand_written_module():
+    table = stages.table_from_text(HLO)
+    # a fusion takes its working instructions' stage (the constant's
+    # op_name does not vote), whatever its own op_name says
+    assert table["fusion.1"] == "act"
+    # a fused-in convolution decides alone over the epilogue's stage
+    assert table["fusion.2"] == "loss_grad"
+    # no hero and no 3/4 majority: mixed
+    assert table["fusion.3"] == stages.MIXED
+    # compiler-inserted movement: its consumers' stage, through a bitcast;
+    # with no staged consumer, its producer's
+    assert table["copy.1"] == table["bitcast.1"] == "act"
+    assert table["copy.2"] == stages.MIXED
+    # under no stage: left out
+    assert "add.9" not in table and "while.1" not in table
+
+
+# -- nobody asks: no table; the host spans ------------------------------------
+@pytest.fixture()
+def fresh_flight():
+    tm_flight._reset_for_tests()
+    yield
+    tm_flight._reset_for_tests()
+
+
+def test_train_builds_no_table_until_asked_and_spans_every_chunk(
+        fresh_flight, monkeypatch):
+    calls = []
+    for cls in (jax.stages.Lowered, jax.stages.Compiled):
+        monkeypatch.setattr(
+            cls, "as_text",
+            lambda self, *a, _orig=cls.as_text, **k:
+            calls.append(type(self).__name__) or _orig(self, *a, **k))
+    stages.keep(None)
+    _, history = train(_toy_cfg(), total_env_steps=8 * 25 * 4, chunk_iters=25,
+                       log_fn=lambda _line: None)
+    assert len(history) == 4
+    assert not stages.table_built() and calls == []
+    spans = [e["name"] for e in tm_flight.get_flight().tail()
+             if e["kind"] == "span"]
+    assert spans == ["fused.dispatch", "fused.fence",
+                     "fused.bookkeeping"] * len(history)
+    # asked: built once, from the program train kept
+    table = stages.table()
+    assert COMMON <= set(table.values()) and stages.table_built()
+    assert stages.table() is table and calls == ["Compiled"]
+    assert stages.table_seconds() > 0
+
+
+def test_trace_path_writes_the_spans_and_feeds_the_histogram(fresh_flight,
+                                                             tmp_path):
+    import json
+
+    from dist_dqn_tpu import telemetry
+
+    path = tmp_path / "host.json"
+    _, history = train(_toy_cfg(), total_env_steps=8 * 25 * 2, chunk_iters=25,
+                       log_fn=lambda _line: None, trace_path=str(path))
+    spans = [e["name"] for e in json.loads(path.read_text())
+             if e.get("ph") == "X"]
+    assert spans == ["fused.dispatch", "fused.fence",
+                     "fused.bookkeeping"] * len(history)
+    text = telemetry.render_prometheus(telemetry.get_registry())
+    assert 'dqn_host_span_seconds_count{span="fused.dispatch"}' in text
+
+
+def test_no_flight_recorder_leaves_no_span(fresh_flight):
+    tm_flight.configure(enabled=False)
+    _, history = train(_toy_cfg(), total_env_steps=8 * 25 * 2, chunk_iters=25,
+                       log_fn=lambda _line: None)
+    assert len(history) == 2
+    assert tm_flight.get_flight().tail() == []
+
+
+def test_spans_are_profiler_annotations_beside_the_device_ops(
+        fresh_flight, tmp_path):
+    """``profile_dir`` traces a STEADY chunk — the first after one with the
+    full cadence's grad steps — and the trace holds the step and the three
+    spans on the profiler's own clock."""
+    lines = []
+    _, history = train(_toy_cfg(), total_env_steps=8 * 25 * 4, chunk_iters=25,
+                       log_fn=lines.append, profile_dir=str(tmp_path))
+    assert [r["grad_steps_in_chunk"] for r in history] == [18.0, 25, 25, 25]
+    # chunk 0 is not full (min_fill), chunk 1 is: chunk 2 is traced
+    assert lines.index('{"profile_trace": "%s"}' % tmp_path) == 3
+    (pb,) = tmp_path.glob("plugins/profile/*/*.xplane.pb")
+    names = {e.name for plane in jax.profiler.ProfileData.from_file(
+        str(pb)).planes for line in plane.lines for e in line.events}
+    assert {"fused.chunk", "fused.dispatch", "fused.fence",
+            "fused.bookkeeping"} <= names
+
+
+def test_a_run_too_short_for_a_steady_chunk_writes_no_trace(tmp_path):
+    lines = []
+    train(_toy_cfg(), total_env_steps=8 * 25, chunk_iters=25,
+          log_fn=lines.append, profile_dir=str(tmp_path / "p"))
+    assert len(lines) == 1 and "profile_trace" not in lines[0]
+    assert not (tmp_path / "p").exists()
+
+
+# -- names survive the compile cache ------------------------------------------
+_STALE = '''
+import contextlib, sys
+import jax, jax.numpy as jnp
+from dist_dqn_tpu.telemetry import stages
+from dist_dqn_tpu.utils import backend
+backend.enable_compile_cache()
+jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+key = (backend.names_in_cache_key if sys.argv[1] == "1"
+       else contextlib.nullcontext)
+f = lambda x: jnp.tanh(x @ x)
+for name in ("act", "env"):            # the scope is renamed, the cache kept
+    jax.clear_caches()
+    def g(x, name=name):
+        with jax.named_scope(name):
+            return f(x)
+    lowered = jax.jit(g).lower(jnp.ones((64, 64)))
+    with key():
+        text = lowered.compile().as_text()
+    assert not jax.config.jax_compilation_cache_include_metadata_in_key
+    print(name, sorted(set(stages.table_from_text(text).values())))
+'''
+
+
+@pytest.mark.parametrize("metadata_in_key,after_rename", [
+    (True, "env"),      # compiled as train.train compiles its chunk
+    (False, "act"),     # JAX's own key: the OLD executable, the OLD name
+])
+def test_a_renamed_scope_with_the_cache_kept(tmp_path, metadata_in_key,
+                                             after_rename):
+    script = tmp_path / "stale.py"
+    script.write_text(_STALE)
+    env = dict(os.environ, JAX_COMPILATION_CACHE_DIR=str(tmp_path / "cache"),
+               JAX_ENABLE_COMPILATION_CACHE="true", JAX_PLATFORMS="cpu",
+               PYTHONPATH=str(CHECKOUT))
+    out = subprocess.run(
+        [sys.executable, str(script), str(int(metadata_in_key))], env=env,
+        capture_output=True, text=True, timeout=120)
+    assert out.returncode == 0, out.stderr[-2000:]
+    assert out.stdout.splitlines()[-2:] == ["act ['act']",
+                                            f"env ['{after_rename}']"]
