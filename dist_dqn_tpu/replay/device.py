@@ -216,6 +216,8 @@ def stack_rebuild_indices(done_at, t_idx: Array, frame_stack: int,
     ``done_at(slots) -> [len(t_idx)] bool`` abstracts the done-flag
     lookup so callers own the (merge-rows vs tiled) indexing. Returns
     slot indices per lookback, NEWEST-first: [(d, [S] slots), ...].
+    ``gather_transitions`` stacks them oldest-first into ONE [S, N] index
+    so the whole rebuild is a single row gather (see there).
     """
     S = frame_stack
     age = jnp.full_like(t_idx, S - 1)
@@ -241,7 +243,11 @@ def gather_transitions(state: TimeRingState, t_idx: Array, b_idx: Array,
     the reset-boundary re-tiling (see ``stack_rebuild_indices``). In
     merge_obs_rows mode the stored rows are flat; ``frame_shape`` (e.g.
     (84, 84, 1)) is then required to reshape gathered rows — gathered
-    stacks come back UNFLATTENED either way.
+    stacks come back UNFLATTENED either way. The slot index is [S, N]
+    (stack-major), one row gather per leaf: the learner's input is
+    batch-minor on TPU, and [S*N, H*W] rows ARE [N, H, W, S] in that
+    layout once transposed — one 2-D relayout instead of S size-1-minor
+    frame copies and a concatenate (PERF.md, PR 28).
     """
     if frame_stack and state.final_obs is not None:
         raise ValueError(
@@ -269,7 +275,7 @@ def _gather_transitions(state: TimeRingState, t_idx: Array, b_idx: Array,
         if merge_obs_rows:
             out = x[t * num_envs + b_idx]
             if frame_stack and frame_shape is not None:
-                out = out.reshape(out.shape[:1] + tuple(frame_shape))
+                out = out.reshape(out.shape[:-1] + tuple(frame_shape))
             return out
         return x[t, b_idx]
 
@@ -278,11 +284,17 @@ def _gather_transitions(state: TimeRingState, t_idx: Array, b_idx: Array,
             return jax.tree.map(lambda x: take_one(x, t), tree)
         slots = stack_rebuild_indices(lambda tt: done[tt, b_idx], t,
                                       frame_stack, num_slots)
-        # Channel order oldest -> newest = lookback S-1 -> 0.
-        return jax.tree.map(
-            lambda x: jnp.concatenate(
-                [take_one(x, ts) for d, ts in reversed(slots)], axis=-1),
-            tree)
+        # [S, N] slot index, channel order oldest -> newest = lookback
+        # S-1 -> 0: ONE row gather fetches every frame of every sample.
+        ts = jnp.stack([s for _, s in reversed(slots)])
+
+        def rebuild(x):
+            # [S, N, H, W, 1] -> [N, H, W, S]: stack-major rows make the
+            # stack a plain 2-D transpose of [S*N, H*W].
+            frames = jnp.moveaxis(take_one(x, ts), 0, -2)
+            return frames.reshape(frames.shape[:-2] + (-1,))
+
+        return jax.tree.map(rebuild, tree)
 
     obs = take(state.obs, t_idx)
     action = state.action[t_idx, b_idx]

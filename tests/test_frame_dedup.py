@@ -15,12 +15,14 @@ from dist_dqn_tpu.replay import device as ring
 H, W, S = 6, 5, 4
 
 
-def _rolling_stream(rng, steps, lanes):
+def _rolling_stream(rng, steps, lanes, done=None):
     """Synthesize (obs[t], action, reward, term, trunc) honoring the
     rolling-stack contract the pixel envs declare (envs/base.py):
-    obs shifts one frame per step; a done at t re-tiles obs_{t+1}."""
+    obs shifts one frame per step; a done at t re-tiles obs_{t+1}.
+    ``done`` [steps, lanes] places the episode ends by hand."""
     frames = rng.integers(0, 255, (steps + 1, lanes, H, W), np.uint8)
-    done = rng.random((steps, lanes)) < 0.25
+    if done is None:
+        done = rng.random((steps, lanes)) < 0.25
     term = np.logical_and(done, rng.random((steps, lanes)) < 0.5)
     trunc = np.logical_and(done, ~term)
     obs = np.zeros((steps, lanes, H, W, S), np.uint8)
@@ -36,12 +38,13 @@ def _rolling_stream(rng, steps, lanes):
     return obs, action, reward, term, trunc
 
 
-def _fill(state, obs, action, reward, term, trunc, dedup, merge):
+def _fill(state, obs, action, reward, term, trunc, dedup, merge,
+          add=ring.time_ring_add):
     for t in range(obs.shape[0]):
         o = obs[t][..., -1:] if dedup else obs[t]
         if merge:
             o = o.reshape(o.shape[0], -1)
-        state = ring.time_ring_add(
+        state = add(
             state, jnp.asarray(o), jnp.asarray(action[t]),
             jnp.asarray(reward[t]), jnp.asarray(term[t]),
             jnp.asarray(trunc[t]), merge_obs_rows=merge)
@@ -96,6 +99,88 @@ def test_dedup_gather_exactly_matches_stacked(merge, steps, slots):
                                   np.asarray(b.discount))
 
 
+@pytest.mark.parametrize("sampler", ["uniform", "prioritized"])
+@pytest.mark.parametrize("merge", [False, True])
+@pytest.mark.parametrize("steps,slots", [(5, 8), (12, 5)])  # unwrapped / wrapped
+@pytest.mark.parametrize("j", range(1, S))
+@pytest.mark.parametrize("leaf", ["obs", "next_obs"])
+def test_dedup_reset_at_every_lookback_distance(leaf, j, steps, slots, merge,
+                                                sampler):
+    """A reset exactly ``j`` steps before the sampled slot (``obs``) or
+    before its bootstrap slot (``next_obs``), for every j in 1..S-1: the
+    stacks both samplers return are the rolling-stack stream's, bitwise.
+    The ring holds S + 1 steps, so n_step=1 leaves ONE valid start and the
+    draw cannot miss the reset; lane 1 has no reset at all; each step's
+    action names its (step, lane), which the oracle reads back."""
+    from dist_dqn_tpu.replay import prioritized_device as pring
+
+    lanes, n_step, batch = 2, 1, 32
+    start = steps - 1 - n_step                 # the one valid window start
+    anchor = start if leaf == "obs" else start + n_step
+    done = np.zeros((steps, lanes), bool)
+    done[anchor - j, 0] = True
+    obs, _, reward, term, trunc = _rolling_stream(
+        np.random.default_rng(10 * j + steps), steps, lanes, done=done)
+    action = np.arange(steps * lanes, dtype=np.int32).reshape(steps, lanes)
+
+    stored = jnp.zeros((H * W,) if merge else (H, W, 1), jnp.uint8)
+    kw = dict(merge_obs_rows=merge, frame_stack=S, frame_shape=(H, W, 1))
+    if sampler == "uniform":
+        st = ring.time_ring_init(slots, lanes, stored, merge_obs_rows=merge)
+        st = _fill(st, obs, action, reward, term, trunc, True, merge)
+        got = ring.time_ring_sample(st, jax.random.PRNGKey(j), batch, n_step,
+                                    0.97, **kw)
+    else:
+        st = pring.prioritized_ring_init(slots, lanes, stored,
+                                         merge_obs_rows=merge)
+        st = _fill(st, obs, action, reward, term, trunc, True, merge,
+                   add=pring.prioritized_ring_add)
+        got = pring.prioritized_ring_sample(
+            st, jax.random.PRNGKey(j), batch, n_step, 0.97, alpha=0.6,
+            beta=jnp.float32(0.4), **kw).batch
+
+    t, b = np.divmod(np.asarray(got.action), lanes)
+    assert (t == start).all() and set(b) == {0, 1}
+    np.testing.assert_array_equal(np.asarray(got.obs), obs[t, b])
+    # The stream's post-reset obs IS the tiled stack the rebuild returns.
+    np.testing.assert_array_equal(np.asarray(got.next_obs),
+                                  obs[t + n_step, b])
+
+
+def _eqns(jaxpr):
+    """Every equation of a jaxpr, sub-jaxprs (pjit, cond, scan) included."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _eqns(sub)
+
+
+def test_dedup_rebuild_is_one_row_gather_per_leaf():
+    """What carries over from the CPU to the chip is the program's shape:
+    on a flat ring the rebuild reads the obs buffer with ONE row gather for
+    ``obs`` and one for ``next_obs`` (not one per frame), and never
+    concatenates size-1-minor frames — the form XLA:TPU lowers to a
+    pad-and-add pass over transposed [N, H, W, 1] copies (PERF.md, PR 28)."""
+    slots, lanes, n = 16, 4, 8
+    st = ring.time_ring_init(slots, lanes, jnp.zeros((H * W,), jnp.uint8),
+                             merge_obs_rows=True)
+    idx = jnp.zeros((n,), jnp.int32)
+    jaxpr = jax.make_jaxpr(
+        lambda s, t, b: ring.gather_transitions(
+            s, t, b, 3, 0.97, merge_obs_rows=True, frame_stack=S,
+            frame_shape=(H, W, 1)))(st, idx, idx)
+    assert jaxpr.out_avals[0].shape == (n, H, W, S)  # Transition.obs
+    eqns = list(_eqns(jaxpr.jaxpr))
+    ring_reads = [e for e in eqns if e.primitive.name == "gather"
+                  and e.invars[0].aval.shape == st.obs.shape
+                  and e.invars[0].aval.dtype == jnp.uint8]
+    assert 1 <= len(ring_reads) <= 2, len(ring_reads)
+    frame_concats = [e for e in eqns if e.primitive.name == "concatenate"
+                     and e.invars[0].aval.dtype == jnp.uint8
+                     and e.invars[0].aval.shape[-1] == 1]
+    assert not frame_concats
+
+
 def test_dedup_uniform_sample_range_excludes_contextless_slots():
     """time_ring_sample with frame_stack must never draw a start whose
     rebuild context is unstored (the oldest S-1 slots)."""
@@ -123,11 +208,8 @@ def test_dedup_prioritized_mask_and_gather():
     obs, action, reward, term, trunc = _rolling_stream(rng, steps, lanes)
     st = pring.prioritized_ring_init(slots, lanes,
                                      jnp.zeros((H, W, 1), jnp.uint8))
-    for t in range(steps):
-        st = pring.prioritized_ring_add(
-            st, jnp.asarray(obs[t][..., -1:]), jnp.asarray(action[t]),
-            jnp.asarray(reward[t]), jnp.asarray(term[t]),
-            jnp.asarray(trunc[t]))
+    st = _fill(st, obs, action, reward, term, trunc, True, False,
+               add=pring.prioritized_ring_add)
     mask = np.asarray(pring._valid_start_mask(st.ring, n_step,
                                               frame_stack=S))
     assert not mask[:S - 1].any()          # contextless slots excluded
