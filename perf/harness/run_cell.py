@@ -13,6 +13,7 @@ import gc
 import json
 import math
 import shutil
+import sys
 import time
 from pathlib import Path
 from typing import Dict, List, Optional
@@ -224,9 +225,9 @@ def run(args, t_process_start: float) -> int:
     # The reference check: part of set-up, before the trainer takes the
     # chip's memory. Under a mesh each shard's learner sees its own rows of
     # the batch, so that is the size checked (on one device).
+    reference = manifest.reference(plan["reference"])
     check = reference_check.make_check(
-        manifest.reference(plan["reference"]), cfg, env,
-        build_network(cfg.network, env.num_actions),
+        reference, cfg, env, build_network(cfg.network, env.num_actions),
         cfg.learner.batch_size // plan["num_devices"])(args.seed)
     t_check = time.perf_counter()
 
@@ -268,10 +269,9 @@ def run(args, t_process_start: float) -> int:
         "seed": args.seed, "seconds": args.seconds,
         "chunk_iters": recorder.chunk_iters, "lanes": recorder.lanes,
         "batch_size": cfg.learner.batch_size,
-        "double_dqn": bool(cfg.learner.double_dqn),
-        "dueling": bool(cfg.network.dueling), "hidden": cfg.network.hidden,
-        "obs_shape": list(env.observation_shape),
-        "num_actions": int(env.num_actions),
+        # FLOPs a grad step requires, counted by the configuration's
+        # reference module from its own shapes (whole mesh)
+        "grad_step_flops": float(reference.grad_step_flops(cfg, env)),
         "grad_steps_per_chunk": recorder.grad_steps_per_chunk,
         "device": device, "series": series, "rates": rates,
         "host_loop": estimator.host_loop_summary(
@@ -309,12 +309,41 @@ def run(args, t_process_start: float) -> int:
         for m in manifest.metrics_of("end_to_end", plan["cell"]):
             line["metrics"][m["name"]] = {"value": values[m["name"]],
                                           "unit": m["unit"]}
+    # Every number ``correct`` rests on, beside its limit: last in the line
+    # and as the last lines of standard error.
+    line["compared"] = dict(
+        {name: [value, check["tolerances"][name]]
+         for name, value in check["errors"].items()},
+        failed_chunks=[failed, 0],
+        compiles_in_window=[in_window["compiles"], 0])
     if args.record:
         Path(args.record).parent.mkdir(parents=True, exist_ok=True)
         Path(args.record).write_text(json.dumps(dict(record,
                                                      last_line=line)))
+    if args.dump_hlo:
+        _dump_chunk_program(Path(args.dump_hlo))
+    for name, (value, limit) in line["compared"].items():
+        print(f"compared {name}: {value!r} (limit {limit!r})",
+              file=sys.stderr)
+    sys.stderr.flush()
     print(json.dumps(line), flush=True)
     return 0
+
+
+def _dump_chunk_program(path: Path) -> None:
+    """The optimized HLO text of the chunk program ``train.train`` kept for
+    its stage table, gzipped: with a dumped trace, what a stage-metric test
+    needs (``perf/testdata``)."""
+    import gzip
+
+    from dist_dqn_tpu.telemetry import stages
+
+    program = getattr(stages, "_program", None)
+    if program is None:
+        raise RunFailure("--dump-hlo: the program kept no chunk executable")
+    path.parent.mkdir(parents=True, exist_ok=True)
+    with gzip.open(path, "wt") as f:
+        f.write(program.as_text())
 
 
 def _traced_metrics(manifest: Manifest, args, record: Dict, line: Dict,

@@ -3,7 +3,6 @@ over the device time of stage ``loss_grad`` alone x chips x the bf16 peak:
 what the matmul part reaches once sampling, gathering, the optimizer and the
 acting half of the chunk are taken out of the denominator."""
 from perf.metrics import _stages
-from perf.reduce.flops import grad_step_flops
 from perf.reduce.peaks import peak
 
 
@@ -11,10 +10,5 @@ def read(run, trace):
     ms = _stages.ms_per_grad_step(run, trace, "loss_grad")
     if not ms:
         return None
-    flops = grad_step_flops(run["batch_size"], obs_shape=run["obs_shape"],
-                            hidden=run["hidden"],
-                            num_actions=run["num_actions"],
-                            dueling=run["dueling"],
-                            double_dqn=run["double_dqn"])
-    return 100.0 * flops / (1e-3 * ms * run["chips"]
-                            * peak(run["device"]["kind"], "bf16_flops"))
+    return 100.0 * run["grad_step_flops"] / (
+        1e-3 * ms * run["chips"] * peak(run["device"]["kind"], "bf16_flops"))

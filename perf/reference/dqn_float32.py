@@ -7,10 +7,11 @@ written in straightforward ``jax.numpy`` in float32 under
 ``jax.default_matmul_precision("highest")``. It imports nothing from
 ``dist_dqn_tpu``: the parameter tree is read by its key names only.
 
-A convolution is written as what it is: the windows of the input laid side
-by side (strided slices), times the kernel as one matrix. The batch is
-walked in blocks of ``ROW_BLOCK`` rows and the blocks' losses and gradients
-summed, which is exact because every term of the loss belongs to one row.
+The layers come from ``plain.py``, where a convolution is written as what it
+is: the windows of the input laid side by side, times the kernel as one
+matrix. The batch is walked in blocks of ``ROW_BLOCK`` rows and the blocks'
+losses and gradients summed, which is exact because every term of the loss
+belongs to one row.
 Both are for the compiler, not for the mathematics: XLA's float32
 convolution backward at "highest" precision takes two minutes to compile
 for a TPU, these matmuls seconds, and the window matrix of a whole batch
@@ -20,6 +21,12 @@ Departures from the papers, all taken from the configuration as it is run:
 the batch already holds the n-step return and ``gamma**n * (1 - done)`` as
 ``reward`` and ``discount`` (the program folds n steps when it samples), and
 the optimizer is Adam, not RMSProp.
+
+Besides the step it holds what ``perf/harness/reference_check.py`` asks of
+every reference module (``perf/README.md``): which learner of the program
+this reference stands beside (``make_program`` — the one place that names
+it; the arithmetic above it imports nothing), a seeded batch in that
+learner's layout, the tolerances, and the FLOPs of a grad step.
 """
 from __future__ import annotations
 
@@ -27,12 +34,38 @@ from typing import Dict, NamedTuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
-# (features, kernel, stride) per VALID conv; the kernels' own shapes come
-# from the parameter tree, only the strides are not stored there.
-CONV_STRIDES = {"nature": (4, 2, 1), "small": (4, 2)}
-ADAM_B1, ADAM_B2 = 0.9, 0.999
+from perf.reference.plain import (ADAM_B1, CONV_STRIDES, CONVS,  # noqa: F401
+                                  adam_delta, clip_by_global_norm, cnn_torso,
+                                  dense, global_norm, mlp_torso)
+
 ROW_BLOCK = 32
+
+# Largest error allowed for each quantity ``reference_check`` compares (its
+# docstring defines them), by the dtype the configuration computes in.
+#
+# bfloat16 keeps 8 significant bits (2^-8 = 0.4% per rounding); through
+# five layers, the loss and the backward pass the roundings add up. The
+# bf16 bounds are at least three times the largest error over the seeded
+# states of the study on the chip (PERF.md section 6, PR 23: 64 seeds for
+# each configuration at its own widths; largest readings Q 0.94%, |TD|
+# 0.94%, loss 1.2%, gradient 1.6%), rounded up; the optimizer's, float32
+# against float32, read 1.8e-5 at most. A type with fewer bits fails them:
+# with the program's weights rounded through float8 (e4m3, 4 significant
+# bits) the Q-values are 4-7% off (perf/tests pins it). A wrong formula — no
+# importance weights, a dropped dueling mean, another learning rate — moves
+# loss, gradient or optimizer by tens of percent; double-Q against the
+# plain maximum, at a state this close to initialisation, moves |TD| and
+# loss by 1-9% depending on the seed, so that one is caught in most seeds
+# and not in all. float32 configurations differ from the reference only by
+# summation order.
+TOLERANCES = {
+    "bfloat16": {"q": 0.03, "priorities": 0.03, "loss": 0.04, "grad": 0.05,
+                 "optimizer": 1e-3},
+    "float32": {"q": 1e-4, "priorities": 1e-4, "loss": 1e-4, "grad": 1e-3,
+                "optimizer": 1e-3},
+}
 
 
 class Hyper(NamedTuple):
@@ -47,25 +80,6 @@ class Hyper(NamedTuple):
     max_grad_norm: float
 
 
-def _dense(p: Dict, x):
-    return x @ p["kernel"].astype(jnp.float32) + p["bias"].astype(
-        jnp.float32)
-
-
-def _conv_valid(x, kernel, stride: int):
-    """VALID convolution of NHWC ``x`` with an HWIO ``kernel``: the
-    ``kh * kw`` strided window slices side by side in the kernel's own
-    (row, column, channel) order, times the kernel as a matrix."""
-    kh, kw, cin, cout = kernel.shape
-    ho = (x.shape[1] - kh) // stride + 1
-    wo = (x.shape[2] - kw) // stride + 1
-    windows = jnp.concatenate(
-        [x[:, i:i + stride * (ho - 1) + 1:stride,
-           j:j + stride * (wo - 1) + 1:stride, :]
-         for i in range(kh) for j in range(kw)], axis=-1)
-    return windows @ kernel.reshape(kh * kw * cin, cout)
-
-
 def q_values(params: Dict, obs, hp: Hyper):
     """[B, A] Q-values of the (dueling) Nature network in float32."""
     p = params["params"]
@@ -73,23 +87,15 @@ def q_values(params: Dict, obs, hp: Hyper):
     if obs.dtype == jnp.uint8:
         x = x / 255.0
     if hp.torso == "mlp":
-        x = x.reshape((x.shape[0], -1))
-        torso = p["MLPTorso_0"]
-        for i in range(len(torso)):
-            x = jax.nn.relu(_dense(torso[f"Dense_{i}"], x))
+        x = mlp_torso(p["MLPTorso_0"], x)
     else:
-        torso = p["CNNTorso_0"]
-        for i, stride in enumerate(CONV_STRIDES[hp.torso]):
-            conv = torso[f"Conv_{i}"]
-            x = _conv_valid(x, conv["kernel"].astype(jnp.float32), stride)
-            x = jax.nn.relu(x + conv["bias"].astype(jnp.float32))
-        x = x.reshape((x.shape[0], -1))
+        x = cnn_torso(p["CNNTorso_0"], x, CONV_STRIDES[hp.torso])
     if "Dense_0" in p:
-        x = jax.nn.relu(_dense(p["Dense_0"], x))
-    adv = _dense(p["advantage"], x)
+        x = jax.nn.relu(dense(p["Dense_0"], x))
+    adv = dense(p["advantage"], x)
     if not hp.dueling:
         return adv
-    val = _dense(p["value"], x)
+    val = dense(p["value"], x)
     return val + adv - jnp.mean(adv, axis=1, keepdims=True)
 
 
@@ -113,10 +119,6 @@ def _loss_sum(params, target_params, batch: Dict, weights, hp: Hyper):
     return jnp.sum(weights * huber), (jnp.abs(td), q)
 
 
-def _global_norm(tree):
-    return jnp.sqrt(sum(jnp.sum(g * g) for g in jax.tree.leaves(tree)))
-
-
 def _pull_sum(params, batch: Dict, pull, hp: Hyper):
     """Sum over the rows of ``pull * Q(obs, action)``: with ``pull`` the
     size of each row's ``d loss / d Q``, its gradient is what the loss's
@@ -127,13 +129,16 @@ def _pull_sum(params, batch: Dict, pull, hp: Hyper):
     return jnp.sum(pull * qa)
 
 
-def step(params, target_params, batch: Dict, weights, hp: Hyper) -> Dict:
-    """Loss and gradient of one learner step: Q-values of ``obs``, the mean
+def step(params, target_params, batch: Dict, hp: Hyper) -> Dict:
+    """Loss and gradient of one learner step on a batch as ``seeded_batch``
+    lays it out (its ``weights`` among the rows): Q-values of ``obs``, the mean
     weighted Huber loss, per-row |TD| (the priorities), the gradient's
     global norm, the gradient as the optimizer takes it (clipped to
     ``max_grad_norm``), and ``grad_scale``: the norm that gradient would
     have if no two rows' TD errors cancelled — the yardstick for an error of
     the gradient, which its own norm is not where the rows cancel."""
+    weights = batch["weights"]
+    batch = {k: v for k, v in batch.items() if k != "weights"}
     rows = batch["action"].shape[0]
     block = max(b for b in range(1, ROW_BLOCK + 1) if rows % b == 0)
     blocks = jax.tree.map(
@@ -157,30 +162,11 @@ def step(params, target_params, batch: Dict, weights, hp: Hyper) -> Dict:
             one_block, (jnp.float32(0.0), zeros, zeros), blocks)
         loss = loss / rows
         grads = jax.tree.map(lambda g: g / rows, grads)
-        norm = _global_norm(grads)
-        scale = 1.0
-        if hp.max_grad_norm:
-            scale = jnp.where(norm < hp.max_grad_norm, 1.0,
-                              hp.max_grad_norm / norm)
-            grads = jax.tree.map(lambda g: g * scale, grads)
+        grads, norm, scale = clip_by_global_norm(grads, hp.max_grad_norm)
     return {"q": q.reshape((rows,) + q.shape[2:]), "loss": loss,
             "priorities": abs_td.reshape(rows), "grad_norm": norm,
             "grads": grads,
-            "grad_scale": scale * _global_norm(one_way) / rows}
-
-
-def adam_delta(grads, adam_mu, adam_nu, adam_count, hp: Hyper):
-    """The parameter change Adam makes from moments ``(mu, nu)`` after
-    ``count`` steps when handed ``grads`` (already clipped)."""
-    count = adam_count.astype(jnp.float32) + 1.0
-    mu = jax.tree.map(lambda m, g: ADAM_B1 * m + (1 - ADAM_B1) * g,
-                      adam_mu, grads)
-    nu = jax.tree.map(lambda v, g: ADAM_B2 * v + (1 - ADAM_B2) * g * g,
-                      adam_nu, grads)
-    return jax.tree.map(
-        lambda m, v: -hp.learning_rate * (m / (1 - ADAM_B1 ** count))
-        / (jnp.sqrt(v / (1 - ADAM_B2 ** count)) + hp.adam_eps),
-        mu, nu)
+            "grad_scale": scale * global_norm(one_way) / rows}
 
 
 def hyper_from_config(cfg) -> Hyper:
@@ -202,3 +188,82 @@ def hyper_from_config(cfg) -> Hyper:
                  learning_rate=float(learner.learning_rate),
                  adam_eps=float(learner.adam_eps),
                  max_grad_norm=float(learner.max_grad_norm))
+
+
+# -- what the harness asks of a reference module, beside the step -----------
+
+def make_program(cfg, env, net):
+    """The program's side of the comparison: ``init(key)`` and
+    ``train_step(state, batch)`` of the learner ``train.train`` builds for
+    this configuration (``agents/dqn.py make_learner``), and ``q_of(params,
+    batch)``, the program's Q-values of ``obs``. ``batch`` is a
+    ``seeded_batch``."""
+    from dist_dqn_tpu.agents.dqn import make_learner
+    from dist_dqn_tpu.types import Transition
+
+    init, train_step = make_learner(net, cfg.learner)
+    # made inside the caller's trace: a constant of its program, not a
+    # buffer that stays on the device
+    def example():
+        return jnp.zeros(tuple(env.observation_shape),
+                         np.dtype(env.observation_dtype))
+
+    def program_step(state, batch):
+        rows = {k: v for k, v in batch.items() if k != "weights"}
+        return train_step(state, Transition(**rows), batch["weights"])
+
+    return (lambda key: init(key, example()), program_step,
+            lambda params, batch: net.apply(params, batch["obs"]))
+
+
+def seeded_batch(seed: int, index: int, batch_size: int, cfg, env
+                 ) -> Dict[str, np.ndarray]:
+    """Batch ``index`` of ``seed`` in the learner's own layout: n-step
+    ``reward``, ``discount = gamma**n * (1 - done)``, importance ``weights``
+    in (0, 1] where the configuration samples by priority, ones elsewhere."""
+    rng = np.random.default_rng([seed, index])
+    obs_shape = tuple(env.observation_shape)
+    gamma_n = cfg.learner.gamma ** cfg.learner.n_step
+
+    def frames():
+        if np.dtype(env.observation_dtype) == np.uint8:
+            return rng.integers(0, 256, (batch_size, *obs_shape),
+                                dtype=np.uint8)
+        return rng.standard_normal((batch_size, *obs_shape)).astype(
+            np.float32)
+
+    return {
+        "obs": frames(),
+        "next_obs": frames(),
+        "action": rng.integers(0, env.num_actions, batch_size).astype(
+            np.int32),
+        # One sign and larger than a fresh network's Q-values, so that the
+        # rows' TD errors share a sign and their gradients add up: a sum
+        # that cancels is small against its own rounding noise, and its
+        # relative error says little. On both sides of huber_delta = 1.
+        "reward": rng.choice([0.5, 1.0, 1.5, 2.0],
+                             batch_size).astype(np.float32),
+        "discount": (gamma_n * (rng.random(batch_size) > 0.05)).astype(
+            np.float32),
+        "weights": (rng.uniform(0.2, 1.0, batch_size)
+                    if cfg.replay.prioritized
+                    else np.ones(batch_size)).astype(np.float32),
+    }
+
+
+def grad_step_flops(cfg, env) -> float:
+    """FLOPs one grad step of this configuration requires, from its shapes
+    (``perf/reduce/flops.py``: the Nature CNN on ``batch_size`` transitions,
+    whole mesh)."""
+    from perf.reduce import flops
+
+    if cfg.network.torso not in CONVS:
+        raise NotImplementedError(
+            f"dqn_float32 counts {sorted(CONVS)} torsos, not "
+            f"{cfg.network.torso!r}")
+    return flops.grad_step_flops(
+        cfg.learner.batch_size, obs_shape=tuple(env.observation_shape),
+        convs=CONVS[cfg.network.torso], hidden=cfg.network.hidden,
+        num_actions=env.num_actions,
+        dueling=bool(cfg.network.dueling),
+        double_dqn=bool(cfg.learner.double_dqn))

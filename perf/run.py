@@ -36,6 +36,10 @@ def main(argv=None) -> int:
     parser.add_argument("--dump-trace", default=None,
                         help="with --trace 1: keep the device planes of the "
                              "trace as .json.gz (how perf/testdata was made)")
+    parser.add_argument("--dump-hlo", default=None,
+                        help="also write the chunk program's optimized HLO "
+                             "text as .txt.gz (the stage table's source; "
+                             "how perf/testdata was made)")
     args = parser.parse_args(argv)
 
     from perf.harness import run_cell

@@ -46,7 +46,7 @@ def _check(setup, seed=11, net=None):
 def test_reference_agrees_with_the_programs_learner(case, compute_dtype):
     result = _check(_setup(*CASES[case], compute_dtype))
     assert result["ok"], result
-    assert result["tolerances"] == reference_check.TOLERANCES[compute_dtype]
+    assert result["tolerances"] == dqn_float32.TOLERANCES[compute_dtype]
 
 
 def test_the_result_is_a_function_of_the_seed_alone():
@@ -72,9 +72,9 @@ def test_a_wrong_formula_fails_the_comparison(wrong, monkeypatch):
 
     real_hyper, real_step = dqn_float32.hyper_from_config, dqn_float32.step
     if wrong == "weights":
-        def step(params, target, batch, weights, hp):
-            return real_step(params, target, batch, jnp.ones_like(weights),
-                             hp)
+        def step(params, target, batch, hp):
+            return real_step(params, target, dict(
+                batch, weights=jnp.ones_like(batch["weights"])), hp)
         monkeypatch.setattr(dqn_float32, "step", step)
     else:
         def hyper(c):
@@ -90,31 +90,12 @@ def test_a_wrong_formula_fails_the_comparison(wrong, monkeypatch):
         assert result["errors"]["optimizer"] > 0.3
 
 
-class _CoarseNet:
-    """The program's network computing from parameters rounded through
-    float8 (e4m3: 4 significant bits against bfloat16's 8)."""
-
-    def __init__(self, net):
-        self._net = net
-
-    def __getattr__(self, name):
-        return getattr(self._net, name)
-
-    def apply(self, params, *args, **kwargs):
-        import jax
-        import jax.numpy as jnp
-
-        coarse = jax.tree.map(
-            lambda x: x.astype(jnp.float8_e4m3fn).astype(x.dtype), params)
-        return self._net.apply(coarse, *args, **kwargs)
-
-
 def test_a_lower_precision_than_bf16_fails():
     """The bf16 tolerances tell bf16 from a coarser type: the program's side
     in float8-rounded weights is outside them, by a wide margin."""
     setup = _setup(True, True, True, "bfloat16")
     fine = _check(setup)
-    coarse = _check(setup, net=_CoarseNet(setup[2]))
+    coarse = _check(setup, net=reference_check.CoarseNet(setup[2]))
     assert fine["ok"] and not coarse["ok"], coarse
     assert coarse["errors"]["q"] > 4 * fine["errors"]["q"]
     assert coarse["errors"]["q"] > fine["tolerances"]["q"]
@@ -130,9 +111,21 @@ def test_hyper_refuses_what_the_reference_does_not_compute():
 
 
 def test_reference_imports_nothing_of_the_program():
+    """The arithmetic imports nothing of the program; ``make_program`` and,
+    where a module has one, ``make_further_check`` alone name it (the
+    learner and the replay this reference stands beside), inside their
+    bodies."""
     import inspect
 
-    source = inspect.getsource(dqn_float32)
-    assert "dist_dqn_tpu" not in source.replace(
-        "``dist_dqn_tpu``", "").replace("nothing from\n``dist_dqn_tpu", "")
-    assert "import flax" not in source and "import optax" not in source
+    from perf.reference import plain, r2d2_float32, sequence_ring
+
+    for module in (dqn_float32, r2d2_float32, plain, sequence_ring):
+        source = inspect.getsource(module)
+        for name in ("make_program", "make_further_check"):
+            if hasattr(module, name):
+                source = source.replace(
+                    inspect.getsource(getattr(module, name)), "")
+        assert "dist_dqn_tpu" not in source.replace(
+            "``dist_dqn_tpu``", "").replace(
+            "nothing from\n``dist_dqn_tpu", ""), module.__name__
+        assert "import flax" not in source and "import optax" not in source

@@ -12,20 +12,19 @@ from dist_dqn_tpu.telemetry import flight, stages
 from perf.harness.manifest import Manifest
 from perf.metrics import _stages
 from perf.reduce import trace_reduce as tr
+from perf.reduce.flops import grad_step_flops
 from perf.reduce import xplane
 
 TESTDATA = Path(__file__).resolve().parents[1] / "testdata"
 READ = Manifest(TESTDATA.parents[1]).metric_reader
-_BASE = {"chips": 1, "obs_shape": [84, 84, 4], "hidden": 512,
-         "num_actions": 6, "double_dqn": True,
-         "device": {"kind": "TPU v5 lite"}}
+_BASE = {"chips": 1, "device": {"kind": "TPU v5 lite"}}
 CELLS = {
     "atari": ("atari_preset_2x40iters", "atari_preset_v5e", dict(
         _BASE, traced_chunks=2, chunk_iters=40, grad_steps_per_chunk=10,
-        batch_size=256, dueling=False)),
+        grad_step_flops=grad_step_flops(256, dueling=False))),
     "apex": ("apex_preset_2x20iters", "apex_preset_v5e", dict(
         _BASE, traced_chunks=2, chunk_iters=20, grad_steps_per_chunk=20,
-        batch_size=512, dueling=True)),
+        grad_step_flops=grad_step_flops(512, dueling=True))),
 }
 PER_ITER = ["act_ms_per_iter", "env_ms_per_iter", "insert_ms_per_iter"]
 PER_GRAD_STEP = ["sample_ms_per_grad_step", "gather_ms_per_grad_step",
@@ -160,6 +159,77 @@ def test_hand_written_table_is_joined_by_instruction_name(recorded,
     use_table({a: "env", b: stages.MIXED})
     assert READ("stage_unattributed_share")(run, trace) == pytest.approx(
         100.0 * (1.0 - by_inst[a] / sum(by_inst.values())))
+
+
+# -- the recurrent loop (PR 29): split without names --------------------------
+R2D2 = ("r2d2_preset_2x2iters", "r2d2_preset_v5e", dict(
+    _BASE, traced_chunks=2, chunk_iters=2, grad_steps_per_chunk=2,
+    grad_step_flops=579525672960.0, rates={"grad_steps_per_s": 42.46}))
+
+
+@pytest.fixture(scope="module")
+def recorded_r2d2():
+    """``r2d2.preset`` at 2 iterations a chunk (a traffic file in a copy of
+    the root, ``perf/README.md``): the reduced trace, the program's stage
+    table and the run record."""
+    trace, hlo, run = R2D2
+    with gzip.open(TESTDATA / f"{hlo}.hlo.txt.gz", "rt") as f:
+        table = stages.table_from_text(f.read())
+    return (tr.reduce(xplane.read_dump(TESTDATA / f"{trace}.json.gz"),
+                      chips=1), table, run)
+
+
+def test_recurrent_chunk_is_split_at_its_train_conditional(recorded_r2d2,
+                                                           use_table):
+    """The metrics that need no stage name read the sequence loop as they
+    read the fused one: the outermost ``while`` is the scan over iterations,
+    the ``conditional`` with most time in it the sequence train step (the
+    LSTM's own ``while`` loops lie inside it), the ring's layout copies
+    stand outside the loop."""
+    trace, table, run = recorded_r2d2
+    use_table(table)
+    v = _read_all(["collect_ms_per_iter", "learn_ms_per_grad_step",
+                   "chunk_outside_loop_ms", "loop_gap_share",
+                   "device_idle_share", "train_mfu"], run, trace)
+    assert v["collect_ms_per_iter"] == pytest.approx(0.139596, rel=1e-5)
+    assert v["learn_ms_per_grad_step"] == pytest.approx(22.4440988, rel=1e-6)
+    assert v["chunk_outside_loop_ms"] == pytest.approx(34.9300345, rel=1e-6)
+    assert v["loop_gap_share"] == pytest.approx(0.311052, rel=1e-4)
+    assert v["device_idle_share"] == pytest.approx(2.62302, rel=1e-4)
+    # 0.5795 TFLOP a grad step x 42.46 steps/s over 197 TFLOP/s
+    assert v["train_mfu"] == pytest.approx(12.49, abs=0.01)
+    d = trace.devices[0]
+    assert d.train_conditional()[1] == 4          # one run an iteration
+    # loop + what stands outside it is the chunk program, to a hundredth
+    chunk_ms = 1e3 * sum(m.duration for m in d.chunks) * tr.NS / 2
+    inside = 2 * (v["collect_ms_per_iter"] + v["learn_ms_per_grad_step"])
+    assert inside + v["chunk_outside_loop_ms"] == pytest.approx(chunk_ms,
+                                                                rel=0.01)
+
+
+def test_recurrent_program_enters_no_stage_names_yet(recorded_r2d2,
+                                                     use_table):
+    """``r2d2_loop.py`` and ``agents/r2d2.py`` enter no stage: the table of
+    the compiled program holds one name, ``gather`` — the XLA primitive's
+    own name on the op path of every indexed read, which the table's rule
+    takes for the stage — most of the loop stays under no stage, and the
+    instrument says so itself. That is why the metrics that need a stage
+    name leave this cell out of their ``workloads`` lists until a PR that
+    may touch the program enters the names; the ones that need none (the
+    loop's gaps from the trace, the dispatch span from the flight ring) are
+    reported here as in every cell."""
+    trace, table, run = recorded_r2d2
+    use_table(table)
+    assert set(table.values()) <= {"gather"}
+    assert READ("stage_unattributed_share")(run, trace) > 50.0
+    v = _read_all(STAGE_METRICS + ["sampler_kernel_ms_per_draw"], run, trace)
+    assert [n for n in v if v[n] is not None] == ["gather_ms_per_grad_step"]
+    per_layer = Manifest(TESTDATA.parents[1]).metrics_of("per_layer",
+                                                         "r2d2.preset")
+    listed = {m["name"] for m in per_layer}
+    assert not listed & set(STAGE_METRICS + ["stage_unattributed_share"])
+    assert {"loop_gap_share", "chunk_dispatch_ms",
+            "chunk_dispatch_worst_ms"} <= listed
 
 
 # -- the host span ---------------------------------------------------------

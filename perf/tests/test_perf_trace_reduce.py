@@ -212,8 +212,7 @@ def test_readers_of_the_record_take_what_the_harness_computed():
            "memory_stats": {"peak_bytes_in_use": 5_701_156_352,
                             "peak_bytes_reserved": 5_665_521_664},
            "rates": {"grad_steps_per_s": 690.0}, "chips": 1,
-           "batch_size": 256, "obs_shape": [84, 84, 4], "hidden": 512,
-           "num_actions": 6, "dueling": False, "double_dqn": True,
+           "grad_step_flops": 256 * 86.9e6,
            "device": {"kind": "TPU v5 lite"}}
     assert read("chunk_host_gap_ms")(run, None) == pytest.approx(2.0)
     assert read("chunk_wall_ms")(run, None) == pytest.approx(898.0)

@@ -28,7 +28,9 @@ def main(cells) -> int:
     from dist_dqn_tpu import loop_common
     from dist_dqn_tpu.envs import make_jax_env
     from dist_dqn_tpu.models import build_network
-    from dist_dqn_tpu.parallel import make_mesh, make_mesh_fused_train
+    from dist_dqn_tpu.parallel import (make_mesh, make_mesh_fused_train,
+                                       make_mesh_r2d2_train)
+    from dist_dqn_tpu.r2d2_loop import make_r2d2_train
     from dist_dqn_tpu.train_loop import make_fused_train
     from perf.harness.manifest import Manifest, resolve_cell
     from perf.harness.run_cell import build_config
@@ -46,8 +48,13 @@ def main(cells) -> int:
         cfg = build_config(plan)
         env = make_jax_env(cfg.env_name)
         net = build_network(cfg.network, env.num_actions)
+        # train.train's own routing: a recurrent network takes the
+        # sequence loop.
+        one_chip, mesh_train = (
+            (make_r2d2_train, make_mesh_r2d2_train) if cfg.network.lstm_size
+            else (make_fused_train, make_mesh_fused_train))
         if plan["num_devices"] == 1:
-            init, run_chunk = make_fused_train(cfg, env, net)
+            init, run_chunk = one_chip(cfg, env, net)
             one = SingleDeviceSharding(topo.devices[0])
             carry = jax.tree.map(
                 lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one),
@@ -55,7 +62,7 @@ def main(cells) -> int:
             run = jax.jit(run_chunk, static_argnums=1, donate_argnums=0)
         else:
             mesh = make_mesh(devices=topo.devices[:plan["num_devices"]])
-            init, run = make_mesh_fused_train(cfg, env, net, mesh)
+            init, run = mesh_train(cfg, env, net, mesh)
             carry = init.lower(key).compile().output_shardings
             shapes = jax.eval_shape(init, key)
             carry = jax.tree.map(

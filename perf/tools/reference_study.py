@@ -26,6 +26,12 @@ def main() -> int:
     p.add_argument("--seeds", type=int, default=64)
     p.add_argument("--seed-base", type=int, default=0)
     p.add_argument("--out", type=Path, required=True)
+    p.add_argument("--cells", nargs="*", default=None,
+                   help="only the checks of these cells (default: all)")
+    p.add_argument("--control", type=int, default=0, metavar="N",
+                   help="also draw N seeds of the control: the program's "
+                        "network on float8-rounded weights "
+                        "(reference_check.CoarseNet), which has to fail")
     p.add_argument("--allow-cpu", action="store_true")
     args = p.parse_args()
 
@@ -48,6 +54,8 @@ def main() -> int:
     manifest = Manifest(CHECKOUT)
     checks = {}
     for cell in manifest.data["workloads"]:
+        if args.cells is not None and cell["name"] not in args.cells:
+            continue
         plan = resolve_cell(manifest, cell["name"])
         cfg = build_config(plan)
         rows = cfg.learner.batch_size // plan["num_devices"]
@@ -60,10 +68,16 @@ def main() -> int:
     for (config, reference, *_), (cfg, rows, cells) in checks.items():
         env = make_jax_env(cfg.env_name)
         t0 = time.perf_counter()
-        check = reference_check.make_check(
-            manifest.reference(reference), cfg, env,
-            build_network(cfg.network, env.num_actions), rows)
+        net = build_network(cfg.network, env.num_actions)
+        module = manifest.reference(reference)
+        check = reference_check.make_check(module, cfg, env, net, rows)
         results = [check(args.seed_base + i) for i in range(args.seeds)]
+        control = []
+        if args.control:
+            coarse = reference_check.make_check(
+                module, cfg, env, reference_check.CoarseNet(net), rows)
+            control = [coarse(args.seed_base + i)
+                       for i in range(args.control)]
         study = {
             "config": config, "rows": rows, "cells": cells,
             "compute_dtype": cfg.network.compute_dtype,
@@ -75,11 +89,18 @@ def main() -> int:
             "errors": {k: [r["errors"][k] for r in results]
                        for k in results[0]["errors"]},
             "also": {k: [r["also"][k] for r in results]
-                     for k in results[0]["also"]}}
+                     for k in results[0]["also"]},
+            "control_any_ok": any(r["ok"] for r in control),
+            "control_errors": {k: [r["errors"][k] for r in control]
+                               for k in results[0]["errors"]}}
         out["studies"].append(study)
         print(json.dumps({
             "config": config, "rows": rows, "cells": cells,
             "all_ok": study["all_ok"],
+            "control_any_ok": study["control_any_ok"],
+            "control_min_median_max": {
+                k: [min(v), median(v), max(v)]
+                for k, v in study["control_errors"].items() if v},
             "first_check_s": study["first_check_s"],
             "median_check_s": study["median_check_s"],
             "min_median_max": {
