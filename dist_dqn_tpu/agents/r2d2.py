@@ -72,13 +72,27 @@ def make_r2d2_learner(net, cfg: LearnerConfig, rcfg: ReplayConfig,
         """Burn in (stop-grad) then unroll the loss+bootstrap region.
 
         Returns q over steps [burn, burn+unroll+n): [unroll+n, S, A].
+
+        The two regions are row ranges of the FLAT ``[L*B, ...]`` batch,
+        handed on in the ``[T, B, ...]`` shape ``net.unroll`` flattens
+        again: the same values as ``obs[:burn]`` / ``obs[burn:]``, but the
+        torso then reads the sampler's batch-minor stacks where they were
+        written — a slice on the time axis of ``[L, B, ...]`` put a cast
+        and two relayouts of every frame between them (PERF.md, PR 31).
         """
+        obs = sample.obs
+        B = obs.shape[1]
+        flat = obs.reshape((-1,) + obs.shape[2:])
+
+        def steps(lo, hi):
+            return flat[lo * B:hi * B].reshape((hi - lo,) + obs.shape[1:])
+
         carry = sample.start_state
         if burn:
-            carry, _ = net.apply(params, carry, sample.obs[:burn],
+            carry, _ = net.apply(params, carry, steps(0, burn),
                                  sample.reset[:burn], method=net.unroll)
             carry = jax.lax.stop_gradient(carry)
-        _, q = net.apply(params, carry, sample.obs[burn:],
+        _, q = net.apply(params, carry, steps(burn, obs.shape[0]),
                          sample.reset[burn:], method=net.unroll)
         return q
 

@@ -92,8 +92,10 @@ class ReplayConfig:
     # pixel window cap lifts from ~200k to ~1M transitions. Requires the
     # env to declare the rolling-stack contract (JaxEnv.frame_stack > 0)
     # and store_final_obs off. Covers BOTH fused loops: the feedforward
-    # ring (replay/device.py) and the R2D2 sequence ring
-    # (replay/sequence_device.py _rebuild_seq_stacks).
+    # ring (replay/device.py gather_transitions: one row gather, one
+    # transpose) and the R2D2 sequence ring (replay/sequence_device.py
+    # _rebuild_seq_stacks: one window gather, the four channels of a
+    # pixel packed into a word and transposed).
     frame_dedup: bool = False
     # On-device replay ratio (ISSUE 6, --replay-ratio): grad sub-steps
     # per train event, each drawing an INDEPENDENT replay batch from a

@@ -82,9 +82,11 @@ def make_r2d2_train(cfg: ExperimentConfig, env: JaxEnv, net,
             f"seq_len+stride={seq_len + stride}; raise replay.capacity")
 
     # Frame-dedup (replay.frame_dedup): the sequence ring stores single
-    # frames and the sampler rebuilds [L, S, H, W, stack] stacks — same
-    # 4x HBM saving and exactness contract as the feedforward ring
-    # (replay/sequence_device.py _rebuild_seq_stacks).
+    # frames and the sampler rebuilds [L, B, H, W, stack] stacks — same
+    # 4x HBM saving and exactness contract as the feedforward ring. Each
+    # frame is gathered once and the stack assembled batch-minor, as the
+    # learner's first convolution reads it (replay/sequence_device.py
+    # _rebuild_seq_stacks; agents/r2d2.py slices its regions off that).
     _obs_shape = tuple(env.observation_shape)
     stack, _stored_shape, _frame_shape, _slice_newest = \
         loop_common.resolve_frame_dedup(rcfg, env, _obs_shape)

@@ -20,6 +20,7 @@ learner, agents/r2d2.py); stored raw with alpha applied at sample time.
 """
 from __future__ import annotations
 
+import functools
 from typing import NamedTuple, Tuple
 
 import jax
@@ -113,46 +114,76 @@ def _gather_seq(field: Array, t_idx: Array, b_idx: Array, L: int,
 def _rebuild_seq_stacks(r: ring.TimeRingState, t_idx: Array, b_idx: Array,
                         seq_len: int, frame_stack: int,
                         merge_obs_rows: bool, frame_shape) -> PyTree:
-    """[L, S, ..., frame_stack] stacks for every window position, from a
-    dedup ring (single stored frames — replay/device.py semantics).
+    """[L, B, ..., frame_stack] stacks for every window position, from a
+    dedup ring (single stored ``[..., 1]`` frames — replay/device.py
+    semantics).
 
-    One extended gather of ``seq_len + frame_stack - 1`` frames (offsets
-    -(S-1)..L-1) covers every position's context; each position's
-    channels then index into it with the same ``min(d, age)`` clamp as
-    ``device.stack_rebuild_indices`` (reset re-tiling). Callers mask out
-    window starts whose context predates the ring (sequence_ring_sample).
+    Every frame is read from the ring ONCE: one extended gather of
+    ``E = seq_len + frame_stack - 1`` frames a window (offsets
+    -(S-1)..L-1) as flat time-major ``[E*B, H*W]`` rows. Position ``i``'s
+    channel with lookback ``d`` is ext frame ``i + (S-1) - min(d, age_i)``
+    — the ``min(d, age)`` clamp of ``device.stack_rebuild_indices`` (reset
+    re-tiling). Unclamped that is a ROW SLICE, ``L*B`` rows from
+    ``(S-1-d)*B``; and since ``min(d, age)`` is ``d`` where ``age >= d``
+    and ``min(d-1, age)`` elsewhere, each channel is the previous one with
+    its own slice selected in where ``age >= d``: S-1 selects, no second
+    gather.
+
+    Four uint8 channels of a pixel are then packed into one uint32 word,
+    the words transposed to ``[H*W, L*B]`` and split back into bytes:
+    byte for byte that IS the batch-minor ``[L*B, H, W, S]`` array the
+    first convolution reads on TPU (its tile holds the S channels of 128
+    frames, four bytes a word), so the stack costs three 32-bit passes at
+    the HBM's speed and the learner slices its burn-in and unroll regions
+    off the flat batch (agents/r2d2.py) — where per-lookback
+    ``take_along_axis`` passes over ``[E, B, H, W, 1]`` and a concatenate
+    on the size-1 minor axis cost four frame gathers, four byte-wise frame
+    copies, two casts and two relayouts a network pass (PERF.md, PR 31).
+    Any other depth or dtype stacks the channels as they are: exact, and
+    as slow as a byte-wise relayout is. Callers mask out window starts
+    whose context predates the ring (sequence_ring_sample).
     """
     num_slots, num_envs = r.action.shape
     S = frame_stack
     L = seq_len
-    ext_offs = jnp.arange(-(S - 1), L, dtype=jnp.int32)        # [L+S-1]
-    tt = (t_idx[None, :] + ext_offs[:, None]) % num_slots      # [E, S_]
-
-    def gather_ext(x):
-        if merge_obs_rows:
-            out = x[tt * num_envs + b_idx[None, :]]
-            return out.reshape(out.shape[:2] + tuple(frame_shape))
-        return x[tt, b_idx[None, :]]
-
+    batch = t_idx.shape[0]
+    ext_offs = jnp.arange(-(S - 1), L, dtype=jnp.int32)        # [E]
+    tt = (t_idx[None, :] + ext_offs[:, None]) % num_slots      # [E, B]
     done_ext = jnp.logical_or(r.terminated, r.truncated)[
-        tt, b_idx[None, :]]                                    # [E, S_]
+        tt, b_idx[None, :]]                                    # [E, B]
     # age[i] = distance-1 to the nearest done among positions i-1..i-(S-1)
     # (window position i lives at ext index i + S - 1).
-    batch = t_idx.shape[0]
     age = jnp.full((L, batch), S - 1, jnp.int32)
     for j in range(S - 1, 0, -1):   # descending: the nearest done wins
         # done at position i-j = ext index i + S - 1 - j.
         age = jnp.where(done_ext[S - 1 - j:S - 1 - j + L], j - 1, age)
+    age = age.reshape(L * batch, 1)
 
     def rebuild(x):
-        ext = gather_ext(x)                                    # [E, S_, ...]
-        pos = jnp.arange(L, dtype=jnp.int32)[:, None]          # [L, 1]
-        chans = []
-        for d in range(S - 1, -1, -1):                         # oldest first
-            idx = pos + (S - 1) - jnp.minimum(d, age)          # [L, S_]
-            idx = idx.reshape(idx.shape + (1,) * (ext.ndim - 2))
-            chans.append(jnp.take_along_axis(ext, idx, axis=0))
-        return jnp.concatenate(chans, axis=-1)
+        if merge_obs_rows:
+            shape = tuple(frame_shape)
+            rows = x[(tt * num_envs + b_idx[None, :]).reshape(-1)]
+        else:
+            shape = x.shape[2:]
+            rows = x[tt, b_idx[None, :]].reshape(tt.size, -1)
+        chans = []                                             # newest first
+        for d in range(S):
+            lo = (S - 1 - d) * batch
+            shift = rows[lo:lo + L * batch]
+            chans.append(shift if d == 0
+                         else jnp.where(age >= d, shift, chans[-1]))
+        chans.reverse()                                        # oldest first
+        if S == 4 and rows.dtype == jnp.uint8 and shape[-1] == 1:
+            word = functools.reduce(jnp.bitwise_or, (
+                c.astype(jnp.uint32) << (8 * k)    # channel k = byte k
+                for k, c in enumerate(chans)))                 # [L*B, H*W]
+            stack = jax.lax.bitcast_convert_type(word.T, jnp.uint8)
+            stack = jnp.moveaxis(
+                stack.reshape(shape[:-1] + (L * batch, S)), -2, 0)
+        else:
+            stack = jnp.concatenate(
+                [c.reshape((L * batch,) + shape) for c in chans], axis=-1)
+        return stack.reshape((L, batch) + stack.shape[1:])
 
     return jax.tree.map(rebuild, r.obs)
 
@@ -171,9 +202,12 @@ def sequence_ring_sample(state: SequenceRingState, rng: Array,
     Pallas kernel routing (ops/pallas_sampler.py) for large planes on TPU.
 
     ``frame_stack=S > 0``: the ring stores single frames (dedup) and the
-    returned obs are rebuilt [L, S_, ..., S] stacks; starts whose
-    rebuild context predates the stored region (the oldest S-1 slots)
-    are masked out of the draw.
+    returned obs are rebuilt stacks — the logical ``[L, B, ..., S]`` array
+    of the stacked ring, byte for byte, assembled from ONE gather of each
+    frame in the batch-minor layout the learner's first convolution reads
+    (``_rebuild_seq_stacks``, looked up at call time so that a check can
+    put a broken one in its place); starts whose rebuild context predates
+    the stored region (the oldest S-1 slots) are masked out of the draw.
     """
     from dist_dqn_tpu.ops.pallas_sampler import (importance_weights,
                                                  stratified_sample)

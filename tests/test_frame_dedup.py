@@ -4,6 +4,7 @@ including reset-boundary re-tiling, ring wrap-around, both storage
 layouts, and the prioritized plane (VERDICT round-4 next #2: the 4x HBM
 saving that lifts the v5e pixel window toward 1M transitions)."""
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -269,6 +270,160 @@ def test_sequence_dedup_rebuild_matches_stacked(merge, steps):
     got = sring._rebuild_seq_stacks(dd.ring, t_idx, b_idx, L, S,
                                     merge, (H, W, 1))
     np.testing.assert_array_equal(np.asarray(want), np.asarray(got))
+
+
+# The hand-placed stream of the sequence-rebuild cases: 44 steps into 32
+# slots (steps 12..43 stored, step t in slot t % 32), windows of L = 6. A
+# window wraps the ring's end when it starts at steps 27..31. One episode
+# end a lane, so that a window sees the one it asks for: lane 0 has none.
+_SEQ_L, _SEQ_SLOTS, _SEQ_STEPS = 6, 32, 44
+_SEQ_END = {("inside", False): (1, 20), ("before", False): (2, 20),
+            ("inside", True): (3, 31), ("before", True): (4, 27)}
+
+
+@functools.lru_cache(maxsize=None)
+def _seq_rings(merge, dtype=np.uint8):
+    """(stream's done flags, stacked ring, dedup ring) of that stream."""
+    from dist_dqn_tpu.replay import sequence_device as sring
+
+    rng = np.random.default_rng(11)
+    lanes = 1 + len(_SEQ_END)
+    done = np.zeros((_SEQ_STEPS, lanes), bool)
+    for lane, step in _SEQ_END.values():
+        done[step, lane] = True
+    obs, action, reward, term, trunc = _rolling_stream(rng, _SEQ_STEPS,
+                                                       lanes, done=done)
+    obs = obs.astype(dtype)
+    carry = (jnp.zeros((lanes, 4), jnp.float32),) * 2
+    add = jax.jit(functools.partial(
+        sring.sequence_ring_add, seq_len=_SEQ_L, stride=1,
+        merge_obs_rows=merge))
+
+    def fill(stored):
+        shape = (H * W * stored.shape[-1],) if merge else stored.shape[2:]
+        st = sring.sequence_ring_init(_SEQ_SLOTS, lanes,
+                                      jnp.zeros(shape, dtype), 4,
+                                      merge_obs_rows=merge)
+        for t in range(_SEQ_STEPS):
+            o = stored[t].reshape(lanes, -1) if merge else stored[t]
+            st = add(st, jnp.asarray(o), jnp.asarray(action[t]),
+                     jnp.asarray(reward[t]), jnp.asarray(term[t]),
+                     jnp.asarray(trunc[t]), carry)
+        return st
+
+    return done, fill(obs), fill(obs[..., -1:])
+
+
+def _stacked_windows(full, merge, t_idx, b_idx):
+    """The stacked ring's windows at those starts: what a rebuild owes."""
+    from dist_dqn_tpu.replay import sequence_device as sring
+
+    obs = full.ring.obs
+    if merge:
+        obs = obs.reshape(_SEQ_SLOTS, -1, H, W, S)
+    return sring._gather_seq(obs, t_idx, b_idx, _SEQ_L, _SEQ_SLOTS)
+
+
+@pytest.mark.parametrize("same_lane", [False, True])
+@pytest.mark.parametrize("wrap", [False, True])
+@pytest.mark.parametrize("where", ["inside", "before"])
+@pytest.mark.parametrize("age", [0, 1, 2, None])
+@pytest.mark.parametrize("merge", [False, True])
+def test_sequence_rebuild_at_every_end_position(merge, age, where, wrap,
+                                                same_lane):
+    """The sequence ring's rebuild against the stacked ring's window
+    gather, byte for byte, with the episode end at every distance of the
+    lookback (``age`` 0, 1, 2; None: no end in sight) from the window's
+    LAST position (``inside``) or its FIRST (``before``: the end lies in
+    the context the extended gather fetches), in a window that wraps the
+    ring's end or does not, beside a second window of the same lane (one
+    step on, so the two overlap) or of another."""
+    from dist_dqn_tpu.replay import sequence_device as sring
+
+    done, full, dd = _seq_rings(merge)
+    L, slots = _SEQ_L, _SEQ_SLOTS
+    lane, end = _SEQ_END[(where, wrap)]
+    if age is None:
+        lane, start = 0, (28 if wrap else 20)
+    elif where == "inside":
+        start = end + 1 + age - (L - 1)
+        assert done[start + L - 2 - age, lane]
+    else:
+        start = end + 1 + age
+        assert done[start - 1 - age, lane]
+    assert (start % slots + L > slots) == wrap
+    t_idx = jnp.asarray([start % slots, (start + 1) % slots], jnp.int32)
+    b_idx = jnp.asarray([lane, lane if same_lane else 0], jnp.int32)
+
+    want = _stacked_windows(full, merge, t_idx, b_idx)
+    got = sring._rebuild_seq_stacks(dd.ring, t_idx, b_idx, L, S, merge,
+                                    (H, W, 1))
+    assert got.shape == (L, 2, H, W, S) and got.dtype == jnp.uint8
+    np.testing.assert_array_equal(np.asarray(want), np.asarray(got))
+
+
+@pytest.mark.parametrize("merge", [False, True])
+def test_sequence_rebuild_of_wider_frames_is_exact(merge):
+    """Frames that are not four uint8 to a word take the plain stacking
+    of the same channels: every window start the ring holds, all lanes."""
+    from dist_dqn_tpu.replay import sequence_device as sring
+
+    _, full, dd = _seq_rings(merge, np.uint16)
+    L, slots, lanes = _SEQ_L, _SEQ_SLOTS, 1 + len(_SEQ_END)
+    starts = np.arange(_SEQ_STEPS - slots + S - 1, _SEQ_STEPS - L + 1)
+    t_idx = jnp.asarray(np.repeat(starts % slots, lanes), jnp.int32)
+    b_idx = jnp.asarray(np.tile(np.arange(lanes), len(starts)), jnp.int32)
+    want = _stacked_windows(full, merge, t_idx, b_idx)
+    got = sring._rebuild_seq_stacks(dd.ring, t_idx, b_idx, L, S, merge,
+                                    (H, W, 1))
+    assert got.dtype == jnp.uint16
+    np.testing.assert_array_equal(np.asarray(want), np.asarray(got))
+
+
+@pytest.mark.parametrize("merge", [False, True])
+def test_sequence_ring_sample_dedup_obs_contract(merge):
+    """What the learner and the benchmark's ring comparison read:
+    ``sample.obs`` is the logical ``[L, B, H, W, S]`` uint8 array, and it
+    holds the stacked ring's windows at the starts that were drawn."""
+    from dist_dqn_tpu.replay import sequence_device as sring
+
+    _, full, dd = _seq_rings(merge)
+    L, batch = _SEQ_L, 7
+    s = sring.sequence_ring_sample(
+        dd, jax.random.PRNGKey(4), batch, L, 0.9, jnp.float32(0.6),
+        merge_obs_rows=merge, frame_stack=S, frame_shape=(H, W, 1))
+    assert s.obs.shape == (L, batch, H, W, S) and s.obs.dtype == jnp.uint8
+    np.testing.assert_array_equal(
+        np.asarray(_stacked_windows(full, merge, s.t_idx, s.b_idx)),
+        np.asarray(s.obs))
+
+
+@pytest.mark.parametrize("merge", [False, True])
+def test_sequence_rebuild_reads_each_frame_once(merge):
+    """What carries over from the CPU to the chip is the program's shape
+    (as for ``gather_transitions`` above): the only gather of frames reads
+    the RING, once — no ``take_along_axis`` pass over already gathered
+    frames — and nothing is concatenated on a size-1 minor axis: the two
+    constructs that cost ``r2d2.preset`` four frame gathers and four
+    size-1-minor copies a grad step (PERF.md, PR 31)."""
+    from dist_dqn_tpu.replay import sequence_device as sring
+
+    _, _, dd = _seq_rings(merge)
+    idx = jnp.zeros((5,), jnp.int32)
+    jaxpr = jax.make_jaxpr(
+        lambda r, t, b: sring._rebuild_seq_stacks(
+            r, t, b, _SEQ_L, S, merge, (H, W, 1)))(dd.ring, idx, idx)
+    assert jaxpr.out_avals[0].shape == (_SEQ_L, 5, H, W, S)
+    eqns = list(_eqns(jaxpr.jaxpr))
+    frame_gathers = [e.invars[0].aval.shape for e in eqns
+                     if e.primitive.name == "gather"
+                     and e.invars[0].aval.dtype == jnp.uint8]
+    assert frame_gathers == [dd.ring.obs.shape], frame_gathers
+    minor_concats = [e for e in eqns if e.primitive.name == "concatenate"
+                     and e.invars[0].aval.dtype == jnp.uint8
+                     and e.invars[0].aval.shape[-1] == 1
+                     and e.params["dimension"] == e.invars[0].aval.ndim - 1]
+    assert not minor_concats
 
 
 def test_r2d2_fused_loop_dedup_trains():
