@@ -86,19 +86,11 @@ def _build_eval(cfg: ExperimentConfig, episodes: int, epsilon: float,
     rng = jax.random.PRNGKey(seed)
     rng, k_init, k_eval = jax.random.split(rng, 3)
 
-    if cfg.network.lstm_size:
-        from dist_dqn_tpu.agents.r2d2 import make_r2d2_learner
-        from dist_dqn_tpu.r2d2_loop import make_r2d2_evaluator
-        init, _ = make_r2d2_learner(net, cfg.learner, cfg.replay)
-        evaluator = make_r2d2_evaluator(cfg, env, net,
-                                        num_episodes=episodes,
-                                        epsilon=epsilon)
-    else:
-        from dist_dqn_tpu.agents.dqn import make_learner
-        from dist_dqn_tpu.train_loop import make_evaluator
-        init, _ = make_learner(net, cfg.learner)
-        evaluator = make_evaluator(cfg, env, net, num_episodes=episodes,
-                                   epsilon=epsilon)
+    from dist_dqn_tpu.agents.agent import make_agent
+    from dist_dqn_tpu.train_loop import make_evaluator
+    init = make_agent(net, cfg).init_learner
+    evaluator = make_evaluator(cfg, env, net, num_episodes=episodes,
+                               epsilon=epsilon)
 
     obs_example = jax.numpy.zeros(env.observation_shape,
                                   env.observation_dtype)
@@ -226,27 +218,18 @@ def evaluate_checkpoint_host(cfg: ExperimentConfig, checkpoint_dir: str,
                         for_eval=True)
     net = build_network(cfg.network, env.num_actions)
     obs = env.reset()
-    recurrent = cfg.network.lstm_size > 0
-    if recurrent:
-        from dist_dqn_tpu.agents.r2d2 import (make_r2d2_learner,
-                                              make_recurrent_actor_step)
-        init, _ = make_r2d2_learner(net, cfg.learner, cfg.replay)
-        act = jax.jit(make_recurrent_actor_step(net))
-        carry = net.initial_state(episodes)
-    else:
-        from dist_dqn_tpu.agents.dqn import make_actor_step, make_learner
-        init, _ = make_learner(net, cfg.learner)
-        act = jax.jit(make_actor_step(net))
+    from dist_dqn_tpu.agents.agent import make_agent
+    agent = make_agent(net, cfg)
 
     rng = jax.random.PRNGKey(seed)
     rng, k_init = jax.random.split(rng)
-    example = init(k_init, jax.numpy.asarray(obs[0]))
+    example = agent.init_learner(k_init, jax.numpy.asarray(obs[0]))
     frames, params = _restore_latest(checkpoint_dir, example.params,
                                      step=step, member=member)
 
     returns, truncated, _ = run_greedy_episodes(
-        env, act, params, rng, episodes=episodes,
-        recurrent_carry=carry if recurrent else None, epsilon=epsilon,
+        env, jax.jit(agent.act), params, rng, episodes=episodes,
+        recurrent_carry=agent.initial_state(episodes), epsilon=epsilon,
         max_steps=max_steps)
     out = {"eval_return": float(returns.mean()), "frames": frames,
            "episodes": episodes, "config": cfg.name, "host_env": host_env,

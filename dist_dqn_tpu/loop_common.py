@@ -1,11 +1,9 @@
-"""Scaffolding shared by the fused training loops (train_loop.py, r2d2_loop.py).
-
-Both loops are the same Anakin-style SPMD program — per-device env lanes +
-replay shard, pmean-allreduced learner — differing only in what the carry
-threads (feed-forward vs LSTM state) and which replay/learner pair they
-drive. The schedule construction, per-device rng handling and chunk-metric
-reduction live here exactly once so a fix (e.g. to beta annealing or the
-psum block) cannot silently diverge between the two.
+"""Scaffolding of the fused chunk program (train_loop.py) and of the device
+ring it runs over (replay/device_ring.py): the schedules, the per-device rng
+handling, the chunk-metric reduction, and the rules that size and lay out a
+ring (batch bucket, frame dedup, flat storage, sampler routing). The
+host-replay runtime and the benchmark's reference checks call the same
+rules, so each is written once, here.
 """
 from __future__ import annotations
 
@@ -25,7 +23,7 @@ def resolve_train_batch(cfg: ExperimentConfig) -> int:
 
     ``replay.train_batch == 0`` keeps ``learner.batch_size`` EXACTLY
     (the bit-identity contract for existing configs); > 0 widens the
-    train batch to that many rows — sequences, on the R2D2 loops —
+    train batch to that many rows — sequences, for a recurrent agent —
     rounded up to the next power of two by the SAME ``pad_pow2`` the
     ingest act bucketing uses (replay/host.py), so the two bucket
     policies cannot drift apart. Every runtime's learner resolves
@@ -104,7 +102,6 @@ def resolve_flat_storage(rcfg, obs_shape, obs_dtype, num_slots: int, B: int,
     logical bytes exceed FLAT_AUTO_BYTES, where memory dominates; the
     gather speed of the two layouts is not measured on the current
     installation (PERF.md §7: a mesh shard falls under the threshold).
-    Shared by both fused loops so the rule cannot diverge.
     """
     if rcfg.flat_storage is None:
         if prefer_flat and len(obs_shape) >= 2:
@@ -127,8 +124,8 @@ def flat_obs_codecs(flat_storage: bool, obs_shape):
     insert boundary (identity when tiled). ``unflatten_rows``:
     [..., prod] leaves -> [..., *obs_shape] after a gather —
     rank-agnostic, so the feed-forward [N, prod] batch and the R2D2
-    [L, S, prod] sequence gather share it. Both loops must use these
-    (not local reshapes) so the layout boundary cannot diverge.
+    [L, S, prod] sequence gather share it. Every ring uses these (not
+    local reshapes) so the layout boundary cannot diverge.
     """
     obs_shape = tuple(obs_shape)
 
@@ -167,14 +164,13 @@ def ring_obs_example(obs_example, flat_storage: bool):
 
 def resolve_frame_dedup(rcfg, env, obs_shape,
                         store_final: bool = False):
-    """Validate + resolve ``replay.frame_dedup`` for a fused loop.
+    """Validate + resolve ``replay.frame_dedup`` for a device ring.
 
     Returns (stack, stored_shape, frame_shape, slice_newest): the
     declared rolling-stack depth (0 = dedup off), the per-step shape as
     STORED in the ring (single frame under dedup), the static frame
     shape the merge-rows gather reshapes to (None when off), and the
-    insert-side obs slicer. Shared by train_loop and r2d2_loop so the
-    contract checks cannot diverge."""
+    insert-side obs slicer."""
     obs_shape = tuple(obs_shape)
     stack = rcfg.frame_dedup and getattr(env, "frame_stack", 0) or 0
     if rcfg.frame_dedup:
@@ -277,7 +273,7 @@ def make_rng_splitter(spmd: bool) -> Callable:
 
 def reduce_chunk_metrics(carry, axis_name: Optional[str], B: int,
                          num_shards: int) -> Tuple[Dict, Dict]:
-    """Reduce the chunk accumulators carried by either loop into the global
+    """Reduce the chunk accumulators of the carry into the global
     metrics dict; returns (metrics, zeroed accumulator replacements).
 
     In SPMD mode episode stats are psum-ed (global counts), loss/train

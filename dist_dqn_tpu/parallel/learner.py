@@ -1,7 +1,7 @@
 """Multi-chip fused training: shard_map over the ICI mesh.
 
-Composition of the per-device fused loops (train_loop.py, r2d2_loop.py) into
-the pod-scale program the driver describes (BASELINE.json:5):
+Composition of the per-device fused loop (train_loop.py) into the pod-scale
+program the driver describes (BASELINE.json:5):
 
   * envs + replay shard over the ``dp`` mesh axis — each device rolls out
     its own env lanes and owns one replay shard in its HBM (the TPU-native
@@ -30,64 +30,28 @@ from dist_dqn_tpu.telemetry import get_registry
 from dist_dqn_tpu.agents.dqn import LearnerState
 from dist_dqn_tpu.config import ExperimentConfig
 from dist_dqn_tpu.envs.base import JaxEnv
-from dist_dqn_tpu.replay.device import TimeRingState
-from dist_dqn_tpu.replay.prioritized_device import PrioritizedRingState
-from dist_dqn_tpu.train_loop import TrainCarry, make_fused_train
+from dist_dqn_tpu.train_loop import TrainCarry, fused_parts, \
+    make_fused_train
 
 
-def _ring_spec(axis: str) -> TimeRingState:
-    """Ring leaves are [slots, envs, ...]: env axis 1 sharded."""
-    shard1 = P(None, axis)
-    repl = P()
-    return TimeRingState(
-        obs=shard1, action=shard1, reward=shard1, terminated=shard1,
-        truncated=shard1, final_obs=shard1, pos=repl, size=repl)
-
-
-def _learner_spec() -> LearnerState:
-    repl = P()
-    return LearnerState(params=repl, target_params=repl, opt_state=repl,
-                        steps=repl, rng=repl)
-
-
-def _carry_specs(prioritized: bool, axis: str) -> TrainCarry:
+def _carry_specs(replay_spec, axis: str) -> TrainCarry:
     """Pytree-prefix PartitionSpecs for every TrainCarry field.
 
-    Env-batched leaves shard their env axis; learner state and scalar
-    counters are replicated (kept consistent by pmean/psum inside the body).
+    Env-batched leaves — the actor state's among them, whatever its tree —
+    shard their leading env axis; the ring brings its own specs
+    (replay/device_ring.py); learner state and scalar counters are
+    replicated (kept consistent by pmean/psum inside the body).
     """
     shard0 = P(axis)            # leading env axis
-    shard1 = P(None, axis)
     repl = P()
-    ring_spec = _ring_spec(axis)
-    replay_spec = (PrioritizedRingState(ring=ring_spec, priorities=shard1,
-                                        max_priority=repl)
-                   if prioritized else ring_spec)
     return TrainCarry(
-        env_state=shard0, obs=shard0, replay=replay_spec,
-        learner=_learner_spec(), rng=shard0, iteration=repl,
+        env_state=shard0, obs=shard0, actor_carry=shard0,
+        replay=replay_spec,
+        learner=LearnerState(params=repl, target_params=repl,
+                             opt_state=repl, steps=repl, rng=repl),
+        rng=shard0, iteration=repl,
         ep_return=shard0, completed_return=repl, completed_count=repl,
         loss_sum=repl, train_count=repl)
-
-
-def _r2d2_carry_specs(axis: str) -> "R2D2Carry":
-    """R2D2 carry: same layout story plus the actor LSTM carry ([B, lstm] —
-    env axis sharded) and the stored per-step recurrent-state planes
-    ([T, B, lstm] — env axis 1 sharded)."""
-    from dist_dqn_tpu.r2d2_loop import R2D2Carry
-    from dist_dqn_tpu.replay.sequence_device import SequenceRingState
-
-    shard0 = P(axis)
-    shard1 = P(None, axis)
-    repl = P()
-    replay_spec = SequenceRingState(
-        ring=_ring_spec(axis), state_c=shard1, state_h=shard1,
-        priorities=shard1, max_priority=repl, writes=repl)
-    return R2D2Carry(
-        env_state=shard0, obs=shard0, actor_carry=(shard0, shard0),
-        replay=replay_spec, learner=_learner_spec(), rng=shard0,
-        iteration=repl, ep_return=shard0, completed_return=repl,
-        completed_count=repl, loss_sum=repl, train_count=repl)
 
 
 def _mesh_wrap(mesh: Mesh, specs, init_local, run_local):
@@ -150,23 +114,16 @@ def make_mesh_fused_train(cfg: ExperimentConfig, env: JaxEnv, net,
     (utils/donation.py): ``run`` donates argnum 0 below.
     """
     ndp = mesh.shape[axis]
+    _, replay = fused_parts(cfg, env, net, axis, ndp)
     init_local, run_local = make_fused_train(cfg, env, net, axis_name=axis,
                                              num_shards=ndp)
-    return _mesh_wrap(mesh, _carry_specs(cfg.replay.prioritized, axis),
+    return _mesh_wrap(mesh, _carry_specs(replay.specs(axis), axis),
                       init_local, run_local)
 
 
-def make_mesh_r2d2_train(cfg: ExperimentConfig, env: JaxEnv, net,
-                         mesh: Mesh, axis: str = "dp"):
-    """R2D2 across the mesh: env lanes + sequence-replay shard per device,
-    sequence learner pmean-allreduced — same contract as
-    ``make_mesh_fused_train`` (BASELINE.json:5,10)."""
-    from dist_dqn_tpu.r2d2_loop import make_r2d2_train
-
-    ndp = mesh.shape[axis]
-    init_local, run_local = make_r2d2_train(cfg, env, net, axis_name=axis,
-                                            num_shards=ndp)
-    return _mesh_wrap(mesh, _r2d2_carry_specs(axis), init_local, run_local)
+# Debt D1c (ROADMAP.md): perf/tools/compile_rehearsal.py imports this name;
+# the next `benchmark` PR points it at make_mesh_fused_train and removes it.
+make_mesh_r2d2_train = make_mesh_fused_train
 
 
 def train_step_specs(axis: str, recurrent: bool = False):

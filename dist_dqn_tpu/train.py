@@ -177,30 +177,16 @@ def train(cfg: ExperimentConfig, total_env_steps: int = 0, seed: int = None,
     net = build_network(cfg.network, env.num_actions)
 
     use_mesh = num_devices != 1 or multiprocess
-    mesh = None
     if use_mesh:
-        from dist_dqn_tpu.parallel import (make_mesh, make_mesh_fused_train,
-                                           make_mesh_r2d2_train)
+        from dist_dqn_tpu.parallel import make_mesh, make_mesh_fused_train
         mesh = make_mesh(devices=_pick_mesh_devices(num_devices,
                                                     multiprocess))
-    if cfg.network.lstm_size:
-        from dist_dqn_tpu.r2d2_loop import make_r2d2_evaluator, \
-            make_r2d2_train
-        if use_mesh:
-            init, run = make_mesh_r2d2_train(cfg, env, net, mesh)
-        else:
-            init, run_chunk = make_r2d2_train(cfg, env, net)
-        evaluate = jax.jit(make_r2d2_evaluator(
-            cfg, env, net, num_episodes=cfg.eval_episodes))
+        init, run = make_mesh_fused_train(cfg, env, net, mesh)
     else:
-        if use_mesh:
-            init, run = make_mesh_fused_train(cfg, env, net, mesh)
-        else:
-            init, run_chunk = make_fused_train(cfg, env, net)
-        evaluate = jax.jit(make_evaluator(cfg, env, net,
-                                          num_episodes=cfg.eval_episodes))
-    if not use_mesh:
+        init, run_chunk = make_fused_train(cfg, env, net)
         run = jax.jit(run_chunk, static_argnums=1, donate_argnums=0)
+    evaluate = jax.jit(make_evaluator(cfg, env, net,
+                                      num_episodes=cfg.eval_episodes))
     # Chip-time attribution (ISSUE 19): the fused chunk is ONE program —
     # acting, replay and the grad scan fused into a single dispatch — so
     # it registers with role="train" and execs_per_dispatch=1 (the XLA
@@ -236,6 +222,7 @@ def train(cfg: ExperimentConfig, total_env_steps: int = 0, seed: int = None,
     resumed_frames = 0    # where the loop's cursor actually starts
     if checkpoint_dir:
         from dist_dqn_tpu.utils.checkpoint import (TrainCheckpointer,
+                                                   checkpoint_tree,
                                                    record_checkpoint_kind)
         # The cadence chain must never bottom out at 0 (an explicit
         # --eval-every-steps 0 zeroes the eval period): save_every=0
@@ -250,7 +237,7 @@ def train(cfg: ExperimentConfig, total_env_steps: int = 0, seed: int = None,
         record_checkpoint_kind(checkpoint_dir,
                                "carry" if checkpoint_replay else "learner")
         restored = ckpt.restore_latest(
-            carry if checkpoint_replay else carry.learner)
+            checkpoint_tree(carry, checkpoint_replay))
         if restored is not None:
             # Resume continues toward the SAME total: the frame cursor picks
             # up at the checkpoint step so relaunching the identical command
@@ -269,7 +256,7 @@ def train(cfg: ExperimentConfig, total_env_steps: int = 0, seed: int = None,
                 # The carry's own iteration counter came back with it, so
                 # the cumulative env_frames metric already continues from
                 # the checkpoint — a host-side offset would double-count.
-                carry = tree
+                carry = carry._replace(**tree)
                 frame_offset = 0
             else:
                 carry = carry._replace(learner=tree)
@@ -289,10 +276,9 @@ def train(cfg: ExperimentConfig, total_env_steps: int = 0, seed: int = None,
         def _emergency_save():
             import os
 
-            tree = (_emerg["carry"] if checkpoint_replay
-                    else _emerg["carry"].learner)
             _save_pt(os.path.join(checkpoint_dir, "emergency_learner"),
-                     {"learner": tree})
+                     {"learner": checkpoint_tree(_emerg["carry"],
+                                                 checkpoint_replay)})
 
         tm_watchdog.register_emergency_hook("fused.checkpoint",
                                             _emergency_save)
@@ -309,8 +295,7 @@ def train(cfg: ExperimentConfig, total_env_steps: int = 0, seed: int = None,
     # reported the full cadence's grad steps (past min_fill, every train
     # event taken), so the trace shows the stages and spans as they repeat.
     full_grad_steps = (chunk_iters // cfg.train_every * cfg.updates_per_train
-                       * (1 if cfg.network.lstm_size
-                          else _lc.resolve_replay_ratio(cfg)))
+                       * _lc.resolve_replay_ratio(cfg))
     steady = profiled = False
     # Host spans (utils/trace.py): durations to the flight ring (and, with
     # --trace-path, the Chrome trace + dqn_host_span_seconds); each span is
@@ -431,8 +416,8 @@ def train(cfg: ExperimentConfig, total_env_steps: int = 0, seed: int = None,
                                    else v for k, v in row.items()}))
                 _emerg["frames"], _emerg["carry"] = frames, carry
                 if ckpt is not None:
-                    ckpt.maybe_save(frames, carry if checkpoint_replay
-                                    else carry.learner)
+                    ckpt.maybe_save(
+                        frames, checkpoint_tree(carry, checkpoint_replay))
                 # Early stop (single-process only: a data-dependent exit
                 # would desync multi-process lockstep): stop_fn sees each
                 # metric row — solve-detection for tests, target-return
@@ -454,7 +439,7 @@ def train(cfg: ExperimentConfig, total_env_steps: int = 0, seed: int = None,
         tm_watchdog.unregister_emergency_hook("fused.checkpoint")
         tracer.close()
     if ckpt is not None:
-        ckpt.save(frames, carry if checkpoint_replay else carry.learner)
+        ckpt.save(frames, checkpoint_tree(carry, checkpoint_replay))
         ckpt.close()
     if telemetry_server is not None:
         telemetry_server.close()
@@ -505,16 +490,15 @@ def _train_population(cfg: ExperimentConfig, total_env_steps: int = 0,
             "only for now: the population fills ONE chip by vmap-stacking "
             "members; run one population process per device instead of "
             "--mesh-devices")
-    if cfg.network.lstm_size:
-        raise ValueError(
-            "--population is not supported by the recurrent (R2D2) fused "
-            "loop yet (its sequence learner has no member axis)")
     spec = pop.resolve_spec(cfg)
     hp = pop.member_hp(cfg, spec)
     seed = cfg.seed if seed is None else seed
     total = total_env_steps or cfg.total_env_steps
     env = make_jax_env(cfg.env_name)
     net = build_network(cfg.network, env.num_actions)
+    # Built before any heartbeat or server exists: a combination the one
+    # chunk program refuses (train_loop.fused_parts) leaves nothing behind.
+    init_p, run_population_chunk = pop.make_population_train(cfg, env, net)
 
     _flight = telemetry.get_flight()
     _hb_chunk = tm_watchdog.heartbeat(
@@ -575,7 +559,6 @@ def _train_population(cfg: ExperimentConfig, total_env_steps: int = 0,
     for k in range(M):
         host_rngs[k], k_init = jax.random.split(host_rngs[k])
         k_inits.append(np.asarray(k_init))
-    init_p, run_population_chunk = pop.make_population_train(cfg, env, net)
     carries = init_p(np.stack(k_inits), hp)
     run = jax.jit(run_population_chunk, static_argnums=2, donate_argnums=0)
     evaluate = jax.jit(jax.vmap(make_evaluator(
@@ -593,6 +576,7 @@ def _train_population(cfg: ExperimentConfig, total_env_steps: int = 0,
     resumed_frames = 0
     if checkpoint_dir:
         from dist_dqn_tpu.utils.checkpoint import (TrainCheckpointer,
+                                                   checkpoint_tree,
                                                    record_checkpoint_kind,
                                                    record_population_size)
         ckpt = TrainCheckpointer(
@@ -613,7 +597,7 @@ def _train_population(cfg: ExperimentConfig, total_env_steps: int = 0,
                          {**_fl, "reason": "population"}).inc()
             raise
         restored = ckpt.restore_latest(
-            carries if checkpoint_replay else carries.learner)
+            checkpoint_tree(carries, checkpoint_replay))
         if restored is not None:
             frame_offset, tree = restored
             resumed_frames = frame_offset
@@ -621,7 +605,7 @@ def _train_population(cfg: ExperimentConfig, total_env_steps: int = 0,
                                "with_replay": checkpoint_replay,
                                "population": M}))
             if checkpoint_replay:
-                carries = tree
+                carries = carries._replace(**tree)
                 frame_offset = 0
             else:
                 carries = carries._replace(learner=tree)
@@ -633,10 +617,9 @@ def _train_population(cfg: ExperimentConfig, total_env_steps: int = 0,
         def _emergency_save():
             import os
 
-            tree = (_emerg["carry"] if checkpoint_replay
-                    else _emerg["carry"].learner)
             _save_pt(os.path.join(checkpoint_dir, "emergency_learner"),
-                     {"learner": tree})
+                     {"learner": checkpoint_tree(_emerg["carry"],
+                                                 checkpoint_replay)})
 
         tm_watchdog.register_emergency_hook("population.checkpoint",
                                             _emergency_save)
@@ -747,16 +730,15 @@ def _train_population(cfg: ExperimentConfig, total_env_steps: int = 0,
             log_fn(json.dumps({k: _round(v) for k, v in row.items()}))
             _emerg["frames"], _emerg["carry"] = frames, carries
             if ckpt is not None:
-                ckpt.maybe_save(frames, carries if checkpoint_replay
-                                else carries.learner)
+                ckpt.maybe_save(
+                    frames, checkpoint_tree(carries, checkpoint_replay))
             if stop_fn is not None and stop_fn(row):
                 break
     finally:
         _hb_chunk.close()
         tm_watchdog.unregister_emergency_hook("population.checkpoint")
     if ckpt is not None:
-        ckpt.save(frames, carries if checkpoint_replay
-                  else carries.learner)
+        ckpt.save(frames, checkpoint_tree(carries, checkpoint_replay))
         ckpt.close()
     if telemetry_server is not None:
         telemetry_server.close()

@@ -21,12 +21,12 @@ import jax
 import jax.numpy as jnp
 
 from dist_dqn_tpu import loop_common
-from dist_dqn_tpu.agents.dqn import LearnerState, make_actor_step, \
-    make_learner, make_population_optimizer, set_member_lr
+from dist_dqn_tpu.agents.agent import make_agent
+from dist_dqn_tpu.agents.dqn import LearnerState, \
+    make_population_optimizer, set_member_lr
 from dist_dqn_tpu.config import ExperimentConfig
 from dist_dqn_tpu.envs.base import JaxEnv
-from dist_dqn_tpu.replay import device as ring
-from dist_dqn_tpu.replay import prioritized_device as pring
+from dist_dqn_tpu.replay.device_ring import make_device_ring
 from dist_dqn_tpu.types import PyTree
 
 Array = jnp.ndarray
@@ -53,7 +53,10 @@ class MemberHP(NamedTuple):
 class TrainCarry(NamedTuple):
     env_state: PyTree
     obs: PyTree
-    replay: PyTree         # TimeRingState or PrioritizedRingState
+    # What the actor holds between steps, leaves [B, ...] (an LSTM's
+    # (c, h)); () for a feed-forward network (agents/agent.py).
+    actor_carry: PyTree
+    replay: PyTree         # the ring's state (replay/device_ring.py)
     learner: LearnerState
     rng: Array             # single key; shape [1] key array in SPMD mode
     iteration: Array       # scalar int32 — env vector steps taken
@@ -65,11 +68,43 @@ class TrainCarry(NamedTuple):
     train_count: Array
 
 
+def fused_parts(cfg: ExperimentConfig, env: JaxEnv, net,
+                axis_name: Optional[str] = None, num_shards: int = 1,
+                member_hp: bool = False, member_lr: bool = False):
+    """(agent, replay) the chunk program is built over — agents/agent.py,
+    replay/device_ring.py — and the one place a combination that a part
+    cannot serve is refused (honest-unsupported-surface gates: fail
+    loudly, never train silently at other settings)."""
+    agent = make_agent(
+        net, cfg, axis_name=axis_name,
+        tx=make_population_optimizer(cfg.learner) if member_lr else None)
+    replay = make_device_ring(
+        cfg, env, num_shards,
+        jax.eval_shape(lambda: agent.initial_state(1)))
+    # The ISSUE 6 replay-ratio scan and the population's member axis exist
+    # for the transition rings only (the --replay-ratio CLI flag is
+    # warned-and-stripped by train.py before it gets here; this catches the
+    # --set/config path). replay.train_batch IS honored: it widens the
+    # sequence batch through shard_sizes.
+    if replay.sequence and cfg.replay.updates_per_chunk != 1:
+        raise ValueError(
+            "replay.updates_per_chunk (the replay-ratio scan) is not "
+            "supported by the recurrent R2D2 loop yet; leave it at 1 "
+            "or use a feed-forward config")
+    if replay.sequence and member_hp:
+        raise ValueError(
+            "--population is not supported by the recurrent (R2D2) fused "
+            "loop yet (its sequence learner has no member axis)")
+    return agent, replay
+
+
 def make_fused_train(cfg: ExperimentConfig, env: JaxEnv, net,
                      axis_name: Optional[str] = None, num_shards: int = 1,
                      member_hp: bool = False, member_lr: bool = False):
     """Returns (init, run_chunk): ``run_chunk(carry, num_iters)`` executes
-    ``num_iters`` fused iterations and reports aggregated metrics.
+    ``num_iters`` fused iterations and reports aggregated metrics. The ONE
+    chunk program of the fused runtime: feed-forward or recurrent agent,
+    uniform, prioritized or sequence ring (``fused_parts``).
 
     With ``axis_name`` set the returned functions are per-device bodies to be
     wrapped in ``shard_map`` (parallel/learner.py); all sizes below become
@@ -86,12 +121,9 @@ def make_fused_train(cfg: ExperimentConfig, env: JaxEnv, net,
     ``hp.lr`` into its opt_state. ``member_hp=False`` (every existing
     caller) compiles the EXACT pre-knob program.
     """
-    prioritized = cfg.replay.prioritized
     spmd = axis_name is not None
-    init_learner, train_step = make_learner(
-        net, cfg.learner, axis_name=axis_name,
-        tx=make_population_optimizer(cfg.learner) if member_lr else None)
-    act = make_actor_step(net)
+    agent, replay = fused_parts(cfg, env, net, axis_name, num_shards,
+                                member_hp, member_lr)
     # Replay-ratio engine (ISSUE 6): each train event scans
     # updates_per_train * updates_per_chunk grad sub-steps over
     # independently-drawn batches. At ratio 1 the scan length and the
@@ -103,65 +135,14 @@ def make_fused_train(cfg: ExperimentConfig, env: JaxEnv, net,
     # ratio engine is on (sub-steps sample event-entry priorities; the
     # host loops' prio_writeback_batch lag contract). Ratio 1 keeps the
     # in-scan sequential updates — the bit-identity contract.
-    defer_writeback = prioritized and replay_ratio > 1
+    defer_writeback = replay.update_batched is not None and replay_ratio > 1
     _cast_actor, _actor_split = loop_common.make_actor_param_cast(
         cfg.network.actor_dtype)
-    B, batch_size = loop_common.shard_sizes(cfg, num_shards)
-    min_fill = max(cfg.replay.min_fill // num_shards, 1)
-    num_slots = max(cfg.replay.capacity // (B * num_shards),
-                    cfg.learner.n_step + 2)
-    # Exact truncation bootstrap for cheap (non-pixel) observations; pixel
-    # rings skip final_obs to halve HBM use (truncation treated as terminal).
-    # cfg.replay.store_final_obs overrides the heuristic either way.
-    store_final = (env.observation_dtype != jnp.uint8
-                   if cfg.replay.store_final_obs is None
-                   else cfg.replay.store_final_obs)
-
+    B, _ = loop_common.shard_sizes(cfg, num_shards)
     epsilon, beta_at = loop_common.make_schedules(cfg, B, num_shards)
     eps_member = (loop_common.make_member_epsilon(cfg, B, num_shards)
                   if member_hp else None)
     _split_rng = loop_common.make_rng_splitter(spmd)
-    use_pallas, pallas_interpret = loop_common.pallas_routing(
-        prioritized and cfg.replay.pallas_sampler)
-
-    # Frame-dedup (replay.frame_dedup): store each step's NEWEST frame
-    # only and rebuild stacks at sample time — a 4x HBM saving that
-    # lifts the v5e pixel window cap from ~200k to ~1M transitions.
-    # Exactness relies on the env's declared rolling-stack contract.
-    _obs_shape = tuple(env.observation_shape)
-    stack, _stored_shape, _frame_shape, _slice_newest = \
-        loop_common.resolve_frame_dedup(cfg.replay, env, _obs_shape,
-                                        store_final=store_final)
-    # Dedup rebuild needs frame_stack-1 context slots beyond the n-step
-    # window; a ring under that floor would be permanently unsampleable.
-    num_slots = max(num_slots,
-                    cfg.learner.n_step + max(stack - 1, 0) + 2)
-
-    # Multi-dim obs can be STORED FLAT in the ring — [slots*B, 28224]
-    # for 84x84x4, via replay/device.py merge_obs_rows — with reshapes
-    # at the insert/sample boundary (rationale + measured padding
-    # factors: loop_common.resolve_flat_storage).
-    flat_storage = loop_common.resolve_flat_storage(
-        cfg.replay, _stored_shape, env.observation_dtype, num_slots, B,
-        store_final=store_final, prefer_flat=bool(stack))
-
-    _flatten_batched, _unflatten_batched = loop_common.flat_obs_codecs(
-        flat_storage, _stored_shape)
-    # Dedup gathers return UNFLATTENED rebuilt stacks (gather owns the
-    # reshape via frame_shape); without dedup the flat codec decodes.
-    _decode_batch_obs = (lambda x: x) if stack else _unflatten_batched
-
-    def _ring_of(replay) -> ring.TimeRingState:
-        return replay.ring if prioritized else replay
-
-    def can_train(replay, iteration: Array) -> Array:
-        r = _ring_of(replay)
-        filled = r.size * B >= min_fill
-        return jnp.logical_and(
-            jnp.logical_and(filled,
-                            ring.time_ring_can_sample(r, cfg.learner.n_step,
-                                                      frame_stack=stack)),
-            iteration % cfg.train_every == 0)
 
     def init(rng: Array, hp: Optional[MemberHP] = None) -> TrainCarry:
         base = rng
@@ -177,26 +158,16 @@ def make_fused_train(cfg: ExperimentConfig, env: JaxEnv, net,
         # Envs may return obs aliasing their own state (e.g. CartPole's
         # phys vector); the carry is donated, so every leaf must be distinct.
         obs = jax.tree.map(jnp.copy, obs)
-        obs_example = jax.tree.map(lambda x: x[0], obs)
-        # The ring stores single frames under dedup; the learner (below)
-        # still inits on the full stacked obs.
-        stored_example = jax.tree.map(lambda x: _slice_newest(x)[0], obs)
-        ring_example = loop_common.ring_obs_example(stored_example,
-                                                    flat_storage)
-        if prioritized:
-            replay = pring.prioritized_ring_init(
-                num_slots, B, ring_example, store_final_obs=store_final,
-                merge_obs_rows=flat_storage)
-        else:
-            replay = ring.time_ring_init(num_slots, B, ring_example,
-                                         store_final_obs=store_final,
-                                         merge_obs_rows=flat_storage)
-        learner = init_learner(k_learn, obs_example)
+        replay_state = replay.init(obs)
+        # The learner inits on the full stacked obs of one lane.
+        learner = agent.init_learner(k_learn,
+                                     jax.tree.map(lambda x: x[0], obs))
         if member_lr:
             learner = set_member_lr(learner, hp.lr)
         zero = jnp.float32(0.0)
-        return TrainCarry(env_state=env_state, obs=obs, replay=replay,
-                          learner=learner,
+        return TrainCarry(env_state=env_state, obs=obs,
+                          actor_carry=agent.initial_state(B),
+                          replay=replay_state, learner=learner,
                           rng=k_run[None] if spmd else k_run,
                           iteration=jnp.int32(0),
                           ep_return=jnp.zeros((B,), jnp.float32),
@@ -218,19 +189,13 @@ def make_fused_train(cfg: ExperimentConfig, env: JaxEnv, net,
                          else carry.learner.params)
         # Stage names (telemetry/stages.py STAGES): trace metadata only.
         with jax.named_scope("act"):
-            actions = act(acting_params, carry.obs, k_act, eps)
+            actor_carry, actions = agent.act(
+                acting_params, carry.actor_carry, carry.obs, k_act, eps)
         with jax.named_scope("env"):
             env_state, out = env.v_step(carry.env_state, actions)
-        add = (pring.prioritized_ring_add if prioritized
-               else ring.time_ring_add)
         with jax.named_scope("insert"):
-            replay = add(carry.replay,
-                         _flatten_batched(jax.tree.map(_slice_newest,
-                                                       carry.obs)),
-                         actions, out.reward, out.terminated, out.truncated,
-                         final_obs=_flatten_batched(out.next_obs)
-                         if store_final else None,
-                         merge_obs_rows=flat_storage)
+            replay_state = replay.add(carry.replay, carry.obs, actions, out,
+                                      carry.actor_carry)
         beta = beta_at(carry.iteration)
 
         def do_train(operand):
@@ -238,40 +203,15 @@ def make_fused_train(cfg: ExperimentConfig, env: JaxEnv, net,
 
             def one_update(c, key):
                 l, rep = c
-                if prioritized:
-                    s = pring.prioritized_ring_sample(
-                        rep, key, batch_size, cfg.learner.n_step,
-                        gamma, cfg.replay.priority_exponent,
-                        beta, use_pallas=use_pallas,
-                        pallas_interpret=pallas_interpret,
-                        merge_obs_rows=flat_storage,
-                        frame_stack=stack, frame_shape=_frame_shape)
-                    with jax.named_scope("gather"):
-                        batch = s.batch._replace(
-                            obs=_decode_batch_obs(s.batch.obs),
-                            next_obs=_decode_batch_obs(s.batch.next_obs))
-                    l, metrics = train_step(l, batch, s.weights)
-                    if defer_writeback:
-                        # Replay-ratio scan: stack this sub-step's draw
-                        # + |TD| plane as scan outputs; ONE last-wins
-                        # flush lands them after the scan.
-                        return (l, rep), (metrics["loss"], s.t_idx,
-                                          s.b_idx, metrics["priorities"])
-                    rep = pring.prioritized_ring_update(
-                        rep, s.t_idx, s.b_idx, metrics["priorities"],
-                        eps=cfg.replay.priority_eps)
-                else:
-                    batch = ring.time_ring_sample(rep, key, batch_size,
-                                                  cfg.learner.n_step,
-                                                  gamma,
-                                                  merge_obs_rows=flat_storage,
-                                                  frame_stack=stack,
-                                                  frame_shape=_frame_shape)
-                    with jax.named_scope("gather"):
-                        batch = batch._replace(
-                            obs=_decode_batch_obs(batch.obs),
-                            next_obs=_decode_batch_obs(batch.next_obs))
-                    l, metrics = train_step(l, batch)
+                s = replay.sample(rep, key, gamma, beta)
+                l, metrics = agent.train_step(l, s)
+                if defer_writeback:
+                    # Replay-ratio scan: stack this sub-step's draw
+                    # + |TD| plane as scan outputs; ONE last-wins
+                    # flush lands them after the scan.
+                    return (l, rep), (metrics["loss"], s.t_idx,
+                                      s.b_idx, metrics["priorities"])
+                rep = replay.update(rep, s, metrics["priorities"])
                 return (l, rep), (metrics["loss"],)
 
             with jax.named_scope("sample"):
@@ -280,8 +220,7 @@ def make_fused_train(cfg: ExperimentConfig, env: JaxEnv, net,
                                               (learner, rep), keys)
             if defer_writeback:
                 losses_u, t_i, b_i, prios = ys
-                rep = pring.prioritized_ring_update_batched(
-                    rep, t_i, b_i, prios, eps=cfg.replay.priority_eps)
+                rep = replay.update_batched(rep, t_i, b_i, prios)
             else:
                 (losses_u,) = ys
             return (learner, rep, jnp.sum(losses_u),
@@ -291,16 +230,19 @@ def make_fused_train(cfg: ExperimentConfig, env: JaxEnv, net,
             learner, rep = operand
             return learner, rep, jnp.float32(0.0), jnp.float32(0.0)
 
-        learner, replay, loss, trained = jax.lax.cond(
-            can_train(replay, carry.iteration), do_train, no_train,
-            (carry.learner, replay))
+        learner, replay_state, loss, trained = jax.lax.cond(
+            jnp.logical_and(replay.can_sample(replay_state),
+                            carry.iteration % cfg.train_every == 0),
+            do_train, no_train, (carry.learner, replay_state))
 
         done = jnp.logical_or(out.terminated, out.truncated)
         ep_return, completed_return, completed_count = \
             loop_common.episode_stats_update(carry, out.reward, done)
 
         return TrainCarry(
-            env_state=env_state, obs=out.obs, replay=replay, learner=learner,
+            env_state=env_state, obs=out.obs,
+            actor_carry=agent.reset_state(actor_carry, done),
+            replay=replay_state, learner=learner,
             rng=rng, iteration=carry.iteration + 1, ep_return=ep_return,
             completed_return=completed_return,
             completed_count=completed_count,
@@ -321,7 +263,7 @@ def make_fused_train(cfg: ExperimentConfig, env: JaxEnv, net,
             carry, None, length=num_iters)
         metrics, replace = loop_common.reduce_chunk_metrics(
             carry, axis_name, B, num_shards)
-        if spmd and prioritized:
+        if spmd and replay.prioritized:
             # Keep the new-item priority seed replicated (global max).
             replace["replay"] = carry.replay._replace(
                 max_priority=jax.lax.pmax(carry.replay.max_priority,
@@ -351,31 +293,33 @@ def make_fused_train(cfg: ExperimentConfig, env: JaxEnv, net,
 
 def make_evaluator(cfg: ExperimentConfig, env: JaxEnv, net,
                    num_episodes: int = 10, epsilon: float = 0.001):
-    """Greedy-policy evaluation: one episode per vmapped env instance.
+    """Greedy-policy evaluation: one episode per vmapped env instance,
+    the agent's actor state threaded (and reset on done) as in training.
 
     Runs ``env.max_steps`` steps under a mask that freezes each env at its
     first episode end; returns mean undiscounted return.
     """
-    act = make_actor_step(net)
+    agent = make_agent(net, cfg)
 
     def evaluate(params: PyTree, rng: Array) -> Array:
         k_reset, k_run = jax.random.split(rng)
         env_state, obs = env.v_reset(k_reset, num_episodes)
 
         def step(carry, _):
-            env_state, obs, ret, alive, rng = carry
+            env_state, obs, state, ret, alive, rng = carry
             rng, k = jax.random.split(rng)
-            a = act(params, obs, k, jnp.float32(epsilon))
+            state, a = agent.act(params, state, obs, k, jnp.float32(epsilon))
             env_state, out = env.v_step(env_state, a)
             ret = ret + out.reward * alive
             done = jnp.logical_or(out.terminated, out.truncated)
+            state = agent.reset_state(state, done)
             alive = jnp.logical_and(alive > 0, ~done).astype(jnp.float32)
-            return (env_state, out.obs, ret, alive, rng), None
+            return (env_state, out.obs, state, ret, alive, rng), None
 
-        init = (env_state, obs, jnp.zeros((num_episodes,), jnp.float32),
+        init = (env_state, obs, agent.initial_state(num_episodes),
+                jnp.zeros((num_episodes,), jnp.float32),
                 jnp.ones((num_episodes,), jnp.float32), k_run)
         carry, _ = jax.lax.scan(step, init, None, length=env.max_steps)
-        returns = carry[2]
-        return jnp.mean(returns)
+        return jnp.mean(carry[3])
 
     return evaluate

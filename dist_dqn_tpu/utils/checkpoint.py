@@ -406,6 +406,17 @@ class TrainCheckpointer:
                 self._pytree_mgr = None
 
 
+def checkpoint_tree(carry, whole: bool) -> PyTree:
+    """What a fused run saves and restores: the learner, or (``whole``,
+    ``--checkpoint-replay``) the carry as its fields by name WITHOUT those
+    that hold no leaf — a feed-forward agent's actor state is ``()`` — so
+    the tree on disk is the one written before that field existed and
+    those checkpoints restore (``carry._replace(**restored)``)."""
+    if not whole:
+        return carry.learner
+    return {k: v for k, v in carry._asdict().items() if jax.tree.leaves(v)}
+
+
 class CheckpointMissingError(FileNotFoundError):
     """The requested checkpoint (dir or step) is absent. A distinct type
     so bounded-retry launchers (evaluate/serving --wait-for-checkpoint)

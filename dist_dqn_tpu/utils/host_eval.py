@@ -21,8 +21,10 @@ def run_greedy_episodes(env, act, params, rng, *, episodes: int,
     ``act`` is the jitted actor step: ``act(params, obs, k, eps) ->
     actions`` for feed-forward nets, or — when ``recurrent_carry`` is
     given — ``act(params, carry, obs, k, eps) -> (carry, actions, ...)``
-    (extra outputs such as Q planes are ignored). The recurrent carry is
-    zeroed on each lane's episode end, matching training-side acting.
+    (extra outputs such as Q planes are ignored; ``agents/agent.py``'s
+    ``act`` has this form for any network, its carry ``()`` for a
+    feed-forward one). The carry's leaves, ``[B, n]``, are zeroed on each
+    lane's episode end, matching training-side acting.
     """
     import jax
     import jax.numpy as jnp
@@ -44,7 +46,7 @@ def run_greedy_episodes(env, act, params, rng, *, episodes: int,
         done = np.logical_or(term, trunc)
         if carry is not None and done.any():
             keep = jnp.asarray(~done, jnp.float32)[:, None]
-            carry = (carry[0] * keep, carry[1] * keep)
+            carry = jax.tree.map(lambda x: x * keep, carry)
         alive &= ~done
         if not alive.any():
             break

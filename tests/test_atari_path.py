@@ -118,8 +118,6 @@ def test_r2d2_flat_storage_bit_equal_to_tiled():
     R2D2 training under tiled vs flat obs storage is bit-identical."""
     import numpy as np
 
-    from dist_dqn_tpu.r2d2_loop import make_r2d2_train
-
     def run(flat):
         cfg = CONFIGS["r2d2"]
         cfg = dataclasses.replace(
@@ -141,7 +139,7 @@ def test_r2d2_flat_storage_bit_equal_to_tiled():
         )
         env = make_jax_env(cfg.env_name)
         net = build_network(cfg.network, env.num_actions)
-        init, run_chunk = make_r2d2_train(cfg, env, net)
+        init, run_chunk = make_fused_train(cfg, env, net)
         run_j = jax.jit(run_chunk, static_argnums=1)
         carry = init(jax.random.PRNGKey(7))
         carry, metrics = run_j(carry, 40)

@@ -598,6 +598,68 @@ def test_checkpoint_replay_resumes_bit_equal(tmp_path, mode):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
+@pytest.mark.parametrize("kind", ["feed_forward", "recurrent"])
+def test_whole_carry_checkpoint_from_before_the_one_carry_restores(
+        tmp_path, kind):
+    """A ``--checkpoint-replay`` directory written by the two-loop code —
+    ``TrainCarry`` without an actor state, ``R2D2Carry`` with the pair in
+    third place: the trees built here, saved as that code saved them —
+    restores under the one carry and continues bit-equal to the
+    uninterrupted run (utils/checkpoint.py checkpoint_tree)."""
+    import collections
+
+    from dist_dqn_tpu.train import train
+    from dist_dqn_tpu.utils.checkpoint import record_checkpoint_kind
+
+    shared = ["replay", "learner", "rng", "iteration", "ep_return",
+              "completed_return", "completed_count", "loss_sum",
+              "train_count"]
+    if kind == "feed_forward":
+        cfg = CONFIGS["cartpole"]
+        cfg = dataclasses.replace(
+            cfg,
+            network=dataclasses.replace(cfg.network, mlp_features=(16,)),
+            replay=dataclasses.replace(cfg.replay, capacity=512,
+                                       min_fill=64),
+            learner=dataclasses.replace(cfg.learner, batch_size=16))
+        Old = collections.namedtuple("TrainCarry",
+                                     ["env_state", "obs"] + shared)
+    else:
+        cfg = CONFIGS["r2d2"]
+        cfg = dataclasses.replace(
+            cfg,
+            env_name="cartpole",
+            network=dataclasses.replace(
+                cfg.network, torso="mlp", mlp_features=(16,), hidden=0,
+                lstm_size=8, compute_dtype="float32"),
+            replay=dataclasses.replace(
+                cfg.replay, capacity=512, min_fill=64, burn_in=2,
+                unroll_length=4, sequence_stride=2),
+            learner=dataclasses.replace(cfg.learner, n_step=2,
+                                        batch_size=16))
+        Old = collections.namedtuple(
+            "R2D2Carry", ["env_state", "obs", "actor_carry"] + shared)
+    cfg = dataclasses.replace(
+        cfg, actor=dataclasses.replace(cfg.actor, num_envs=4),
+        eval_every_steps=0)
+    kw = dict(chunk_iters=75, log_fn=lambda s: None)
+
+    ref_carry, _ = train(cfg, total_env_steps=600, **kw)
+    half, _ = train(cfg, total_env_steps=300, **kw)
+    d = str(tmp_path / "run")
+    ckpt = TrainCheckpointer(d, save_every_frames=100_000)
+    record_checkpoint_kind(d, "carry")
+    ckpt.save(300, Old(**{f: getattr(half, f) for f in Old._fields}))
+    ckpt.close()
+
+    carry, hist = train(cfg, total_env_steps=600, checkpoint_dir=d,
+                        checkpoint_replay=True, **kw)
+    assert [row["env_frames"] for row in hist] == [600]   # resumed at 300
+    assert type(carry) is type(ref_carry)
+    for a, b in zip(jax.tree.leaves(ref_carry), jax.tree.leaves(carry)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
 def test_checkpoint_replay_completed_run_does_not_rerun(tmp_path):
     """Relaunching a FINISHED --checkpoint-replay run must be a no-op
     (the restored carry's cumulative counter must not reset the loop

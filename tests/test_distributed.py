@@ -109,8 +109,6 @@ def _tiny_cartpole_cfg(prioritized: bool):
 @pytest.mark.slow
 def test_mesh_r2d2_train_runs(mesh):
     """R2D2 across the mesh: sequence replay sharded, learner allreduced."""
-    from dist_dqn_tpu.parallel import make_mesh_r2d2_train
-
     cfg = CONFIGS["r2d2"]
     cfg = dataclasses.replace(
         cfg,
@@ -127,7 +125,7 @@ def test_mesh_r2d2_train_runs(mesh):
     )
     env = make_jax_env(cfg.env_name)
     net = build_network(cfg.network, env.num_actions)
-    init, run = make_mesh_r2d2_train(cfg, env, net, mesh)
+    init, run = make_mesh_fused_train(cfg, env, net, mesh)
     carry = init(jax.random.PRNGKey(0))
     carry, metrics = run(carry, 40)
     carry, metrics = run(carry, 40)

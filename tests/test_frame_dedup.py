@@ -427,12 +427,12 @@ def test_sequence_rebuild_reads_each_frame_once(merge):
 
 
 def test_r2d2_fused_loop_dedup_trains():
-    """make_r2d2_train with frame_dedup: sequence replay over single
-    stored frames trains a recurrent learner end to end."""
+    """make_fused_train with a recurrent net and frame_dedup: sequence
+    replay over single stored frames trains the learner end to end."""
     from dist_dqn_tpu.config import CONFIGS
     from dist_dqn_tpu.envs import make_jax_env
     from dist_dqn_tpu.models import build_network
-    from dist_dqn_tpu.r2d2_loop import make_r2d2_train
+    from dist_dqn_tpu.train_loop import make_fused_train
 
     cfg = CONFIGS["r2d2"]
     cfg = dataclasses.replace(
@@ -449,7 +449,7 @@ def test_r2d2_fused_loop_dedup_trains():
     )
     env = make_jax_env(cfg.env_name)
     net = build_network(cfg.network, env.num_actions)
-    init, run = make_r2d2_train(cfg, env, net)
+    init, run = make_fused_train(cfg, env, net)
     carry = init(jax.random.PRNGKey(0))
     carry, metrics = run(carry, 80)
     assert float(metrics["grad_steps_in_chunk"]) > 0

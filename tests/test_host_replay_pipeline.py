@@ -82,7 +82,10 @@ def test_pipeline_matches_serial_numerics():
     assert out_s["evac_overlap_frac_mean"] == 0.0
     for row in out_p["history"]:
         assert 0.0 <= row["evac_overlap_frac"] <= 1.0
-        assert row["evac_fence_wait_s"] <= row["evac_s"] + 1e-6
+        # The wait also holds the worker's wake-up, so with nothing left
+        # to overlap it can pass the evacuation's own wall by microseconds:
+        # compare at the rows' rounding quantum (4 decimals).
+        assert row["evac_fence_wait_s"] <= row["evac_s"] + 1e-4
 
 
 def test_pipeline_rows_account_stats_and_loop_rate():

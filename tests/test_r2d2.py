@@ -330,11 +330,11 @@ def test_r2d2_fused_loop_with_pallas_sampler_runs(monkeypatch):
     )
     from dist_dqn_tpu.envs import make_jax_env
     from dist_dqn_tpu.models import build_network
-    from dist_dqn_tpu.r2d2_loop import make_r2d2_train
+    from dist_dqn_tpu.train_loop import make_fused_train
 
     env = make_jax_env(cfg.env_name)
     net = build_network(cfg.network, env.num_actions)
-    init, run_chunk = make_r2d2_train(cfg, env, net)
+    init, run_chunk = make_fused_train(cfg, env, net)
     run = jax.jit(run_chunk, static_argnums=1)
     carry = init(jax.random.PRNGKey(0))
     carry, metrics = run(carry, 60)

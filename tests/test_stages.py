@@ -129,6 +129,24 @@ def test_table_holds_every_stage_and_covers_the_loop(overrides, expected):
     assert len(staged) >= 0.8 * len(body), (len(staged), len(body))
 
 
+def test_the_recurrent_program_enters_the_loop_level_names():
+    """The one body's names hold for a recurrent agent over the sequence
+    ring too: ``act``, ``env``, ``insert`` (and ``sample``, ``gather``) are
+    in its table. The sequence ring's and the recurrent learner's own
+    stages are not named yet (ROADMAP.md D7), so nothing is asked of the
+    covered share."""
+    cfg = apply_overrides(CONFIGS["r2d2"], [
+        "env_name=cartpole", "network.torso=mlp",
+        "network.mlp_features=(16,)", "network.hidden=0",
+        "network.lstm_size=8", "network.compute_dtype=float32",
+        "replay.capacity=512", "replay.min_fill=64", "replay.burn_in=2",
+        "replay.unroll_length=4", "replay.sequence_stride=2",
+        "learner.n_step=2", "learner.batch_size=16", "actor.num_envs=4"])
+    table = stages.table_from_text(_chunk_text(cfg))
+    assert {"act", "env", "insert", "sample", "gather"} <= set(
+        table.values()) <= set(stages.STAGES) | {stages.MIXED}
+
+
 def test_the_mesh_program_names_its_allreduce():
     cfg = _toy_cfg("actor.num_envs=16", "learner.batch_size=32")
     table = stages.table_from_text(_chunk_text(cfg, num_devices=2))
