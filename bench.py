@@ -209,7 +209,7 @@ def _measure(jax, device, smoke: bool):
     from dist_dqn_tpu.config import CONFIGS
     from dist_dqn_tpu.envs import make_jax_env
     from dist_dqn_tpu.models import build_network
-    from dist_dqn_tpu.train_loop import make_fused_train
+    from dist_dqn_tpu.train_loop import fused_parts, make_fused_train
     from dist_dqn_tpu.utils import flops as flops_util
 
     # BENCH_SMOKE=1 shrinks every dimension; default sizes target a real
@@ -288,7 +288,8 @@ def _measure(jax, device, smoke: bool):
         .inc(measure_chunks * chunk * num_envs)
     chunk_hist = reg.histogram("dqn_chunk_seconds", "fused chunk wall")
     chunk_hist.observe(dt / measure_chunks)
-    _, ring_slots = tmc.observe_device_ring(carry.replay)
+    _, ring_slots = tmc.observe_device_ring(
+        carry.replay, fused_parts(cfg, env, net)[1].num_slots, num_envs)
     # Experience lineage (ISSUE 16): reconstruct the measured window's
     # collect stamps (the timed loop cannot touch the host per chunk —
     # that would fence it) and age them exactly as train.py does, so

@@ -66,14 +66,14 @@ def bench_device(jax, cells: int, batch: int, iters: int,
     T = cells // LANES
     r = np.random.default_rng(0)
     # Ape-X-shaped mass plane: TD-priority^alpha values, heavy-tailed.
-    w = jnp.asarray(np.abs(r.standard_cauchy((T, LANES)))
+    w = jnp.asarray(np.abs(r.standard_cauchy((T * LANES,)))
                     .astype(np.float32) ** 0.6)
 
     def make_draw(n_draws: int):
         if n_draws == 1:
             @jax.jit
             def draw(w, rng):
-                return stratified_sample(w, rng, batch,
+                return stratified_sample(w, rng, batch, LANES,
                                          use_pallas=use_pallas)[0]
             return draw
 
@@ -87,10 +87,10 @@ def bench_device(jax, cells: int, batch: int, iters: int,
         def draw(w, rng):
             def body(w, k):
                 t_idx, b_idx, p_sel, _ = stratified_sample(
-                    w, k, batch, use_pallas=use_pallas)
-                return w.at[t_idx, b_idx].set(p_sel * 0.999), None
+                    w, k, batch, LANES, use_pallas=use_pallas)
+                return w.at[t_idx * LANES + b_idx].set(p_sel * 0.999), None
             w, _ = jax.lax.scan(body, w, jax.random.split(rng, n_draws))
-            return w[0, 0]
+            return w[0]
         return draw
 
     def timed_at(n_draws: int) -> dict:

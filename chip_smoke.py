@@ -238,21 +238,22 @@ def _train_direct(cfg, chunk_iters: int, chunks: int, num_devices: int = 1):
         chunk_iters=chunk_iters, num_devices=num_devices)
 
 
-def _compare_draws(w, u, interpret: bool, integer_masses: bool) -> Dict:
-    """Kernel vs XLA sampler vs a float64 host reference on one mass
-    plane ``w`` [T, B] at uniforms ``u`` [S] (see MASS_GAP_TOL)."""
+def _compare_draws(w, u, B: int, interpret: bool,
+                   integer_masses: bool) -> Dict:
+    """Kernel vs XLA sampler vs a float64 host reference on the flat cells
+    ``w`` [T * B] of one mass plane at uniforms ``u`` [S] (see
+    MASS_GAP_TOL)."""
     import jax
 
     from dist_dqn_tpu.ops.pallas_sampler import stratified_sample_at
 
-    B = w.shape[1]
     tk, bk, pk, tot_k = jax.device_get(stratified_sample_at(
-        w, u, use_pallas=True, interpret=interpret))
+        w, u, B, use_pallas=True, interpret=interpret))
     tx, bx, _, tot_x = jax.device_get(stratified_sample_at(
-        w, u, use_pallas=False))
+        w, u, B, use_pallas=False))
     flat_k = tk.astype(np.int64) * B + bk
     flat_x = tx.astype(np.int64) * B + bx
-    w64 = np.asarray(jax.device_get(w), np.float64).reshape(-1)
+    w64 = np.asarray(jax.device_get(w), np.float64)
     cdf = np.cumsum(w64)
     total = cdf[-1]
     u32 = np.asarray(jax.device_get(u), np.float32)
@@ -354,13 +355,13 @@ def leg_kernel(meter: CompileMeter, config: str = "apex",
     u = (jnp.arange(S, dtype=jnp.float32)
          + jax.random.uniform(k_u, (S,))) / S
     out["run_plane"] = _compare_draws(
-        plane ** cfg.replay.priority_exponent, u, interpret,
-        integer_masses=False)
+        plane ** cfg.replay.priority_exponent, u, cfg.actor.num_envs,
+        interpret, integer_masses=False)
     sparse = jnp.where(jax.random.uniform(k_w, plane.shape) < 0.125,
                        64.0, 0.0)
     check(float(jnp.sum(sparse)) < 2 ** 24, "integer plane too heavy")
-    out["integer_plane"] = _compare_draws(sparse, u, interpret,
-                                          integer_masses=True)
+    out["integer_plane"] = _compare_draws(sparse, u, cfg.actor.num_envs,
+                                          interpret, integer_masses=True)
     return out
 
 

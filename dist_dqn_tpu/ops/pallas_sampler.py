@@ -33,16 +33,17 @@ chip both paths pick a float64 reference's cell for all but a fraction
 of a percent of draws (chip_smoke.py, leg kernel).
 ``ReplayConfig.pallas_sampler`` stays opt-in per config.
 
-The kernel sees every plane through ONE shape family. The fused loop's
-plane is ``[capacity / num_envs, num_envs]`` — ``[62500, 16]`` for the
-apex preset — and Mosaic pads the minor dimension to 128 lanes, so such a
-plane would sit in VMEM at 8x its bytes with every MXU tile 7/8 empty.
-The draw is an inverse CDF over the plane's ROW-MAJOR flat order, so any
-``[R, L]`` view of the same flat cells selects the same cell:
-``pallas_stratified_sample`` views every plane as ``[ceil(T*B / 512),
-512]`` and maps the kernel's (row, lane) back to (t, b). For a 512-wide
-plane (replay/host.py) the view is the plane itself; a zero-mass tail is
-padded only when ``T*B`` is not a multiple of 512.
+The samplers take a plane as its flat cells, slot ``t`` of lane ``b`` at
+cell ``t * num_envs + b`` — how the device rings store theirs
+(replay/device.py: ``[1_000_000]`` for the apex preset's 62500 slots of 16
+lanes) — plus ``num_envs``, and hand back (t, b). The draw is an inverse
+CDF over that flat order, so any ``[R, L]`` view of the same cells selects
+the same cell, and the kernel sees every plane through ONE shape family:
+``pallas_stratified_sample`` views the cells as ``[ceil(T*B / 512), 512]``
+(as ``[62500, 16]`` Mosaic would pad the minor dimension to 128 lanes: 8x
+the bytes in VMEM, every MXU tile 7/8 empty). For a 512-wide plane
+(replay/host.py) the view is the plane itself; a zero-mass tail is padded
+only when ``T*B`` is not a multiple of 512.
 """
 from __future__ import annotations
 
@@ -159,11 +160,12 @@ def _sample_kernel(w_ref, u_ref, t_out, b_out, p_out, tot_out, cdf_ref, *,
                        keepdims=True)
 
 
-def stratified_sample(w: Array, rng: Array, batch_size: int,
+def stratified_sample(w: Array, rng: Array, batch_size: int, num_envs: int,
                       use_pallas: bool = False, interpret: bool = False
                       ) -> Tuple[Array, Array, Array, Array]:
-    """Stratified inverse-CDF draw from a [T, B] mass plane — the ONE
-    implementation both replay samplers (transition and sequence) share.
+    """Stratified inverse-CDF draw from the flat cells ``w`` [T * B] of a
+    mass plane of ``num_envs`` lanes — the ONE implementation both replay
+    samplers (transition and sequence) share.
 
     Returns (t_idx [S], b_idx [S], mass_sel [S], total []). Routing:
     ``use_pallas`` runs the VMEM kernel below; otherwise the portable XLA
@@ -171,14 +173,14 @@ def stratified_sample(w: Array, rng: Array, batch_size: int,
     """
     u01 = (jnp.arange(batch_size, dtype=jnp.float32)
            + jax.random.uniform(rng, (batch_size,))) / batch_size
-    return stratified_sample_at(w, u01, use_pallas=use_pallas,
+    return stratified_sample_at(w, u01, num_envs, use_pallas=use_pallas,
                                 interpret=interpret)
 
 
-def stratified_sample_at(w: Array, u: Array, use_pallas: bool = False,
-                         interpret: bool = False
+def stratified_sample_at(w: Array, u: Array, num_envs: int,
+                         use_pallas: bool = False, interpret: bool = False
                          ) -> Tuple[Array, Array, Array, Array]:
-    """Inverse-CDF draw from a [T, B] mass plane at EXPLICIT uniforms
+    """Inverse-CDF draw from a mass plane's flat cells at EXPLICIT uniforms
     ``u`` [S] in [0, 1) — the per-shard leg of a cross-shard stratified
     draw (replay/sharded.py): the coordinator lays ONE global ladder
     over the concatenated per-shard totals and hands each shard its
@@ -188,15 +190,13 @@ def stratified_sample_at(w: Array, u: Array, use_pallas: bool = False,
     routing as :func:`stratified_sample`.
     """
     if use_pallas:
-        return pallas_stratified_sample(w, u, interpret=interpret)
-    num_envs = w.shape[1]
-    flat = w.reshape(-1)
-    cdf = jnp.cumsum(flat)
+        return pallas_stratified_sample(w, u, num_envs, interpret=interpret)
+    cdf = jnp.cumsum(w)
     total = cdf[-1]
-    idx = jnp.clip(jnp.searchsorted(cdf, u * total), 0, flat.shape[0] - 1)
+    idx = jnp.clip(jnp.searchsorted(cdf, u * total), 0, w.shape[0] - 1)
     t_idx = (idx // num_envs).astype(jnp.int32)
     b_idx = (idx % num_envs).astype(jnp.int32)
-    return t_idx, b_idx, flat[idx], total
+    return t_idx, b_idx, w[idx], total
 
 
 SAMPLE_BLOCK = 32  # lanes per second-level block of the hierarchical draw
@@ -263,11 +263,12 @@ def importance_weights(mass_sel: Array, total: Array, n_valid: Array,
     return weights / jnp.maximum(jnp.max(weights), 1e-12)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def pallas_stratified_sample(w: Array, u: Array, interpret: bool = False
+@functools.partial(jax.jit, static_argnames=("num_envs", "interpret"))
+def pallas_stratified_sample(w: Array, u: Array, num_envs: int,
+                             interpret: bool = False
                              ) -> Tuple[Array, Array, Array, Array]:
-    """Draw samples ~ w (a [T, B] non-negative mass plane) at stratified
-    uniforms ``u`` [S] in [0, 1).
+    """Draw samples ~ w (the flat [T * B] cells of a non-negative mass
+    plane of ``num_envs`` lanes) at stratified uniforms ``u`` [S] in [0, 1).
 
     Returns (t_idx [S], b_idx [S], p_sel [S], total []): ring rows, env
     lanes, the selected masses (for importance weights) and the total mass.
@@ -278,13 +279,12 @@ def pallas_stratified_sample(w: Array, u: Array, interpret: bool = False
     if interpret and jax.default_backend() == "tpu":
         raise ValueError(
             "the Pallas sampler is never interpreted on a TPU backend")
-    T, B = w.shape
     S = u.shape[0]
-    cells = T * B
+    cells = w.shape[0]
     rows = -(-cells // _LANES)
     # Rows padded to a chunk multiple; zero-mass padding is never selected.
     rows_pad = -(-rows // _CHUNK) * _CHUNK
-    dense = jnp.pad(w.reshape(-1), (0, rows_pad * _LANES - cells))
+    dense = jnp.pad(w, (0, rows_pad * _LANES - cells))
     num_chunks = rows_pad // _CHUNK
     # Scoped VMEM stated from the shape: Mosaic's 16 MiB default is under
     # the kernel's need from S >= 768 draws on a 1M-cell plane. Compiled
@@ -319,4 +319,4 @@ def pallas_stratified_sample(w: Array, u: Array, interpret: bool = False
         interpret=interpret,
     )(dense.reshape(rows_pad, _LANES), u.reshape((S, 1)))
     flat = jnp.minimum(r_idx[:, 0] * _LANES + l_idx[:, 0], cells - 1)
-    return flat // B, flat % B, p_sel[:, 0], total[0, 0]
+    return flat // num_envs, flat % num_envs, p_sel[:, 0], total[0, 0]

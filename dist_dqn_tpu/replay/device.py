@@ -2,8 +2,14 @@
 
 Replaces the reference's host/GPU replay store (BASELINE.json:5) with a
 TPU-native layout: one ring of ``T`` time slots, each holding one step from
-all ``B`` parallel envs — leaves are ``[T, B, ...]``. The fused (Anakin)
-training loop appends one time slice per env step, entirely inside jit.
+all ``B`` parallel envs. Observations are ``[T, B, ...]`` (or merged rows,
+``[T * B, width]``); every scalar-per-step plane — action, reward,
+terminated, truncated, and the priorities of the prioritized ring — is
+stored ONCE, flat ``[T * B]``, slot ``t`` of env ``b`` at cell
+``t * B + b``: the order of the merged rows, of the sampler's draw and of
+the priority write-back, so every reader indexes it in place. The fused
+(Anakin) training loop appends one time slice per env step, entirely inside
+jit.
 
 n-step returns are computed *at sample time* from the stored per-step
 (reward, terminated, truncated) fields, which
@@ -33,10 +39,10 @@ Array = jnp.ndarray
 
 class TimeRingState(NamedTuple):
     obs: PyTree        # [T, B, ...] observation at each step (post auto-reset)
-    action: Array      # [T, B] int32
-    reward: Array      # [T, B] float32
-    terminated: Array  # [T, B] bool
-    truncated: Array   # [T, B] bool
+    action: Array      # [T * B] int32, cell t * B + b
+    reward: Array      # [T * B] float32
+    terminated: Array  # [T * B] bool
+    truncated: Array   # [T * B] bool
     final_obs: PyTree  # [T, B, ...] pre-reset successor obs, or None.
     #   Only differs from the next slot's ``obs`` at episode ends; storing it
     #   buys exact bootstrapping through *truncation*. When None (memory-
@@ -57,9 +63,14 @@ def time_ring_init(num_slots: int, num_envs: int, obs_example: PyTree,
     lanes) minormost and tile-padding it: the atari config's 200k-slot
     ring as ``[3125, 64, 28224]`` has its lanes padded 64->128 (2.0x)
     against its 5.3 GB logical size as ``[200000, 28224]``. Callers pass
-    the same flag to add/gather/sample. Only obs/final_obs merge; the
-    small per-step fields keep ``[T, B]`` (their padding is irrelevant
-    and the n-step window math wants the time axis explicit).
+    the same flag to add/gather/sample. The scalar-per-step planes are
+    flat ``[num_slots * num_envs]`` in that same order whatever the flag:
+    as ``[T, B]`` a plane of few lanes is either padded to 128 of them
+    (8x its bytes at ``B = 16``) or stored time-minor, and its readers —
+    a row write, a flattened draw, single-cell gathers and scatters — made
+    the loop convert the whole plane between the two every iteration
+    (PERF.md §6, PR 37). The planes do not say how they divide into slots
+    and lanes: readers are handed ``num_envs``.
     """
     def zeros(x):
         if merge_obs_rows:
@@ -69,10 +80,10 @@ def time_ring_init(num_slots: int, num_envs: int, obs_example: PyTree,
     obs = jax.tree.map(zeros, obs_example)
     return TimeRingState(
         obs=obs,
-        action=jnp.zeros((num_slots, num_envs), jnp.int32),
-        reward=jnp.zeros((num_slots, num_envs), jnp.float32),
-        terminated=jnp.zeros((num_slots, num_envs), jnp.bool_),
-        truncated=jnp.zeros((num_slots, num_envs), jnp.bool_),
+        action=jnp.zeros((num_slots * num_envs,), jnp.int32),
+        reward=jnp.zeros((num_slots * num_envs,), jnp.float32),
+        terminated=jnp.zeros((num_slots * num_envs,), jnp.bool_),
+        truncated=jnp.zeros((num_slots * num_envs,), jnp.bool_),
         final_obs=jax.tree.map(zeros, obs_example) if store_final_obs
         else None,
         pos=jnp.int32(0),
@@ -98,24 +109,24 @@ def time_ring_add(state: TimeRingState, obs: PyTree, action: Array,
                   reward: Array, terminated: Array, truncated: Array,
                   final_obs: PyTree = None,
                   merge_obs_rows: bool = False) -> TimeRingState:
-    """Append one time slice (all envs) at ``pos``; wraps around."""
-    num_slots, num_envs = state.action.shape
+    """Append one time slice (all envs) at ``pos``; wraps around. The
+    slice's own length is the ring's lane count."""
+    num_envs = action.shape[0]
+    num_slots = state.action.shape[0] // num_envs
     p = state.pos
 
     def write(buf, x):
-        return buf.at[p].set(x)
+        # Cells (or merged rows) [p*B, (p+1)*B) — x is the [B, ...] slice.
+        start = (p * num_envs,) + (0,) * (buf.ndim - 1)
+        return jax.lax.dynamic_update_slice(buf, x.astype(buf.dtype), start)
 
     def write_obs(buf, x):
-        if merge_obs_rows:
-            # Rows [p*B, (p+1)*B) — x is the [B, ...] time slice.
-            start = (p * num_envs,) + (0,) * (buf.ndim - 1)
-            return jax.lax.dynamic_update_slice(buf, x, start)
-        return buf.at[p].set(x)
+        return write(buf, x) if merge_obs_rows else buf.at[p].set(x)
 
     return TimeRingState(
         obs=jax.tree.map(write_obs, state.obs, obs),
-        action=write(state.action, action.astype(jnp.int32)),
-        reward=write(state.reward, reward.astype(jnp.float32)),
+        action=write(state.action, action),
+        reward=write(state.reward, reward),
         terminated=write(state.terminated, terminated),
         truncated=write(state.truncated, truncated),
         final_obs=jax.tree.map(write_obs, state.final_obs, final_obs)
@@ -135,12 +146,12 @@ def time_ring_can_sample(state: TimeRingState, n_step: int,
 
 
 def _gather_window(field: Array, t_idx: Array, b_idx: Array, n: int,
-                   num_slots: int) -> Array:
+                   num_slots: int, num_envs: int) -> Array:
     """Gather [..., n] windows starting at ring slot ``t_idx`` for env
-    ``b_idx``. field: [T, B]; t_idx/b_idx: [S]. Returns [S, n]."""
+    ``b_idx``. field: [T * B] cells; t_idx/b_idx: [S]. Returns [S, n]."""
     offs = jnp.arange(n, dtype=jnp.int32)
     tt = (t_idx[:, None] + offs[None, :]) % num_slots  # [S, n]
-    return field[tt, b_idx[:, None]]
+    return field[tt * num_envs + b_idx[:, None]]
 
 
 def compute_n_step(reward_w: Array, term_w: Array, trunc_w: Array,
@@ -175,19 +186,28 @@ def compute_n_step(reward_w: Array, term_w: Array, trunc_w: Array,
     return returns, discount, kstar
 
 
-def contextful_start_mask(state: TimeRingState, frame_stack: int) -> Array:
-    """[T] bool — slots whose frame-dedup rebuild context is stored: the
-    oldest ``frame_stack - 1`` stored slots are excluded (their context
-    holds the other lap's frames, or nothing on the first lap). All-true
-    when ``frame_stack`` is 0/1. Shared by the prioritized transition
-    sampler, the sequence sampler, and the loops' can_train gates so the
-    exclusion region cannot diverge."""
-    num_slots = state.action.shape[0]
-    extra = max(frame_stack - 1, 0)
-    t = jnp.arange(num_slots, dtype=jnp.int32)
-    oldest = (state.pos - state.size) % num_slots
-    offset = (t - oldest) % num_slots
-    return jnp.logical_and(offset >= extra, offset < state.size)
+def stored_offset(state: TimeRingState, num_slots: int,
+                  t: Array = None) -> Array:
+    """Age rank of ring slots ``t`` (default every slot, ``[T]``): 0 for
+    the oldest stored, ``size`` and above for slots not stored. With
+    ``t = arange(T * B) // B`` it ranks the flat planes' cells in place."""
+    if t is None:
+        t = jnp.arange(num_slots, dtype=jnp.int32)
+    return (t - (state.pos - state.size)) % num_slots
+
+
+def contextful_start_mask(state: TimeRingState, frame_stack: int,
+                          num_slots: int, t: Array = None) -> Array:
+    """bool over slots ``t`` (default ``[T]``) — slots whose frame-dedup
+    rebuild context is stored: the oldest ``frame_stack - 1`` stored slots
+    are excluded (their context holds the other lap's frames, or nothing
+    on the first lap). All-true over the stored slots when ``frame_stack``
+    is 0/1. Shared by the prioritized transition sampler, the sequence
+    sampler, and the loops' can_train gates so the exclusion region cannot
+    diverge."""
+    offset = stored_offset(state, num_slots, t)
+    return jnp.logical_and(offset >= max(frame_stack - 1, 0),
+                           offset < state.size)
 
 
 def last_write_wins_scatter(plane: Array, flat_idx: Array, values: Array
@@ -243,11 +263,12 @@ def stack_rebuild_indices(done_at, t_idx: Array, frame_stack: int,
 
 
 def gather_transitions(state: TimeRingState, t_idx: Array, b_idx: Array,
-                       n_step: int, gamma: float,
+                       n_step: int, gamma: float, num_envs: int,
                        merge_obs_rows: bool = False,
                        frame_stack: int = 0,
                        frame_shape=None) -> Transition:
-    """Window-gather + n-step fold for explicit (t_idx, b_idx) pairs.
+    """Window-gather + n-step fold for explicit (t_idx, b_idx) pairs of a
+    ring of ``num_envs`` lanes.
 
     Shared by the uniform and prioritized samplers so the episode-boundary
     semantics live in exactly one place.
@@ -271,20 +292,27 @@ def gather_transitions(state: TimeRingState, t_idx: Array, b_idx: Array,
             "build the ring with store_final_obs=False for frame dedup")
     with jax.named_scope("gather"):
         return _gather_transitions(state, t_idx, b_idx, n_step, gamma,
-                                   merge_obs_rows, frame_stack, frame_shape)
+                                   num_envs, merge_obs_rows, frame_stack,
+                                   frame_shape)
 
 
 def _gather_transitions(state: TimeRingState, t_idx: Array, b_idx: Array,
-                        n_step: int, gamma: float, merge_obs_rows: bool,
-                        frame_stack: int, frame_shape) -> Transition:
-    num_slots, num_envs = state.action.shape
-    reward_w = _gather_window(state.reward, t_idx, b_idx, n_step, num_slots)
-    term_w = _gather_window(state.terminated, t_idx, b_idx, n_step, num_slots)
-    trunc_w = _gather_window(state.truncated, t_idx, b_idx, n_step, num_slots)
+                        n_step: int, gamma: float, num_envs: int,
+                        merge_obs_rows: bool, frame_stack: int,
+                        frame_shape) -> Transition:
+    num_slots = state.action.shape[0] // num_envs
+    reward_w, term_w, trunc_w = (
+        _gather_window(field, t_idx, b_idx, n_step, num_slots, num_envs)
+        for field in (state.reward, state.terminated, state.truncated))
     returns, discount, kstar = compute_n_step(reward_w, term_w, trunc_w,
                                               gamma)
 
+    # One dense pass over the flat cells, then ONE look-up a lookback: at
+    # 1M cells the pass is 9 us and a 512-cell look-up 5 (PERF.md §6, PR 37).
     done = jnp.logical_or(state.terminated, state.truncated)
+
+    def done_at(tt):
+        return done[tt * num_envs + b_idx]
 
     def take_one(x, t):
         if merge_obs_rows:
@@ -297,8 +325,7 @@ def _gather_transitions(state: TimeRingState, t_idx: Array, b_idx: Array,
     def take(tree, t):
         if not frame_stack:
             return jax.tree.map(lambda x: take_one(x, t), tree)
-        slots = stack_rebuild_indices(lambda tt: done[tt, b_idx], t,
-                                      frame_stack, num_slots)
+        slots = stack_rebuild_indices(done_at, t, frame_stack, num_slots)
         # [S, N] slot index, channel order oldest -> newest = lookback
         # S-1 -> 0: ONE row gather fetches every frame of every sample.
         ts = jnp.stack([s for _, s in reversed(slots)])
@@ -312,7 +339,7 @@ def _gather_transitions(state: TimeRingState, t_idx: Array, b_idx: Array,
         return jax.tree.map(rebuild, tree)
 
     obs = take(state.obs, t_idx)
-    action = state.action[t_idx, b_idx]
+    action = state.action[t_idx * num_envs + b_idx]
     if state.final_obs is not None:
         # Exact path: the stored pre-reset successor of step k*.
         boot_t = (t_idx + kstar) % num_slots
@@ -331,10 +358,11 @@ def _gather_transitions(state: TimeRingState, t_idx: Array, b_idx: Array,
 
 
 def time_ring_sample(state: TimeRingState, rng: Array, batch_size: int,
-                     n_step: int, gamma: float,
+                     n_step: int, gamma: float, num_envs: int,
                      merge_obs_rows: bool = False,
                      frame_stack: int = 0, frame_shape=None) -> Transition:
-    """Uniformly sample ``batch_size`` n-step transitions.
+    """Uniformly sample ``batch_size`` n-step transitions from a ring of
+    ``num_envs`` lanes.
 
     Valid window starts are the oldest ``size - n_step`` slots, so the
     bootstrap slot (start + k* + 1 <= start + n_step) is always a stored,
@@ -342,7 +370,7 @@ def time_ring_sample(state: TimeRingState, rng: Array, batch_size: int,
     the oldest ``frame_stack - 1`` starts (their rebuild context is not
     stored — time_ring_can_sample gates the same way).
     """
-    num_slots, num_envs = state.action.shape
+    num_slots = state.action.shape[0] // num_envs
     extra = max(frame_stack - 1, 0)
     with jax.named_scope("sample"):
         k_t, k_b = jax.random.split(rng)
@@ -351,7 +379,7 @@ def time_ring_sample(state: TimeRingState, rng: Array, batch_size: int,
                                jnp.maximum(num_valid, 1))
         t_idx = (state.pos - state.size + extra + u) % num_slots
         b_idx = jax.random.randint(k_b, (batch_size,), 0, num_envs)
-    return gather_transitions(state, t_idx, b_idx, n_step, gamma,
+    return gather_transitions(state, t_idx, b_idx, n_step, gamma, num_envs,
                               merge_obs_rows=merge_obs_rows,
                               frame_stack=frame_stack,
                               frame_shape=frame_shape)

@@ -98,6 +98,9 @@ class DeviceRing(NamedTuple):
     boundary: Optional[BoundaryLayout]
     prioritized: bool     # carries max_priority (pmax-ed over a mesh)
     sequence: bool
+    num_slots: int        # time slots of one shard's ring
+    num_envs: int         # ... and its lanes: a scalar plane is
+    #   [num_slots * num_envs] cells, slot t of lane b at t * num_envs + b
 
 
 def make_device_ring(cfg: ExperimentConfig, env, num_shards: int = 1,
@@ -211,7 +214,8 @@ def make_device_ring(cfg: ExperimentConfig, env, num_shards: int = 1,
         alive = state.priorities > 0.0
         if stack:
             alive = jnp.logical_and(
-                alive, ring.contextful_start_mask(r, stack)[:, None])
+                alive,
+                ring.contextful_start_mask(r, stack, num_slots)[:, None])
         return jnp.logical_and(
             jnp.logical_and(filled, jnp.any(alive)),
             sring.sequence_ring_can_sample(state, seq_len))
@@ -227,12 +231,12 @@ def make_device_ring(cfg: ExperimentConfig, env, num_shards: int = 1,
         if prioritized:
             s = pring.prioritized_ring_sample(
                 state, key, batch_size, n_step, gamma,
-                rcfg.priority_exponent, beta, use_pallas=use_pallas,
+                rcfg.priority_exponent, beta, B, use_pallas=use_pallas,
                 pallas_interpret=pallas_interpret, **layout)
         else:
             s = pring.PrioritizedSample(
                 ring.time_ring_sample(state, key, batch_size, n_step, gamma,
-                                      **layout), None, None, None)
+                                      B, **layout), None, None, None)
         with jax.named_scope("gather"):
             return s._replace(batch=s.batch._replace(
                 obs=decode(s.batch.obs), next_obs=decode(s.batch.next_obs)))
@@ -240,32 +244,35 @@ def make_device_ring(cfg: ExperimentConfig, env, num_shards: int = 1,
     def update(state, s, priorities):
         if not prioritized:
             return state
-        write = (sring.sequence_ring_update if sequence
-                 else pring.prioritized_ring_update)
-        return write(state, s.t_idx, s.b_idx, priorities,
-                     eps=rcfg.priority_eps)
+        if sequence:
+            return sring.sequence_ring_update(
+                state, s.t_idx, s.b_idx, priorities, eps=rcfg.priority_eps)
+        return pring.prioritized_ring_update(
+            state, s.t_idx, s.b_idx, priorities, B, eps=rcfg.priority_eps)
 
     def update_batched(state, t_idx, b_idx, priorities):
         return pring.prioritized_ring_update_batched(
-            state, t_idx, b_idx, priorities, eps=rcfg.priority_eps)
+            state, t_idx, b_idx, priorities, B, eps=rcfg.priority_eps)
 
     def specs(axis: str):
-        """Leaves are [slots, lanes, ...] (or merged rows of both): the lane
-        axis is sharded, cursors and the priority seed replicated."""
-        lanes, repl = P(None, axis), P()
+        """Leaves are [slots, lanes, ...] (or merged rows of both), their
+        lane axis sharded; a scalar plane is one shard's flat cells, the
+        shards' planes end to end; cursors and the priority seed are
+        replicated."""
+        lanes, cells, repl = P(None, axis), P(axis), P()
         r = ring.TimeRingState(
-            obs=lanes, action=lanes, reward=lanes, terminated=lanes,
-            truncated=lanes, final_obs=lanes, pos=repl, size=repl)
+            obs=lanes, action=cells, reward=cells, terminated=cells,
+            truncated=cells, final_obs=lanes, pos=repl, size=repl)
         if sequence:
             return sring.SequenceRingState(
                 ring=r, state_c=lanes, state_h=lanes, priorities=lanes,
                 max_priority=repl, writes=repl)
         if prioritized:
-            return pring.PrioritizedRingState(ring=r, priorities=lanes,
+            return pring.PrioritizedRingState(ring=r, priorities=cells,
                                               max_priority=repl)
         return r
 
     return DeviceRing(
         init, add, can_sample, sample, update,
         update_batched if prioritized and not sequence else None, specs,
-        boundary, prioritized, sequence)
+        boundary, prioritized, sequence, num_slots, B)

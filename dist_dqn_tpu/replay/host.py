@@ -354,7 +354,8 @@ class DevicePrioritySampler:
             # plane in VMEM (TPU / the CPU interpret pin); the XLA path
             # draws three-level off the incremental partial sums.
             if use_pallas:
-                return stratified_sample_at(plane, u, use_pallas=True,
+                return stratified_sample_at(plane.reshape(-1), u, lanes,
+                                            use_pallas=True,
                                             interpret=interpret)
             return stratified_sample_rows(plane, blk_sums, u)
 

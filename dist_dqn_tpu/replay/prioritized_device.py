@@ -26,7 +26,8 @@ Array = jnp.ndarray
 
 class PrioritizedRingState(NamedTuple):
     ring: ring.TimeRingState
-    priorities: Array    # [T, B] float32, raw |TD| (+eps), 0 = never written
+    priorities: Array    # [T * B] float32 cells (replay/device.py), raw
+    #   |TD| (+eps), 0 = never written
     max_priority: Array  # scalar float32 running max — seed for new items
 
 
@@ -45,7 +46,7 @@ def prioritized_ring_init(num_slots: int, num_envs: int, obs_example: PyTree,
         ring=ring.time_ring_init(num_slots, num_envs, obs_example,
                                  store_final_obs=store_final_obs,
                                  merge_obs_rows=merge_obs_rows),
-        priorities=jnp.zeros((num_slots, num_envs), jnp.float32),
+        priorities=jnp.zeros((num_slots * num_envs,), jnp.float32),
         max_priority=jnp.float32(1.0),
     )
 
@@ -62,30 +63,29 @@ def prioritized_ring_add(state: PrioritizedRingState, obs: PyTree,
     new_ring = ring.time_ring_add(state.ring, obs, action, reward,
                                   terminated, truncated, final_obs=final_obs,
                                   merge_obs_rows=merge_obs_rows)
-    priorities = state.priorities.at[p].set(
-        jnp.full((state.priorities.shape[1],), state.max_priority))
+    num_envs = action.shape[0]
+    priorities = jax.lax.dynamic_update_slice(
+        state.priorities, jnp.full((num_envs,), state.max_priority),
+        (p * num_envs,))
     return PrioritizedRingState(ring=new_ring, priorities=priorities,
                                 max_priority=state.max_priority)
 
 
 def _valid_start_mask(state: ring.TimeRingState, n_step: int,
-                      frame_stack: int = 0) -> Array:
-    """[T] bool — slots that are valid n-step window starts (same region the
-    uniform sampler draws from: the oldest size - n_step slots; frame-dedup
-    rings also exclude the oldest frame_stack - 1, whose stack-rebuild
-    context is not stored — ring.contextful_start_mask)."""
-    num_slots = state.action.shape[0]
-    t = jnp.arange(num_slots, dtype=jnp.int32)
-    oldest = (state.pos - state.size) % num_slots
-    offset = (t - oldest) % num_slots
+                      frame_stack: int, num_slots: int,
+                      t: Array = None) -> Array:
+    """bool over slots ``t`` (default ``[T]``) — valid n-step window starts
+    (same region the uniform sampler draws from: the oldest size - n_step
+    slots; frame-dedup rings also exclude the oldest frame_stack - 1, whose
+    stack-rebuild context is not stored — ring.contextful_start_mask)."""
     return jnp.logical_and(
-        ring.contextful_start_mask(state, frame_stack),
-        offset < (state.size - n_step))
+        ring.contextful_start_mask(state, frame_stack, num_slots, t),
+        ring.stored_offset(state, num_slots, t) < (state.size - n_step))
 
 
 def prioritized_ring_sample(state: PrioritizedRingState, rng: Array,
                             batch_size: int, n_step: int, gamma: float,
-                            alpha: float, beta: Array,
+                            alpha: float, beta: Array, num_envs: int,
                             use_pallas: bool = False,
                             pallas_interpret: bool = False,
                             merge_obs_rows: bool = False,
@@ -100,39 +100,56 @@ def prioritized_ring_sample(state: PrioritizedRingState, rng: Array,
     from dist_dqn_tpu.ops.pallas_sampler import (importance_weights,
                                                  stratified_sample)
 
-    num_slots, num_envs = state.priorities.shape
+    cells = state.priorities.shape[0]
+    num_slots = cells // num_envs
     with jax.named_scope("sample"):
-        mask = _valid_start_mask(state.ring, n_step, frame_stack)     # [T]
-        w = jnp.where(mask[:, None], state.priorities ** alpha, 0.0)  # [T, B]
+        mask = _valid_start_mask(state.ring, n_step, frame_stack, num_slots)
         n_valid = (jnp.sum(mask.astype(jnp.float32)) * num_envs)
+        # The same mask per cell, in the plane's own order: one elementwise
+        # pass over the flat cells is all the draw costs outside the kernel.
+        slot_of_cell = jnp.arange(cells, dtype=jnp.int32) // num_envs
+        w = jnp.where(
+            _valid_start_mask(state.ring, n_step, frame_stack, num_slots,
+                              slot_of_cell),
+            state.priorities ** alpha, 0.0)                      # [T * B]
         t_idx, b_idx, mass_sel, total = stratified_sample(
-            w, rng, batch_size, use_pallas=use_pallas,
+            w, rng, batch_size, num_envs, use_pallas=use_pallas,
             interpret=pallas_interpret)
         weights = importance_weights(mass_sel, total, n_valid, beta)
 
     batch = ring.gather_transitions(state.ring, t_idx, b_idx, n_step, gamma,
-                                    merge_obs_rows=merge_obs_rows,
+                                    num_envs, merge_obs_rows=merge_obs_rows,
                                     frame_stack=frame_stack,
                                     frame_shape=frame_shape)
     return PrioritizedSample(batch=batch, weights=weights, t_idx=t_idx,
                              b_idx=b_idx)
 
 
+def _write_back(state: PrioritizedRingState, t_idx: Array, b_idx: Array,
+                new_priorities: Array, eps: float, num_envs: int,
+                scatter) -> PrioritizedRingState:
+    """|TD| + eps into cells ``t_idx * B + b_idx`` through ``scatter(plane,
+    cells, values)``: the one indexing of both write-backs below."""
+    with jax.named_scope("writeback"):
+        p = jnp.abs(new_priorities.reshape(-1)) + eps
+        cells = t_idx.reshape(-1) * num_envs + b_idx.reshape(-1)
+        return PrioritizedRingState(
+            ring=state.ring, priorities=scatter(state.priorities, cells, p),
+            max_priority=jnp.maximum(state.max_priority, jnp.max(p)))
+
+
 def prioritized_ring_update(state: PrioritizedRingState, t_idx: Array,
                             b_idx: Array, new_priorities: Array,
-                            eps: float = 1e-6) -> PrioritizedRingState:
+                            num_envs: int, eps: float = 1e-6
+                            ) -> PrioritizedRingState:
     """Write back learner TD magnitudes for the sampled transitions."""
-    with jax.named_scope("writeback"):
-        p = jnp.abs(new_priorities) + eps
-        priorities = state.priorities.at[t_idx, b_idx].set(p)
-        max_priority = jnp.maximum(state.max_priority, jnp.max(p))
-    return PrioritizedRingState(ring=state.ring, priorities=priorities,
-                                max_priority=max_priority)
+    return _write_back(state, t_idx, b_idx, new_priorities, eps, num_envs,
+                       lambda plane, cells, p: plane.at[cells].set(p))
 
 
 def prioritized_ring_update_batched(state: PrioritizedRingState,
                                     t_idx: Array, b_idx: Array,
-                                    new_priorities: Array,
+                                    new_priorities: Array, num_envs: int,
                                     eps: float = 1e-6
                                     ) -> PrioritizedRingState:
     """One flush for N sub-steps' write-backs (ISSUE 6 replay ratio).
@@ -145,14 +162,5 @@ def prioritized_ring_update_batched(state: PrioritizedRingState,
     already flat [M]) in sub-step order; flattening row-major keeps
     chronology, so ``last_write_wins_scatter``'s election is exact.
     """
-    T, B = state.priorities.shape
-    with jax.named_scope("writeback"):
-        t_flat = t_idx.reshape(-1)
-        b_flat = b_idx.reshape(-1)
-        p = jnp.abs(new_priorities.reshape(-1)) + eps
-        flat = ring.last_write_wins_scatter(
-            state.priorities.reshape(-1), t_flat * B + b_flat, p)
-        max_priority = jnp.maximum(state.max_priority, jnp.max(p))
-    return PrioritizedRingState(ring=state.ring,
-                                priorities=flat.reshape(T, B),
-                                max_priority=max_priority)
+    return _write_back(state, t_idx, b_idx, new_priorities, eps, num_envs,
+                       ring.last_write_wins_scatter)

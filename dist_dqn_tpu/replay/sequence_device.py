@@ -3,8 +3,9 @@
 The reference's sequence replay stores fixed-length (burn-in + unroll)
 trajectory slices with the recurrent state at the slice start. The TPU-native
 layout reuses the time-ring (replay/device.py): every step is stored exactly
-once as a [T, B] slice together with the actor's LSTM carry *entering* that
-step, and a "sequence" is just a length-L window gather at sample time —
+once, as one slice of all B lanes, together with the actor's LSTM carry
+*entering* that step, and a "sequence" is just a length-L window gather at
+sample time —
 overlapping sequences (stride < L) therefore cost zero extra HBM, where the
 reference's per-sequence storage pays length/stride x duplication.
 
@@ -38,6 +39,8 @@ class SequenceRingState(NamedTuple):
     state_c: Array       # [T, B, lstm] float32 — carry entering each step
     state_h: Array       # [T, B, lstm] float32
     priorities: Array    # [T, B] float32; >0 exactly at valid window starts
+    #   (the one plane still [T, B]: the benchmark's reference check reads
+    #   it so — perf/reference/r2d2_float32.py, PERF.md §7)
     max_priority: Array  # scalar float32 — seed for fresh windows
     writes: Array        # scalar int32 — total time slices ever written
 
@@ -47,9 +50,10 @@ def sequence_ring_init(num_slots: int, num_envs: int, obs_example: PyTree,
                        merge_obs_rows: bool = False) -> SequenceRingState:
     """``merge_obs_rows`` stores obs as flat ``[T*B, ...]`` rows (same
     records, same order — see replay/device.py:time_ring_init); callers
-    pass the same flag to add/sample. The carry planes and priority
-    plane keep ``[T, B]``: they are small and the seeding math wants the
-    time axis explicit."""
+    pass the same flag to add/sample. The inner ring's scalar planes are
+    flat cells like every ring's; the carry planes are ``[T, B, lstm]``,
+    lane-dense as they are, and the ring's slots and lanes are read off
+    them."""
     return SequenceRingState(
         ring=ring.time_ring_init(num_slots, num_envs, obs_example,
                                  store_final_obs=False,
@@ -75,7 +79,7 @@ def sequence_ring_add(state: SequenceRingState, obs: PyTree, action: Array,
     whose full window just completed — write index ``writes + 1 - L`` — is
     seeded with the running max priority when stride-aligned.
     """
-    num_slots = state.priorities.shape[0]
+    num_slots = state.state_c.shape[0]
     p = state.ring.pos
     new_ring = ring.time_ring_add(state.ring, obs, action, reward,
                                   terminated, truncated,
@@ -104,17 +108,17 @@ def sequence_ring_can_sample(state: SequenceRingState, seq_len: int) -> Array:
     return state.writes >= seq_len
 
 
-def _gather_seq(field: Array, t_idx: Array, b_idx: Array, L: int,
-                num_slots: int) -> Array:
-    """[T, B, ...] field -> [L, S, ...] windows (time-major)."""
+def _window_slots(t_idx: Array, L: int, num_slots: int) -> Array:
+    """[L, S] ring slots of the length-``L`` windows from ``t_idx`` [S]
+    (time-major)."""
     offs = jnp.arange(L, dtype=jnp.int32)
-    tt = (t_idx[None, :] + offs[:, None]) % num_slots   # [L, S]
-    return field[tt, b_idx[None, :]]
+    return (t_idx[None, :] + offs[:, None]) % num_slots
 
 
 def _rebuild_seq_stacks(r: ring.TimeRingState, t_idx: Array, b_idx: Array,
                         seq_len: int, frame_stack: int,
-                        merge_obs_rows: bool, frame_shape) -> PyTree:
+                        merge_obs_rows: bool, frame_shape,
+                        num_slots: int, num_envs: int) -> PyTree:
     """[L, B, ..., frame_stack] stacks for every window position, from a
     dedup ring (single stored ``[..., 1]`` frames — replay/device.py
     semantics).
@@ -144,14 +148,13 @@ def _rebuild_seq_stacks(r: ring.TimeRingState, t_idx: Array, b_idx: Array,
     as slow as a byte-wise relayout is. Callers mask out window starts
     whose context predates the ring (sequence_ring_sample).
     """
-    num_slots, num_envs = r.action.shape
     S = frame_stack
     L = seq_len
     batch = t_idx.shape[0]
     ext_offs = jnp.arange(-(S - 1), L, dtype=jnp.int32)        # [E]
     tt = (t_idx[None, :] + ext_offs[:, None]) % num_slots      # [E, B]
-    done_ext = jnp.logical_or(r.terminated, r.truncated)[
-        tt, b_idx[None, :]]                                    # [E, B]
+    cells = tt * num_envs + b_idx[None, :]
+    done_ext = jnp.logical_or(r.terminated, r.truncated)[cells]  # [E, B]
     # age[i] = distance-1 to the nearest done among positions i-1..i-(S-1)
     # (window position i lives at ext index i + S - 1).
     age = jnp.full((L, batch), S - 1, jnp.int32)
@@ -163,9 +166,8 @@ def _rebuild_seq_stacks(r: ring.TimeRingState, t_idx: Array, b_idx: Array,
     def rebuild(x):
         if merge_obs_rows:
             shape = tuple(frame_shape)
-            rows = ring.row_head(
-                x[(tt * num_envs + b_idx[None, :]).reshape(-1)],
-                (math.prod(shape),))
+            rows = ring.row_head(x[cells.reshape(-1)],
+                                 (math.prod(shape),))
         else:
             shape = x.shape[2:]
             rows = x[tt, b_idx[None, :]].reshape(tt.size, -1)
@@ -215,40 +217,37 @@ def sequence_ring_sample(state: SequenceRingState, rng: Array,
     from dist_dqn_tpu.ops.pallas_sampler import (importance_weights,
                                                  stratified_sample)
 
-    num_slots, num_envs = state.priorities.shape
+    num_slots, num_envs = state.state_c.shape[:2]
     w = jnp.where(state.priorities > 0.0, state.priorities ** alpha, 0.0)
     if frame_stack:
         # Exclude the oldest frame_stack-1 starts: their context slots
         # hold the other lap's frames (or nothing, first lap). Shared
         # region logic: replay/device.py contextful_start_mask.
         w = jnp.where(
-            ring.contextful_start_mask(state.ring, frame_stack)[:, None],
+            ring.contextful_start_mask(state.ring, frame_stack,
+                                       num_slots)[:, None],
             w, 0.0)
     t_idx, b_idx, mass_sel, total = stratified_sample(
-        w, rng, batch_size, use_pallas=use_pallas,
+        w.reshape(-1), rng, batch_size, num_envs, use_pallas=use_pallas,
         interpret=pallas_interpret)
     n_valid = jnp.sum((w > 0.0).astype(jnp.float32))
     weights = importance_weights(mass_sel, total, n_valid, beta)
 
     r = state.ring
+    tt = _window_slots(t_idx, seq_len, num_slots)              # [L, S]
+    # Slot t of env b lives at cell (and merged row) t*B + b.
+    cells = tt * num_envs + b_idx[None, :]
     if frame_stack:
         obs = _rebuild_seq_stacks(r, t_idx, b_idx, seq_len, frame_stack,
-                                  merge_obs_rows, frame_shape)
+                                  merge_obs_rows, frame_shape, num_slots,
+                                  num_envs)
     elif merge_obs_rows:
-        # Flat rows: slot t of env b lives at row t*B + b.
-        offs = jnp.arange(seq_len, dtype=jnp.int32)
-        tt = (t_idx[None, :] + offs[:, None]) % num_slots      # [L, S]
-        rows = tt * num_envs + b_idx[None, :]
-        obs = jax.tree.map(lambda x: x[rows], r.obs)
+        obs = jax.tree.map(lambda x: x[cells], r.obs)
     else:
-        obs = jax.tree.map(
-            lambda x: _gather_seq(x, t_idx, b_idx, seq_len, num_slots),
-            r.obs)
-    action = _gather_seq(r.action, t_idx, b_idx, seq_len, num_slots)
-    reward = _gather_seq(r.reward, t_idx, b_idx, seq_len, num_slots)
-    term = _gather_seq(r.terminated, t_idx, b_idx, seq_len, num_slots)
-    trunc = _gather_seq(r.truncated, t_idx, b_idx, seq_len, num_slots)
-    done = jnp.logical_or(term, trunc)
+        obs = jax.tree.map(lambda x: x[tt, b_idx[None, :]], r.obs)
+    action, reward = r.action[cells], r.reward[cells]
+    # (one dense pass and one look-up, as replay/device.py's ``done``)
+    done = jnp.logical_or(r.terminated, r.truncated)[cells]
     # obs[t] opens a new episode iff the previous stored step ended one. The
     # first step never resets: its stored carry is already episode-correct.
     reset = jnp.concatenate(

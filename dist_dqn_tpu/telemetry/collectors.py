@@ -377,10 +377,11 @@ def replay_gauges(store: str, registry: Optional[Registry] = None):
                       labels))
 
 
-def observe_device_ring(replay_state,
+def observe_device_ring(replay_state, slots: int, lanes: int,
                         registry: Optional[Registry] = None
                         ) -> Tuple[int, int]:
-    """Record occupancy of a jit-resident device ring between chunks.
+    """Record occupancy of a jit-resident device ring of ``slots`` time
+    slots by ``lanes`` envs between chunks.
 
     Accepts any of the device replay states (TimeRingState, or the
     prioritized/sequence wrappers that carry one as ``.ring``) — the ring
@@ -390,7 +391,6 @@ def observe_device_ring(replay_state,
     chunk metrics fetch every caller already performs.
     """
     ring = getattr(replay_state, "ring", replay_state)
-    slots, lanes = (int(ring.action.shape[0]), int(ring.action.shape[1]))
     size = int(ring.size)
     g_size, g_cap, g_ratio = replay_gauges("device", registry)
     g_size.set(size * lanes)

@@ -189,16 +189,13 @@ def train(cfg: ExperimentConfig, total_env_steps: int = 0, seed: int = None,
     # Whether the ring's merged-row buffer crosses the chunk program's
     # boundary row-major (replay/device_ring.py merged_row_boundary): where
     # it does not, every chunk copies the whole ring in and out.
-    boundary = fused_parts(
-        cfg, env, net,
-        num_shards=mesh.shape["dp"] if use_mesh else 1)[1].boundary
+    device_ring = fused_parts(
+        cfg, env, net, num_shards=mesh.shape["dp"] if use_mesh else 1)[1]
+    boundary = device_ring.boundary
     _reg.gauge("dqn_ring_boundary_row_major",
                "1: the device ring's merged-row buffer is carried row-major "
                "between chunks (no whole-ring copy in the chunk program)"
                ).set(int(bool(boundary and boundary.row_major)))
-    if boundary is not None:
-        log_fn(json.dumps({"ring_boundary": dict(
-            boundary._asdict(), row_major=int(boundary.row_major))}))
     evaluate = jax.jit(make_evaluator(cfg, env, net,
                                       num_episodes=cfg.eval_episodes))
     # Chip-time attribution (ISSUE 19): the fused chunk is ONE program —
@@ -230,6 +227,15 @@ def train(cfg: ExperimentConfig, total_env_steps: int = 0, seed: int = None,
     # plain numpy keys are treated as replicated (identical on every
     # process by construction — same seed).
     carry = init(np.asarray(k_init))
+    if boundary is not None:
+        # ... and what one shard's scalar-per-step planes (replay/device.py)
+        # are stored as, read off the reward plane the carry holds.
+        plane = getattr(carry.replay, "ring",
+                        carry.replay).reward.addressable_shards[0].data
+        log_fn(json.dumps({"ring_boundary": dict(
+            boundary._asdict(), row_major=int(boundary.row_major),
+            planes={"cells": device_ring.num_slots * device_ring.num_envs,
+                    "shape": list(plane.shape), "bytes": plane.nbytes})}))
 
     ckpt = None
     frame_offset = 0      # added to the carry's cumulative frame metric
@@ -385,7 +391,8 @@ def train(cfg: ExperimentConfig, total_env_steps: int = 0, seed: int = None,
                 _tm["episodes"].inc(max(float(metrics["episodes"]), 0.0))
                 if float(metrics["episodes"]):
                     _tm["ep_return"].set(float(metrics["episode_return"]))
-                _, ring_slots = tmc.observe_device_ring(carry.replay)
+                _, ring_slots = tmc.observe_device_ring(
+                    carry.replay, device_ring.num_slots, B)
                 # Experience lineage (ISSUE 16): the fused loop stamps at
                 # collect — one (birth, version) row per chunk, aged over
                 # the live ring window into the same families the apex and

@@ -214,6 +214,16 @@ class TrainCheckpointer:
             # ValueError (corruption, sharding mapping, ...) passes
             # through untouched.
             msg = str(e)
+            if "not compatible with the stored shape" in msg:
+                # The same tree, an array in another shape: refused,
+                # never reinterpreted.
+                raise ValueError(
+                    "checkpoint holds an array in another shape than this "
+                    "program stores it in: a whole-carry checkpoint "
+                    "(--checkpoint-replay) from before the device ring "
+                    "kept its per-step planes flat ([slots * lanes], once "
+                    "[slots, lanes]) cannot be resumed; start the run "
+                    f"again.\n\nOriginal error:\n{e}") from e
             if not ("structures do not match" in msg
                     or "User-provided restore item" in msg):
                 raise

@@ -61,7 +61,7 @@ def test_leg_kernel_toy(meter, monkeypatch):
                                 overrides=overrides, chunk_iters=40,
                                 chunks=2)
     assert out["mosaic_custom_call"] is False
-    assert out["plane_shape"] == [128, 4]
+    assert out["plane_shape"] == [128 * 4]
     assert out["run_plane"]["mass_gap_kernel_vs_xla"] \
         <= chip_smoke.MASS_GAP_TOL
     assert out["run_plane"]["exact_kernel_vs_xla"] \

@@ -82,9 +82,9 @@ def test_dedup_gather_exactly_matches_stacked(merge, steps, slots):
     b_idx = jnp.asarray(np.tile(np.arange(lanes), reps)[:len(offsets)],
                         jnp.int32)
 
-    a = ring.gather_transitions(full, t_idx, b_idx, n_step, 0.97,
+    a = ring.gather_transitions(full, t_idx, b_idx, n_step, 0.97, lanes,
                                 merge_obs_rows=merge)
-    b = ring.gather_transitions(dd, t_idx, b_idx, n_step, 0.97,
+    b = ring.gather_transitions(dd, t_idx, b_idx, n_step, 0.97, lanes,
                                 merge_obs_rows=merge, frame_stack=S,
                                 frame_shape=(H, W, 1))
     a_obs = np.asarray(a.obs).reshape(len(offsets), H, W, S)
@@ -130,7 +130,7 @@ def test_dedup_reset_at_every_lookback_distance(leaf, j, steps, slots, merge,
         st = ring.time_ring_init(slots, lanes, stored, merge_obs_rows=merge)
         st = _fill(st, obs, action, reward, term, trunc, True, merge)
         got = ring.time_ring_sample(st, jax.random.PRNGKey(j), batch, n_step,
-                                    0.97, **kw)
+                                    0.97, lanes, **kw)
     else:
         st = pring.prioritized_ring_init(slots, lanes, stored,
                                          merge_obs_rows=merge)
@@ -138,7 +138,7 @@ def test_dedup_reset_at_every_lookback_distance(leaf, j, steps, slots, merge,
                    add=pring.prioritized_ring_add)
         got = pring.prioritized_ring_sample(
             st, jax.random.PRNGKey(j), batch, n_step, 0.97, alpha=0.6,
-            beta=jnp.float32(0.4), **kw).batch
+            beta=jnp.float32(0.4), num_envs=lanes, **kw).batch
 
     t, b = np.divmod(np.asarray(got.action), lanes)
     assert (t == start).all() and set(b) == {0, 1}
@@ -168,7 +168,7 @@ def test_dedup_rebuild_is_one_row_gather_per_leaf():
     idx = jnp.zeros((n,), jnp.int32)
     jaxpr = jax.make_jaxpr(
         lambda s, t, b: ring.gather_transitions(
-            s, t, b, 3, 0.97, merge_obs_rows=True, frame_stack=S,
+            s, t, b, 3, 0.97, lanes, merge_obs_rows=True, frame_stack=S,
             frame_shape=(H, W, 1)))(st, idx, idx)
     assert jaxpr.out_avals[0].shape == (n, H, W, S)  # Transition.obs
     eqns = list(_eqns(jaxpr.jaxpr))
@@ -193,7 +193,7 @@ def test_dedup_uniform_sample_range_excludes_contextless_slots():
     # 20 steps stored at slots 0..19; dedup-valid starts are 3..15.
     for seed in range(5):
         batch = ring.time_ring_sample(dd, jax.random.PRNGKey(seed), 64,
-                                      n_step, 0.97, frame_stack=S,
+                                      n_step, 0.97, lanes, frame_stack=S,
                                       frame_shape=(H, W, 1))
         assert batch.obs.shape == (64, H, W, S)
     assert bool(ring.time_ring_can_sample(dd, n_step, frame_stack=S))
@@ -211,13 +211,12 @@ def test_dedup_prioritized_mask_and_gather():
                                      jnp.zeros((H, W, 1), jnp.uint8))
     st = _fill(st, obs, action, reward, term, trunc, True, False,
                add=pring.prioritized_ring_add)
-    mask = np.asarray(pring._valid_start_mask(st.ring, n_step,
-                                              frame_stack=S))
+    mask = np.asarray(pring._valid_start_mask(st.ring, n_step, S, slots))
     assert not mask[:S - 1].any()          # contextless slots excluded
     assert mask[S - 1:steps - n_step].all()
     s = pring.prioritized_ring_sample(st, jax.random.PRNGKey(0), 32,
                                       n_step, 0.97, alpha=0.6,
-                                      beta=jnp.float32(0.4),
+                                      beta=jnp.float32(0.4), num_envs=lanes,
                                       frame_stack=S, frame_shape=(H, W, 1))
     assert s.batch.obs.shape == (32, H, W, S)
     assert bool((np.asarray(s.t_idx) >= S - 1).all())
@@ -264,11 +263,11 @@ def test_sequence_dedup_rebuild_matches_stacked(merge, steps):
                 (len(offsets) + lanes - 1) // lanes)[:len(offsets)],
         jnp.int32)
 
-    want = sring._gather_seq(
-        full.ring.obs.reshape(slots, lanes, H, W, S) if merge
-        else full.ring.obs, t_idx, b_idx, L, slots)
+    want = (full.ring.obs.reshape(slots, lanes, H, W, S) if merge
+            else full.ring.obs)[sring._window_slots(t_idx, L, slots),
+                                b_idx[None, :]]
     got = sring._rebuild_seq_stacks(dd.ring, t_idx, b_idx, L, S,
-                                    merge, (H, W, 1))
+                                    merge, (H, W, 1), slots, lanes)
     np.testing.assert_array_equal(np.asarray(want), np.asarray(got))
 
 
@@ -321,7 +320,8 @@ def _stacked_windows(full, merge, t_idx, b_idx):
     obs = full.ring.obs
     if merge:
         obs = obs.reshape(_SEQ_SLOTS, -1, H, W, S)
-    return sring._gather_seq(obs, t_idx, b_idx, _SEQ_L, _SEQ_SLOTS)
+    return obs[sring._window_slots(t_idx, _SEQ_L, _SEQ_SLOTS),
+               b_idx[None, :]]
 
 
 @pytest.mark.parametrize("same_lane", [False, True])
@@ -357,7 +357,7 @@ def test_sequence_rebuild_at_every_end_position(merge, age, where, wrap,
 
     want = _stacked_windows(full, merge, t_idx, b_idx)
     got = sring._rebuild_seq_stacks(dd.ring, t_idx, b_idx, L, S, merge,
-                                    (H, W, 1))
+                                    (H, W, 1), slots, 1 + len(_SEQ_END))
     assert got.shape == (L, 2, H, W, S) and got.dtype == jnp.uint8
     np.testing.assert_array_equal(np.asarray(want), np.asarray(got))
 
@@ -375,7 +375,7 @@ def test_sequence_rebuild_of_wider_frames_is_exact(merge):
     b_idx = jnp.asarray(np.tile(np.arange(lanes), len(starts)), jnp.int32)
     want = _stacked_windows(full, merge, t_idx, b_idx)
     got = sring._rebuild_seq_stacks(dd.ring, t_idx, b_idx, L, S, merge,
-                                    (H, W, 1))
+                                    (H, W, 1), slots, lanes)
     assert got.dtype == jnp.uint16
     np.testing.assert_array_equal(np.asarray(want), np.asarray(got))
 
@@ -412,7 +412,8 @@ def test_sequence_rebuild_reads_each_frame_once(merge):
     idx = jnp.zeros((5,), jnp.int32)
     jaxpr = jax.make_jaxpr(
         lambda r, t, b: sring._rebuild_seq_stacks(
-            r, t, b, _SEQ_L, S, merge, (H, W, 1)))(dd.ring, idx, idx)
+            r, t, b, _SEQ_L, S, merge, (H, W, 1), _SEQ_SLOTS,
+            1 + len(_SEQ_END)))(dd.ring, idx, idx)
     assert jaxpr.out_avals[0].shape == (_SEQ_L, 5, H, W, S)
     eqns = list(_eqns(jaxpr.jaxpr))
     frame_gathers = [e.invars[0].aval.shape for e in eqns

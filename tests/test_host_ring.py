@@ -53,7 +53,7 @@ def test_host_ring_matches_device_ring(dedup, steps, slots):
 
     hb = host.gather(t_idx, b_idx, n_step, 0.97)
     db = dring.gather_transitions(dev, jnp.asarray(t_idx),
-                                  jnp.asarray(b_idx), n_step, 0.97,
+                                  jnp.asarray(b_idx), n_step, 0.97, lanes,
                                   frame_stack=S if dedup else 0)
     np.testing.assert_array_equal(hb.obs, np.asarray(db.obs))
     np.testing.assert_array_equal(hb.next_obs, np.asarray(db.next_obs))

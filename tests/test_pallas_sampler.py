@@ -25,7 +25,7 @@ def test_kernel_matches_numpy_reference():
     w = _mass(rng, T, B)
     u = ((np.arange(S) + rng.uniform(size=S)) / S).astype(np.float32)
     t, b, p, tot = map(np.asarray, pallas_stratified_sample(
-        jnp.asarray(w), jnp.asarray(u), interpret=True))
+        jnp.asarray(w).reshape(-1), jnp.asarray(u), B, interpret=True))
 
     flat = w.reshape(-1)
     cdf = np.cumsum(flat)
@@ -45,7 +45,7 @@ def test_kernel_never_selects_zero_mass():
     w = _mass(rng, T, B, zero_frac=0.9)
     u = ((np.arange(S) + rng.uniform(size=S)) / S).astype(np.float32)
     t, b, p, _ = map(np.asarray, pallas_stratified_sample(
-        jnp.asarray(w), jnp.asarray(u), interpret=True))
+        jnp.asarray(w).reshape(-1), jnp.asarray(u), B, interpret=True))
     assert (p > 0).all()
     assert (w[t, b] > 0).all()
     assert (t < T).all()                    # padded rows never selected
@@ -67,7 +67,7 @@ def test_top_of_cdf_stops_at_the_last_row_with_mass(T, B):
     u = np.asarray([0.25, 1.0 - 1e-6, np.nextafter(one, np.float32(0)),
                     one], np.float32)
     t, b, p, _ = map(np.asarray, pallas_stratified_sample(
-        jnp.asarray(w), jnp.asarray(u), interpret=True))
+        jnp.asarray(w).reshape(-1), jnp.asarray(u), B, interpret=True))
     assert (w[t, b] > 0).all() and (p > 0).all()
     assert t.max() == live - 1
     assert (t[-1], b[-1]) == (live - 1, B // 2)
@@ -79,7 +79,7 @@ def test_kernel_distribution_tracks_mass():
     w = _mass(rng, T, B, zero_frac=0.5)
     u = ((np.arange(S) + rng.uniform(size=S)) / S).astype(np.float32)
     t, b, _, _ = map(np.asarray, pallas_stratified_sample(
-        jnp.asarray(w), jnp.asarray(u), interpret=True))
+        jnp.asarray(w).reshape(-1), jnp.asarray(u), B, interpret=True))
     counts = np.zeros((T, B))
     np.add.at(counts, (t, b), 1.0)
     expect = w / w.sum() * S
@@ -102,11 +102,12 @@ def test_ring_sampler_pallas_agrees_with_xla():
     state = pring.prioritized_ring_update(
         state, jnp.arange(32, dtype=jnp.int32) % 100,
         jnp.arange(32, dtype=jnp.int32) % 4,
-        jnp.asarray(rng.uniform(0.5, 3.0, 32).astype(np.float32)))
+        jnp.asarray(rng.uniform(0.5, 3.0, 32).astype(np.float32)),
+        num_envs=4)
 
     key = jax.random.PRNGKey(0)
     kw = dict(batch_size=64, n_step=3, gamma=0.99, alpha=0.6,
-              beta=jnp.float32(0.4))
+              beta=jnp.float32(0.4), num_envs=4)
     s_xla = pring.prioritized_ring_sample(state, key, **kw)
     s_pal = pring.prioritized_ring_sample(state, key, use_pallas=True,
                                           pallas_interpret=True, **kw)
@@ -162,14 +163,14 @@ def test_routing_never_interprets_on_tpu(monkeypatch):
     assert pallas_routing(True) == (True, False)
     assert pallas_routing(False) == (False, False)
     with pytest.raises(ValueError, match="never interpreted"):
-        pallas_stratified_sample(jnp.ones((8, 128)), jnp.full((4,), 0.5),
-                                 interpret=True)
+        pallas_stratified_sample(jnp.ones((8 * 128,)), jnp.full((4,), 0.5),
+                                 128, interpret=True)
 
 
 def test_narrow_plane_draws_match_lane_dense_plane():
-    """The kernel sees a narrow [T, 16] plane through a lane-dense view
-    of the same row-major cells: its draws are those of the XLA sampler
-    on the original plane (the apex preset's shape, cut short)."""
+    """The kernel sees the flat cells of a narrow plane of 16 lanes
+    through a lane-dense view: its draws are those of the XLA sampler on
+    the same cells (the apex preset's shape, cut short)."""
     from dist_dqn_tpu.ops.pallas_sampler import stratified_sample_at
 
     rng = np.random.default_rng(4)
@@ -178,8 +179,8 @@ def test_narrow_plane_draws_match_lane_dense_plane():
     u = jnp.asarray(((np.arange(S) + rng.uniform(size=S)) / S)
                     .astype(np.float32))
     tk, bk, pk, tot = map(np.asarray, stratified_sample_at(
-        w, u, use_pallas=True, interpret=True))
-    tx, bx, _, _ = map(np.asarray, stratified_sample_at(w, u))
+        w.reshape(-1), u, B, use_pallas=True, interpret=True))
+    tx, bx, _, _ = map(np.asarray, stratified_sample_at(w.reshape(-1), u, B))
     assert tk.max() < T and bk.max() < B
     assert np.mean((tk == tx) & (bk == bx)) >= 0.95  # fp boundary jitter
     # Same float64 reference as the dense test.

@@ -235,7 +235,7 @@ def _device_state(num_slots=16, num_envs=2, steps=12, priorities=None):
             jnp.ones((num_envs,)),
             jnp.zeros((num_envs,), bool), jnp.zeros((num_envs,), bool))
     if priorities is not None:
-        st = st._replace(priorities=jnp.asarray(priorities))
+        st = st._replace(priorities=jnp.asarray(priorities).reshape(-1))
     return st
 
 
@@ -248,7 +248,7 @@ def test_device_sample_proportional_to_priority_alpha():
     alpha = 0.6
     sample = pring.prioritized_ring_sample(
         st, jax.random.PRNGKey(0), 4096, n_step=n, gamma=0.99, alpha=alpha,
-        beta=jnp.float32(1.0))
+        beta=jnp.float32(1.0), num_envs=num_envs)
     # Valid starts: slots [0, steps - n) across both envs.
     valid = pr[:steps - n] ** alpha
     expect = valid / valid.sum()
@@ -268,7 +268,7 @@ def test_device_weights_match_formula():
     beta = 0.5
     s = pring.prioritized_ring_sample(
         st, jax.random.PRNGKey(1), 512, n_step=n, gamma=0.99, alpha=1.0,
-        beta=jnp.float32(beta))
+        beta=jnp.float32(beta), num_envs=num_envs)
     valid = pr[:steps - n, 0]
     total, n_valid = valid.sum(), len(valid)
     p_sel = valid[np.asarray(s.t_idx)] / total
@@ -280,14 +280,17 @@ def test_device_weights_match_formula():
 def test_device_update_and_max_priority_seeding():
     st = _device_state(steps=10)
     st = pring.prioritized_ring_update(
-        st, jnp.array([2, 3]), jnp.array([0, 0]), jnp.array([7.0, 0.5]))
+        st, jnp.array([2, 3]), jnp.array([0, 0]), jnp.array([7.0, 0.5]),
+        num_envs=2)
     assert float(st.max_priority) >= 7.0
-    np.testing.assert_allclose(st.priorities[2, 0], 7.0 + 1e-6, rtol=1e-5)
+    np.testing.assert_allclose(st.priorities[2 * 2 + 0], 7.0 + 1e-6,
+                               rtol=1e-5)
     # The next added slice is seeded at the new max.
     st2 = pring.prioritized_ring_add(
         st, jnp.zeros((2, 2)), jnp.zeros((2,), jnp.int32), jnp.ones((2,)),
         jnp.zeros((2,), bool), jnp.zeros((2,), bool))
-    np.testing.assert_allclose(st2.priorities[10], float(st.max_priority))
+    np.testing.assert_allclose(st2.priorities[10 * 2:11 * 2],
+                               float(st.max_priority))
 
 
 def test_device_sample_payload_matches_uniform_semantics():
@@ -296,8 +299,8 @@ def test_device_sample_payload_matches_uniform_semantics():
     st = _device_state(steps=12)
     s = pring.prioritized_ring_sample(
         st, jax.random.PRNGKey(4), 64, n_step=2, gamma=0.9, alpha=0.0,
-        beta=jnp.float32(1.0))
-    ref = ring.gather_transitions(st.ring, s.t_idx, s.b_idx, 2, 0.9)
+        beta=jnp.float32(1.0), num_envs=2)
+    ref = ring.gather_transitions(st.ring, s.t_idx, s.b_idx, 2, 0.9, 2)
     np.testing.assert_allclose(s.batch.obs, ref.obs)
     np.testing.assert_allclose(s.batch.reward, ref.reward)
     np.testing.assert_allclose(s.batch.discount, ref.discount)

@@ -72,7 +72,7 @@ def test_sample_transitions_consistent():
     state = ring.time_ring_init(64, num_envs, jnp.zeros((2,)))
     state = _fill(state, 50, num_envs, rewards=np.ones(50))
     batch = ring.time_ring_sample(state, jax.random.PRNGKey(0), 128,
-                                  n_step=n, gamma=0.9)
+                                  n_step=n, gamma=0.9, num_envs=num_envs)
     obs_t = np.asarray(batch.obs)[:, 0]
     next_t = np.asarray(batch.next_obs)[:, 0]
     np.testing.assert_allclose(next_t - obs_t, n)
@@ -89,7 +89,7 @@ def test_sample_with_termination_mid_window():
     state = ring.time_ring_init(64, num_envs, jnp.zeros((2,)))
     state = _fill(state, steps, num_envs, rewards=rewards, term=term)
     batch = ring.time_ring_sample(state, jax.random.PRNGKey(1), 256,
-                                  n_step=3, gamma=1.0)
+                                  n_step=3, gamma=1.0, num_envs=num_envs)
     obs_t = np.asarray(batch.obs)[:, 0].astype(int)
     for i, t in enumerate(obs_t):
         if t <= 10:
@@ -114,7 +114,7 @@ def test_final_obs_used_for_truncation_bootstrap():
     state = _fill(state, steps, num_envs, rewards=np.ones(steps),
                   trunc=trunc, store_final=True)
     batch = ring.time_ring_sample(state, jax.random.PRNGKey(2), 256,
-                                  n_step=3, gamma=0.9)
+                                  n_step=3, gamma=0.9, num_envs=num_envs)
     obs_t = np.asarray(batch.obs)[:, 0]
     next_t = np.asarray(batch.next_obs)[:, 0]
     disc = np.asarray(batch.discount)
@@ -136,7 +136,7 @@ def test_without_final_obs_truncation_kills_bootstrap():
     state = _fill(state, steps, num_envs, rewards=np.ones(steps),
                   trunc=trunc)
     batch = ring.time_ring_sample(state, jax.random.PRNGKey(3), 256,
-                                  n_step=3, gamma=0.9)
+                                  n_step=3, gamma=0.9, num_envs=num_envs)
     obs_t = np.asarray(batch.obs)[:, 0].astype(int)
     disc = np.asarray(batch.discount)
     crossing = (obs_t <= 7) & (obs_t + 2 >= 7)

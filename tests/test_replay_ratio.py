@@ -153,16 +153,16 @@ def test_per_batched_writeback_last_wins():
     b_idx = jnp.array([[2, 1], [1, 0], [2, 1]], jnp.int32)
     prios = jnp.array([[10.0, 20.0], [30.0, 40.0], [1.0, 2.0]])
     out = pring.prioritized_ring_update_batched(state, t_idx, b_idx,
-                                                prios, eps=0.5)
-    got = np.asarray(out.priorities)
-    assert got[1, 2] == pytest.approx(1.0 + 0.5)    # last writer: step 2
-    assert got[3, 1] == pytest.approx(2.0 + 0.5)    # last writer: step 2
-    assert got[5, 0] == pytest.approx(40.0 + 0.5)   # single writer
+                                                prios, 4, eps=0.5)
+    got = np.asarray(out.priorities)                # cells t * 4 + b
+    assert got[1 * 4 + 2] == pytest.approx(1.0 + 0.5)   # last: step 2
+    assert got[3 * 4 + 1] == pytest.approx(2.0 + 0.5)   # last: step 2
+    assert got[5 * 4 + 0] == pytest.approx(40.0 + 0.5)  # single writer
     assert float(out.max_priority) == pytest.approx(40.5)
     # Jitted path (how the chunk program runs it) agrees.
     out_j = jax.jit(pring.prioritized_ring_update_batched,
-                    static_argnames=("eps",))(state, t_idx, b_idx, prios,
-                                              eps=0.5)
+                    static_argnames=("num_envs", "eps"))(
+                        state, t_idx, b_idx, prios, num_envs=4, eps=0.5)
     np.testing.assert_array_equal(got, np.asarray(out_j.priorities))
 
 

@@ -248,7 +248,10 @@ def test_the_set_up_row_and_the_gauge_say_whether_it_engaged(family, engaged):
     assert row == {"row_major": engaged,
                    "row_major_bytes": 1024 * stored,
                    "default_bytes": min(logical * 1024, 1024 * stored),
-                   "row_width": stored if engaged else logical}
+                   "row_width": stored if engaged else logical,
+                   # ... and the scalar planes: 1024 flat cells, f32
+                   "planes": {"cells": 1024, "shape": [1024],
+                              "bytes": 4096}}
     gauge = telemetry.get_registry().gauge("dqn_ring_boundary_row_major", "")
     assert gauge.value == engaged
 
