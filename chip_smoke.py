@@ -386,8 +386,13 @@ def leg_mesh(meter: CompileMeter, config: str = "atari",
     out.update(_check_census())
 
     ring = carry.replay.ring if cfg.replay.prioritized else carry.replay
+    # The acting observation: carried beside the env state, or — an env
+    # that holds it (envs/base.py observe) — inside it.
+    from dist_dqn_tpu.envs import make_jax_env
+    held = make_jax_env(cfg.env_name).observe(carry.env_state)
     for name, leaf in (("replay.obs", jax.tree.leaves(ring.obs)[0]),
-                       ("obs", jax.tree.leaves(carry.obs)[0])):
+                       ("obs", jax.tree.leaves(carry.obs)[0]
+                        if held is None else held)):
         shards = leaf.addressable_shards
         holders = len({s.device for s in shards})
         check(holders == num_devices, f"{name} lives on {holders} devices")

@@ -25,7 +25,7 @@ from typing import NamedTuple, Tuple
 import jax
 import jax.numpy as jnp
 
-from dist_dqn_tpu.envs.base import JaxEnv
+from dist_dqn_tpu.envs.base import JaxEnv, pixel_grid
 
 Array = jnp.ndarray
 
@@ -48,14 +48,13 @@ class PixelBreakoutState(NamedTuple):
     lives: Array      # scalar int32
     in_play: Array    # scalar bool — False until FIRE serves
     t: Array          # scalar int32
-    frames: Array     # [84, 84, 4] uint8 frame stack
+    frames: Array     # the frame stack as held (envs/base.py stack_reset)
     rng: Array
 
 
 def _render(ball: Array, pad_x: Array, bricks: Array,
             in_play: Array) -> Array:
-    r = jnp.arange(_H, dtype=jnp.float32)[:, None]
-    c = jnp.arange(_W, dtype=jnp.float32)[None, :]
+    r, c = pixel_grid(_H, _W)
     # Brick wall: map each pixel to its brick cell and gather liveness.
     cell_r = jnp.clip(((r - _WALL_TOP) // _BRICK_H).astype(jnp.int32),
                       0, _ROWS - 1)
@@ -82,6 +81,7 @@ class PixelBreakout(JaxEnv):
     num_actions = 4    # NOOP, FIRE, RIGHT, LEFT (ale-py minimal order)
     observation_shape = (_H, _W, 4)
     frame_stack = 4  # rolling stack (envs/base.py contract; replay.frame_dedup)
+    obs_field = "frames"
     observation_dtype = jnp.uint8
 
     def __init__(self, max_steps: int = 2000):
@@ -94,12 +94,12 @@ class PixelBreakout(JaxEnv):
         ball = jnp.stack([pad_x, _PAD_Y - 3.0, jnp.float32(0.0),
                           jnp.float32(0.0)])
         frame = _render(ball, pad_x, bricks, jnp.bool_(False))
-        frames = jnp.tile(frame[:, :, None], (1, 1, 4))
+        frames = self.stack_reset(frame)
         state = PixelBreakoutState(
             ball=ball, pad_x=pad_x, bricks=bricks,
             lives=jnp.int32(_LIVES), in_play=jnp.bool_(False),
             t=jnp.int32(0), frames=frames, rng=rng)
-        return state, frames
+        return state, self.stack_obs(frames)
 
     def _reset_rng(self, state: PixelBreakoutState) -> Array:
         return state.rng
@@ -161,9 +161,9 @@ class PixelBreakout(JaxEnv):
         truncated = jnp.logical_and(t >= self.max_steps, ~terminated)
 
         frame = _render(ball, pad_x, bricks, in_play)
-        frames = jnp.concatenate(
-            [state.frames[:, :, 1:], frame[:, :, None]], axis=2)
+        frames = self.stack_roll(state.frames, frame)
         new_state = PixelBreakoutState(
             ball=ball, pad_x=pad_x, bricks=bricks, lives=lives,
             in_play=in_play, t=t, frames=frames, rng=rng)
-        return new_state, frames, reward, terminated, truncated
+        return (new_state, self.stack_obs(frames), reward, terminated,
+                truncated)

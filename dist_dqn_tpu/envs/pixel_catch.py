@@ -23,7 +23,7 @@ from typing import NamedTuple, Tuple
 import jax
 import jax.numpy as jnp
 
-from dist_dqn_tpu.envs.base import JaxEnv
+from dist_dqn_tpu.envs.base import JaxEnv, pixel_grid
 
 Array = jnp.ndarray
 
@@ -39,13 +39,12 @@ class PixelCatchState(NamedTuple):
     ball_y: Array
     pad_x: Array
     t: Array          # scalar int32
-    frames: Array     # [84, 84, 4] uint8
+    frames: Array     # the frame stack as held (envs/base.py stack_reset)
     rng: Array
 
 
 def _render(ball_x: Array, ball_y: Array, pad_x: Array) -> Array:
-    r = jnp.arange(_H, dtype=jnp.float32)[:, None]
-    c = jnp.arange(_W, dtype=jnp.float32)[None, :]
+    r, c = pixel_grid(_H, _W)
     ball_m = (jnp.abs(r - ball_y) <= 1.5) & (jnp.abs(c - ball_x) <= 1.5)
     pad_m = (jnp.abs(r - _PAD_Y) <= 1.5) & (jnp.abs(c - pad_x) <= _PAD_HALF)
     return (ball_m.astype(jnp.uint8) * 255 | pad_m.astype(jnp.uint8) * 200)
@@ -55,6 +54,7 @@ class PixelCatch(JaxEnv):
     num_actions = 3    # NOOP, LEFT, RIGHT (minimal-set convention)
     observation_shape = (_H, _W, 4)
     frame_stack = 4  # rolling stack (envs/base.py contract; replay.frame_dedup)
+    obs_field = "frames"
     observation_dtype = jnp.uint8
 
     def __init__(self, max_steps: int = 200):
@@ -67,9 +67,10 @@ class PixelCatch(JaxEnv):
                                    _W - 1.0 - _PAD_HALF)
         ball_y = jnp.float32(4.0)
         frame = _render(ball_x, ball_y, pad_x)
-        frames = jnp.tile(frame[:, :, None], (1, 1, 4))
-        return PixelCatchState(ball_x=ball_x, ball_y=ball_y, pad_x=pad_x,
-                               t=jnp.int32(0), frames=frames, rng=rng), frames
+        frames = self.stack_reset(frame)
+        state = PixelCatchState(ball_x=ball_x, ball_y=ball_y, pad_x=pad_x,
+                                t=jnp.int32(0), frames=frames, rng=rng)
+        return state, self.stack_obs(frames)
 
     def _reset_rng(self, state: PixelCatchState) -> Array:
         return state.rng
@@ -87,9 +88,9 @@ class PixelCatch(JaxEnv):
         terminated = reached
         truncated = jnp.logical_and(t >= self.max_steps, ~terminated)
         frame = _render(state.ball_x, ball_y, pad_x)
-        frames = jnp.concatenate(
-            [state.frames[:, :, 1:], frame[:, :, None]], axis=2)
+        frames = self.stack_roll(state.frames, frame)
         new_state = PixelCatchState(ball_x=state.ball_x, ball_y=ball_y,
                                     pad_x=pad_x, t=t, frames=frames,
                                     rng=state.rng)
-        return new_state, frames, reward, terminated, truncated
+        return (new_state, self.stack_obs(frames), reward, terminated,
+                truncated)

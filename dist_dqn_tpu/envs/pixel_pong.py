@@ -18,7 +18,7 @@ from typing import NamedTuple, Tuple
 import jax
 import jax.numpy as jnp
 
-from dist_dqn_tpu.envs.base import JaxEnv
+from dist_dqn_tpu.envs.base import JaxEnv, pixel_grid
 
 Array = jnp.ndarray
 
@@ -45,14 +45,14 @@ class PixelPongState(NamedTuple):
     opp_y: Array      # opponent paddle center
     score: Array      # [2] int32 = (agent, opponent)
     t: Array          # scalar int32
-    frames: Array     # [84, 84, 4] uint8 frame stack
+    frames: Array     # the frame stack as held (envs/base.py stack_reset)
     rng: Array
 
 
 def _render(ball: Array, pad_y: Array, opp_y: Array) -> Array:
-    """Rasterize one [84, 84] uint8 frame with pure broadcasting."""
-    r = jnp.arange(_H, dtype=jnp.float32)[:, None]
-    c = jnp.arange(_W, dtype=jnp.float32)[None, :]
+    """Rasterize one uint8 frame, [84 * 84] on the flat pixel index
+    (envs/base.py pixel_grid), elementwise."""
+    r, c = pixel_grid(_H, _W)
     ball_m = (jnp.abs(r - ball[1]) <= 1.0) & (jnp.abs(c - ball[0]) <= 1.0)
     pad_m = (jnp.abs(r - pad_y) <= _PAD_HALF) & (jnp.abs(c - _AGENT_X) <= 1.0)
     opp_m = (jnp.abs(r - opp_y) <= _PAD_HALF) & (jnp.abs(c - _OPP_X) <= 1.0)
@@ -73,6 +73,7 @@ class PixelPong(JaxEnv):
     num_actions = 6
     observation_shape = (_H, _W, 4)
     frame_stack = 4  # rolling stack (envs/base.py contract; replay.frame_dedup)
+    obs_field = "frames"
     observation_dtype = jnp.uint8
 
     def __init__(self, max_steps: int = 2000):
@@ -85,11 +86,11 @@ class PixelPong(JaxEnv):
         pad_y = jnp.float32(_H / 2.0)
         opp_y = jnp.float32(_H / 2.0)
         frame = _render(ball, pad_y, opp_y)
-        frames = jnp.tile(frame[:, :, None], (1, 1, 4))
+        frames = self.stack_reset(frame)
         state = PixelPongState(ball=ball, pad_y=pad_y, opp_y=opp_y,
                                score=jnp.zeros((2,), jnp.int32),
                                t=jnp.int32(0), frames=frames, rng=rng)
-        return state, frames
+        return state, self.stack_obs(frames)
 
     def _reset_rng(self, state: PixelPongState) -> Array:
         return state.rng
@@ -137,11 +138,11 @@ class PixelPong(JaxEnv):
         ball = jnp.where(point, served, jnp.stack([bx, by, vx, vy]))
 
         frame = _render(ball, pad_y, opp_y)
-        frames = jnp.concatenate([state.frames[:, :, 1:], frame[:, :, None]],
-                                 axis=2)
+        frames = self.stack_roll(state.frames, frame)
         t = state.t + 1
         terminated = jnp.max(score) >= _WIN_SCORE
         truncated = jnp.logical_and(t >= self.max_steps, ~terminated)
         new_state = PixelPongState(ball=ball, pad_y=pad_y, opp_y=opp_y,
                                    score=score, t=t, frames=frames, rng=rng)
-        return new_state, frames, reward, terminated, truncated
+        return (new_state, self.stack_obs(frames), reward, terminated,
+                truncated)

@@ -20,7 +20,7 @@ from typing import NamedTuple, Tuple
 import jax
 import jax.numpy as jnp
 
-from dist_dqn_tpu.envs.base import JaxEnv
+from dist_dqn_tpu.envs.base import JaxEnv, pixel_grid
 
 Array = jnp.ndarray
 
@@ -47,7 +47,7 @@ class PixelReacherState(NamedTuple):
     theta_dot: Array  # [2] joint velocities
     target: Array   # [2] (x, y) px
     t: Array        # scalar int32
-    frames: Array   # [84, 84, 4] uint8
+    frames: Array     # the frame stack as held (envs/base.py stack_reset)
     rng: Array
 
 
@@ -61,9 +61,8 @@ def _tip_positions(theta: Array) -> Tuple[Array, Array]:
 
 
 def _segment_mask(a: Array, b: Array, half_width: float) -> Array:
-    """[84, 84] bool: pixels within ``half_width`` of segment a->b."""
-    r = jnp.arange(_H, dtype=jnp.float32)[:, None]
-    c = jnp.arange(_W, dtype=jnp.float32)[None, :]
+    """[84 * 84] bool: pixels within ``half_width`` of segment a->b."""
+    r, c = pixel_grid(_H, _W)
     ab = b - a
     denom = jnp.maximum(jnp.sum(ab * ab), 1e-6)
     # Project each pixel onto the segment, clamp to [0, 1].
@@ -79,8 +78,7 @@ def _render(theta: Array, target: Array) -> Array:
     anchor = jnp.stack([jnp.float32(_CX), jnp.float32(_CY)])
     link1 = _segment_mask(anchor, elbow, 1.5)
     link2 = _segment_mask(elbow, tip, 1.5)
-    r = jnp.arange(_H, dtype=jnp.float32)[:, None]
-    c = jnp.arange(_W, dtype=jnp.float32)[None, :]
+    r, c = pixel_grid(_H, _W)
     d2_target = (c - target[0]) ** 2 + (r - target[1]) ** 2
     ring = (d2_target <= _TARGET_R ** 2) & (d2_target >= (_TARGET_R - 2.0) ** 2)
     d2_tip = (c - tip[0]) ** 2 + (r - tip[1]) ** 2
@@ -112,6 +110,7 @@ class PixelReacher(JaxEnv):
     num_actions = 9
     observation_shape = (_H, _W, 4)
     frame_stack = 4  # rolling stack (envs/base.py contract; replay.frame_dedup)
+    obs_field = "frames"
     observation_dtype = jnp.uint8
 
     def __init__(self, max_steps: int = 1000, shaping: float = 0.0):
@@ -124,12 +123,12 @@ class PixelReacher(JaxEnv):
                                    jnp.pi)
         target = _sample_target(k_target)
         frame = _render(theta, target)
-        frames = jnp.tile(frame[:, :, None], (1, 1, 4))
+        frames = self.stack_reset(frame)
         state = PixelReacherState(theta=theta,
                                   theta_dot=jnp.zeros((2,), jnp.float32),
                                   target=target, t=jnp.int32(0),
                                   frames=frames, rng=rng)
-        return state, frames
+        return state, self.stack_obs(frames)
 
     def _reset_rng(self, state: PixelReacherState) -> Array:
         return state.rng
@@ -148,12 +147,12 @@ class PixelReacher(JaxEnv):
             reward = reward - self.shaping * dist / (_L1 + _L2)
 
         frame = _render(theta, state.target)
-        frames = jnp.concatenate([state.frames[:, :, 1:], frame[:, :, None]],
-                                 axis=2)
+        frames = self.stack_roll(state.frames, frame)
         t = state.t + 1
         terminated = jnp.zeros((), jnp.bool_)      # DMC: time limits only
         truncated = t >= self.max_steps
         new_state = PixelReacherState(theta=theta, theta_dot=theta_dot,
                                       target=state.target, t=t,
                                       frames=frames, rng=state.rng)
-        return new_state, frames, reward, terminated, truncated
+        return (new_state, self.stack_obs(frames), reward, terminated,
+                truncated)

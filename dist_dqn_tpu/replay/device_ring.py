@@ -24,6 +24,8 @@ from jax.sharding import PartitionSpec as P
 
 from dist_dqn_tpu import loop_common
 from dist_dqn_tpu.config import ExperimentConfig
+from dist_dqn_tpu.envs.base import held_in_words, split_rows, \
+    words_newest
 from dist_dqn_tpu.replay import device as ring
 from dist_dqn_tpu.replay import prioritized_device as pring
 from dist_dqn_tpu.replay import sequence_device as sring
@@ -85,7 +87,9 @@ def merged_row_boundary(shape, dtype) -> BoundaryLayout:
 
 class DeviceRing(NamedTuple):
     init: Callable        # obs [B, ...] -> state
-    # (state, obs, actions, StepOut, the actor state held entering obs)
+    # (state, obs, actions, StepOut, the actor state held entering obs);
+    # ``obs`` as the env HOLDS it (envs/base.py: ``StackWords`` for four
+    # uint8 frames), else the observation itself
     add: Callable
     can_sample: Callable  # state -> bool: past min_fill, a whole window held
     sample: Callable      # (state, key, gamma, beta) -> sample
@@ -183,8 +187,18 @@ def make_device_ring(cfg: ExperimentConfig, env, num_shards: int = 1,
         return make(num_slots, B, example, store_final_obs=store_final,
                     merge_obs_rows=flat)
 
+    def stored_rows(obs):
+        if not held_in_words(env):
+            return flatten(jax.tree.map(slice_newest, obs))
+        # Words, one a pixel: the newest frame is byte 3 of each (not a
+        # size-1-minor slice of the stack); the whole stack is the rows of
+        # the split the actor reads too.
+        frames = (words_newest(obs.words) if stack
+                  else split_rows(obs.split))
+        return flatten(frames.reshape(frames.shape[:1] + stored_shape))
+
     def add(state, obs, actions, out, held):
-        stored = flatten(jax.tree.map(slice_newest, obs))
+        stored = stored_rows(obs)
         if sequence:
             # The *pre-step* state: what the actor held entering obs.
             return sring.sequence_ring_add(

@@ -17,9 +17,10 @@ import numpy as np
 
 from dist_dqn_tpu.config import CONFIGS, ExperimentConfig, apply_overrides
 from dist_dqn_tpu.envs import make_jax_env
+from dist_dqn_tpu.envs.base import held_in_words
 from dist_dqn_tpu.models import build_network
 from dist_dqn_tpu.train_loop import (fused_parts, make_evaluator,
-                                     make_fused_train)
+                                     make_fused_train, twin_obs_checkpoint)
 
 
 def _pick_mesh_devices(num_devices: int, multiprocess: bool):
@@ -196,6 +197,13 @@ def train(cfg: ExperimentConfig, total_env_steps: int = 0, seed: int = None,
                "1: the device ring's merged-row buffer is carried row-major "
                "between chunks (no whole-ring copy in the chunk program)"
                ).set(int(bool(boundary and boundary.row_major)))
+    # Whether the loop carries the acting observation once, as 32-bit words
+    # (envs/base.py held_in_words: four uint8 frames kept in the env state).
+    obs_words = held_in_words(env)
+    _reg.gauge("dqn_obs_words",
+               "1: the fused loop carries the acting observation once, as "
+               "32-bit words (one word = a pixel's four stacked frames)"
+               ).set(int(obs_words))
     evaluate = jax.jit(make_evaluator(cfg, env, net,
                                       num_episodes=cfg.eval_episodes))
     # Chip-time attribution (ISSUE 19): the fused chunk is ONE program —
@@ -235,7 +243,8 @@ def train(cfg: ExperimentConfig, total_env_steps: int = 0, seed: int = None,
         log_fn(json.dumps({"ring_boundary": dict(
             boundary._asdict(), row_major=int(boundary.row_major),
             planes={"cells": device_ring.num_slots * device_ring.num_envs,
-                    "shape": list(plane.shape), "bytes": plane.nbytes})}))
+                    "shape": list(plane.shape), "bytes": plane.nbytes}),
+            "obs_words": obs_words}))
 
     ckpt = None
     frame_offset = 0      # added to the carry's cumulative frame metric
@@ -256,8 +265,10 @@ def train(cfg: ExperimentConfig, total_env_steps: int = 0, seed: int = None,
         # otherwise fail as a misleading structure-mismatch error).
         record_checkpoint_kind(checkpoint_dir,
                                "carry" if checkpoint_replay else "learner")
+        saved = checkpoint_tree(carry, checkpoint_replay)
         restored = ckpt.restore_latest(
-            checkpoint_tree(carry, checkpoint_replay))
+            saved, older=twin_obs_checkpoint(env, saved)
+            if checkpoint_replay else None)
         if restored is not None:
             # Resume continues toward the SAME total: the frame cursor picks
             # up at the checkpoint step so relaunching the identical command
@@ -617,8 +628,10 @@ def _train_population(cfg: ExperimentConfig, total_env_steps: int = 0,
                          "resume attempts refused at the sidecar pins",
                          {**_fl, "reason": "population"}).inc()
             raise
+        saved = checkpoint_tree(carries, checkpoint_replay)
         restored = ckpt.restore_latest(
-            checkpoint_tree(carries, checkpoint_replay))
+            saved, older=twin_obs_checkpoint(env, saved)
+            if checkpoint_replay else None)
         if restored is not None:
             frame_offset, tree = restored
             resumed_frames = frame_offset

@@ -25,7 +25,8 @@ from dist_dqn_tpu.agents.agent import make_agent
 from dist_dqn_tpu.agents.dqn import LearnerState, \
     make_population_optimizer, set_member_lr
 from dist_dqn_tpu.config import ExperimentConfig
-from dist_dqn_tpu.envs.base import JaxEnv
+from dist_dqn_tpu.envs.base import JaxEnv, StackWords, held_in_words, \
+    split_stack, words_split
 from dist_dqn_tpu.replay.device_ring import make_device_ring
 from dist_dqn_tpu.types import PyTree
 
@@ -52,6 +53,9 @@ class MemberHP(NamedTuple):
 
 class TrainCarry(NamedTuple):
     env_state: PyTree
+    # The observation the next act reads, carried beside the env state
+    # only where the state does not hold it: () for an env that does
+    # (envs/base.py ``observe``) — no second buffer of the same bytes.
     obs: PyTree
     # What the actor holds between steps, leaves [B, ...] (an LSTM's
     # (c, h)); () for a feed-forward network (agents/agent.py).
@@ -66,6 +70,35 @@ class TrainCarry(NamedTuple):
     completed_count: Array   # scalar float32
     loss_sum: Array
     train_count: Array
+
+
+def twin_obs_checkpoint(env: JaxEnv, tree):
+    """For a whole-carry checkpoint (``--checkpoint-replay``) written while
+    the carry held the observation twice, ``obs`` beside the env state's
+    own copy, both as the observation itself (before PERF.md §6, PR 41):
+    ``(example, adopt)`` for ``TrainCheckpointer.restore_latest`` — what
+    that program saved, given ``tree``, what this one saves; and how its
+    tree restored becomes this one's: the twin is dropped, the state's
+    copy put in the form the env holds it in now. None where the carry
+    still holds ``obs``."""
+    if "obs" in tree:
+        return None
+    field = env.obs_field
+    held = getattr(tree["env_state"], field)
+    obs = jax.eval_shape(env.stack_obs, held)
+    saved = jax.ShapeDtypeStruct(obs.shape, obs.dtype,
+                                 sharding=getattr(held, "sharding", None))
+    example = dict(tree, obs=saved,
+                   env_state=tree["env_state"]._replace(**{field: saved}))
+
+    def adopt(restored):
+        state = restored["env_state"]
+        return dict(
+            {k: v for k, v in restored.items() if k != "obs"},
+            env_state=state._replace(
+                **{field: env.stack_held(getattr(state, field))}))
+
+    return example, adopt
 
 
 def fused_parts(cfg: ExperimentConfig, env: JaxEnv, net,
@@ -143,6 +176,11 @@ def make_fused_train(cfg: ExperimentConfig, env: JaxEnv, net,
     eps_member = (loop_common.make_member_epsilon(cfg, B, num_shards)
                   if member_hp else None)
     _split_rng = loop_common.make_rng_splitter(spmd)
+    # Acting and the insert read the observation AS HELD: words for four
+    # uint8 frames, with the one relayout both share (envs/base.py
+    # StackWords) — the network its batch-minor stack, the ring its rows
+    # or the newest frame (replay/device_ring.py).
+    obs_words = held_in_words(env)
 
     def init(rng: Array, hp: Optional[MemberHP] = None) -> TrainCarry:
         base = rng
@@ -155,9 +193,6 @@ def make_fused_train(cfg: ExperimentConfig, env: JaxEnv, net,
         if spmd:
             k_learn = jax.random.fold_in(base, 7)
         env_state, obs = env.v_reset(k_env, B)
-        # Envs may return obs aliasing their own state (e.g. CartPole's
-        # phys vector); the carry is donated, so every leaf must be distinct.
-        obs = jax.tree.map(jnp.copy, obs)
         replay_state = replay.init(obs)
         # The learner inits on the full stacked obs of one lane.
         learner = agent.init_learner(k_learn,
@@ -165,6 +200,10 @@ def make_fused_train(cfg: ExperimentConfig, env: JaxEnv, net,
         if member_lr:
             learner = set_member_lr(learner, hp.lr)
         zero = jnp.float32(0.0)
+        # Envs may return obs aliasing their own state (e.g. CartPole's
+        # phys vector); the carry is donated, so every leaf must be distinct.
+        obs = (jax.tree.map(jnp.copy, obs)
+               if env.observe(env_state) is None else ())
         return TrainCarry(env_state=env_state, obs=obs,
                           actor_carry=agent.initial_state(B),
                           replay=replay_state, learner=learner,
@@ -187,14 +226,20 @@ def make_fused_train(cfg: ExperimentConfig, env: JaxEnv, net,
         # the live fp32 learner params, exactly the pre-split program.
         acting_params = (actor_params if actor_params is not None
                          else carry.learner.params)
+        held = env.observe(carry.env_state)
+        obs = carry.obs if held is None else held
         # Stage names (telemetry/stages.py STAGES): trace metadata only.
         with jax.named_scope("act"):
+            if obs_words:
+                obs = StackWords(held, words_split(held))
+            acting_obs = (split_stack(obs.split, env.observation_shape[:-1])
+                          if obs_words else obs)
             actor_carry, actions = agent.act(
-                acting_params, carry.actor_carry, carry.obs, k_act, eps)
+                acting_params, carry.actor_carry, acting_obs, k_act, eps)
         with jax.named_scope("env"):
             env_state, out = env.v_step(carry.env_state, actions)
         with jax.named_scope("insert"):
-            replay_state = replay.add(carry.replay, carry.obs, actions, out,
+            replay_state = replay.add(carry.replay, obs, actions, out,
                                       carry.actor_carry)
         beta = beta_at(carry.iteration)
 
@@ -240,7 +285,7 @@ def make_fused_train(cfg: ExperimentConfig, env: JaxEnv, net,
             loop_common.episode_stats_update(carry, out.reward, done)
 
         return TrainCarry(
-            env_state=env_state, obs=out.obs,
+            env_state=env_state, obs=out.obs if held is None else (),
             actor_carry=agent.reset_state(actor_carry, done),
             replay=replay_state, learner=learner,
             rng=rng, iteration=carry.iteration + 1, ep_return=ep_return,

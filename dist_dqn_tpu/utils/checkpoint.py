@@ -31,7 +31,7 @@ shardings, so a pod checkpoint restores onto the same mesh layout).
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Optional, Tuple
+from typing import Any, Callable, Optional, Tuple
 
 import jax
 import numpy as np
@@ -181,14 +181,19 @@ class TrainCheckpointer:
             steps.append(int(mgr_step))
         return max(steps) if steps else None
 
-    def restore_latest(self, example: PyTree, step: Optional[int] = None
+    def restore_latest(self, example: PyTree, step: Optional[int] = None,
+                       older: Optional[Tuple[PyTree, Callable]] = None
                        ) -> Optional[Tuple[int, PyTree]]:
         """Restore the newest checkpoint (or a specific retained ``step``
         from ``all_steps()``) as (frames, learner), or None.
 
         ``example`` is a live learner pytree of the target structure; its
         shapes/dtypes/shardings template the restore, so restoring onto a
-        different mesh layout re-shards on load.
+        different mesh layout re-shards on load. ``older`` is
+        ``(example, adopt)``: the tree an earlier program saved where it
+        was another one, and how that tree restored becomes this one's
+        (train_loop.py ``twin_obs_checkpoint``); tried where the
+        checkpoint does not fit ``example``.
         """
         # The save schedule advances only on the latest-resume path: an
         # explicitly requested OLD step (the eval surfaces walk
@@ -199,43 +204,59 @@ class TrainCheckpointer:
             step = self.latest_step()
         if step is None:
             return None
-        abstract = jax.tree.map(
-            lambda x: jax.ShapeDtypeStruct(
-                np.shape(x), x.dtype,
-                sharding=getattr(x, "sharding", None)),
-            example)
-        try:
-            restored = self._mgr.restore(
+
+        def restore(tree):
+            abstract = jax.tree.map(
+                lambda x: jax.ShapeDtypeStruct(
+                    np.shape(x), x.dtype,
+                    sharding=getattr(x, "sharding", None)),
+                tree)
+            return self._mgr.restore(
                 step, args=ocp.args.StandardRestore(abstract))
+
+        try:
+            restored = restore(example)
         except ValueError as e:
-            # Orbax's structure-mismatch error lists raw pytree paths;
-            # the usual cause is a config drift, so say that first — but
-            # only for actual structure mismatches; any other restore
-            # ValueError (corruption, sharding mapping, ...) passes
-            # through untouched.
-            msg = str(e)
-            if "not compatible with the stored shape" in msg:
-                # The same tree, an array in another shape: refused,
-                # never reinterpreted.
-                raise ValueError(
-                    "checkpoint holds an array in another shape than this "
-                    "program stores it in: a whole-carry checkpoint "
-                    "(--checkpoint-replay) from before the device ring "
-                    "kept its per-step planes flat ([slots * lanes], once "
-                    "[slots, lanes]) cannot be resumed; start the run "
-                    f"again.\n\nOriginal error:\n{e}") from e
-            if not ("structures do not match" in msg
-                    or "User-provided restore item" in msg):
-                raise
-            raise ValueError(
-                "checkpoint does not match the current config's learner "
-                "structure — it was saved with a different network/"
-                "optimizer architecture. Rebuild with the same --config "
-                "and --set overrides used at save time.\n\nOriginal "
-                f"error:\n{e}") from e
+            restored = None
+            if older is not None:
+                try:
+                    restored = older[1](restore(older[0]))
+                except ValueError:
+                    pass    # the first refusal stands
+            if restored is None:
+                self._refuse(e)
         if advance_schedule:
             self._next_save = step + self.save_every_frames
         return int(step), restored
+
+    @staticmethod
+    def _refuse(e: ValueError) -> None:
+        """Re-raise a restore's ValueError, the usual causes said first."""
+        # Orbax's structure-mismatch error lists raw pytree paths;
+        # the usual cause is a config drift, so say that first — but
+        # only for actual structure mismatches; any other restore
+        # ValueError (corruption, sharding mapping, ...) passes
+        # through untouched.
+        msg = str(e)
+        if "not compatible with the stored shape" in msg:
+            # The same tree, an array in another shape: refused,
+            # never reinterpreted.
+            raise ValueError(
+                "checkpoint holds an array in another shape than this "
+                "program stores it in: a whole-carry checkpoint "
+                "(--checkpoint-replay) from before the device ring "
+                "kept its per-step planes flat ([slots * lanes], once "
+                "[slots, lanes]) cannot be resumed; start the run "
+                f"again.\n\nOriginal error:\n{e}") from e
+        if not ("structures do not match" in msg
+                or "User-provided restore item" in msg):
+            raise e
+        raise ValueError(
+            "checkpoint does not match the current config's learner "
+            "structure — it was saved with a different network/"
+            "optimizer architecture. Rebuild with the same --config "
+            "and --set overrides used at save time.\n\nOriginal "
+            f"error:\n{e}") from e
 
     def restore_params(self, example_params: PyTree,
                        step: Optional[int] = None,

@@ -127,10 +127,11 @@ def test_host_pong_step_parity_with_jax_twin():
         jstate = pixel_pong.PixelPongState(
             ball=jnp.asarray(ball, jnp.float32), pad_y=jnp.float32(pad_y),
             opp_y=jnp.float32(opp_y), score=jnp.zeros((2,), jnp.int32),
-            t=jnp.int32(0), frames=jnp.zeros((84, 84, 4), jnp.uint8),
+            t=jnp.int32(0),
+            frames=jenv.stack_held(jnp.zeros((84, 84, 4), jnp.uint8)),
             rng=jax.random.PRNGKey(0))
-        jnew, _, jr, jterm, jtrunc = jenv.env_step(jstate,
-                                                   jnp.int32(action))
+        jnew, jobs, jr, jterm, jtrunc = jenv.env_step(jstate,
+                                                      jnp.int32(action))
         hobs, hr, hterm, htrunc = henv.step(action)
         np.testing.assert_allclose(np.asarray(jnew.ball), henv._ball,
                                    rtol=1e-5, err_msg=str(ball))
@@ -140,7 +141,7 @@ def test_host_pong_step_parity_with_jax_twin():
                                    rtol=1e-6)
         assert float(jr) == hr and bool(jterm) == hterm
         # Rendering parity: the freshly rasterized frame is identical.
-        np.testing.assert_array_equal(np.asarray(jnew.frames[:, :, -1]),
+        np.testing.assert_array_equal(np.asarray(jobs[:, :, -1]),
                                       hobs[:, :, -1])
 
 
@@ -222,7 +223,7 @@ def test_host_breakout_contract_and_parity_with_jax_twin():
         jstate = jstate._replace(
             ball=jnp.asarray(ball, jnp.float32),
             pad_x=jnp.float32(pad_x), in_play=jnp.bool_(True))
-        jnew, _, jr, jterm, _ = jenv.env_step(jstate, jnp.int32(action))
+        jnew, jobs, jr, jterm, _ = jenv.env_step(jstate, jnp.int32(action))
         hobs, hr, hterm, _ = henv.step(action)
         np.testing.assert_allclose(np.asarray(jnew.ball), henv._ball,
                                    rtol=1e-5, err_msg=str(ball))
@@ -233,5 +234,5 @@ def test_host_breakout_contract_and_parity_with_jax_twin():
         assert bool(jnew.in_play) == henv._in_play, ball
         np.testing.assert_array_equal(np.asarray(jnew.bricks),
                                       henv._bricks, err_msg=str(ball))
-        np.testing.assert_array_equal(np.asarray(jnew.frames[:, :, -1]),
+        np.testing.assert_array_equal(np.asarray(jobs[:, :, -1]),
                                       hobs[:, :, -1])
