@@ -68,10 +68,16 @@ def make_r2d2_learner(net, cfg: LearnerConfig, rcfg: ReplayConfig,
             rng=rng,
         )
 
-    def _unrolled_q(params: PyTree, sample: SequenceSample) -> Array:
+    def _unrolled_q(params: PyTree, sample: SequenceSample,
+                    unroll_pass) -> Array:
         """Burn in (stop-grad) then unroll the loss+bootstrap region.
 
         Returns q over steps [burn, burn+unroll+n): [unroll+n, S, A].
+
+        Each region is entered under its pass name (telemetry/stages.py
+        PASSES, children of stage ``loss_grad``): ``burn_in`` for either
+        network, then ``unroll_pass`` — the caller's ``named_scope`` for
+        this network's loss+bootstrap region.
 
         The two regions are row ranges of the FLAT ``[L*B, ...]`` batch,
         handed on in the ``[T, B, ...]`` shape ``net.unroll`` flattens
@@ -89,17 +95,22 @@ def make_r2d2_learner(net, cfg: LearnerConfig, rcfg: ReplayConfig,
 
         carry = sample.start_state
         if burn:
-            carry, _ = net.apply(params, carry, steps(0, burn),
-                                 sample.reset[:burn], method=net.unroll)
-            carry = jax.lax.stop_gradient(carry)
-        _, q = net.apply(params, carry, steps(burn, obs.shape[0]),
-                         sample.reset[burn:], method=net.unroll)
+            with jax.named_scope("burn_in"):
+                carry, _ = net.apply(params, carry, steps(0, burn),
+                                     sample.reset[:burn], method=net.unroll)
+                carry = jax.lax.stop_gradient(carry)
+        with unroll_pass:
+            _, q = net.apply(params, carry, steps(burn, obs.shape[0]),
+                             sample.reset[burn:], method=net.unroll)
         return q
 
     def loss_fn(params: PyTree, target_params: PyTree,
                 sample: SequenceSample) -> Tuple[Array, Tuple]:
-        q_online = _unrolled_q(params, sample)          # [unroll+n, S, A]
-        q_target = _unrolled_q(target_params, sample)   # [unroll+n, S, A]
+        # [unroll+n, S, A] each
+        q_online = _unrolled_q(params, sample,
+                               jax.named_scope("online_unroll"))
+        q_target = _unrolled_q(target_params, sample,
+                               jax.named_scope("target_unroll"))
 
         # Per-step n-step returns inside the window; d_t = gamma*(1 - done_t)
         # zeroes everything past an episode end (and the bootstrap with it).
@@ -141,26 +152,34 @@ def make_r2d2_learner(net, cfg: LearnerConfig, rcfg: ReplayConfig,
 
     def train_step(state: LearnerState, sample: SequenceSample
                    ) -> Tuple[LearnerState, dict]:
-        rng, _ = jax.random.split(state.rng)
-        (loss, (priorities, raw_loss)), grads = jax.value_and_grad(
-            loss_fn, has_aux=True)(state.params, state.target_params, sample)
+        # Stage names (telemetry/stages.py STAGES), as agents/dqn.py
+        # enters them: trace metadata only.
+        with jax.named_scope("loss_grad"):
+            rng, _ = jax.random.split(state.rng)
+            (loss, (priorities, raw_loss)), grads = jax.value_and_grad(
+                loss_fn, has_aux=True)(state.params, state.target_params,
+                                       sample)
         if axis_name is not None:
-            grads = jax.lax.pmean(grads, axis_name)
-            loss = jax.lax.pmean(loss, axis_name)
-            raw_loss = jax.lax.pmean(raw_loss, axis_name)
-        updates, opt_state = tx.update(grads, state.opt_state, state.params)
-        params = optax.apply_updates(state.params, updates)
+            with jax.named_scope("allreduce"):
+                grads = jax.lax.pmean(grads, axis_name)
+                loss = jax.lax.pmean(loss, axis_name)
+                raw_loss = jax.lax.pmean(raw_loss, axis_name)
+        with jax.named_scope("optimizer"):
+            updates, opt_state = tx.update(grads, state.opt_state,
+                                           state.params)
+            params = optax.apply_updates(state.params, updates)
         steps = state.steps + 1
 
-        if cfg.target_tau > 0.0:
-            target_params = jax.tree.map(
-                lambda t, p: t + cfg.target_tau * (p - t),
-                state.target_params, params)
-        else:
-            do_sync = (steps % cfg.target_update_period) == 0
-            target_params = jax.tree.map(
-                lambda t, p: jnp.where(do_sync, p, t),
-                state.target_params, params)
+        with jax.named_scope("target_sync"):
+            if cfg.target_tau > 0.0:
+                target_params = jax.tree.map(
+                    lambda t, p: t + cfg.target_tau * (p - t),
+                    state.target_params, params)
+            else:
+                do_sync = (steps % cfg.target_update_period) == 0
+                target_params = jax.tree.map(
+                    lambda t, p: jnp.where(do_sync, p, t),
+                    state.target_params, params)
 
         new_state = LearnerState(params=params, target_params=target_params,
                                  opt_state=opt_state, steps=steps, rng=rng)

@@ -218,41 +218,47 @@ def sequence_ring_sample(state: SequenceRingState, rng: Array,
                                                  stratified_sample)
 
     num_slots, num_envs = state.state_c.shape[:2]
-    w = jnp.where(state.priorities > 0.0, state.priorities ** alpha, 0.0)
-    if frame_stack:
-        # Exclude the oldest frame_stack-1 starts: their context slots
-        # hold the other lap's frames (or nothing, first lap). Shared
-        # region logic: replay/device.py contextful_start_mask.
-        w = jnp.where(
-            ring.contextful_start_mask(state.ring, frame_stack,
-                                       num_slots)[:, None],
-            w, 0.0)
-    t_idx, b_idx, mass_sel, total = stratified_sample(
-        w.reshape(-1), rng, batch_size, num_envs, use_pallas=use_pallas,
-        interpret=pallas_interpret)
-    n_valid = jnp.sum((w > 0.0).astype(jnp.float32))
-    weights = importance_weights(mass_sel, total, n_valid, beta)
+    # Stage names (telemetry/stages.py STAGES): trace metadata only.
+    with jax.named_scope("sample"):
+        w = jnp.where(state.priorities > 0.0, state.priorities ** alpha,
+                      0.0)
+        if frame_stack:
+            # Exclude the oldest frame_stack-1 starts: their context slots
+            # hold the other lap's frames (or nothing, first lap). Shared
+            # region logic: replay/device.py contextful_start_mask.
+            w = jnp.where(
+                ring.contextful_start_mask(state.ring, frame_stack,
+                                           num_slots)[:, None],
+                w, 0.0)
+        t_idx, b_idx, mass_sel, total = stratified_sample(
+            w.reshape(-1), rng, batch_size, num_envs, use_pallas=use_pallas,
+            interpret=pallas_interpret)
+        n_valid = jnp.sum((w > 0.0).astype(jnp.float32))
+        weights = importance_weights(mass_sel, total, n_valid, beta)
 
     r = state.ring
-    tt = _window_slots(t_idx, seq_len, num_slots)              # [L, S]
-    # Slot t of env b lives at cell (and merged row) t*B + b.
-    cells = tt * num_envs + b_idx[None, :]
-    if frame_stack:
-        obs = _rebuild_seq_stacks(r, t_idx, b_idx, seq_len, frame_stack,
-                                  merge_obs_rows, frame_shape, num_slots,
-                                  num_envs)
-    elif merge_obs_rows:
-        obs = jax.tree.map(lambda x: x[cells], r.obs)
-    else:
-        obs = jax.tree.map(lambda x: x[tt, b_idx[None, :]], r.obs)
-    action, reward = r.action[cells], r.reward[cells]
-    # (one dense pass and one look-up, as replay/device.py's ``done``)
-    done = jnp.logical_or(r.terminated, r.truncated)[cells]
-    # obs[t] opens a new episode iff the previous stored step ended one. The
-    # first step never resets: its stored carry is already episode-correct.
-    reset = jnp.concatenate(
-        [jnp.zeros((1, batch_size), jnp.bool_), done[:-1]], axis=0)
-    start_state = (state.state_c[t_idx, b_idx], state.state_h[t_idx, b_idx])
+    with jax.named_scope("gather"):
+        tt = _window_slots(t_idx, seq_len, num_slots)          # [L, S]
+        # Slot t of env b lives at cell (and merged row) t*B + b.
+        cells = tt * num_envs + b_idx[None, :]
+        if frame_stack:
+            obs = _rebuild_seq_stacks(r, t_idx, b_idx, seq_len, frame_stack,
+                                      merge_obs_rows, frame_shape,
+                                      num_slots, num_envs)
+        elif merge_obs_rows:
+            obs = jax.tree.map(lambda x: x[cells], r.obs)
+        else:
+            obs = jax.tree.map(lambda x: x[tt, b_idx[None, :]], r.obs)
+        action, reward = r.action[cells], r.reward[cells]
+        # (one dense pass and one look-up, as replay/device.py's ``done``)
+        done = jnp.logical_or(r.terminated, r.truncated)[cells]
+        # obs[t] opens a new episode iff the previous stored step ended one.
+        # The first step never resets: its stored carry is already
+        # episode-correct.
+        reset = jnp.concatenate(
+            [jnp.zeros((1, batch_size), jnp.bool_), done[:-1]], axis=0)
+        start_state = (state.state_c[t_idx, b_idx],
+                       state.state_h[t_idx, b_idx])
     return SequenceSample(obs=obs, action=action, reward=reward, done=done,
                           reset=reset, start_state=start_state,
                           weights=weights, t_idx=t_idx, b_idx=b_idx)
@@ -266,10 +272,11 @@ def sequence_ring_update(state: SequenceRingState, t_idx: Array,
     Guarded by ``priorities > 0`` at the written cell so a start that was
     overwritten (cleared) between sample and update cannot be resurrected.
     """
-    p = jnp.abs(new_priorities) + eps
-    still_valid = state.priorities[t_idx, b_idx] > 0.0
-    p = jnp.where(still_valid, p, 0.0)
-    priorities = state.priorities.at[t_idx, b_idx].set(p)
-    return state._replace(
-        priorities=priorities,
-        max_priority=jnp.maximum(state.max_priority, jnp.max(p)))
+    with jax.named_scope("writeback"):
+        p = jnp.abs(new_priorities) + eps
+        still_valid = state.priorities[t_idx, b_idx] > 0.0
+        p = jnp.where(still_valid, p, 0.0)
+        priorities = state.priorities.at[t_idx, b_idx].set(p)
+        return state._replace(
+            priorities=priorities,
+            max_priority=jnp.maximum(state.max_priority, jnp.max(p)))

@@ -3,9 +3,20 @@ device trace to them.
 
 One vocabulary, :data:`STAGES`, each name entered as a ``jax.named_scope``
 where the work happens (train_loop.py, replay/device.py,
-replay/prioritized_device.py, agents/dqn.py). A scope is metadata: it lands
-in every HLO instruction's ``op_name`` and leaves the optimized program as
-it was.
+replay/prioritized_device.py, replay/sequence_device.py, agents/dqn.py,
+agents/r2d2.py). A scope is metadata: it lands in every HLO instruction's
+``op_name`` and leaves the optimized program as it was.
+
+Stage ``loss_grad`` has child names, in two groups that each split it
+another way: :data:`PASSES`, scopes the recurrent learner enters
+(agents/r2d2.py ``_unrolled_q``), and :data:`PARTS`, which nobody enters —
+they are the names models/recurrent.py gives its two sub-modules (parameter
+keys, so they cannot drift), and Flax puts a module's name on the op path.
+A child is read (:func:`child_of`) through the wrappers a transform puts
+around the outermost name inside it (``transpose(jvp(online_unroll))``),
+and counts only on an instruction whose STAGE is ``loss_grad``
+(:func:`children`): ``torso`` is on the acting path too. The stage table
+never sees a child, so a child takes no time away from its stage.
 
 A device trace names each op by its HLO instruction (``fusion.584``), so the
 join back to a stage goes through the executable that actually ran:
@@ -19,6 +30,7 @@ Stdlib only, like the rest of the package.
 """
 from __future__ import annotations
 
+import functools
 import re
 import time
 from collections import Counter
@@ -26,7 +38,16 @@ from typing import Dict, Iterable, Iterator, List, Optional, Tuple
 
 STAGES = ("act", "env", "insert", "sample", "gather", "loss_grad",
           "allreduce", "optimizer", "target_sync", "writeback")
-#: A fusion whose instructions come from more than one stage.
+#: Children of stage ``loss_grad``, entered: the recurrent learner's three
+#: network passes (burn-in of either network; online loss + bootstrap
+#: region, forward and backward; the target's, forward only).
+PASSES = ("burn_in", "online_unroll", "target_unroll")
+#: Children of stage ``loss_grad``, read: models/recurrent.py's sub-modules
+#: (convolutions + ``embed``; the scanned cell), forward and backward.
+PARTS = ("torso", "core")
+#: The stage whose instructions the child names split.
+PARENT = "loss_grad"
+#: A fusion whose instructions come from more than one stage (or child).
 MIXED = "mixed"
 
 _STAGE_SET = frozenset(STAGES)
@@ -37,6 +58,9 @@ _INSTRUCTION = re.compile(
     r"(?P<operands>[^)]*)\)")
 _COMPUTATION = re.compile(r"^(?:ENTRY )?%?([\w.\-]+) \(.*\) -> .* \{\s*$")
 _NAME = re.compile(r"%([\w.\-]+)")
+# A path part as a transform wraps it: ``jvp(..)``, ``transpose(jvp(..))``,
+# ``jit(..)``, ``vmap(..)``.
+_WRAPPED = re.compile(r"\w+\((.*)\)")
 _OP_NAME = re.compile(r'op_name="([^"]*)"')
 _CALLS = re.compile(r"\bcalls=%?([\w.\-]+)")
 # Inside a fusion these do no work of their own and do not vote (a shared
@@ -56,35 +80,61 @@ _MOVES = frozenset(("copy", "copy-start", "copy-done", "bitcast", "transpose",
                     "reshape", "get-tuple-element"))
 
 _program = None                     # the chunk program's Compiled
-_table: Optional[Dict[str, str]] = None
-_table_seconds: Optional[float] = None
+_tables: Dict[Tuple[str, ...], Dict[str, str]] = {}     # by vocabulary
+_seconds: Dict[Tuple[str, ...], float] = {}
+_children: Dict[Tuple[str, ...], Dict[str, Optional[str]]] = {}
 
 
 def keep(compiled) -> None:
     """Remember the chunk program (a reference; nothing is computed)."""
-    global _program, _table, _table_seconds
-    _program, _table, _table_seconds = compiled, None, None
+    global _program
+    _program = compiled
+    for memo in (_tables, _seconds, _children):
+        memo.clear()
 
 
-def table_built() -> bool:
-    return _table is not None
+def table_built(names: Tuple[str, ...] = STAGES) -> bool:
+    return names in _tables
 
 
-def table_seconds() -> Optional[float]:
-    """Seconds the one build of the table took; None before it."""
-    return _table_seconds
+def table_seconds(names: Tuple[str, ...] = STAGES) -> Optional[float]:
+    """Seconds the one build of that vocabulary's table took; None before
+    it."""
+    return _seconds.get(names)
 
 
-def table() -> Dict[str, str]:
-    """``{instruction name: stage}`` of the kept executable, built on the
-    first call: its text and one walk over it. Empty where no program was
-    kept."""
-    global _table, _table_seconds
-    if _table is None and _program is not None:
+def table(names: Tuple[str, ...] = STAGES) -> Dict[str, str]:
+    """``{instruction name: name}`` of the kept executable over one
+    vocabulary (:data:`STAGES`, :data:`PASSES` or :data:`PARTS`), built on
+    the first call for it: the executable's text and one walk over it.
+    Empty where no program was kept."""
+    if names not in _tables and _program is not None:
         t0 = time.perf_counter()
-        _table = table_from_text(_program.as_text())
-        _table_seconds = time.perf_counter() - t0
-    return _table or {}
+        _tables[names] = table_from_text(_program.as_text(), names)
+        _seconds[names] = time.perf_counter() - t0
+    return _tables.get(names, {})
+
+
+def children(names: Tuple[str, ...]) -> Dict[str, Optional[str]]:
+    """``{instruction: child}`` over the instructions of stage
+    :data:`PARENT` and no others: a name of ``names``, :data:`MIXED`, or
+    None where the group names nothing (the stage's own time). One dict a
+    group for as long as the program is kept."""
+    if names not in _children:
+        _children[names] = _under_parent(table(), table(names))
+    return _children[names]
+
+
+def children_from_text(hlo_text: str, names: Tuple[str, ...]
+                       ) -> Dict[str, Optional[str]]:
+    return _under_parent(table_from_text(hlo_text),
+                         table_from_text(hlo_text, names))
+
+
+def _under_parent(stage: Dict[str, str], child: Dict[str, str]
+                  ) -> Dict[str, Optional[str]]:
+    return {inst: child.get(inst) for inst, s in stage.items()
+            if s == PARENT}
 
 
 def stage_of(op_name: str) -> Optional[str]:
@@ -92,6 +142,22 @@ def stage_of(op_name: str) -> Optional[str]:
     (``jit(run_chunk)/while/body/.../gather/...``), or None."""
     for part in reversed(op_name.split("/")):
         if part in _STAGE_SET:
+            return part
+    return None
+
+
+def child_of(op_name: str, names: Iterable[str]) -> Optional[str]:
+    """The innermost part of an ``op_name`` path that is one of ``names``
+    once the transforms' wrappers are taken off it: inside
+    ``value_and_grad`` the outermost name reads ``jvp(online_unroll)`` on
+    the forward ops and ``transpose(jvp(online_unroll))`` on the backward
+    ones; the names under it (``torso``, ``core``) stay whole."""
+    for part in reversed(op_name.split("/")):
+        wrapped = _WRAPPED.fullmatch(part)
+        while wrapped:
+            part = wrapped.group(1)
+            wrapped = _WRAPPED.fullmatch(part)
+        if part in names:
             return part
     return None
 
@@ -135,14 +201,19 @@ def instructions(hlo_text: str) -> Iterator[Tuple[str, "re.Match", str]]:
                 yield current, m, line
 
 
-def table_from_text(hlo_text: str) -> Dict[str, str]:
-    """Map the instructions of an optimized HLO module to stages.
+def table_from_text(hlo_text: str, names: Tuple[str, ...] = STAGES
+                    ) -> Dict[str, str]:
+    """Map the instructions of an optimized HLO module to the names of one
+    vocabulary: the stages (read by :func:`stage_of`, whole path parts) or
+    a group of child names (:func:`child_of`). "Stage" below is whichever.
 
     An instruction takes the stage its own ``op_name`` carries. One that
     calls a computation (a fusion above all) takes the stage of the
     instructions inside it (:func:`_fusion_stage`). Compiler-inserted data
     movement under no stage takes its consumers' stage, else its
     producer's. Instructions still under no stage are left out."""
+    read = (stage_of if names == STAGES
+            else functools.partial(child_of, names=frozenset(names)))
     opcode: Dict[str, str] = {}
     own: Dict[str, Optional[str]] = {}      # instruction -> its own stage
     operands: Dict[str, List[str]] = {}
@@ -154,7 +225,7 @@ def table_from_text(hlo_text: str) -> Dict[str, str]:
         opcode[inst] = m.group("op")
         operands[inst] = _NAME.findall(m.group("operands"))
         name = _OP_NAME.search(line)
-        own[inst] = stage_of(name.group(1)) if name else None
+        own[inst] = read(name.group(1)) if name else None
         called = _CALLS.search(line)
         if called:
             calls[inst] = called.group(1)

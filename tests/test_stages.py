@@ -2,6 +2,10 @@
 demand, the compile-cache flag that keeps them true, and the host spans of
 ``train.train`` (telemetry/stages.py, utils/trace.py, utils/backend.py)."""
 import dataclasses
+import functools
+import gzip
+import hashlib
+import json
 import os
 import re
 import subprocess
@@ -34,6 +38,28 @@ def _toy_cfg(*extra):
     return dataclasses.replace(cfg, eval_every_steps=0)
 
 
+def _toy_recurrent_cfg(*extra):
+    """The recurrent learner over the sequence ring at toy size; one LSTM
+    step a trip of the cell's scan, so that the cell's ``while`` is on the
+    op paths as at the preset's 125 steps, and a float32 cell (the CPU
+    backend widens a bf16 one with converts that carry no name)."""
+    return apply_overrides(CONFIGS["r2d2"], [
+        "env_name=cartpole", "network.torso=mlp",
+        "network.mlp_features=(16,)", "network.hidden=0",
+        "network.lstm_size=8", "network.lstm_unroll=1",
+        "network.lstm_dtype=float32",
+        "network.compute_dtype=float32",
+        "replay.capacity=512", "replay.min_fill=64", "replay.burn_in=2",
+        "replay.unroll_length=4", "replay.sequence_stride=2",
+        "learner.n_step=2", "learner.batch_size=16", "actor.num_envs=4"]
+        + list(extra))
+
+
+@functools.lru_cache(maxsize=None)
+def _recurrent_text():
+    return _chunk_text(_toy_recurrent_cfg())
+
+
 def _chunk_text(cfg, num_devices=1):
     env = make_jax_env(cfg.env_name)
     net = build_network(cfg.network, env.num_actions)
@@ -50,11 +76,35 @@ def _chunk_text(cfg, num_devices=1):
 
 # -- the vocabulary --------------------------------------------------------
 def test_every_scope_in_the_package_is_a_stage_and_every_stage_is_entered():
+    """... or a pass of ``loss_grad``: the two vocabularies that ARE
+    entered. The parts are read, not entered (the next test)."""
     entered = set()
     for path in (CHECKOUT / "dist_dqn_tpu").rglob("*.py"):
         entered.update(re.findall(r'named_scope\("([^"]+)"\)',
                                   path.read_text()))
-    assert entered == set(stages.STAGES)
+    assert entered == set(stages.STAGES) | set(stages.PASSES)
+    assert not set(stages.STAGES) & set(stages.PASSES + stages.PARTS)
+
+
+def test_the_parts_are_the_recurrent_networks_module_names():
+    """``torso`` and ``core`` are parameter keys of the recurrent network
+    (so a rename breaks every checkpoint before it breaks a metric), and
+    whole parts of the op paths of all three passes, forward and
+    backward."""
+    cfg = _toy_recurrent_cfg()
+    env = make_jax_env(cfg.env_name)
+    net = build_network(cfg.network, env.num_actions)
+    params = net.init(jax.random.PRNGKey(0), net.initial_state(1),
+                      np.zeros((1, 1) + env.observation_shape, np.float32),
+                      method=net.unroll)
+    assert set(stages.PARTS) <= set(params["params"])
+    paths = set(re.findall(r'op_name="([^"]*)"', _recurrent_text()))
+    for wrapper in ("jvp(burn_in)", "jvp(online_unroll)",
+                    "jvp(target_unroll)", "transpose(jvp(online_unroll))"):
+        for part in stages.PARTS:
+            assert any(f"/loss_grad/{wrapper}/" in p
+                       and part in p.split("/") for p in paths), (wrapper,
+                                                                  part)
 
 
 @pytest.mark.parametrize("op_name,stage", [
@@ -67,6 +117,76 @@ def test_every_scope_in_the_package_is_a_stage_and_every_stage_is_entered():
 ])
 def test_stage_of_reads_the_innermost_whole_path_part(op_name, stage):
     assert stages.stage_of(op_name) == stage
+
+
+# Op paths copied from the lowering of ``_toy_recurrent_cfg()``'s chunk
+# program (``_recurrent_text()``; the live text is held to them below).
+_TRAIN = ("jit(run_chunk)/while/body/closed_call/cond/branch_1_fun/while/body/"
+          "closed_call/loss_grad/")
+_UNROLL = "RecurrentQNetwork.unroll/"
+_CELL = _UNROLL + "while/body/closed_call/core/lstm/dot_general"
+_TORSO = _UNROLL + "RecurrentQNetwork._embed/torso/MLPTorso_0/"
+REAL_PATHS = [
+    # forward only, outside any transform: the acting path
+    ("jit(run_chunk)/while/body/closed_call/act/RecurrentQNetwork/" + _TORSO
+     + "Dense_0/dot_general", None, "torso"),
+    ("jit(run_chunk)/while/body/closed_call/act/RecurrentQNetwork/" + _CELL,
+     None, "core"),
+    # inside value_and_grad every pass reads jvp(..), the target's too;
+    # the cell's own scan is a while under it
+    (_TRAIN + "jvp(burn_in)/" + _CELL, "burn_in", "core"),
+    (_TRAIN + "jvp(online_unroll)/" + _TORSO + "Dense_0/dot_general",
+     "online_unroll", "torso"),
+    (_TRAIN + "jvp(target_unroll)/" + _UNROLL
+     + "RecurrentQNetwork._q_head/advantage/dot_general", "target_unroll",
+     None),
+    # the backward ops
+    (_TRAIN + "transpose(jvp(online_unroll))/" + _CELL, "online_unroll",
+     "core"),
+    (_TRAIN + "transpose(jvp(online_unroll))/" + _TORSO
+     + "Dense_0/reduce_sum", "online_unroll", "torso"),
+    (_TRAIN + "transpose(loss_grad)/jvp(online_unroll)/" + _TORSO
+     + "select_n", "online_unroll", "torso"),
+    # loss_fn's own ops: under no child
+    (_TRAIN + "jvp()/abs", None, None),
+    (_TRAIN + "transpose(jvp())/add_any", None, None),
+    (_TRAIN + "jvp(jit(take_along_axis))/gather", None, None),
+]
+
+
+@pytest.mark.parametrize("op_name,a_pass,part", REAL_PATHS)
+def test_child_of_reads_through_the_transforms_wrappers(op_name, a_pass,
+                                                        part):
+    assert stages.child_of(op_name, stages.PASSES) == a_pass
+    assert stages.child_of(op_name, stages.PARTS) == part
+    # the stage reading takes whole parts only, as before
+    assert stages.stage_of(op_name) in ("act", "loss_grad", "gather")
+
+
+def test_the_pinned_paths_are_the_live_lowerings():
+    paths = set(re.findall(r'op_name="([^"]*)"', _recurrent_text()))
+    assert {p for p, _, _ in REAL_PATHS} <= paths
+
+
+def test_a_child_counts_only_under_loss_grad():
+    """``torso`` and ``core`` are on the acting path too: the parts' table
+    names those instructions, ``children`` leaves them out, and every
+    instruction of stage ``loss_grad`` is in it — under a child, ``mixed``
+    or None (the stage's own: the loss, the heads)."""
+    text = _recurrent_text()
+    stage = stages.table_from_text(text)
+    parts = stages.table_from_text(text, stages.PARTS)
+    acting = {i for i, s in stage.items() if s == "act" and i in parts}
+    assert {parts[i] for i in acting} >= set(stages.PARTS)
+    for group in (stages.PASSES, stages.PARTS):
+        children = stages.children_from_text(text, group)
+        assert set(children) == {i for i, s in stage.items()
+                                 if s == stages.PARENT}
+        assert not acting & set(children)
+        assert set(group) <= set(children.values()) <= set(group) | {
+            None, stages.MIXED}
+    # the stage table never sees a child
+    assert set(stage.values()) <= set(stages.STAGES) | {stages.MIXED}
 
 
 # -- the table, from the executable's text -----------------------------------
@@ -106,14 +226,20 @@ def _loop_body_instructions(text):
     return out
 
 
-@pytest.mark.parametrize("overrides,expected", [
-    ((), COMMON),
-    (("replay.prioritized=true",), COMMON | {"writeback"}),
-    (("replay.prioritized=true", "replay.updates_per_chunk=2"),
+@pytest.mark.parametrize("text_of,expected", [
+    (lambda: _chunk_text(_toy_cfg()), COMMON),
+    (lambda: _chunk_text(_toy_cfg("replay.prioritized=true")),
      COMMON | {"writeback"}),
-], ids=["uniform", "prioritized", "prioritized_ratio2"])
-def test_table_holds_every_stage_and_covers_the_loop(overrides, expected):
-    text = _chunk_text(_toy_cfg(*overrides))
+    (lambda: _chunk_text(_toy_cfg("replay.prioritized=true",
+                                  "replay.updates_per_chunk=2")),
+     COMMON | {"writeback"}),
+    # the recurrent learner over the sequence ring (its write-back has no
+    # batched flush)
+    (_recurrent_text, COMMON | {"writeback"}),
+], ids=["uniform", "prioritized", "prioritized_ratio2",
+        "the_recurrent_program_enters_the_loop_level_names"])
+def test_table_holds_every_stage_and_covers_the_loop(text_of, expected):
+    text = text_of()
     table = stages.table_from_text(text)
     assert expected <= set(table.values()) <= set(stages.STAGES) | {
         stages.MIXED}
@@ -129,28 +255,33 @@ def test_table_holds_every_stage_and_covers_the_loop(overrides, expected):
     assert len(staged) >= 0.8 * len(body), (len(staged), len(body))
 
 
-def test_the_recurrent_program_enters_the_loop_level_names():
-    """The one body's names hold for a recurrent agent over the sequence
-    ring too: ``act``, ``env``, ``insert`` (and ``sample``, ``gather``) are
-    in its table. The sequence ring's and the recurrent learner's own
-    stages are not named yet (ROADMAP.md D7), so nothing is asked of the
-    covered share."""
-    cfg = apply_overrides(CONFIGS["r2d2"], [
-        "env_name=cartpole", "network.torso=mlp",
-        "network.mlp_features=(16,)", "network.hidden=0",
-        "network.lstm_size=8", "network.compute_dtype=float32",
-        "replay.capacity=512", "replay.min_fill=64", "replay.burn_in=2",
-        "replay.unroll_length=4", "replay.sequence_stride=2",
-        "learner.n_step=2", "learner.batch_size=16", "actor.num_envs=4"])
-    table = stages.table_from_text(_chunk_text(cfg))
-    assert {"act", "env", "insert", "sample", "gather"} <= set(
-        table.values()) <= set(stages.STAGES) | {stages.MIXED}
-
-
-def test_the_mesh_program_names_its_allreduce():
-    cfg = _toy_cfg("actor.num_envs=16", "learner.batch_size=32")
+@pytest.mark.parametrize("cfg,expected", [
+    (_toy_cfg("actor.num_envs=16", "learner.batch_size=32"), COMMON),
+    (_toy_recurrent_cfg(), COMMON | {"writeback"}),
+], ids=["dqn", "recurrent"])
+def test_the_mesh_program_names_its_allreduce(cfg, expected):
     table = stages.table_from_text(_chunk_text(cfg, num_devices=2))
-    assert COMMON | {"allreduce"} <= set(table.values())
+    assert expected | {"allreduce"} <= set(table.values())
+
+
+# ``table_from_text`` with no vocabulary named, on the three programs
+# recorded under perf/testdata: (entries, sha256 of the sorted items) as
+# the walk gave them before it took a vocabulary (PR 41's tree).
+RECORDED = {
+    "atari_preset_v5e": (5684, "e566fdcfcb0b87fc"),
+    "apex_preset_v5e": (5243, "e460484c1c26d3b1"),
+    "r2d2_preset_v5e": (405, "478d1dc41706d6cc"),
+}
+
+
+@pytest.mark.parametrize("program", sorted(RECORDED))
+def test_the_stage_table_of_a_recorded_program_is_what_it_was(program):
+    with gzip.open(CHECKOUT / "perf" / "testdata"
+                   / f"{program}.hlo.txt.gz", "rt") as f:
+        table = stages.table_from_text(f.read())
+    digest = hashlib.sha256(
+        json.dumps(sorted(table.items())).encode()).hexdigest()
+    assert (len(table), digest[:16]) == RECORDED[program]
 
 
 HLO = """HloModule toy
