@@ -85,6 +85,12 @@ def make_r2d2_learner(net, cfg: LearnerConfig, rcfg: ReplayConfig,
         torso then reads the sampler's batch-minor stacks where they were
         written — a slice on the time axis of ``[L, B, ...]`` put a cast
         and two relayouts of every frame between them (PERF.md, PR 31).
+
+        Three of the four regions keep nothing for a backward (either
+        network's burn-in, the target's unroll); the torso holds its
+        convolutions' outputs in every one (models/recurrent.py
+        ``_HeldCNNTorso``), or the compiler nests conv1 in conv2's kernel
+        there, a third slower than apart (PERF.md §7.8).
         """
         obs = sample.obs
         B = obs.shape[1]

@@ -21,10 +21,50 @@ from __future__ import annotations
 from typing import Optional, Tuple
 
 import flax.linen as nn
+import jax
 import jax.numpy as jnp
 
 Array = jnp.ndarray
 LSTMCarry = Tuple[Array, Array]  # (c, h), each [B, lstm_size] float32
+
+
+@jax.custom_jvp
+def _hold(x: Array) -> Array:
+    """``x`` held as an array of its own in the forward computation (an
+    optimization barrier): what made it stays out of its reader's fusion.
+    The tangent passes by, so a backward is what it was — a barrier on
+    the cotangent would split the bias gradient's reduction from the
+    kernel that makes the cotangent."""
+    return jax.lax.optimization_barrier(x)
+
+
+@_hold.defjvp
+def _hold_jvp(primals, tangents):
+    return _hold(primals[0]), tangents[0]
+
+
+class _HeldCNNTorso(nn.Module):
+    """``models/qnets.py CNNTorso`` — its layers, parameter names and values
+    — with the output of every convolution that another convolution reads
+    held (``_hold``), before its ``relu``: the held array then serves the
+    next convolution, that one's weight gradient and the ``relu``'s mask.
+    ``_Embed`` names it ``CNNTorso_0``, what flax names a ``CNNTorso``
+    there, so checkpoints interchange. A path of the recurrent network's
+    own: the DQN programs build ``CNNTorso`` and stay what they were."""
+
+    layers: Tuple[Tuple[int, int, int], ...]
+    dtype: jnp.dtype
+
+    @nn.compact
+    def __call__(self, x: Array) -> Array:
+        x = x.astype(self.dtype)
+        for i, (features, kernel, stride) in enumerate(self.layers):
+            x = nn.Conv(features, (kernel, kernel), strides=(stride, stride),
+                        padding="VALID", dtype=self.dtype)(x)
+            if i + 1 < len(self.layers):
+                x = _hold(x)
+            x = nn.relu(x)
+        return x.reshape((x.shape[0], -1))
 
 
 class _Embed(nn.Module):
@@ -34,6 +74,13 @@ class _Embed(nn.Module):
     rematerialization the unroll's [T*B] conv activations — the dominant
     learner-memory term for pixel R2D2 — are recomputed in the backward
     pass instead of living in HBM across the whole sequence loss.
+
+    The torso holds each convolution's output that another convolution
+    reads (``_HeldCNNTorso``), inside what remat wraps: conv1 reads the
+    uint8 stacks with the cast fused, and where its output has no second
+    reader — a pass that keeps nothing for a backward — the v5e compiler
+    nests it in conv2's fusion, a third slower than the two apart, and
+    conv2 in conv3's once conv1 alone is held (PERF.md §7.8).
     """
 
     torso: str
@@ -43,15 +90,15 @@ class _Embed(nn.Module):
 
     @nn.compact
     def __call__(self, obs: Array) -> Array:
-        from dist_dqn_tpu.models.qnets import (CNN_TORSO_LAYERS, CNNTorso,
-                                               MLPTorso)
+        from dist_dqn_tpu.models.qnets import CNN_TORSO_LAYERS, MLPTorso
 
         x = obs
         if x.dtype == jnp.uint8:
             x = x.astype(self.compute_dtype) / 255.0
         if self.torso in CNN_TORSO_LAYERS:
-            x = CNNTorso(CNN_TORSO_LAYERS[self.torso],
-                         dtype=self.compute_dtype)(x)
+            x = _HeldCNNTorso(CNN_TORSO_LAYERS[self.torso],
+                              dtype=self.compute_dtype,
+                              name="CNNTorso_0")(x)
         elif self.torso == "mlp":
             x = MLPTorso(self.mlp_features, dtype=self.compute_dtype)(x)
         else:
