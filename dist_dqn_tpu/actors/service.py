@@ -407,6 +407,12 @@ class ApexLearnerService:
         # Recurrent (R2D2) configs swap in the sequence learner, the
         # carry-threaded policy and the sequence assembler; the transport,
         # actors and replay shard are shared (BASELINE.json:10).
+        if cfg.network.core.kind != "lstm":
+            raise ValueError(
+                "the apex service assembles sequences with the LSTM's "
+                "(c, h) pair on the wire (actors/assembler.py); a "
+                f"network.core.kind={cfg.network.core.kind!r} state is "
+                "not carried there — use the fused loop")
         self.recurrent = cfg.network.lstm_size > 0
         if self.recurrent:
             from dist_dqn_tpu.actors.assembler import SequenceAssembler

@@ -7,7 +7,9 @@ feed-forward learner and the EMPTY state ``()`` — no leaf, so no
 operation and no buffer in the compiled program. The loop, the evaluator
 and the mesh specs treat the state as an opaque pytree whose leaves are
 ``[B, ...]``, so a state that is not an LSTM pair changes ``models/`` and
-this package only.
+this package only. What of it a sequence ring stores with each step is the
+network's word too (``stored_state``: the LSTM pair whole; nothing of a
+core whose state is megabytes a lane, models/sequence_core.py).
 """
 from __future__ import annotations
 
@@ -18,8 +20,8 @@ import jax.numpy as jnp
 import optax
 
 from dist_dqn_tpu.agents.dqn import make_actor_step, make_learner
-from dist_dqn_tpu.agents.r2d2 import make_r2d2_learner, \
-    make_recurrent_actor_step
+from dist_dqn_tpu.agents.r2d2 import ROUTING_COUNTERS, \
+    make_r2d2_learner, make_recurrent_actor_step
 from dist_dqn_tpu.config import ExperimentConfig
 
 
@@ -32,6 +34,13 @@ class Agent(NamedTuple):
     act: Callable
     initial_state: Callable  # B -> actor state for B lanes; () = none
     reset_state: Callable    # (actor_state, done [B]) -> actor_state
+    # actor_state -> what a sequence ring keeps of it with each step, for
+    # the learner's windows to start from; () = nothing (the learner
+    # starts from an empty state and burns in)
+    stored_state: Callable
+    # names of scalar ``train_step`` metrics beside the loss that the chunk
+    # program averages over a chunk's grad steps into its row
+    chunk_metrics: tuple
 
 
 def make_agent(net, cfg: ExperimentConfig, axis_name: Optional[str] = None,
@@ -44,6 +53,9 @@ def make_agent(net, cfg: ExperimentConfig, axis_name: Optional[str] = None,
             net, cfg.learner, cfg.replay, axis_name=axis_name)
         act = make_recurrent_actor_step(net)
         initial_state = net.initial_state
+        stored_state = net.stored_state
+        chunk_metrics = (ROUTING_COUNTERS if getattr(net, "sows_routing",
+                                                     False) else ())
     else:
         init_learner, train = make_learner(net, cfg.learner,
                                            axis_name=axis_name, tx=tx)
@@ -58,6 +70,11 @@ def make_agent(net, cfg: ExperimentConfig, axis_name: Optional[str] = None,
         def initial_state(num_lanes: int):
             return ()
 
+        def stored_state(actor_state):
+            return ()
+
+        chunk_metrics = ()
+
     def reset_state(actor_state, done):
         # Zero the state of lanes that just finished an episode so the
         # next act (and the state stored with it) starts the new one fresh.
@@ -66,4 +83,5 @@ def make_agent(net, cfg: ExperimentConfig, axis_name: Optional[str] = None,
             lambda x: x * jax.lax.expand_dims(keep, range(1, x.ndim)),
             actor_state)
 
-    return Agent(init_learner, train_step, act, initial_state, reset_state)
+    return Agent(init_learner, train_step, act, initial_state, reset_state,
+                 stored_state, chunk_metrics)

@@ -69,10 +69,13 @@ def make_r2d2_learner(net, cfg: LearnerConfig, rcfg: ReplayConfig,
         )
 
     def _unrolled_q(params: PyTree, sample: SequenceSample,
-                    unroll_pass) -> Array:
+                    unroll_pass) -> Tuple[Array, dict]:
         """Burn in (stop-grad) then unroll the loss+bootstrap region.
 
-        Returns q over steps [burn, burn+unroll+n): [unroll+n, S, A].
+        Returns q over steps [burn, burn+unroll+n): [unroll+n, S, A], and
+        what the network's layers sowed into the ``routing`` collection in
+        that region (models/sequence_core.py: an expert layer's counters;
+        empty for a network that sows none).
 
         Each region is entered under its pass name (telemetry/stages.py
         PASSES, children of stage ``loss_grad``): ``burn_in`` for either
@@ -99,24 +102,26 @@ def make_r2d2_learner(net, cfg: LearnerConfig, rcfg: ReplayConfig,
         def steps(lo, hi):
             return flat[lo * B:hi * B].reshape((hi - lo,) + obs.shape[1:])
 
-        carry = sample.start_state
+        # the stored pair, or an empty state for a core that stores none
+        carry = net.window_state(sample.start_state, B, burn)
         if burn:
             with jax.named_scope("burn_in"):
                 carry, _ = net.apply(params, carry, steps(0, burn),
                                      sample.reset[:burn], method=net.unroll)
                 carry = jax.lax.stop_gradient(carry)
         with unroll_pass:
-            _, q = net.apply(params, carry, steps(burn, obs.shape[0]),
-                             sample.reset[burn:], method=net.unroll)
-        return q
+            (_, q), sown = net.apply(
+                params, carry, steps(burn, obs.shape[0]),
+                sample.reset[burn:], method=net.unroll, mutable=["routing"])
+        return q, sown.get("routing", {})
 
     def loss_fn(params: PyTree, target_params: PyTree,
                 sample: SequenceSample) -> Tuple[Array, Tuple]:
         # [unroll+n, S, A] each
-        q_online = _unrolled_q(params, sample,
-                               jax.named_scope("online_unroll"))
-        q_target = _unrolled_q(target_params, sample,
-                               jax.named_scope("target_unroll"))
+        q_online, routing = _unrolled_q(params, sample,
+                                        jax.named_scope("online_unroll"))
+        q_target, _ = _unrolled_q(target_params, sample,
+                                  jax.named_scope("target_unroll"))
 
         # Per-step n-step returns inside the window; d_t = gamma*(1 - done_t)
         # zeroes everything past an episode end (and the bootstrap with it).
@@ -153,7 +158,8 @@ def make_r2d2_learner(net, cfg: LearnerConfig, rcfg: ReplayConfig,
         priorities = (eta * jnp.max(abs_td, axis=0)
                       + (1.0 - eta) * jnp.mean(abs_td, axis=0))
         aux = (jax.lax.stop_gradient(priorities),
-               jax.lax.stop_gradient(jnp.mean(per_seq)))
+               jax.lax.stop_gradient(jnp.mean(per_seq)),
+               jax.lax.stop_gradient(_routing_counters(routing)))
         return loss, aux
 
     def train_step(state: LearnerState, sample: SequenceSample
@@ -162,7 +168,7 @@ def make_r2d2_learner(net, cfg: LearnerConfig, rcfg: ReplayConfig,
         # enters them: trace metadata only.
         with jax.named_scope("loss_grad"):
             rng, _ = jax.random.split(state.rng)
-            (loss, (priorities, raw_loss)), grads = jax.value_and_grad(
+            (loss, (priorities, raw_loss, routing)), grads = jax.value_and_grad(
                 loss_fn, has_aux=True)(state.params, state.target_params,
                                        sample)
         if axis_name is not None:
@@ -194,10 +200,33 @@ def make_r2d2_learner(net, cfg: LearnerConfig, rcfg: ReplayConfig,
             "raw_loss": raw_loss,
             "priorities": priorities,
             "grad_norm": optax.global_norm(grads),
+            **routing,
         }
         return new_state, metrics
 
     return init, train_step
+
+
+#: The train step's routing counters (a network with expert layers only),
+#: over the online network's loss + bootstrap region: the share of the
+#: tokens' expert choices that fall on experts this chip holds (mean over
+#: the expert layers), and the busiest held expert's load over the held
+#: experts' mean (the worst layer).
+ROUTING_COUNTERS = ("routing_held_share", "routing_busiest_over_mean")
+
+
+def _routing_counters(sown: dict) -> dict:
+    """``ROUTING_COUNTERS`` from what the expert layers sowed; {} where
+    no layer sowed anything."""
+    by_name = {}
+    for path, value in jax.tree_util.tree_leaves_with_path(sown):
+        by_name.setdefault(path[-2].key, []).append(value)
+    if not by_name:
+        return {}
+    return {
+        "routing_held_share": jnp.mean(jnp.stack(by_name["held_share"])),
+        "routing_busiest_over_mean": jnp.max(
+            jnp.stack(by_name["busiest_over_mean"]))}
 
 
 def make_recurrent_actor_step(net, return_q: bool = False):

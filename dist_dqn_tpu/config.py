@@ -14,8 +14,49 @@ from typing import Dict, Tuple
 
 
 @dataclasses.dataclass(frozen=True)
+class CoreConfig:
+    """The recurrent network's sequence core (models/).
+
+    ``kind`` "lstm": the LSTM of ``network.lstm_size`` (recurrent only
+    where that is > 0; models/recurrent.py). ``kind`` "hybrid": a stack of
+    pre-norm residual layers over ``network.hidden`` channels, one letter
+    of ``pattern`` each (models/sequence_core.py): ``M`` a Mamba-2
+    state-space mixer, ``E`` a mixture of experts beside a shared expert,
+    ``*`` grouped-query attention. The defaults are the widths the
+    ``twotower_q`` preset runs (``nemotron_h``'s keys, where it has one).
+    """
+
+    kind: str = "lstm"
+    pattern: str = "MEMEM*EME"
+    norm_eps: float = 1e-5
+    # M: heads x head_dim channels, B and C shared by the heads of a group.
+    mamba_num_heads: int = 64
+    mamba_head_dim: int = 64
+    ssm_state_size: int = 128
+    n_groups: int = 8
+    conv_kernel: int = 4
+    chunk_size: int = 128              # steps a chunk of the chunked scan
+    # E: sigmoid scores over all routed experts, the top k of score + bias
+    # chosen; the layer COMPUTES the experts it holds (their indices) and
+    # leaves out what the others would add (expert parallelism's share).
+    n_routed_experts: int = 128
+    experts_held: Tuple[int, ...] = (0, 1, 2, 3, 4, 5, 6, 7)
+    num_experts_per_tok: int = 6
+    routed_scaling_factor: float = 2.5
+    moe_intermediate_size: int = 1856
+    moe_shared_expert_intermediate_size: int = 3712
+    # *: query heads over KV heads; acting keeps the keys and values of a
+    # lane's last ``attention_window`` steps.
+    num_attention_heads: int = 32
+    num_key_value_heads: int = 2
+    head_dim: int = 128
+    attention_window: int = 512
+
+
+@dataclasses.dataclass(frozen=True)
 class NetworkConfig:
-    """Q-network architecture knobs (models/qnets.py, models/recurrent.py)."""
+    """Q-network architecture knobs (models/qnets.py, models/recurrent.py,
+    models/sequence_core.py)."""
 
     torso: str = "nature"  # "mlp" | "nature" (84x84 Atari CNN) | "small"
     #                        (cheap 84x84 CNN — models/qnets.py presets)
@@ -42,6 +83,7 @@ class NetworkConfig:
     # the risk-neutral mean.
     risk_cvar_eta: float = 1.0
     lstm_size: int = 0                 # >0 => recurrent core (R2D2)
+    core: CoreConfig = CoreConfig()    # which core a recurrent net scans
     remat_torso: bool = False          # recompute torso acts in backward
     compute_dtype: str = "float32"     # "bfloat16" for the TPU MXU path
     # R2D2 learner-throughput knobs (models/recurrent.py): gate-matmul
@@ -58,6 +100,12 @@ class NetworkConfig:
     # params exactly as before — bit-identical, pinned by the
     # param_checksum A/B in tests/test_replay_ratio.py.
     actor_dtype: str = "float32"
+
+    @property
+    def recurrent(self) -> bool:
+        """The network carries a state between steps: sequence replay,
+        the sequence learner, a threaded actor state."""
+        return self.lstm_size > 0 or self.core.kind != "lstm"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -388,9 +436,34 @@ MDQN = ExperimentConfig(
     train_every=4,
 )
 
+TWOTOWER_Q = ExperimentConfig(
+    # An R2D2-style agent whose memory is a hybrid sequence core at the
+    # published widths of nemotron_h's first nine layers (CoreConfig's
+    # defaults; models/sequence_core.py): long windows drawn by priority,
+    # from a zero state (the ring stores no start state for this core),
+    # one learner step per acting step. 8 windows x (128 burn-in + 379 + 5)
+    # = 4,096 tokens a grad step; burn-in is one whole chunk of the scan.
+    name="twotower_q",
+    env_name="pixel_pong",
+    network=NetworkConfig(torso="nature", hidden=2688, dueling=True,
+                          compute_dtype="bfloat16", remat_torso=True,
+                          core=CoreConfig(kind="hybrid")),
+    replay=ReplayConfig(capacity=65_536, prioritized=True,
+                        priority_exponent=0.9, importance_exponent=0.6,
+                        burn_in=128, unroll_length=379, sequence_stride=192,
+                        min_fill=16_384, frame_dedup=True),
+    learner=LearnerConfig(
+        learning_rate=1e-4, adam_eps=1e-3, gamma=0.997, n_step=5,
+        batch_size=8, double_dqn=True, target_update_period=2_500,
+        value_rescale=True,
+    ),
+    actor=ActorConfig(num_envs=16, num_actors=256),
+    total_env_steps=100_000_000,
+)
+
 CONFIGS: Dict[str, ExperimentConfig] = {
     c.name: c for c in (CARTPOLE, ATARI, APEX, R2D2, RAINBOW, QRDQN, IQN,
-                        MDQN)
+                        MDQN, TWOTOWER_Q)
 }
 
 

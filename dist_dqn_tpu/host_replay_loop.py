@@ -336,11 +336,12 @@ def run_host_replay(cfg: ExperimentConfig, total_env_steps: int,
     # Honest-unsupported-surface gate (ADVICE r5): this loop builds the
     # FEED-FORWARD actor/learner; a recurrent config would silently
     # train the wrong program — say so.
-    if cfg.network.lstm_size > 0:
+    if cfg.network.recurrent:
         raise ValueError(
             "host-replay runs the feed-forward collect/train split; "
-            "recurrent (R2D2, network.lstm_size>0) configs need the "
-            "sequence learner — use the apex runtime or the fused loop")
+            "recurrent (network.lstm_size>0 or network.core.kind) "
+            "configs need the sequence learner — use the fused loop (or, "
+            "for the LSTM, the apex runtime)")
     if evac_slices < 1:
         raise ValueError(f"--evac-slices must be >= 1, got {evac_slices}")
     if prio_writeback_batch < 1:

@@ -307,6 +307,9 @@ def reduce_chunk_metrics(carry, axis_name: Optional[str], B: int,
     completed_count = carry.completed_count
     loss_sum = carry.loss_sum
     train_count = carry.train_count
+    # the agent's further per-grad-step metrics (TrainCarry.agent_sums),
+    # where the carry has any
+    agent_sums = dict(getattr(carry, "agent_sums", {}))
     zero = jnp.float32(0.0)
     replace = {}
     if axis_name is not None:
@@ -316,6 +319,9 @@ def reduce_chunk_metrics(carry, axis_name: Optional[str], B: int,
         train_count = jax.lax.pmean(train_count, axis_name)
         replace = dict(completed_return=zero, completed_count=zero,
                        loss_sum=zero, train_count=zero)
+        if agent_sums:
+            agent_sums = jax.lax.pmean(agent_sums, axis_name)
+            replace["agent_sums"] = {k: zero for k in agent_sums}
     metrics = {
         "env_frames": carry.iteration * B * num_shards,
         "episode_return":
@@ -323,6 +329,8 @@ def reduce_chunk_metrics(carry, axis_name: Optional[str], B: int,
         "episodes": completed_count,
         "loss": loss_sum / jnp.maximum(train_count, 1.0),
         "grad_steps_in_chunk": train_count,
+        **{k: v / jnp.maximum(train_count, 1.0)
+           for k, v in agent_sums.items()},
     }
     return metrics, replace
 

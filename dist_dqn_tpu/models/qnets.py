@@ -315,10 +315,13 @@ class ImplicitQuantileNetwork(nn.Module):
 
 
 def build_network(cfg: NetworkConfig, num_actions: int) -> nn.Module:
-    """Build the Q-network for a config; recurrent if cfg.lstm_size > 0."""
+    """Build the Q-network for a config; recurrent if ``cfg.recurrent``
+    (an LSTM of ``lstm_size``, or the core ``cfg.core`` names)."""
     dtype = jnp.bfloat16 if cfg.compute_dtype == "bfloat16" else jnp.float32
+    if cfg.core.kind not in ("lstm", "hybrid"):
+        raise ValueError(f"unknown network.core.kind {cfg.core.kind!r}")
     if cfg.iqn:
-        if cfg.lstm_size or cfg.noisy or cfg.num_atoms > 1:
+        if cfg.recurrent or cfg.noisy or cfg.num_atoms > 1:
             raise ValueError(
                 "the IQN head is feed-forward, epsilon-greedy and already "
                 "distributional; unset lstm_size/noisy/num_atoms or iqn")
@@ -335,12 +338,23 @@ def build_network(cfg: NetworkConfig, num_actions: int) -> nn.Module:
             num_tau_target=cfg.iqn_tau_target_samples,
             num_tau_act=cfg.iqn_tau_act,
             risk_cvar_eta=cfg.risk_cvar_eta, compute_dtype=dtype)
-    if cfg.lstm_size:
+    if cfg.recurrent:
         if cfg.noisy or cfg.num_atoms > 1:
             raise ValueError(
                 "noisy/distributional heads are not supported on the "
                 "recurrent (R2D2) network; unset noisy/num_atoms or "
-                "lstm_size")
+                "lstm_size / core.kind")
+        if cfg.core.kind == "hybrid":
+            if cfg.lstm_size:
+                raise ValueError(
+                    "network.core.kind=hybrid scans its own layers; unset "
+                    "lstm_size")
+            from dist_dqn_tpu.models.sequence_core import HybridQNetwork
+            return HybridQNetwork(
+                num_actions=num_actions, core=cfg.core, torso=cfg.torso,
+                mlp_features=cfg.mlp_features, hidden=cfg.hidden,
+                dueling=cfg.dueling, remat_torso=cfg.remat_torso,
+                compute_dtype=dtype)
         from dist_dqn_tpu.models.recurrent import RecurrentQNetwork
         return RecurrentQNetwork(
             num_actions=num_actions, torso=cfg.torso,

@@ -168,6 +168,17 @@ class RecurrentQNetwork(nn.Module):
         shape = (batch_size, self.lstm_size)
         return (jnp.zeros(shape, jnp.float32), jnp.zeros(shape, jnp.float32))
 
+    def stored_state(self, carry: LSTMCarry) -> LSTMCarry:
+        """What the replay ring keeps of a lane's state with each step:
+        the pair entering it."""
+        return carry
+
+    def window_state(self, stored: LSTMCarry, batch_size: int,
+                     burn_in: int) -> LSTMCarry:
+        """The state a learner's window starts from: the one stored with
+        its first step."""
+        return stored
+
     def _embed(self, obs: Array) -> Array:
         """[N, ...obs] -> [N, E] float32 embedding (torso + pre-LSTM dense).
 

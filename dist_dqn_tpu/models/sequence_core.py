@@ -1,0 +1,503 @@
+"""Recurrent Q-network over a hybrid sequence core: torso -> Dense
+``hidden`` -> a stack of pre-norm residual layers -> RMSNorm -> dueling
+heads (``config.CoreConfig`` kind "hybrid").
+
+Every layer is ``x + mixer(RMSNorm(x))`` with no bias but the state-space
+layer's convolution; one letter of ``CoreConfig.pattern`` a layer, as
+``nemotron_h`` writes its ``hybrid_override_pattern``:
+
+``M``  Mamba-2 (Dao & Gu 2024). ``[z | xBC | dt] = u W_in``; ``xBC =
+       silu(causal depthwise conv(xBC) + b)``, split into ``x [H, P]`` and
+       ``B, C [G, N]`` (head h reads group ``h // (H / G)``); ``dt =
+       softplus(dt + dt_bias)``, ``A = -exp(A_log)``; ``h_t = exp(dt_t A)
+       h_{t-1} + dt_t x_t (x) B_t``; ``y_t = C_t . h_t + D x_t``; ``y =
+       RMSNorm_grouped(y silu(z)) w``; ``out = y W_out``. Computed in the
+       chunked form (``ssd_chunked``): products inside chunks of
+       ``chunk_size`` steps, a scan over the chunks' states; its backward
+       is the chunked form's own.
+``E``  Mixture of experts beside a shared expert. Router logits in
+       float32, ``s = sigmoid(logits)``, the top k of ``s + bias`` chosen,
+       gates ``s[chosen] / sum(s[chosen]) * scale``; an expert is ``W_down
+       relu(W_up u)^2``. The layer is TOLD which experts it holds
+       (``experts_held``, expert parallelism's share of the layer): it
+       routes over all of them and adds what its own give; what the absent
+       ones would add is left out. The held experts are computed DENSELY —
+       two matmuls over all of them, each expert's block scaled by its
+       gate, zero where it was not chosen — so the layer's time does not
+       follow the routing (PERF.md: a dispatch whose rows follow the
+       routing follows the seed). The bias is a leaf no gradient reaches.
+``*``  Grouped-query attention, causal within an episode, no position
+       embedding (``nemotron_h`` builds none in these layers).
+
+State. A lane's acting state is, for every ``M`` layer, the convolution's
+look-back ``[B, K-1, channels]`` and the state ``h [B, H, P, N]``, and for
+every ``*`` layer the keys and values of its last ``history`` steps with a
+validity plane (``[B, history, KV, D]`` twice, ``[B, history]``): all
+float32, all zero when empty, so that ``Agent.reset_state``'s product with
+``1 - done`` empties a lane. ``reset[t]`` (``obs[t]`` opens an episode)
+cuts every look-back at t inside a window: the scan's carried state, the
+convolution's taps and the attention's keys before t. The replay ring
+stores none of it (``stored_state``): at megabytes a lane a step it cannot,
+so a learner's window starts from the zero state and burns in (R2D2's
+zero-state strategy), with ``history`` the burn-in's length.
+
+Same two entry points as ``models/recurrent.py``: ``apply(params, carry,
+obs, reset)`` is one step, ``method=net.unroll`` takes ``[T, B, ...]``.
+"""
+from __future__ import annotations
+
+import math
+from typing import Optional, Tuple
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+
+from dist_dqn_tpu.config import CoreConfig
+from dist_dqn_tpu.models.recurrent import _Embed
+
+Array = jnp.ndarray
+F32 = jnp.float32
+
+
+def _normal(stddev: float):
+    return nn.initializers.normal(stddev)
+
+
+def rms_norm(x: Array, scale: Array, eps: float, groups: int = 1) -> Array:
+    """``x / rms(x) * scale`` in float32, the mean square taken over each
+    of ``groups`` equal slices of the last axis."""
+    x = x.astype(F32)
+    grouped = x.reshape(x.shape[:-1] + (groups, -1))
+    grouped = grouped * jax.lax.rsqrt(
+        jnp.mean(grouped * grouped, axis=-1, keepdims=True) + eps)
+    return grouped.reshape(x.shape) * scale
+
+
+def segments(reset: Array) -> Array:
+    """``[B, T]`` int32: how many episodes have opened up to and including
+    step t. What a call was handed (state, look-back, cached keys) lies in
+    segment 0, so a reset at step 0 already parts from it."""
+    return jnp.cumsum(reset.astype(jnp.int32), axis=1)
+
+
+def ssd_chunked(x: Array, dt: Array, a: Array, b: Array, c: Array,
+                seg: Array, state: Array, chunk: int, dtype
+                ) -> Tuple[Array, Array]:
+    """The selective state-space recurrence in its chunked form.
+
+    ``x [B, T, H, P]``, ``dt [B, T, H]`` (after softplus), ``a [H]``
+    (negative), ``b, c [B, T, G, N]``, ``seg [B, T]`` (``segments``),
+    ``state [B, H, P, N]`` float32 entering step 0. Returns ``y [B, T, H,
+    P]`` float32 (without the ``D x`` skip) and the state after step T-1.
+
+    Inside a chunk of Q steps the output is a masked product: ``y_i = sum_j
+    L_ij (C_i . B_j) dt_j x_j`` with ``L_ij = exp(sum_{j<k<=i} dt_k a)``
+    for ``j <= i`` in one segment; each chunk hands on ``sum_j L_(Q-1)j
+    dt_j x_j (x) B_j`` plus the decayed state it was handed, and adds ``C_i
+    . state_in`` decayed to step i. A step that opens an episode cuts all
+    three by its segment number. T is padded to whole chunks with ``dt =
+    0`` steps: they decay nothing and add nothing. Decays and the carried
+    state are float32; the products take ``dtype`` operands and accumulate
+    in float32."""
+    B, T, H, P = x.shape
+    G, N = b.shape[2:]
+    Q = min(chunk, T)
+    pad = -T % Q
+    if pad:
+        x, dt, b, c = (jnp.pad(v, ((0, 0), (0, pad)) + ((0, 0),) * (v.ndim - 2))
+                       for v in (x, dt, b, c))
+        seg = jnp.pad(seg, ((0, 0), (0, pad)), mode="edge")
+    nc = (T + pad) // Q
+
+    def chunks(v):
+        return v.reshape((B, nc, Q) + v.shape[2:])
+
+    seg = chunks(seg)                                   # [B, nc, Q]
+    dt = chunks(dt.astype(F32))                         # [B, nc, Q, H]
+    # dt folded into x: what a step adds to the state is dt x (x) B
+    xdt = (chunks(x.astype(F32)) * dt[..., None]).astype(dtype)
+    xdt = xdt.reshape(B, nc, Q, G, H // G, P)
+    b, c = chunks(b.astype(dtype)), chunks(c.astype(dtype))
+    cs = jnp.cumsum(dt * a, axis=2)                     # [B, nc, Q, H] <= 0
+    cs = cs.reshape(B, nc, Q, G, H // G)
+    # the segment each chunk's incoming state belongs to
+    seg_in = jnp.concatenate(
+        [jnp.zeros((B, 1), seg.dtype), seg[:, :-1, -1]], axis=1)
+
+    # -- inside the chunks -------------------------------------------------
+    same = seg[:, :, :, None] == seg[:, :, None, :]     # [B, nc, Qi, Qj]
+    causal = jnp.tril(jnp.ones((Q, Q), jnp.bool_))
+    allowed = jnp.logical_and(same, causal)[..., None, None]
+    decay = jnp.exp(jnp.where(
+        allowed, cs[:, :, :, None] - cs[:, :, None, :], -jnp.inf))
+    cb = jnp.einsum("bzign,bzjgn->bzijg", c, b,
+                    preferred_element_type=F32)
+    weights = (decay * cb[..., None]).astype(dtype)     # [B,nc,Qi,Qj,G,Hg]
+    y = jnp.einsum("bzijgh,bzjghp->bzighp", weights, xdt,
+                   preferred_element_type=F32)
+
+    # -- what each chunk hands on -------------------------------------------
+    to_end = jnp.where((seg == seg[:, :, -1:])[..., None, None],
+                       jnp.exp(cs[:, :, -1:] - cs), 0.0)
+    added = jnp.einsum("bzjghp,bzjgn->bzghpn",
+                       (xdt * to_end[..., None]).astype(dtype), b,
+                       preferred_element_type=F32)      # [B,nc,G,Hg,P,N]
+    kept = jnp.where((seg[:, :, -1] == seg_in)[..., None, None],
+                     jnp.exp(cs[:, :, -1]), 0.0)        # [B, nc, G, Hg]
+
+    def hand_on(h, inputs):
+        kept_z, added_z = inputs
+        return h * kept_z[..., None, None] + added_z, h
+
+    state = state.reshape(B, G, H // G, P, N)
+    state, entering = jax.lax.scan(
+        hand_on, state, (jnp.moveaxis(kept, 1, 0), jnp.moveaxis(added, 1, 0)))
+    entering = jnp.moveaxis(entering, 0, 1)             # [B,nc,G,Hg,P,N]
+
+    # -- the incoming state's part of each step's output ----------------------
+    from_in = jnp.where((seg == seg_in[:, :, None])[..., None, None],
+                        jnp.exp(cs), 0.0)               # [B, nc, Q, G, Hg]
+    y = y + from_in[..., None] * jnp.einsum(
+        "bzign,bzghpn->bzighp", c, entering.astype(dtype),
+        preferred_element_type=F32)
+    y = y.reshape(B, nc * Q, H, P)[:, :T]
+    return y, state.reshape(B, H, P, N)
+
+
+class _Mamba2(nn.Module):
+    """``M``: see the module's docstring. ``carry`` is ``(look-back [B,
+    K-1, channels], state [B, H, P, N])``."""
+
+    cfg: CoreConfig
+    dtype: jnp.dtype
+
+    @nn.compact
+    def __call__(self, u: Array, seg: Array, carry):
+        cfg = self.cfg
+        H, P, G, N, K = (cfg.mamba_num_heads, cfg.mamba_head_dim,
+                         cfg.n_groups, cfg.ssm_state_size, cfg.conv_kernel)
+        inner, hidden = H * P, u.shape[-1]
+        channels = inner + 2 * G * N
+        B, T = u.shape[:2]
+        w_in = self.param("in_proj", _normal(hidden ** -0.5),
+                          (hidden, inner + channels + H))
+        conv_w = self.param("conv_kernel", _normal(K ** -0.5), (K, channels))
+        conv_b = self.param("conv_bias", nn.initializers.zeros, (channels,))
+        # dt = softplus(dt_bias) spread log-uniformly over [1e-3, 1e-1]
+        dt_bias = self.param(
+            "dt_bias", lambda key, shape: _inverse_softplus(jnp.exp(
+                jax.random.uniform(key, shape, F32, math.log(1e-3),
+                                   math.log(1e-1)))), (H,))
+        a_log = self.param(
+            "A_log", lambda key, shape: jnp.log(
+                jnp.arange(1, shape[0] + 1, dtype=F32)), (H,))
+        d_skip = self.param("D", nn.initializers.ones, (H,))
+        norm_w = self.param("norm", nn.initializers.ones, (inner,))
+        w_out = self.param("out_proj", _normal(inner ** -0.5),
+                           (inner, hidden))
+        tail, state = carry
+
+        with jax.named_scope("ssm"):
+            proj = jnp.dot(u.astype(self.dtype), w_in.astype(self.dtype),
+                           preferred_element_type=F32)
+            z, xbc, dt = jnp.split(proj, [inner, inner + channels], axis=-1)
+            # causal depthwise convolution: tap d reads step t - d where
+            # that step lies in t's segment (the look-back is segment 0)
+            padded = jnp.concatenate([tail.astype(F32), xbc], axis=1)
+            seg_padded = jnp.concatenate(
+                [jnp.zeros((B, K - 1), seg.dtype), seg], axis=1)
+            conv = conv_b.astype(F32)
+            for d in range(K):
+                lo = K - 1 - d
+                tap = jnp.where(
+                    (seg_padded[:, lo:lo + T] == seg)[..., None],
+                    padded[:, lo:lo + T], 0.0)
+                conv = conv + tap * conv_w[K - 1 - d]
+            new_tail = jnp.where(
+                (seg_padded[:, T:] == seg[:, -1:])[..., None],
+                padded[:, T:], 0.0)
+            xbc = jax.nn.silu(conv)
+            x, b, c = jnp.split(xbc, [inner, inner + G * N], axis=-1)
+            x = x.reshape(B, T, H, P)
+            y, state = ssd_chunked(
+                x, jax.nn.softplus(dt + dt_bias), -jnp.exp(a_log),
+                b.reshape(B, T, G, N), c.reshape(B, T, G, N), seg, state,
+                cfg.chunk_size, self.dtype)
+            y = (y + d_skip[:, None] * x).reshape(B, T, inner)
+            y = rms_norm(y * jax.nn.silu(z), norm_w, cfg.norm_eps, groups=G)
+            out = jnp.dot(y.astype(self.dtype), w_out.astype(self.dtype),
+                          preferred_element_type=F32)
+        return out, (new_tail, state)
+
+
+def _inverse_softplus(x: Array) -> Array:
+    return x + jnp.log(-jnp.expm1(-x))
+
+
+def route(logits: Array, bias: Array, k: int, scale: float):
+    """``(chosen [.., k] int32, gates [.., k] float32)``: sigmoid scores,
+    the top k of score + bias chosen, the chosen scores normalised to sum
+    to ``scale``. The bias only chooses."""
+    scores = jax.nn.sigmoid(logits.astype(F32))
+    _, chosen = jax.lax.top_k(scores + jax.lax.stop_gradient(bias), k)
+    picked = jnp.take_along_axis(scores, chosen, axis=-1)
+    return chosen, picked / jnp.sum(picked, axis=-1, keepdims=True) * scale
+
+
+class _Experts(nn.Module):
+    """``E``: see the module's docstring. No state. Sows two counters of
+    the routing into the ``routing`` collection where it is mutable: the
+    share of the tokens' choices that fall on held experts, and the busiest
+    held expert's load over the held experts' mean."""
+
+    cfg: CoreConfig
+    dtype: jnp.dtype
+
+    @nn.compact
+    def __call__(self, u: Array, seg: Array, carry):
+        cfg = self.cfg
+        hidden = u.shape[-1]
+        held = jnp.asarray(cfg.experts_held, jnp.int32)
+        E, width = len(cfg.experts_held), cfg.moe_intermediate_size
+        shared = cfg.moe_shared_expert_intermediate_size
+        w_router = self.param("router", _normal(hidden ** -0.5),
+                              (hidden, cfg.n_routed_experts))
+        bias = self.param("e_score_correction_bias", nn.initializers.zeros,
+                          (cfg.n_routed_experts,))
+        # [hidden, E, width] / [E, width, hidden]: both reshape to the one
+        # matrix over all held experts without moving a byte
+        w_up = self.param("experts_up", _normal(hidden ** -0.5),
+                          (hidden, E, width))
+        w_down = self.param("experts_down", _normal(width ** -0.5),
+                            (E, width, hidden))
+        s_up = self.param("shared_up", _normal(hidden ** -0.5),
+                          (hidden, shared))
+        s_down = self.param("shared_down", _normal(shared ** -0.5),
+                            (shared, hidden))
+        u16 = u.astype(self.dtype)
+
+        with jax.named_scope("moe_router"):
+            logits = jnp.dot(u.astype(F32), w_router,
+                             precision=jax.lax.Precision.HIGHEST)
+            chosen, gates = route(logits, bias, cfg.num_experts_per_tok,
+                                  cfg.routed_scaling_factor)
+            on_held = chosen[..., None] == held          # [.., k, E]
+            gate_of = jnp.sum(jnp.where(on_held, gates[..., None], 0.0),
+                              axis=-2)                   # [.., E]
+            if (self.is_mutable_collection("routing")
+                    and not self.is_initializing()):
+                load = jnp.sum(on_held.astype(F32),
+                               axis=tuple(range(on_held.ndim - 1)))
+                self.sow("routing", "held_share",
+                         jnp.sum(load) / chosen.size)
+                self.sow("routing", "busiest_over_mean",
+                         jnp.max(load) / jnp.maximum(jnp.mean(load), 1e-9))
+        with jax.named_scope("moe_routed"):
+            act = jnp.square(jax.nn.relu(jnp.dot(
+                u16, w_up.astype(self.dtype).reshape(hidden, E * width),
+                preferred_element_type=F32)))
+            # each expert's block of columns times its gate, as selects
+            # over the column index: a view of the columns as [E, width]
+            # would be a relayout of the whole activation wherever width
+            # is no multiple of the 128 lanes (1856 is not)
+            block = jnp.arange(E * width) // width
+            gate_wide = sum(jnp.where(block == e, gate_of[..., e:e + 1], 0.0)
+                            for e in range(E))
+            routed = jnp.dot(
+                (act * gate_wide).astype(self.dtype),
+                w_down.astype(self.dtype).reshape(E * width, hidden),
+                preferred_element_type=F32)
+        with jax.named_scope("moe_shared"):
+            act = jnp.square(jax.nn.relu(jnp.dot(
+                u16, s_up.astype(self.dtype), preferred_element_type=F32)))
+            out = routed + jnp.dot(act.astype(self.dtype),
+                                   s_down.astype(self.dtype),
+                                   preferred_element_type=F32)
+        return out, carry
+
+
+class _Attention(nn.Module):
+    """``*``: see the module's docstring. ``carry`` is ``(keys, values [B,
+    history, KV, D], valid [B, history])`` of the steps before this call,
+    oldest first."""
+
+    cfg: CoreConfig
+    dtype: jnp.dtype
+
+    @nn.compact
+    def __call__(self, u: Array, seg: Array, carry):
+        cfg = self.cfg
+        heads, kv, D = (cfg.num_attention_heads, cfg.num_key_value_heads,
+                        cfg.head_dim)
+        hidden = u.shape[-1]
+        B, T = u.shape[:2]
+        w_q = self.param("q_proj", _normal(hidden ** -0.5),
+                         (hidden, heads * D))
+        w_k = self.param("k_proj", _normal(hidden ** -0.5), (hidden, kv * D))
+        w_v = self.param("v_proj", _normal(hidden ** -0.5), (hidden, kv * D))
+        w_o = self.param("o_proj", _normal((heads * D) ** -0.5),
+                         (heads * D, hidden))
+        old_k, old_v, old_valid = carry
+
+        with jax.named_scope("attention"):
+            u16 = u.astype(self.dtype)
+
+            def project(w, n):
+                return jnp.dot(u16, w.astype(self.dtype),
+                               preferred_element_type=F32).reshape(B, T, n, D)
+
+            q = project(w_q, heads).reshape(B, T, kv, heads // kv, D)
+            keys = jnp.concatenate([old_k, project(w_k, kv)], axis=1)
+            values = jnp.concatenate([old_v, project(w_v, kv)], axis=1)
+            # a query sees the cached steps while no episode has opened
+            # since (segment 0), and this call's steps of its own segment
+            # up to itself
+            see_old = jnp.logical_and(old_valid[:, None, :] > 0,
+                                      seg[:, :, None] == 0)
+            see_new = jnp.logical_and(
+                seg[:, :, None] == seg[:, None, :],
+                jnp.tril(jnp.ones((T, T), jnp.bool_)))
+            see = jnp.concatenate([see_old, see_new], axis=2)   # [B, T, S]
+            scores = jnp.einsum(
+                "btkgd,bskd->bkgts", q.astype(self.dtype),
+                keys.astype(self.dtype),
+                preferred_element_type=F32) * D ** -0.5
+            scores = jnp.where(see[:, None, None], scores, -jnp.inf)
+            attended = jnp.einsum(
+                "bkgts,bskd->btkgd",
+                jax.nn.softmax(scores, axis=-1).astype(self.dtype),
+                values.astype(self.dtype), preferred_element_type=F32)
+            out = jnp.dot(
+                attended.reshape(B, T, heads * D).astype(self.dtype),
+                w_o.astype(self.dtype), preferred_element_type=F32)
+            # the last ``history`` steps stay, those of the segment the
+            # call ends in
+            valid = jnp.concatenate(
+                [old_valid * (seg[:, -1:] == 0),
+                 (seg == seg[:, -1:]).astype(F32)], axis=1)
+            valid = valid[:, T:]
+            live = valid[:, :, None, None]      # an empty slot holds zeros
+            carry = (keys[:, T:] * live, values[:, T:] * live, valid)
+        return out, carry
+
+
+_MIXERS = {"M": _Mamba2, "E": _Experts, "*": _Attention}
+
+
+class _Layer(nn.Module):
+    """``x + mixer(RMSNorm(x))``; a module of its own so that ``nn.remat``
+    can wrap it: a layer's activations are then recomputed in the backward
+    pass, and only its input lives through the loss."""
+
+    kind: str
+    cfg: CoreConfig
+    dtype: jnp.dtype
+
+    @nn.compact
+    def __call__(self, x: Array, seg: Array, carry):
+        scale = self.param("norm", nn.initializers.ones, (x.shape[-1],))
+        out, carry = _MIXERS[self.kind](self.cfg, self.dtype, name="mixer")(
+            rms_norm(x, scale, self.cfg.norm_eps), seg, carry)
+        return x + out, carry
+
+
+class _Core(nn.Module):
+    """The stack of layers and the final norm over ``[B, T, hidden]``."""
+
+    cfg: CoreConfig
+    dtype: jnp.dtype
+
+    @nn.compact
+    def __call__(self, x: Array, reset: Array, carry):
+        seg = segments(reset)
+        new_carry = []
+        for i, kind in enumerate(self.cfg.pattern):
+            x, layer_carry = nn.remat(_Layer)(
+                kind, self.cfg, self.dtype, name=f"layer_{i}")(
+                    x, seg, carry[i])
+            new_carry.append(layer_carry)
+        scale = self.param("norm_f", nn.initializers.ones, (x.shape[-1],))
+        return rms_norm(x, scale, self.cfg.norm_eps), tuple(new_carry)
+
+
+class HybridQNetwork(nn.Module):
+    """Torso -> Dense ``hidden`` -> hybrid sequence core -> dueling heads;
+    ``models/recurrent.py RecurrentQNetwork``'s two entry points and
+    ``(new_carry, q)`` returns."""
+
+    num_actions: int
+    core: CoreConfig
+    torso: str = "nature"
+    mlp_features: Tuple[int, ...] = (256, 256)
+    hidden: int = 2688
+    dueling: bool = True
+    compute_dtype: jnp.dtype = jnp.float32
+    remat_torso: bool = False
+
+    @property
+    def sows_routing(self) -> bool:
+        """An expert layer sows its counters (``_Experts``)."""
+        return "E" in self.core.pattern
+
+    def initial_state(self, batch_size: int, history: Optional[int] = None):
+        """The empty state of ``batch_size`` lanes, one entry a layer;
+        ``history``: steps of keys and values an attention layer keeps
+        (default ``attention_window``, what acting carries)."""
+        cfg = self.core
+        if history is None:
+            history = cfg.attention_window
+        inner = cfg.mamba_num_heads * cfg.mamba_head_dim
+        channels = inner + 2 * cfg.n_groups * cfg.ssm_state_size
+        cache = (batch_size, history, cfg.num_key_value_heads, cfg.head_dim)
+
+        def zeros(*shape):
+            return jnp.zeros(shape, F32)
+
+        return tuple({
+            "M": lambda: (zeros(batch_size, cfg.conv_kernel - 1, channels),
+                          zeros(batch_size, cfg.mamba_num_heads,
+                                cfg.mamba_head_dim, cfg.ssm_state_size)),
+            "E": lambda: (),
+            "*": lambda: (zeros(*cache), zeros(*cache),
+                          zeros(batch_size, history)),
+        }[kind]() for kind in cfg.pattern)
+
+    def stored_state(self, carry):
+        """What the replay ring keeps of a lane's state with each step:
+        nothing — learner windows start from ``window_state``."""
+        return ()
+
+    def window_state(self, stored, batch_size: int, burn_in: int):
+        """The state a learner's window starts from: empty, its attention
+        history as long as the burn-in that fills it."""
+        return self.initial_state(batch_size, history=burn_in)
+
+    def __call__(self, carry, obs: Array, reset: Optional[Array] = None):
+        """One step: obs [B, ...], reset [B] bool (None = no resets)."""
+        carry, q = self.unroll(carry, obs[None],
+                               None if reset is None else reset[None])
+        return carry, q[0]
+
+    @nn.compact
+    def unroll(self, carry, obs: Array, reset: Optional[Array] = None):
+        """obs [T, B, ...], reset [T, B]; returns (carry, q [T, B, A]).
+        ``reset[t]`` empties the state before step t. The torso runs once
+        over the flat [T*B] batch, the core batch-major."""
+        T, B = obs.shape[:2]
+        if reset is None:
+            reset = jnp.zeros((T, B), jnp.bool_)
+        embed = nn.remat(_Embed) if self.remat_torso else _Embed
+        x = embed(self.torso, self.mlp_features, self.hidden,
+                  self.compute_dtype, name="torso")(
+                      obs.reshape((T * B,) + obs.shape[2:]))
+        x = jnp.swapaxes(x.reshape(T, B, -1), 0, 1)
+        x, carry = _Core(self.core, self.compute_dtype, name="core")(
+            x, reset.T, carry)
+        h = jnp.swapaxes(x, 0, 1).reshape(T * B, -1)
+        adv = nn.Dense(self.num_actions, name="advantage")(h)
+        q = adv
+        if self.dueling:
+            q = (nn.Dense(1, name="value")(h) + adv
+                 - jnp.mean(adv, axis=-1, keepdims=True))
+        return carry, q.reshape(T, B, self.num_actions)

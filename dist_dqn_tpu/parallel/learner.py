@@ -51,7 +51,7 @@ def _carry_specs(replay_spec, axis: str) -> TrainCarry:
                              opt_state=repl, steps=repl, rng=repl),
         rng=shard0, iteration=repl,
         ep_return=shard0, completed_return=repl, completed_count=repl,
-        loss_sum=repl, train_count=repl)
+        loss_sum=repl, train_count=repl, agent_sums=repl)
 
 
 def _mesh_wrap(mesh: Mesh, specs, init_local, run_local):

@@ -6,9 +6,10 @@ The ring's geometry is computed HERE and nowhere else: slots from
 and its stride), the frame-dedup context, ``store_final_obs`` and the
 merged-row ("flat") layout — its stored row width with it, which decides
 the layout that buffer crosses a program's boundary in
-(``merged_row_boundary``). Which ring: one that stores an actor state
-with every step (``actor_state`` has leaves) is the sequence ring;
-otherwise ``replay.prioritized`` chooses. What a sample is stays between
+(``merged_row_boundary``). Which ring: an agent that threads a state
+through acting (``actor_state`` has leaves) gets the sequence ring, which
+stores ``stored_state`` of it with every step (the agent's word: a pytree,
+possibly empty); otherwise ``replay.prioritized`` chooses. What a sample is stays between
 the ring and the agent's ``train_step`` (agents/agent.py): a
 ``PrioritizedSample`` (``weights=None`` from the uniform ring) or a
 ``SequenceSample``; the loop passes it through.
@@ -87,8 +88,8 @@ def merged_row_boundary(shape, dtype) -> BoundaryLayout:
 
 class DeviceRing(NamedTuple):
     init: Callable        # obs [B, ...] -> state
-    # (state, obs, actions, StepOut, the actor state held entering obs);
-    # ``obs`` as the env HOLDS it (envs/base.py: ``StackWords`` for four
+    # (state, obs, actions, StepOut, what is stored of the actor state held
+    # entering obs: ``Agent.stored_state`` of it); ``obs`` as the env HOLDS it (envs/base.py: ``StackWords`` for four
     # uint8 frames), else the observation itself
     add: Callable
     can_sample: Callable  # state -> bool: past min_fill, a whole window held
@@ -108,9 +109,11 @@ class DeviceRing(NamedTuple):
 
 
 def make_device_ring(cfg: ExperimentConfig, env, num_shards: int = 1,
-                     actor_state=()) -> DeviceRing:
-    """``actor_state``: the per-lane state (or its shapes) the agent threads
-    through acting; all sizes are per-shard sizes."""
+                     actor_state=(), stored_state=()) -> DeviceRing:
+    """``actor_state``: ONE lane's state (or its shapes, leaves ``[1,
+    ...]``) as the agent threads it through acting; ``stored_state``: what
+    a sequence ring keeps of it with every step (``Agent.stored_state``).
+    All sizes are per-shard sizes."""
     rcfg = cfg.replay
     n_step = cfg.learner.n_step
     sequence = bool(jax.tree.leaves(actor_state))
@@ -134,7 +137,9 @@ def make_device_ring(cfg: ExperimentConfig, env, num_shards: int = 1,
                                         store_final=store_final)
     context = max(stack - 1, 0)
     if sequence:
-        lstm_size = jax.tree.leaves(actor_state)[0].shape[-1]
+        lane_state = jax.tree.map(
+            lambda x: jax.ShapeDtypeStruct(x.shape[1:], x.dtype),
+            stored_state)
         seq_len = rcfg.burn_in + rcfg.unroll_length + n_step
         stride = rcfg.sequence_stride or rcfg.unroll_length
         num_slots = max(rcfg.capacity // (B * num_shards), seq_len + 2)
@@ -180,8 +185,8 @@ def make_device_ring(cfg: ExperimentConfig, env, num_shards: int = 1,
         example = loop_common.ring_obs_example(
             jax.tree.map(lambda x: slice_newest(x)[0], obs), flat, row_width)
         if sequence:
-            return sring.sequence_ring_init(num_slots, B, example, lstm_size,
-                                            merge_obs_rows=flat)
+            return sring.sequence_ring_init(num_slots, B, example,
+                                            lane_state, merge_obs_rows=flat)
         make = (pring.prioritized_ring_init if prioritized
                 else ring.time_ring_init)
         return make(num_slots, B, example, store_final_obs=store_final,
@@ -200,7 +205,7 @@ def make_device_ring(cfg: ExperimentConfig, env, num_shards: int = 1,
     def add(state, obs, actions, out, held):
         stored = stored_rows(obs)
         if sequence:
-            # The *pre-step* state: what the actor held entering obs.
+            # Of the *pre-step* state: what the actor held entering obs.
             return sring.sequence_ring_add(
                 state, stored, actions, out.reward, out.terminated,
                 out.truncated, held, seq_len, stride,
@@ -279,7 +284,9 @@ def make_device_ring(cfg: ExperimentConfig, env, num_shards: int = 1,
             truncated=cells, final_obs=lanes, pos=repl, size=repl)
         if sequence:
             return sring.SequenceRingState(
-                ring=r, state_c=lanes, state_h=lanes, priorities=lanes,
+                ring=r, start_state=jax.tree.map(lambda _: lanes,
+                                                 stored_state),
+                priorities=lanes,
                 max_priority=repl, writes=repl)
         if prioritized:
             return pring.PrioritizedRingState(ring=r, priorities=cells,

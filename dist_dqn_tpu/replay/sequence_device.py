@@ -3,9 +3,10 @@
 The reference's sequence replay stores fixed-length (burn-in + unroll)
 trajectory slices with the recurrent state at the slice start. The TPU-native
 layout reuses the time-ring (replay/device.py): every step is stored exactly
-once, as one slice of all B lanes, together with the actor's LSTM carry
-*entering* that step, and a "sequence" is just a length-L window gather at
-sample time —
+once, as one slice of all B lanes, together with what the network keeps of
+the actor's state *entering* that step (an opaque pytree: the LSTM's pair,
+nothing for a core whose learner windows start from an empty state), and a
+"sequence" is just a length-L window gather at sample time —
 overlapping sequences (stride < L) therefore cost zero extra HBM, where the
 reference's per-sequence storage pays length/stride x duplication.
 
@@ -23,7 +24,7 @@ from __future__ import annotations
 
 import functools
 import math
-from typing import NamedTuple, Tuple
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -36,8 +37,9 @@ Array = jnp.ndarray
 
 class SequenceRingState(NamedTuple):
     ring: ring.TimeRingState
-    state_c: Array       # [T, B, lstm] float32 — carry entering each step
-    state_h: Array       # [T, B, lstm] float32
+    # what the network stores of the state entering each step: one
+    # [T, B, ...] float32 plane a leaf; () where it stores nothing
+    start_state: PyTree
     priorities: Array    # [T, B] float32; >0 exactly at valid window starts
     #   (the one plane still [T, B]: the benchmark's reference check reads
     #   it so — perf/reference/r2d2_float32.py, PERF.md §7)
@@ -46,20 +48,26 @@ class SequenceRingState(NamedTuple):
 
 
 def sequence_ring_init(num_slots: int, num_envs: int, obs_example: PyTree,
-                       lstm_size: int,
+                       stored_state,
                        merge_obs_rows: bool = False) -> SequenceRingState:
-    """``merge_obs_rows`` stores obs as flat ``[T*B, ...]`` rows (same
+    """``stored_state``: one lane's stored state (a pytree whose leaves, or
+    their shapes, are ``[...]`` a lane), or an int: an LSTM pair of that
+    width. ``merge_obs_rows`` stores obs as flat ``[T*B, ...]`` rows (same
     records, same order — see replay/device.py:time_ring_init); callers
     pass the same flag to add/sample. The inner ring's scalar planes are
-    flat cells like every ring's; the carry planes are ``[T, B, lstm]``,
-    lane-dense as they are, and the ring's slots and lanes are read off
-    them."""
+    flat cells like every ring's; the state planes are ``[T, B, ...]``,
+    lane-dense as they are; the ring's slots and lanes are the priority
+    plane's."""
+    if isinstance(stored_state, int):
+        pair = jax.ShapeDtypeStruct((stored_state,), jnp.float32)
+        stored_state = (pair, pair)
     return SequenceRingState(
         ring=ring.time_ring_init(num_slots, num_envs, obs_example,
                                  store_final_obs=False,
                                  merge_obs_rows=merge_obs_rows),
-        state_c=jnp.zeros((num_slots, num_envs, lstm_size), jnp.float32),
-        state_h=jnp.zeros((num_slots, num_envs, lstm_size), jnp.float32),
+        start_state=jax.tree.map(
+            lambda x: jnp.zeros((num_slots, num_envs) + tuple(x.shape),
+                                jnp.float32), stored_state),
         priorities=jnp.zeros((num_slots, num_envs), jnp.float32),
         max_priority=jnp.float32(1.0),
         writes=jnp.int32(0),
@@ -68,10 +76,11 @@ def sequence_ring_init(num_slots: int, num_envs: int, obs_example: PyTree,
 
 def sequence_ring_add(state: SequenceRingState, obs: PyTree, action: Array,
                       reward: Array, terminated: Array, truncated: Array,
-                      carry: Tuple[Array, Array], seq_len: int,
+                      carry: PyTree, seq_len: int,
                       stride: int,
                       merge_obs_rows: bool = False) -> SequenceRingState:
-    """Append one time slice plus the actor carry that produced ``action``.
+    """Append one time slice plus what is stored of the actor state that
+    produced ``action`` (``carry``: leaves ``[B, ...]``).
 
     ``seq_len`` (L) and ``stride`` are static. Overwriting slot ``p``
     invalidates the window starting at ``p`` (it is the oldest slot of any
@@ -79,7 +88,7 @@ def sequence_ring_add(state: SequenceRingState, obs: PyTree, action: Array,
     whose full window just completed — write index ``writes + 1 - L`` — is
     seeded with the running max priority when stride-aligned.
     """
-    num_slots = state.state_c.shape[0]
+    num_slots = state.priorities.shape[0]
     p = state.ring.pos
     new_ring = ring.time_ring_add(state.ring, obs, action, reward,
                                   terminated, truncated,
@@ -95,8 +104,9 @@ def sequence_ring_add(state: SequenceRingState, obs: PyTree, action: Array,
 
     return SequenceRingState(
         ring=new_ring,
-        state_c=state.state_c.at[p].set(carry[0].astype(jnp.float32)),
-        state_h=state.state_h.at[p].set(carry[1].astype(jnp.float32)),
+        start_state=jax.tree.map(
+            lambda plane, x: plane.at[p].set(x.astype(jnp.float32)),
+            state.start_state, carry),
         priorities=priorities,
         max_priority=state.max_priority,
         writes=writes,
@@ -217,7 +227,7 @@ def sequence_ring_sample(state: SequenceRingState, rng: Array,
     from dist_dqn_tpu.ops.pallas_sampler import (importance_weights,
                                                  stratified_sample)
 
-    num_slots, num_envs = state.state_c.shape[:2]
+    num_slots, num_envs = state.priorities.shape
     # Stage names (telemetry/stages.py STAGES): trace metadata only.
     with jax.named_scope("sample"):
         w = jnp.where(state.priorities > 0.0, state.priorities ** alpha,
@@ -257,8 +267,8 @@ def sequence_ring_sample(state: SequenceRingState, rng: Array,
         # episode-correct.
         reset = jnp.concatenate(
             [jnp.zeros((1, batch_size), jnp.bool_), done[:-1]], axis=0)
-        start_state = (state.state_c[t_idx, b_idx],
-                       state.state_h[t_idx, b_idx])
+        start_state = jax.tree.map(lambda plane: plane[t_idx, b_idx],
+                                   state.start_state)
     return SequenceSample(obs=obs, action=action, reward=reward, done=done,
                           reset=reset, start_state=start_state,
                           weights=weights, t_idx=t_idx, b_idx=b_idx)

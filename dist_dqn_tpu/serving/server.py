@@ -318,7 +318,7 @@ def build_server(cfg, policies: Dict[str, str], *,
     from dist_dqn_tpu.agents.dqn import make_learner
     from dist_dqn_tpu.models import build_network
 
-    if cfg.network.lstm_size:
+    if cfg.network.recurrent:
         raise ValueError(
             "the serving tier is feed-forward only for now — recurrent "
             "(R2D2) policies need per-caller carry state, which the "

@@ -7,11 +7,13 @@ replay/prioritized_device.py, replay/sequence_device.py, agents/dqn.py,
 agents/r2d2.py). A scope is metadata: it lands in every HLO instruction's
 ``op_name`` and leaves the optimized program as it was.
 
-Stage ``loss_grad`` has child names, in two groups that each split it
+Stage ``loss_grad`` has child names, in three groups that each split it
 another way: :data:`PASSES`, scopes the recurrent learner enters
-(agents/r2d2.py ``_unrolled_q``), and :data:`PARTS`, which nobody enters —
-they are the names models/recurrent.py gives its two sub-modules (parameter
-keys, so they cannot drift), and Flax puts a module's name on the op path.
+(agents/r2d2.py ``_unrolled_q``); :data:`PARTS`, which nobody enters —
+they are the names the recurrent networks give their two sub-modules
+(parameter keys, so they cannot drift), and Flax puts a module's name on the
+op path; and :data:`CORE_PARTS`, which splits ``core`` by the scopes the
+hybrid core's mixers enter (models/sequence_core.py).
 A child is read (:func:`child_of`) through the wrappers a transform puts
 around the outermost name inside it (``transpose(jvp(online_unroll))``),
 and counts only on an instruction whose STAGE is ``loss_grad``
@@ -45,6 +47,12 @@ PASSES = ("burn_in", "online_unroll", "target_unroll")
 #: Children of stage ``loss_grad``, read: models/recurrent.py's sub-modules
 #: (convolutions + ``embed``; the scanned cell), forward and backward.
 PARTS = ("torso", "core")
+#: Children of stage ``loss_grad``, read: the torso, and the scopes the
+#: hybrid sequence core's mixers enter (models/sequence_core.py) — the
+#: state-space layers, the attention layer, and an expert layer's three
+#: parts. Norms, residual adds, the heads and the loss stay under no child.
+CORE_PARTS = ("torso", "ssm", "attention", "moe_router", "moe_routed",
+              "moe_shared")
 #: The stage whose instructions the child names split.
 PARENT = "loss_grad"
 #: A fusion whose instructions come from more than one stage (or child).
@@ -105,7 +113,7 @@ def table_seconds(names: Tuple[str, ...] = STAGES) -> Optional[float]:
 
 def table(names: Tuple[str, ...] = STAGES) -> Dict[str, str]:
     """``{instruction name: name}`` of the kept executable over one
-    vocabulary (:data:`STAGES`, :data:`PASSES` or :data:`PARTS`), built on
+    vocabulary (:data:`STAGES` or a group of child names), built on
     the first call for it: the executable's text and one walk over it.
     Empty where no program was kept."""
     if names not in _tables and _program is not None:

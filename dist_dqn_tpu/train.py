@@ -434,6 +434,23 @@ def train(cfg: ExperimentConfig, total_env_steps: int = 0, seed: int = None,
                     "grad_steps_in_chunk": grad_steps_chunk,
                     "grad_steps_per_sec": grad_steps_chunk / dt,
                 }
+                if grad_steps_chunk and "routing_held_share" in metrics:
+                    # an agent with expert layers (agents/r2d2.py
+                    # ROUTING_COUNTERS): the chunk's mean over grad steps
+                    row["routing_held_share"] = float(
+                        metrics["routing_held_share"])
+                    row["routing_busiest_over_mean"] = float(
+                        metrics["routing_busiest_over_mean"])
+                    _reg.gauge(
+                        "dqn_routing_held_share",
+                        "share of the tokens' expert choices that fall on "
+                        "experts this chip holds (chunk mean)").set(
+                            row["routing_held_share"])
+                    _reg.gauge(
+                        "dqn_routing_busiest_over_mean",
+                        "busiest held expert's load over the held "
+                        "experts' mean, worst layer (chunk mean)").set(
+                            row["routing_busiest_over_mean"])
                 if frames >= next_eval:
                     # Every process consumes k_eval so rng streams stay in
                     # lockstep even where run_eval is None (non-logging
@@ -1143,7 +1160,7 @@ def main(argv=None):
     # support them yet — BEFORE the manifest so provenance records the
     # config actually run.
     import dataclasses as _dc
-    _recurrent_fused = args.runtime == "fused" and cfg.network.lstm_size > 0
+    _recurrent_fused = args.runtime == "fused" and cfg.network.recurrent
     if args.replay_ratio is not None:
         if _recurrent_fused:
             print("# --replay-ratio is not supported by the recurrent "

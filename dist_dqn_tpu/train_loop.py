@@ -70,6 +70,9 @@ class TrainCarry(NamedTuple):
     completed_count: Array   # scalar float32
     loss_sum: Array
     train_count: Array
+    # {name: sum over the chunk's grad steps} of the agent's further
+    # train-step metrics (agents/agent.py ``chunk_metrics``); {} for none
+    agent_sums: PyTree = {}
 
 
 def twin_obs_checkpoint(env: JaxEnv, tree):
@@ -111,9 +114,10 @@ def fused_parts(cfg: ExperimentConfig, env: JaxEnv, net,
     agent = make_agent(
         net, cfg, axis_name=axis_name,
         tx=make_population_optimizer(cfg.learner) if member_lr else None)
+    lane_state = jax.eval_shape(lambda: agent.initial_state(1))
     replay = make_device_ring(
-        cfg, env, num_shards,
-        jax.eval_shape(lambda: agent.initial_state(1)))
+        cfg, env, num_shards, lane_state,
+        jax.eval_shape(agent.stored_state, lane_state))
     # The ISSUE 6 replay-ratio scan and the population's member axis exist
     # for the transition rings only (the --replay-ratio CLI flag is
     # warned-and-stripped by train.py before it gets here; this catches the
@@ -211,7 +215,8 @@ def make_fused_train(cfg: ExperimentConfig, env: JaxEnv, net,
                           iteration=jnp.int32(0),
                           ep_return=jnp.zeros((B,), jnp.float32),
                           completed_return=zero, completed_count=zero,
-                          loss_sum=zero, train_count=zero)
+                          loss_sum=zero, train_count=zero,
+                          agent_sums={k: zero for k in agent.chunk_metrics})
 
     def one_iteration(actor_params, hp, carry: TrainCarry, _
                       ) -> Tuple[TrainCarry, None]:
@@ -239,8 +244,9 @@ def make_fused_train(cfg: ExperimentConfig, env: JaxEnv, net,
         with jax.named_scope("env"):
             env_state, out = env.v_step(carry.env_state, actions)
         with jax.named_scope("insert"):
-            replay_state = replay.add(carry.replay, obs, actions, out,
-                                      carry.actor_carry)
+            replay_state = replay.add(
+                carry.replay, obs, actions, out,
+                agent.stored_state(carry.actor_carry))
         beta = beta_at(carry.iteration)
 
         def do_train(operand):
@@ -257,7 +263,9 @@ def make_fused_train(cfg: ExperimentConfig, env: JaxEnv, net,
                     return (l, rep), (metrics["loss"], s.t_idx,
                                       s.b_idx, metrics["priorities"])
                 rep = replay.update(rep, s, metrics["priorities"])
-                return (l, rep), (metrics["loss"],)
+                return (l, rep), (metrics["loss"],
+                                  {k: metrics[k]
+                                   for k in agent.chunk_metrics})
 
             with jax.named_scope("sample"):
                 keys = jax.random.split(k_sample, updates)
@@ -266,16 +274,18 @@ def make_fused_train(cfg: ExperimentConfig, env: JaxEnv, net,
             if defer_writeback:
                 losses_u, t_i, b_i, prios = ys
                 rep = replay.update_batched(rep, t_i, b_i, prios)
+                further = {}
             else:
-                (losses_u,) = ys
-            return (learner, rep, jnp.sum(losses_u),
-                    jnp.float32(updates))
+                losses_u, further = ys
+            return (learner, rep, jnp.sum(losses_u), jnp.float32(updates),
+                    jax.tree.map(jnp.sum, further))
 
         def no_train(operand):
             learner, rep = operand
-            return learner, rep, jnp.float32(0.0), jnp.float32(0.0)
+            return (learner, rep, jnp.float32(0.0), jnp.float32(0.0),
+                    {k: jnp.float32(0.0) for k in carry.agent_sums})
 
-        learner, replay_state, loss, trained = jax.lax.cond(
+        learner, replay_state, loss, trained, further = jax.lax.cond(
             jnp.logical_and(replay.can_sample(replay_state),
                             carry.iteration % cfg.train_every == 0),
             do_train, no_train, (carry.learner, replay_state))
@@ -292,12 +302,16 @@ def make_fused_train(cfg: ExperimentConfig, env: JaxEnv, net,
             completed_return=completed_return,
             completed_count=completed_count,
             loss_sum=carry.loss_sum + loss,
-            train_count=carry.train_count + trained), None
+            train_count=carry.train_count + trained,
+            agent_sums={k: v + further[k]
+                        for k, v in carry.agent_sums.items()}), None
 
     def _run_chunk(carry: TrainCarry, hp, num_iters: int):
         zero = jnp.float32(0.0)
         carry = carry._replace(completed_return=zero, completed_count=zero,
-                               loss_sum=zero, train_count=zero)
+                               loss_sum=zero, train_count=zero,
+                               agent_sums={k: zero
+                                           for k in carry.agent_sums})
         # Actor-dtype split: cast the chunk-entry params ONCE; the cast
         # tree is scan-invariant (closed over), so XLA keeps a single
         # bf16 copy for the whole chunk instead of re-casting per step.

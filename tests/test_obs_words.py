@@ -275,8 +275,10 @@ def test_whole_carry_checkpoint_with_the_twin_observation_restores(tmp_path):
     kw = dict(chunk_iters=25, log_fn=lambda s: None)
     ref, _ = train(cfg, total_env_steps=600, **kw)
     half, _ = train(cfg, total_env_steps=400, **kw)
+    # the fields the carry had then
     Old = collections.namedtuple(
-        "TrainCarry", [f for f in half._fields if f != "actor_carry"])
+        "TrainCarry", [f for f in half._fields
+                       if f not in ("actor_carry", "agent_sums")])
     stack = env.stack_obs(half.env_state.frames)
     old = Old(**dict(
         {f: getattr(half, f) for f in Old._fields}, obs=stack,

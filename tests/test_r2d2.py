@@ -66,7 +66,7 @@ def test_sequence_ring_merged_rows_matches_tiled():
     must yield identical sequences, states, and weights."""
     def drive(merge):
         state = sring.sequence_ring_init(12, 2, jnp.zeros((3, 2)),
-                                         lstm_size=4,
+                                         stored_state=4,
                                          merge_obs_rows=merge)
         for w in range(14):               # wraps past slot 11
             obs = (jnp.full((2, 3, 2), float(w))
@@ -95,7 +95,7 @@ def test_sequence_ring_merged_rows_matches_tiled():
 def test_sequence_seeding_alignment_and_overwrite():
     # 10 slots, L=4, stride=2: writes 0..9; start w becomes seedable when
     # write w+3 lands; seeded starts are the even write indices.
-    state = sring.sequence_ring_init(10, 1, jnp.zeros((2,)), lstm_size=4)
+    state = sring.sequence_ring_init(10, 1, jnp.zeros((2,)), stored_state=4)
     state = _seq_fill(state, 9, 1, seq_len=4, stride=2)
     p = np.asarray(state.priorities)[:, 0]
     # Complete windows start at writes 0..5; stride keeps {0, 2, 4}.
@@ -111,7 +111,7 @@ def test_sequence_seeding_alignment_and_overwrite():
 
 
 def test_sequence_sample_gathers_window_and_state():
-    state = sring.sequence_ring_init(16, 2, jnp.zeros((2,)), lstm_size=4)
+    state = sring.sequence_ring_init(16, 2, jnp.zeros((2,)), stored_state=4)
     state = _seq_fill(state, 12, 2, seq_len=4, stride=1, dones=(5,))
     s = sring.sequence_ring_sample(state, jax.random.PRNGKey(0),
                                    batch_size=8, seq_len=4, alpha=0.6,
@@ -132,7 +132,7 @@ def test_sequence_sample_gathers_window_and_state():
 
 
 def test_sequence_update_ignores_overwritten_starts():
-    state = sring.sequence_ring_init(8, 1, jnp.zeros((2,)), lstm_size=4)
+    state = sring.sequence_ring_init(8, 1, jnp.zeros((2,)), stored_state=4)
     state = _seq_fill(state, 8, 1, seq_len=3, stride=1)
     # Slot 2 is a valid start; slot 7 is not (window incomplete).
     state = sring.sequence_ring_update(
@@ -238,7 +238,7 @@ def test_r2d2_fused_loop_learns_cartpole():
 
 
 def test_sequence_sampler_pallas_agrees_with_xla():
-    state = sring.sequence_ring_init(64, 4, jnp.zeros((2,)), lstm_size=4)
+    state = sring.sequence_ring_init(64, 4, jnp.zeros((2,)), stored_state=4)
     state = _seq_fill(state, 40, 4, seq_len=4, stride=1, dones=(11, 23))
     key = jax.random.PRNGKey(0)
     kw = dict(batch_size=32, seq_len=4, alpha=0.6, beta=jnp.float32(0.4))

@@ -82,8 +82,12 @@ def test_every_scope_in_the_package_is_a_stage_and_every_stage_is_entered():
     for path in (CHECKOUT / "dist_dqn_tpu").rglob("*.py"):
         entered.update(re.findall(r'named_scope\("([^"]+)"\)',
                                   path.read_text()))
-    assert entered == set(stages.STAGES) | set(stages.PASSES)
-    assert not set(stages.STAGES) & set(stages.PASSES + stages.PARTS)
+    # ... and the hybrid core's mixers enter their part names (``torso`` is
+    # a module name there too)
+    assert entered == (set(stages.STAGES) | set(stages.PASSES)
+                       | set(stages.CORE_PARTS) - {"torso"})
+    assert not set(stages.STAGES) & set(
+        stages.PASSES + stages.PARTS + stages.CORE_PARTS)
 
 
 def test_the_parts_are_the_recurrent_networks_module_names():
@@ -105,6 +109,33 @@ def test_the_parts_are_the_recurrent_networks_module_names():
             assert any(f"/loss_grad/{wrapper}/" in p
                        and part in p.split("/") for p in paths), (wrapper,
                                                                   part)
+
+
+def test_the_hybrid_cores_parts_split_loss_grad():
+    """The chunk program over the hybrid sequence core at toy widths: every
+    name of ``CORE_PARTS`` is on the op paths of the online pass, forward
+    and backward (through the layers' rematerialisation), and the children
+    table puts instructions of stage ``loss_grad`` under each of them —
+    while ``PARTS`` still reads the whole stack as ``core``."""
+    from perf.tests.test_perf_run_twotower import TOY_CORE_CONFIG
+
+    # the benchmark's toy hybrid cell, on cartpole's four numbers
+    cfg = apply_overrides(CONFIGS["twotower_q"], TOY_CORE_CONFIG[
+        "overrides"] + ["env_name=cartpole", "network.torso=mlp",
+                        "network.mlp_features=(16,)",
+                        "replay.frame_dedup=false"])
+    text = _chunk_text(cfg)
+    paths = set(re.findall(r'op_name="([^"]*)"', text))
+    for wrapper in ("jvp(online_unroll)", "transpose(jvp(online_unroll))",
+                    "jvp(target_unroll)"):
+        for part in stages.CORE_PARTS:
+            assert any(f"/loss_grad/{wrapper}/" in p
+                       and stages.child_of(p, stages.CORE_PARTS) == part
+                       for p in paths), (wrapper, part)
+    children = stages.children_from_text(text, stages.CORE_PARTS)
+    assert set(stages.CORE_PARTS) <= set(children.values())
+    whole = stages.children_from_text(text, stages.PARTS)
+    assert {"torso", "core"} <= set(whole.values())
 
 
 @pytest.mark.parametrize("op_name,stage", [
