@@ -14,7 +14,19 @@ layer's convolution; one letter of ``CoreConfig.pattern`` a layer, as
        RMSNorm_grouped(y silu(z)) w``; ``out = y W_out``. Computed in the
        chunked form (``ssd_chunked``): products inside chunks of
        ``chunk_size`` steps, a scan over the chunks' states; its backward
-       is the chunked form's own.
+       is the chunked form's own. Between the two projections every
+       activation is made once, in the split and the order its reader
+       takes it (``PERF.md`` §6, PR 45): the window is cut into its chunks
+       BEFORE the in-projection, ``[B, nc, Q, ..]`` from there to the
+       out-projection; ``z``, ``x``, ``B``, ``C`` and ``dt`` are five
+       products of ``u`` with the columns of the one ``W_in``
+       (``split_products``: no ``[.., 10304]`` array, no split of an
+       activation), ``x``, ``B``, ``C`` each
+       with its own columns of the depthwise convolution, whose first taps
+       read the chunk before; the running sum of ``dt A`` inside a chunk
+       and the grouped norm's sums are products with a triangle and with
+       the groups' indicator matrix. ``ssd_chunked`` takes and returns
+       chunked arrays, ``y`` as ``[B, nc, Q, H, P]``.
 ``E``  Mixture of experts beside a shared expert. Router logits in
        float32, ``s = sigmoid(logits)``, the top k of ``s + bias`` chosen,
        gates ``s[chosen] / sum(s[chosen]) * scale``; an expert is ``W_down
@@ -64,14 +76,29 @@ def _normal(stddev: float):
     return nn.initializers.normal(stddev)
 
 
-def rms_norm(x: Array, scale: Array, eps: float, groups: int = 1) -> Array:
-    """``x / rms(x) * scale`` in float32, the mean square taken over each
-    of ``groups`` equal slices of the last axis."""
+def rms_norm(x: Array, scale: Array, eps: float) -> Array:
+    """``x / rms(x) * scale`` in float32 over the last axis."""
     x = x.astype(F32)
-    grouped = x.reshape(x.shape[:-1] + (groups, -1))
-    grouped = grouped * jax.lax.rsqrt(
-        jnp.mean(grouped * grouped, axis=-1, keepdims=True) + eps)
-    return grouped.reshape(x.shape) * scale
+    return x * jax.lax.rsqrt(
+        jnp.mean(x * x, axis=-1, keepdims=True) + eps) * scale
+
+
+def grouped_rms_norm(x: Array, scale: Array, eps: float, groups: int
+                     ) -> Array:
+    """``rms_norm`` with the mean square taken over each of ``groups``
+    equal slices of the last axis. The sum over a group and its spread back
+    over the group's channels are products with the groups' indicator
+    matrix: the array keeps its shape from end to end, where a ``[..,
+    groups, width]`` view is a relayout and its reduce and broadcast run as
+    passes of their own."""
+    x = x.astype(F32)
+    width = x.shape[-1] // groups
+    member = (jnp.arange(x.shape[-1])[:, None] // width
+              == jnp.arange(groups)).astype(F32)        # [channels, groups]
+    exact = jax.lax.Precision.HIGHEST
+    mean_sq = jnp.dot(x * x, member, precision=exact) / width
+    return x * jnp.dot(jax.lax.rsqrt(mean_sq + eps), member.T,
+                       precision=exact) * scale
 
 
 def segments(reset: Array) -> Array:
@@ -81,45 +108,55 @@ def segments(reset: Array) -> Array:
     return jnp.cumsum(reset.astype(jnp.int32), axis=1)
 
 
+def split_products(u: Array, w: Array, splits: Tuple[int, ...]):
+    """``u w`` cut at the columns ``splits``: each part a product of its
+    own with those columns of ``w``, float32 out; no ``[.., columns]``
+    array is made and split. The columns are taken as ROWS of ``w.T``: the
+    weight crosses the jit boundary column-major (the order that pads
+    least), and a view ``w[:, lo:hi]`` made the compiler relay every
+    float32 leaf beside it — parameter, target, both moments — each step
+    (``PERF.md`` §6, PR 45)."""
+    if math.prod(u.shape[:-1]) * 8 <= w.shape[0]:
+        # a few rows (acting): the product is bound by reading w, so w is
+        # read once, its cast inside the product, and the output is small
+        return tuple(jnp.split(
+            jnp.dot(u, w, preferred_element_type=F32), splits, axis=-1))
+    edges = (0,) + splits + (w.shape[1],)
+    rows = w.T
+    return tuple(jnp.einsum("...k,ck->...c", u, rows[lo:hi],
+                            preferred_element_type=F32)
+                 for lo, hi in zip(edges, edges[1:]))
+
+
 def ssd_chunked(x: Array, dt: Array, a: Array, b: Array, c: Array,
-                seg: Array, state: Array, chunk: int, dtype
-                ) -> Tuple[Array, Array]:
-    """The selective state-space recurrence in its chunked form.
+                seg: Array, state: Array, dtype) -> Tuple[Array, Array]:
+    """The selective state-space recurrence in its chunked form, over
+    arrays already cut into chunks of Q steps.
 
-    ``x [B, T, H, P]``, ``dt [B, T, H]`` (after softplus), ``a [H]``
-    (negative), ``b, c [B, T, G, N]``, ``seg [B, T]`` (``segments``),
-    ``state [B, H, P, N]`` float32 entering step 0. Returns ``y [B, T, H,
-    P]`` float32 (without the ``D x`` skip) and the state after step T-1.
+    ``x [B, nc, Q, H, P]``, ``dt [B, nc, Q, H]`` (after softplus; 0 on a
+    padding step: it decays nothing and adds nothing), ``a [H]``
+    (negative), ``b, c [B, nc, Q, G, N]``, ``seg [B, nc, Q]``
+    (``segments``), ``state [B, H, P, N]`` float32 entering step 0. Returns
+    ``y [B, nc, Q, H, P]`` float32 (without the ``D x`` skip) and the state
+    after the last step.
 
-    Inside a chunk of Q steps the output is a masked product: ``y_i = sum_j
-    L_ij (C_i . B_j) dt_j x_j`` with ``L_ij = exp(sum_{j<k<=i} dt_k a)``
-    for ``j <= i`` in one segment; each chunk hands on ``sum_j L_(Q-1)j
-    dt_j x_j (x) B_j`` plus the decayed state it was handed, and adds ``C_i
-    . state_in`` decayed to step i. A step that opens an episode cuts all
-    three by its segment number. T is padded to whole chunks with ``dt =
-    0`` steps: they decay nothing and add nothing. Decays and the carried
-    state are float32; the products take ``dtype`` operands and accumulate
-    in float32."""
-    B, T, H, P = x.shape
-    G, N = b.shape[2:]
-    Q = min(chunk, T)
-    pad = -T % Q
-    if pad:
-        x, dt, b, c = (jnp.pad(v, ((0, 0), (0, pad)) + ((0, 0),) * (v.ndim - 2))
-                       for v in (x, dt, b, c))
-        seg = jnp.pad(seg, ((0, 0), (0, pad)), mode="edge")
-    nc = (T + pad) // Q
-
-    def chunks(v):
-        return v.reshape((B, nc, Q) + v.shape[2:])
-
-    seg = chunks(seg)                                   # [B, nc, Q]
-    dt = chunks(dt.astype(F32))                         # [B, nc, Q, H]
+    Inside a chunk the output is a masked product: ``y_i = sum_j L_ij (C_i
+    . B_j) dt_j x_j`` with ``L_ij = exp(sum_{j<k<=i} dt_k a)`` for ``j <=
+    i`` in one segment; each chunk hands on ``sum_j L_(Q-1)j dt_j x_j (x)
+    B_j`` plus the decayed state it was handed, and adds ``C_i . state_in``
+    decayed to step i. A step that opens an episode cuts all three by its
+    segment number. Decays and the carried state are float32; the products
+    take ``dtype`` operands and accumulate in float32."""
+    B, nc, Q, H, P = x.shape
+    G, N = b.shape[3:]
     # dt folded into x: what a step adds to the state is dt x (x) B
-    xdt = (chunks(x.astype(F32)) * dt[..., None]).astype(dtype)
-    xdt = xdt.reshape(B, nc, Q, G, H // G, P)
-    b, c = chunks(b.astype(dtype)), chunks(c.astype(dtype))
-    cs = jnp.cumsum(dt * a, axis=2)                     # [B, nc, Q, H] <= 0
+    xdt = (x * dt[..., None]).astype(dtype).reshape(B, nc, Q, G, H // G, P)
+    b, c = b.astype(dtype), c.astype(dtype)
+    # the running sum of dt a over a chunk's steps, as a product with the
+    # lower triangle (a [Q, Q] product where a cumsum is a serial scan)
+    causal = jnp.tril(jnp.ones((Q, Q), jnp.bool_))
+    cs = jnp.einsum("ij,bzjh->bzih", causal.astype(F32), dt * a,
+                    precision=jax.lax.Precision.HIGHEST)  # [B,nc,Q,H] <= 0
     cs = cs.reshape(B, nc, Q, G, H // G)
     # the segment each chunk's incoming state belongs to
     seg_in = jnp.concatenate(
@@ -127,7 +164,6 @@ def ssd_chunked(x: Array, dt: Array, a: Array, b: Array, c: Array,
 
     # -- inside the chunks -------------------------------------------------
     same = seg[:, :, :, None] == seg[:, :, None, :]     # [B, nc, Qi, Qj]
-    causal = jnp.tril(jnp.ones((Q, Q), jnp.bool_))
     allowed = jnp.logical_and(same, causal)[..., None, None]
     decay = jnp.exp(jnp.where(
         allowed, cs[:, :, :, None] - cs[:, :, None, :], -jnp.inf))
@@ -161,8 +197,7 @@ def ssd_chunked(x: Array, dt: Array, a: Array, b: Array, c: Array,
     y = y + from_in[..., None] * jnp.einsum(
         "bzign,bzghpn->bzighp", c, entering.astype(dtype),
         preferred_element_type=F32)
-    y = y.reshape(B, nc * Q, H, P)[:, :T]
-    return y, state.reshape(B, H, P, N)
+    return y.reshape(B, nc, Q, H, P), state.reshape(B, H, P, N)
 
 
 class _Mamba2(nn.Module):
@@ -197,37 +232,78 @@ class _Mamba2(nn.Module):
         w_out = self.param("out_proj", _normal(inner ** -0.5),
                            (inner, hidden))
         tail, state = carry
+        # whole chunks of Q steps, each a row of its own from the first
+        # product to the last: [B, nc, Q, ...]; only the convolution's
+        # look-back and the scan's state pass from a chunk to the next
+        Q = min(cfg.chunk_size, T)
+        pad = -T % Q
+        nc = (T + pad) // Q
+
+        def chunks(v, **how):
+            v = jnp.pad(v, ((0, 0), (0, pad)) + ((0, 0),) * (v.ndim - 2),
+                        **how) if pad else v
+            return v.reshape((B, nc, Q) + v.shape[2:])
 
         with jax.named_scope("ssm"):
-            proj = jnp.dot(u.astype(self.dtype), w_in.astype(self.dtype),
-                           preferred_element_type=F32)
-            z, xbc, dt = jnp.split(proj, [inner, inner + channels], axis=-1)
-            # causal depthwise convolution: tap d reads step t - d where
-            # that step lies in t's segment (the look-back is segment 0)
-            padded = jnp.concatenate([tail.astype(F32), xbc], axis=1)
-            seg_padded = jnp.concatenate(
-                [jnp.zeros((B, K - 1), seg.dtype), seg], axis=1)
-            conv = conv_b.astype(F32)
-            for d in range(K):
-                lo = K - 1 - d
-                tap = jnp.where(
-                    (seg_padded[:, lo:lo + T] == seg)[..., None],
-                    padded[:, lo:lo + T], 0.0)
-                conv = conv + tap * conv_w[K - 1 - d]
-            new_tail = jnp.where(
-                (seg_padded[:, T:] == seg[:, -1:])[..., None],
-                padded[:, T:], 0.0)
-            xbc = jax.nn.silu(conv)
-            x, b, c = jnp.split(xbc, [inner, inner + G * N], axis=-1)
-            x = x.reshape(B, T, H, P)
+            live = chunks(jnp.ones((B, T), jnp.bool_))
+            seg_z = chunks(seg, mode="edge")
+            z, *xbc, dt = split_products(
+                chunks(u.astype(self.dtype)), w_in.astype(self.dtype),
+                (inner, 2 * inner, 2 * inner + G * N, inner + channels))
+            # which steps a tap may read: step i - d lies in i's segment
+            # (the look-back is segment 0); a chunk's first taps read the
+            # chunk before it
+            keep = min(T, K - 1)
+            seg_before = jnp.zeros((B, 1, K - 1), seg.dtype)
+            seg_last = jnp.concatenate(
+                [seg_before[:, 0, keep:], seg[:, T - keep:]], axis=1)
+            if nc > 1:
+                seg_before = jnp.concatenate(
+                    [seg_before, seg_z[:, :-1, Q - (K - 1):]], axis=1)
+
+            def taps(v, v_before):
+                """For d = 0 .. K-1, what step i - d of every chunk holds."""
+                joined = jnp.concatenate([v_before, v], axis=2)
+                return [joined[:, :, K - 1 - d:K - 1 - d + Q]
+                        for d in range(K)]
+
+            reads = [(tap == seg_z)[..., None]
+                     for tap in taps(seg_z, seg_before)]
+
+            def convolved(v, lo):
+                """Channels lo: of ``xBC`` from their product ``v``: the
+                causal depthwise convolution and silu; and the look-back
+                they hand on, the last K-1 steps before T."""
+                hi = lo + v.shape[-1]
+                before = tail.astype(F32)[:, None, :, lo:hi]
+                if nc > 1:
+                    before = jnp.concatenate(
+                        [before, v[:, :-1, Q - (K - 1):]], axis=1)
+                conv = conv_b[lo:hi]
+                for d, tap in enumerate(taps(v, before)):
+                    conv = conv + conv_w[K - 1 - d, lo:hi] * jnp.where(
+                        reads[d], tap, 0.0)
+                last = jnp.concatenate(
+                    [before[:, 0, keep:],
+                     v.reshape(B, nc * Q, hi - lo)[:, T - keep:T]], axis=1)
+                return jax.nn.silu(conv), last
+
+            (x, b, c), last = zip(*(
+                convolved(v, lo)
+                for v, lo in zip(xbc, (0, inner, inner + G * N))))
+            new_tail = jnp.where((seg_last == seg[:, -1:])[..., None],
+                                 jnp.concatenate(last, axis=-1), 0.0)
+            x = x.reshape(B, nc, Q, H, P)
+            dt = jnp.where(live[..., None],
+                           jax.nn.softplus(dt + dt_bias), 0.0)
             y, state = ssd_chunked(
-                x, jax.nn.softplus(dt + dt_bias), -jnp.exp(a_log),
-                b.reshape(B, T, G, N), c.reshape(B, T, G, N), seg, state,
-                cfg.chunk_size, self.dtype)
-            y = (y + d_skip[:, None] * x).reshape(B, T, inner)
-            y = rms_norm(y * jax.nn.silu(z), norm_w, cfg.norm_eps, groups=G)
+                x, dt, -jnp.exp(a_log), b.reshape(B, nc, Q, G, N),
+                c.reshape(B, nc, Q, G, N), seg_z, state, self.dtype)
+            y = (y + d_skip[:, None] * x).reshape(B, nc, Q, inner)
+            y = grouped_rms_norm(y * jax.nn.silu(z), norm_w, cfg.norm_eps, G)
             out = jnp.dot(y.astype(self.dtype), w_out.astype(self.dtype),
                           preferred_element_type=F32)
+            out = out.reshape(B, nc * Q, hidden)[:, :T]
         return out, (new_tail, state)
 
 
