@@ -83,5 +83,8 @@ def make_agent(net, cfg: ExperimentConfig, axis_name: Optional[str] = None,
             lambda x: x * jax.lax.expand_dims(keep, range(1, x.ndim)),
             actor_state)
 
+    # a network whose state is not emptied by zeroing all of it says how
+    # (models/sequence_core.py: a ring of keys is emptied by its counter)
+    reset_state = getattr(net, "reset_state", reset_state)
     return Agent(init_learner, train_step, act, initial_state, reset_state,
                  stored_state, chunk_metrics)

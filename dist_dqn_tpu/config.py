@@ -14,16 +14,37 @@ from typing import Dict, Tuple
 
 
 @dataclasses.dataclass(frozen=True)
+class RopeConfig:
+    """One kind of attention layer's rotary embedding (models/
+    sequence_core.py ``rotary_frequencies``): ``theta`` over the first
+    ``rotary_factor`` of the head's dims; ``yarn_factor`` > 0 makes it YaRN
+    (Peng et al. 2023: frequencies interpolated by that factor below a
+    ramp over ``beta_fast`` .. ``beta_slow`` turns in ``original_positions``
+    steps, cos and sin times ``attention_factor``)."""
+
+    theta: float = 10_000.0
+    rotary_factor: float = 1.0
+    yarn_factor: float = 0.0
+    original_positions: int = 4096
+    beta_fast: float = 64.0
+    beta_slow: float = 1.0
+    attention_factor: float = 1.0
+
+
+@dataclasses.dataclass(frozen=True)
 class CoreConfig:
     """The recurrent network's sequence core (models/).
 
     ``kind`` "lstm": the LSTM of ``network.lstm_size`` (recurrent only
     where that is > 0; models/recurrent.py). ``kind`` "hybrid": a stack of
-    pre-norm residual layers over ``network.hidden`` channels, one letter
-    of ``pattern`` each (models/sequence_core.py): ``M`` a Mamba-2
+    pre-norm residual sublayers over ``network.hidden`` channels, one
+    letter of ``pattern`` each (models/sequence_core.py): ``M`` a Mamba-2
     state-space mixer, ``E`` a mixture of experts beside a shared expert,
-    ``*`` grouped-query attention. The defaults are the widths the
-    ``twotower_q`` preset runs (``nemotron_h``'s keys, where it has one).
+    ``*`` grouped-query attention without positions, ``F`` / ``W`` rotary
+    gated attention over the whole history / the last ``sliding_window``
+    steps, ``D`` a dense gated MLP. The defaults are the widths the
+    ``twotower_q`` preset runs (``nemotron_h``'s keys, where it has one);
+    ``laguna_q`` states its own.
     """
 
     kind: str = "lstm"
@@ -51,6 +72,20 @@ class CoreConfig:
     num_key_value_heads: int = 2
     head_dim: int = 128
     attention_window: int = 512
+    # E: the expert's form, "relu2" (``W_down relu(W_up u)^2``) or "silu"
+    # (gated, three matrices: ``W_down (silu(W_gate u) * W_up u)``), and
+    # whether choosing adds a correction bias to the scores.
+    expert_act: str = "relu2"
+    router_bias: bool = True
+    # D: the dense gated MLP's width ("silu" form).
+    intermediate_size: int = 8192
+    # F / W: query heads of each such sublayer, in the pattern's order (KV
+    # heads and head_dim as ``*``); W sees a lane's last ``sliding_window``
+    # steps, F all of the episode (acting keeps ``attention_window``).
+    attention_heads_per_layer: Tuple[int, ...] = ()
+    sliding_window: int = 512
+    rope_full: RopeConfig = RopeConfig()
+    rope_window: RopeConfig = RopeConfig()
 
 
 @dataclasses.dataclass(frozen=True)
@@ -461,9 +496,54 @@ TWOTOWER_Q = ExperimentConfig(
     total_env_steps=100_000_000,
 )
 
+LAGUNA_Q = ExperimentConfig(
+    # An R2D2-style agent whose memory is the first five layers of
+    # Laguna-XS.2 (poolside; ``laguna``) at their published widths — ten
+    # sublayers: full attention (48 heads, YaRN over half the dims), the
+    # leading dense MLP of 8,192, then window-512 attention (64 heads,
+    # plain rotary) and 8 HELD of 256 experts three times, full attention
+    # and experts once more (perf/configs/laguna_q.json). Windows of 2,048
+    # steps from a zero state: 512 burn-in (one whole attention window) +
+    # 1,531 trained + 5 bootstrap, 4 a grad step = 8,192 tokens, a grad
+    # step every 8th acting step. Acting keeps 512 steps of keys and values
+    # in the window layers and 2,048 in the full ones.
+    name="laguna_q",
+    env_name="pixel_pong",
+    network=NetworkConfig(
+        torso="nature", hidden=2048, dueling=True,
+        compute_dtype="bfloat16", remat_torso=True,
+        core=CoreConfig(
+            kind="hybrid", pattern="FDWEWEWEFE", norm_eps=1e-6,
+            n_routed_experts=256, num_experts_per_tok=8,
+            routed_scaling_factor=2.5, moe_intermediate_size=512,
+            moe_shared_expert_intermediate_size=512, expert_act="silu",
+            router_bias=False, intermediate_size=8192,
+            num_key_value_heads=8, head_dim=128, attention_window=2048,
+            attention_heads_per_layer=(48, 64, 64, 64, 48),
+            sliding_window=512,
+            rope_full=RopeConfig(
+                theta=500_000.0, rotary_factor=0.5, yarn_factor=64.0,
+                original_positions=4096, beta_fast=64.0, beta_slow=1.0,
+                attention_factor=1.4158883083359672),
+            rope_window=RopeConfig(theta=10_000.0))),
+    replay=ReplayConfig(capacity=262_144, prioritized=True,
+                        priority_exponent=0.9, importance_exponent=0.6,
+                        burn_in=512, unroll_length=1531,
+                        sequence_stride=512, min_fill=40_960,
+                        frame_dedup=True),
+    learner=LearnerConfig(
+        learning_rate=1e-4, adam_eps=1e-3, gamma=0.997, n_step=5,
+        batch_size=4, double_dqn=True, target_update_period=2_500,
+        value_rescale=True,
+    ),
+    actor=ActorConfig(num_envs=16, num_actors=256),
+    train_every=8,
+    total_env_steps=100_000_000,
+)
+
 CONFIGS: Dict[str, ExperimentConfig] = {
     c.name: c for c in (CARTPOLE, ATARI, APEX, R2D2, RAINBOW, QRDQN, IQN,
-                        MDQN, TWOTOWER_Q)
+                        MDQN, TWOTOWER_Q, LAGUNA_Q)
 }
 
 

@@ -40,32 +40,57 @@ layer's convolution; one letter of ``CoreConfig.pattern`` a layer, as
        routing follows the seed). The bias is a leaf no gradient reaches.
 ``*``  Grouped-query attention, causal within an episode, no position
        embedding (``nemotron_h`` builds none in these layers).
+``F``, ``W``  Grouped-query attention as ``laguna`` writes it
+       (``_RotaryAttention``): queries and keys rotated by their step's
+       position in its episode (``rotary_frequencies``: plain, or YaRN over
+       part of the dims) BEFORE a key is cached, a sigmoid gate a head on
+       the attended values, heads a layer (``attention_heads_per_layer``).
+       ``F`` sees the whole episode, ``W`` its last ``sliding_window``
+       steps. Computed by blocks of ``sliding_window`` queries, each
+       block's scores made, used and — in the backward — made again on
+       their own: ``F`` against the keys up to its block (the causal
+       triangle by blocks), ``W`` against the block before and its own (a
+       band whose cost does not grow with the window's length).
+``D``  A dense gated MLP, ``W_down (silu(W_gate u) * W_up u)``.
+``E`` takes the expert's form from the configuration (``expert_act``:
+``relu2`` above, or ``silu``, gated as ``D``) and its correction bias where
+``router_bias`` says so.
 
 State. A lane's acting state is, for every ``M`` layer, the convolution's
 look-back ``[B, K-1, channels]`` and the state ``h [B, H, P, N]``, and for
 every ``*`` layer the keys and values of its last ``history`` steps with a
 validity plane (``[B, history, KV, D]`` twice, ``[B, history]``): all
 float32, all zero when empty, so that ``Agent.reset_state``'s product with
-``1 - done`` empties a lane. ``reset[t]`` (``obs[t]`` opens an episode)
-cuts every look-back at t inside a window: the scan's carried state, the
-convolution's taps and the attention's keys before t. The replay ring
-stores none of it (``stored_state``): at megabytes a lane a step it cannot,
-so a learner's window starts from the zero state and burns in (R2D2's
-zero-state strategy), with ``history`` the burn-in's length.
+``1 - done`` empties a lane. An ``F`` or ``W`` layer keeps a RING of keys
+and values (``[B, history, KV, D]`` twice) and the lane's step counter
+``[B]``: step p of an episode lies in slot ``p mod history``, so acting
+writes one slot a step and moves none, what is valid follows from the
+counter alone, and emptying a lane is setting its counter to zero
+(``reset_state``: the ring is left as it is). ``history`` is a layer's own:
+``sliding_window`` in a ``W`` layer, ``attention_window`` in an ``F`` layer
+while acting — which then sees that many steps back and no further.
+``reset[t]`` (``obs[t]`` opens an episode) cuts every look-back at t inside
+a window: the scan's carried state, the convolution's taps, the
+attention's keys before t, and the positions, which restart at 0. The
+replay ring stores none of it (``stored_state``): at megabytes a lane a
+step it cannot, so a learner's window starts from the zero state and burns
+in (R2D2's zero-state strategy), with ``history`` the burn-in's length.
 
 Same two entry points as ``models/recurrent.py``: ``apply(params, carry,
 obs, reset)`` is one step, ``method=net.unroll`` takes ``[T, B, ...]``.
 """
 from __future__ import annotations
 
+import functools
 import math
 from typing import Optional, Tuple
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+import numpy as np
 
-from dist_dqn_tpu.config import CoreConfig
+from dist_dqn_tpu.config import CoreConfig, RopeConfig
 from dist_dqn_tpu.models.recurrent import _Embed
 
 Array = jnp.ndarray
@@ -311,6 +336,15 @@ def _inverse_softplus(x: Array) -> Array:
     return x + jnp.log(-jnp.expm1(-x))
 
 
+def silu_gated(u16: Array, w_gate: Array, up: Array) -> Array:
+    """``silu(u W_gate) * up`` for ``up = u W_up``, float32 out: what stands
+    between the two products of a gated MLP; ``w_gate`` in the operands'
+    type, any trailing axes taken as columns."""
+    return jax.nn.silu(jnp.dot(
+        u16, w_gate.reshape(u16.shape[-1], -1),
+        preferred_element_type=F32)) * up
+
+
 def route(logits: Array, bias: Array, k: int, scale: float):
     """``(chosen [.., k] int32, gates [.., k] float32)``: sigmoid scores,
     the top k of score + bias chosen, the chosen scores normalised to sum
@@ -337,10 +371,12 @@ class _Experts(nn.Module):
         held = jnp.asarray(cfg.experts_held, jnp.int32)
         E, width = len(cfg.experts_held), cfg.moe_intermediate_size
         shared = cfg.moe_shared_expert_intermediate_size
+        gated = {"relu2": False, "silu": True}[cfg.expert_act]
         w_router = self.param("router", _normal(hidden ** -0.5),
                               (hidden, cfg.n_routed_experts))
-        bias = self.param("e_score_correction_bias", nn.initializers.zeros,
-                          (cfg.n_routed_experts,))
+        bias = (self.param("e_score_correction_bias", nn.initializers.zeros,
+                           (cfg.n_routed_experts,))
+                if cfg.router_bias else 0.0)
         # [hidden, E, width] / [E, width, hidden]: both reshape to the one
         # matrix over all held experts without moving a byte
         w_up = self.param("experts_up", _normal(hidden ** -0.5),
@@ -352,6 +388,15 @@ class _Experts(nn.Module):
         s_down = self.param("shared_down", _normal(shared ** -0.5),
                             (shared, hidden))
         u16 = u.astype(self.dtype)
+
+        def activation(up, name, shape):
+            """What stands between an expert's two products, over all the
+            columns of ``up = u W_up``: ``relu(up)^2``, or gated ``silu(u
+            W_gate) * up`` with a gate matrix ``name`` shaped as ``W_up``."""
+            if not gated:
+                return jnp.square(jax.nn.relu(up))
+            w_gate = self.param(name, _normal(hidden ** -0.5), shape)
+            return silu_gated(u16, w_gate.astype(self.dtype), up)
 
         with jax.named_scope("moe_router"):
             logits = jnp.dot(u.astype(F32), w_router,
@@ -370,9 +415,9 @@ class _Experts(nn.Module):
                 self.sow("routing", "busiest_over_mean",
                          jnp.max(load) / jnp.maximum(jnp.mean(load), 1e-9))
         with jax.named_scope("moe_routed"):
-            act = jnp.square(jax.nn.relu(jnp.dot(
+            act = activation(jnp.dot(
                 u16, w_up.astype(self.dtype).reshape(hidden, E * width),
-                preferred_element_type=F32)))
+                preferred_element_type=F32), "experts_gate", w_up.shape)
             # each expert's block of columns times its gate, as selects
             # over the column index: a view of the columns as [E, width]
             # would be a relayout of the whole activation wherever width
@@ -385,8 +430,9 @@ class _Experts(nn.Module):
                 w_down.astype(self.dtype).reshape(E * width, hidden),
                 preferred_element_type=F32)
         with jax.named_scope("moe_shared"):
-            act = jnp.square(jax.nn.relu(jnp.dot(
-                u16, s_up.astype(self.dtype), preferred_element_type=F32)))
+            act = activation(jnp.dot(
+                u16, s_up.astype(self.dtype), preferred_element_type=F32),
+                "shared_gate", s_up.shape)
             out = routed + jnp.dot(act.astype(self.dtype),
                                    s_down.astype(self.dtype),
                                    preferred_element_type=F32)
@@ -458,7 +504,245 @@ class _Attention(nn.Module):
         return out, carry
 
 
-_MIXERS = {"M": _Mamba2, "E": _Experts, "*": _Attention}
+def rotary_frequencies(rope: RopeConfig, head_dim: int) -> np.ndarray:
+    """``inv_freq [d / 2]`` (float64) of a rotary embedding over the first
+    ``d = rotary_factor * head_dim`` dims: ``theta^(-2i/d)``; under YaRN
+    (``yarn_factor`` > 0) divided by the factor where a dim turns fewer
+    than ``beta_slow`` times in ``original_positions`` steps, left alone
+    where it turns more than ``beta_fast`` times, a linear ramp over the
+    dims between."""
+    d = int(head_dim * rope.rotary_factor)
+    i = np.arange(d // 2, dtype=np.float64)
+    inv_freq = rope.theta ** (-2.0 * i / d)
+    if not rope.yarn_factor:
+        return inv_freq
+
+    def dim_turning(turns: float) -> float:
+        return (d * math.log(rope.original_positions / (turns * 2 * math.pi))
+                / (2 * math.log(rope.theta)))
+
+    low = max(math.floor(dim_turning(rope.beta_fast)), 0)
+    high = min(math.ceil(dim_turning(rope.beta_slow)), d - 1)
+    ramp = np.clip((i - low) / max(high - low, 1e-3), 0.0, 1.0)
+    return (1.0 - ramp) * inv_freq + ramp * inv_freq / rope.yarn_factor
+
+
+def rotary_tables(position: Array, rope: RopeConfig, head_dim: int):
+    """``(cos, sin) [B, T, 1, d / 2]`` float32 of ``position [B, T]`` (steps
+    since the episode opened), each times ``attention_factor``."""
+    angle = (position.astype(F32)[..., None, None]
+             * jnp.asarray(rotary_frequencies(rope, head_dim), F32))
+    return (jnp.cos(angle) * rope.attention_factor,
+            jnp.sin(angle) * rope.attention_factor)
+
+
+def rotate(x: Array, tables) -> Array:
+    """``x [B, T, n, D]`` float32 rotated by ``rotary_tables``, rotate-half
+    layout: dim i of the first half of the rotary dims pairs with dim i of
+    their second half; the dims past them pass as they are."""
+    cos, sin = tables
+    half = cos.shape[-1]
+    x1, x2, rest = (x[..., :half], x[..., half:2 * half], x[..., 2 * half:])
+    return jnp.concatenate(
+        [x1 * cos - x2 * sin, x2 * cos + x1 * sin, rest], axis=-1)
+
+
+class _RotaryAttention(nn.Module):
+    """``F`` / ``W``: see the module's docstring. ``carry`` is ``(keys,
+    values [B, history, KV, D], steps [B])``: a ring of the lane's rotated
+    keys and its values, step p of the episode in slot ``p mod history``,
+    and how many steps the episode has had."""
+
+    cfg: CoreConfig
+    dtype: jnp.dtype
+    heads: int
+    windowed: bool
+
+    @nn.compact
+    def __call__(self, u: Array, seg: Array, carry):
+        cfg = self.cfg
+        heads, kv, D = self.heads, cfg.num_key_value_heads, cfg.head_dim
+        rope = cfg.rope_window if self.windowed else cfg.rope_full
+        hidden = u.shape[-1]
+        B, T = u.shape[:2]
+        w_q = self.param("q_proj", _normal(hidden ** -0.5),
+                         (hidden, heads * D))
+        w_k = self.param("k_proj", _normal(hidden ** -0.5), (hidden, kv * D))
+        w_v = self.param("v_proj", _normal(hidden ** -0.5), (hidden, kv * D))
+        w_g = self.param("g_proj", _normal(hidden ** -0.5), (hidden, heads))
+        w_o = self.param("o_proj", _normal((heads * D) ** -0.5),
+                         (heads * D, hidden))
+        old_k, old_v, steps = carry
+        history = old_k.shape[1]
+
+        def attend(q, keys, values, see):
+            """``q [B, Q, KV, G, D]`` over ``keys, values [B, S, KV, D]``
+            where ``see [B, Q, S]``: softmax in float32. A row that sees
+            nothing (padding) comes out as a mean, not as NaN."""
+            scores = jnp.einsum(
+                "bqkgd,bskd->bkgqs", q.astype(self.dtype),
+                keys.astype(self.dtype),
+                preferred_element_type=F32) * D ** -0.5
+            scores = jnp.where(see[:, None, None], scores, -1e30)
+            return jnp.einsum(
+                "bkgqs,bskd->bqkgd",
+                jax.nn.softmax(scores, axis=-1).astype(self.dtype),
+                values.astype(self.dtype), preferred_element_type=F32)
+
+        with (jax.named_scope("attention_window") if self.windowed
+              else jax.named_scope("attention_full")):
+            u16 = u.astype(self.dtype)
+
+            def project(w, n):
+                return jnp.dot(u16, w.astype(self.dtype),
+                               preferred_element_type=F32).reshape(B, T, n, D)
+
+            # a step's position in its episode: the counter goes on while
+            # no episode has opened in this call, and restarts where one has
+            index = jnp.arange(T)
+            opened = jax.lax.cummax(jnp.where(
+                jnp.diff(seg, axis=1, prepend=0) > 0, index, -1), axis=1)
+            position = jnp.where(seg == 0,
+                                 steps.astype(jnp.int32)[:, None] + index,
+                                 index - opened)            # [B, T]
+            tables = rotary_tables(position, rope, D)
+            q = rotate(project(w_q, heads), tables)
+            q = q.reshape(B, T, kv, heads // kv, D)
+            new_k = rotate(project(w_k, kv), tables)
+            new_v = project(w_v, kv)
+            gate = jax.nn.sigmoid(jnp.dot(u16, w_g.astype(self.dtype),
+                                          preferred_element_type=F32))
+            if T == 1 and history:
+                # acting: the new key takes its slot, then the one query
+                # reads the ring; what the ring holds is what it may see
+                lanes, slot = jnp.arange(B), position[:, 0] % history
+                ring_k = old_k.at[lanes, slot].set(new_k[:, 0])
+                ring_v = old_v.at[lanes, slot].set(new_v[:, 0])
+                see = (jnp.arange(history)
+                       < jnp.minimum(position + 1, history))   # [B, S]
+                attended = attend(q, ring_k, ring_v, see[:, None])
+            else:
+                attended = self.blockwise(
+                    jax.checkpoint(attend), q, new_k, new_v, position, seg,
+                    carry)
+                ring_k, ring_v = self.ring_after(new_k, new_v, position,
+                                                 opened[:, -1], carry)
+            gated = attended.reshape(B, T, heads, D) * gate[..., None]
+            out = jnp.dot(gated.reshape(B, T, heads * D).astype(self.dtype),
+                          w_o.astype(self.dtype), preferred_element_type=F32)
+            carry = (ring_k, ring_v, (position[:, -1] + 1).astype(F32))
+        return out, carry
+
+    def blockwise(self, attend, q, new_k, new_v, position, seg, carry):
+        """The attended values ``[B, T, KV, G, D]`` of T steps over the
+        ring handed in and their own keys, a block of ``sliding_window``
+        queries at a time. A key is (position, segment, valid); query t
+        sees the valid keys of its segment at positions up to its own and,
+        in a ``W`` layer, less than ``sliding_window`` below it. The ring's
+        slots lie in segment 0 at the positions the counter gives them."""
+        old_k, old_v, steps = carry
+        B, T = position.shape
+        history, window = old_k.shape[1], self.cfg.sliding_window
+        steps = steps.astype(jnp.int32)[:, None]
+        slots = jnp.arange(history)
+        # slot j holds the last position below ``steps`` that is j mod
+        # history, if the episode has come that far
+        old_position = steps - 1 - (steps - 1 - slots) % max(history, 1)
+        old_valid = slots < jnp.minimum(steps, history)
+        block = min(window, T)
+        pad = -T % block
+
+        def padded(v, value=0):
+            return jnp.pad(v, ((0, 0), (0, pad)) + ((0, 0),) * (v.ndim - 2),
+                           constant_values=value) if pad else v
+
+        q, new_k, new_v, position, seg = (
+            padded(v) for v in (q, new_k, new_v, position, seg))
+        live = padded(jnp.ones((B, T), jnp.bool_), False)
+        keys = jnp.concatenate([old_k, new_k], axis=1)
+        values = jnp.concatenate([old_v, new_v], axis=1)
+        key_position = jnp.concatenate([old_position, position], axis=1)
+        key_seg = jnp.concatenate(
+            [jnp.zeros((B, history), seg.dtype), seg], axis=1)
+        key_valid = jnp.concatenate([old_valid, live], axis=1)
+        out = []
+        for lo in range(0, T + pad, block):
+            # the keys a block can see at all: a ``W`` block the block
+            # before it (the ring, for the first) and itself; an ``F``
+            # block everything up to itself
+            first = history + lo - block if self.windowed and lo else 0
+            last = history + lo + block
+            at, its_seg = (position[:, lo:lo + block, None],
+                           seg[:, lo:lo + block, None])
+            below = at - key_position[:, None, first:last]
+            see = ((key_valid[:, None, first:last])
+                   & (key_seg[:, None, first:last] == its_seg)
+                   & (below >= 0))
+            if self.windowed:
+                see = see & (below < window)
+            out.append(attend(q[:, lo:lo + block], keys[:, first:last],
+                              values[:, first:last], see))
+        return jnp.concatenate(out, axis=1)[:, :T]
+
+    def ring_after(self, new_k, new_v, position, opened, carry):
+        """The ring after T steps: slot j holds the last position of the
+        episode the call ends in that is j mod history — one of this call's
+        steps where the call reaches back that far, else what the slot
+        held (valid only if no episode opened in the call, and then the
+        counter says so)."""
+        old_k, old_v, _ = carry
+        history, T = old_k.shape[1], position.shape[1]
+        if not history:
+            return old_k, old_v
+        last = position[:, -1:]                              # [B, 1]
+        back = (last - jnp.arange(history)) % history        # steps back
+        source = T - 1 - back                                # [B, history]
+        from_new = source >= jnp.maximum(opened, 0)[:, None]
+        pick = jnp.clip(source, 0, T - 1)[..., None, None]
+        return tuple(
+            jnp.where(from_new[..., None, None],
+                      jnp.take_along_axis(new, pick, axis=1), old)
+            for new, old in ((new_k, old_k), (new_v, old_v)))
+
+
+class _DenseMLP(nn.Module):
+    """``D``: see the module's docstring. No state."""
+
+    cfg: CoreConfig
+    dtype: jnp.dtype
+
+    @nn.compact
+    def __call__(self, u: Array, seg: Array, carry):
+        hidden, width = u.shape[-1], self.cfg.intermediate_size
+        w_gate = self.param("gate_proj", _normal(hidden ** -0.5),
+                            (hidden, width))
+        w_up = self.param("up_proj", _normal(hidden ** -0.5),
+                          (hidden, width))
+        w_down = self.param("down_proj", _normal(width ** -0.5),
+                            (width, hidden))
+        with jax.named_scope("mlp_dense"):
+            u16 = u.astype(self.dtype)
+            act = silu_gated(u16, w_gate.astype(self.dtype), jnp.dot(
+                u16, w_up.astype(self.dtype), preferred_element_type=F32))
+            out = jnp.dot(act.astype(self.dtype), w_down.astype(self.dtype),
+                          preferred_element_type=F32)
+        return out, carry
+
+
+_MIXERS = {"M": _Mamba2, "E": _Experts, "*": _Attention, "D": _DenseMLP,
+           "F": functools.partial(_RotaryAttention, windowed=False),
+           "W": functools.partial(_RotaryAttention, windowed=True)}
+#: The letters whose sublayer is a ``_RotaryAttention``: each takes its
+#: head count from ``attention_heads_per_layer``, in the pattern's order.
+ROTARY = "FW"
+
+
+def rotary_heads(cfg: CoreConfig) -> Tuple[int, ...]:
+    """Query heads of every sublayer of the pattern; 0 where the sublayer
+    is no ``F`` or ``W``."""
+    count = iter(cfg.attention_heads_per_layer)
+    return tuple(next(count) if kind in ROTARY else 0
+                 for kind in cfg.pattern)
 
 
 class _Layer(nn.Module):
@@ -469,12 +753,15 @@ class _Layer(nn.Module):
     kind: str
     cfg: CoreConfig
     dtype: jnp.dtype
+    heads: int = 0      # an ``F`` or ``W`` sublayer's query heads
 
     @nn.compact
     def __call__(self, x: Array, seg: Array, carry):
         scale = self.param("norm", nn.initializers.ones, (x.shape[-1],))
-        out, carry = _MIXERS[self.kind](self.cfg, self.dtype, name="mixer")(
-            rms_norm(x, scale, self.cfg.norm_eps), seg, carry)
+        its_own = {"heads": self.heads} if self.kind in ROTARY else {}
+        out, carry = _MIXERS[self.kind](
+            self.cfg, self.dtype, name="mixer", **its_own)(
+                rms_norm(x, scale, self.cfg.norm_eps), seg, carry)
         return x + out, carry
 
 
@@ -488,9 +775,10 @@ class _Core(nn.Module):
     def __call__(self, x: Array, reset: Array, carry):
         seg = segments(reset)
         new_carry = []
-        for i, kind in enumerate(self.cfg.pattern):
+        for i, (kind, heads) in enumerate(zip(self.cfg.pattern,
+                                              rotary_heads(self.cfg))):
             x, layer_carry = nn.remat(_Layer)(
-                kind, self.cfg, self.dtype, name=f"layer_{i}")(
+                kind, self.cfg, self.dtype, heads, name=f"layer_{i}")(
                     x, seg, carry[i])
             new_carry.append(layer_carry)
         scale = self.param("norm_f", nn.initializers.ones, (x.shape[-1],))
@@ -518,14 +806,16 @@ class HybridQNetwork(nn.Module):
 
     def initial_state(self, batch_size: int, history: Optional[int] = None):
         """The empty state of ``batch_size`` lanes, one entry a layer;
-        ``history``: steps of keys and values an attention layer keeps
-        (default ``attention_window``, what acting carries)."""
+        ``history``: steps of keys and values a ``*`` or ``F`` layer keeps
+        (default ``attention_window``, what acting carries); a ``W`` layer
+        keeps ``sliding_window``, whoever asks."""
         cfg = self.core
         if history is None:
             history = cfg.attention_window
         inner = cfg.mamba_num_heads * cfg.mamba_head_dim
         channels = inner + 2 * cfg.n_groups * cfg.ssm_state_size
         cache = (batch_size, history, cfg.num_key_value_heads, cfg.head_dim)
+        band = (batch_size, cfg.sliding_window) + cache[2:]
 
         def zeros(*shape):
             return jnp.zeros(shape, F32)
@@ -535,9 +825,41 @@ class HybridQNetwork(nn.Module):
                           zeros(batch_size, cfg.mamba_num_heads,
                                 cfg.mamba_head_dim, cfg.ssm_state_size)),
             "E": lambda: (),
+            "D": lambda: (),
             "*": lambda: (zeros(*cache), zeros(*cache),
                           zeros(batch_size, history)),
+            "F": lambda: (zeros(*cache), zeros(*cache), zeros(batch_size)),
+            "W": lambda: (zeros(*band), zeros(*band), zeros(batch_size)),
         }[kind]() for kind in cfg.pattern)
+
+    def state_bytes_a_lane(self) -> dict:
+        """Bytes of one lane's acting state by kind of cache (the scope a
+        kind's mixer enters): the sum over the pattern's sublayers of that
+        kind; kinds that keep nothing are left out."""
+        names = {"M": "ssm", "*": "attention", "F": "attention_full",
+                 "W": "attention_window"}
+        found: dict = {}
+        lane = jax.eval_shape(lambda: self.initial_state(1))
+        for kind, layer in zip(self.core.pattern, lane):
+            for leaf in jax.tree.leaves(layer):
+                found[names[kind]] = (found.get(names[kind], 0)
+                                      + leaf.size * leaf.dtype.itemsize)
+        return found
+
+    def reset_state(self, carry, done: Array):
+        """``carry`` with the lanes of ``done [B]`` emptied: every leaf
+        times ``1 - done``, but an ``F`` or ``W`` layer's ring, which its
+        counter alone makes valid and which is left where it lies (0.7 GB
+        of the ``laguna_q`` preset's 16 lanes, every acting step)."""
+        keep = (~done).astype(F32)
+
+        def emptied(x):
+            return x * jax.lax.expand_dims(keep, range(1, x.ndim))
+
+        return tuple(
+            layer[:2] + (emptied(layer[2]),) if kind in ROTARY
+            else jax.tree.map(emptied, layer)
+            for kind, layer in zip(self.core.pattern, carry))
 
     def stored_state(self, carry):
         """What the replay ring keeps of a lane's state with each step:
@@ -545,8 +867,8 @@ class HybridQNetwork(nn.Module):
         return ()
 
     def window_state(self, stored, batch_size: int, burn_in: int):
-        """The state a learner's window starts from: empty, its attention
-        history as long as the burn-in that fills it."""
+        """The state a learner's window starts from: empty, the history of
+        its ``*`` and ``F`` layers as long as the burn-in that fills it."""
         return self.initial_state(batch_size, history=burn_in)
 
     def __call__(self, carry, obs: Array, reset: Optional[Array] = None):

@@ -49,10 +49,13 @@ PASSES = ("burn_in", "online_unroll", "target_unroll")
 PARTS = ("torso", "core")
 #: Children of stage ``loss_grad``, read: the torso, and the scopes the
 #: hybrid sequence core's mixers enter (models/sequence_core.py) — the
-#: state-space layers, the attention layer, and an expert layer's three
+#: state-space layers, the attention layer (``attention``: no positions;
+#: ``attention_window`` / ``attention_full``: rotary, gated, over the last
+#: steps / the whole episode), the dense MLP, and an expert layer's three
 #: parts. Norms, residual adds, the heads and the loss stay under no child.
 CORE_PARTS = ("torso", "ssm", "attention", "moe_router", "moe_routed",
-              "moe_shared")
+              "moe_shared", "attention_window", "attention_full",
+              "mlp_dense")
 #: The stage whose instructions the child names split.
 PARENT = "loss_grad"
 #: A fusion whose instructions come from more than one stage (or child).

@@ -204,6 +204,13 @@ def train(cfg: ExperimentConfig, total_env_steps: int = 0, seed: int = None,
                "1: the fused loop carries the acting observation once, as "
                "32-bit words (one word = a pixel's four stacked frames)"
                ).set(int(obs_words))
+    # What one lane's acting state weighs, by kind of cache, where the
+    # network's layers keep several (models/sequence_core.py).
+    for cache, nbytes in getattr(net, "state_bytes_a_lane", dict)().items():
+        _reg.gauge("dqn_actor_state_bytes_a_lane",
+                   "bytes of one lane's acting state, by kind of cache "
+                   "(summed over the layers of that kind)",
+                   {"cache": cache}).set(nbytes)
     evaluate = jax.jit(make_evaluator(cfg, env, net,
                                       num_episodes=cfg.eval_episodes))
     # Chip-time attribution (ISSUE 19): the fused chunk is ONE program —

@@ -111,16 +111,30 @@ def test_the_parts_are_the_recurrent_networks_module_names():
                                                                   part)
 
 
-def test_the_hybrid_cores_parts_split_loss_grad():
-    """The chunk program over the hybrid sequence core at toy widths: every
-    name of ``CORE_PARTS`` is on the op paths of the online pass, forward
-    and backward (through the layers' rematerialisation), and the children
-    table puts instructions of stage ``loss_grad`` under each of them —
-    while ``PARTS`` still reads the whole stack as ``core``."""
-    from perf.tests.test_perf_run_twotower import TOY_CORE_CONFIG
+TWOTOWER_PARTS = ("torso", "ssm", "attention", "moe_router", "moe_routed",
+                  "moe_shared")
+LAGUNA_PARTS = ("torso", "attention_window", "attention_full", "mlp_dense",
+                "moe_router", "moe_routed", "moe_shared")
 
+
+@pytest.mark.parametrize("preset,toy,parts", [
+    ("twotower_q", "test_perf_run_twotower.TOY_CORE_CONFIG", TWOTOWER_PARTS),
+    ("laguna_q", "test_perf_laguna.TOY_LAGUNA_CONFIG", LAGUNA_PARTS)])
+def test_the_hybrid_cores_parts_split_loss_grad(preset, toy, parts):
+    """The chunk program over the hybrid sequence core at toy widths, once
+    with each preset's kinds of sublayer: every name of ``CORE_PARTS`` that
+    the pattern has is on the op paths of the online pass, forward and
+    backward (through the layers' rematerialisation), and the children
+    table puts instructions of stage ``loss_grad`` under each of them —
+    while ``PARTS`` still reads the whole stack as ``core``. Between them
+    the two patterns enter every name."""
+    import importlib
+
+    module, name = toy.split(".")
+    config = getattr(importlib.import_module(f"perf.tests.{module}"), name)
+    assert set(TWOTOWER_PARTS + LAGUNA_PARTS) == set(stages.CORE_PARTS)
     # the benchmark's toy hybrid cell, on cartpole's four numbers
-    cfg = apply_overrides(CONFIGS["twotower_q"], TOY_CORE_CONFIG[
+    cfg = apply_overrides(CONFIGS[preset], config[
         "overrides"] + ["env_name=cartpole", "network.torso=mlp",
                         "network.mlp_features=(16,)",
                         "replay.frame_dedup=false"])
@@ -128,12 +142,13 @@ def test_the_hybrid_cores_parts_split_loss_grad():
     paths = set(re.findall(r'op_name="([^"]*)"', text))
     for wrapper in ("jvp(online_unroll)", "transpose(jvp(online_unroll))",
                     "jvp(target_unroll)"):
-        for part in stages.CORE_PARTS:
+        for part in parts:
             assert any(f"/loss_grad/{wrapper}/" in p
                        and stages.child_of(p, stages.CORE_PARTS) == part
                        for p in paths), (wrapper, part)
     children = stages.children_from_text(text, stages.CORE_PARTS)
-    assert set(stages.CORE_PARTS) <= set(children.values())
+    assert set(parts) <= set(children.values()) <= set(parts) | {
+        None, stages.MIXED}
     whole = stages.children_from_text(text, stages.PARTS)
     assert {"torso", "core"} <= set(whole.values())
 
