@@ -46,11 +46,18 @@ layer's convolution; one letter of ``CoreConfig.pattern`` a layer, as
        part of the dims) BEFORE a key is cached, a sigmoid gate a head on
        the attended values, heads a layer (``attention_heads_per_layer``).
        ``F`` sees the whole episode, ``W`` its last ``sliding_window``
-       steps. Computed by blocks of ``sliding_window`` queries, each
-       block's scores made, used and — in the backward — made again on
-       their own: ``F`` against the keys up to its block (the causal
-       triangle by blocks), ``W`` against the block before and its own (a
-       band whose cost does not grow with the window's length).
+       steps. A learner's window (``T > 1``) is computed by blocks of
+       queries, ``F`` against the keys up to its block (the causal
+       triangle by blocks), ``W`` against the keys a window back and its
+       own (a band whose cost does not grow with the window's length), by
+       one of two paths that hold to one mask rule
+       (``_RotaryAttention.window_keys``): on a TPU the fused kernels of
+       ``ops/pallas_attention.py``, which keep a tile's scores in VMEM,
+       forward and backward; on every other backend ``blockwise``, plain
+       ``jax.numpy`` by blocks of ``sliding_window`` queries whose scores
+       go through HBM — the kernels' oracle. ``loop_common.pallas_routing``
+       chooses (no option does). The acting step (``T == 1``) reads its
+       ring with one small masked softmax on either.
 ``D``  A dense gated MLP, ``W_down (silu(W_gate u) * W_up u)``.
 ``E`` takes the expert's form from the configuration (``expert_act``:
 ``relu2`` above, or ``silu``, gated as ``D``) and its correction bias where
@@ -90,8 +97,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from dist_dqn_tpu import loop_common
 from dist_dqn_tpu.config import CoreConfig, RopeConfig
 from dist_dqn_tpu.models.recurrent import _Embed
+from dist_dqn_tpu.ops import pallas_attention
 
 Array = jnp.ndarray
 F32 = jnp.float32
@@ -622,9 +631,21 @@ class _RotaryAttention(nn.Module):
                        < jnp.minimum(position + 1, history))   # [B, S]
                 attended = attend(q, ring_k, ring_v, see[:, None])
             else:
-                attended = self.blockwise(
-                    jax.checkpoint(attend), q, new_k, new_v, position, seg,
-                    carry)
+                # a learner's window: the fused kernels on a TPU, the plain
+                # blocks anywhere else (``loop_common.pallas_routing``)
+                keys, values, key_position, key_seg = self.window_keys(
+                    new_k, new_v, position, seg, carry)
+                use_kernel, interpret = loop_common.pallas_routing(True)
+                if use_kernel:
+                    attended = pallas_attention.attend(
+                        q, keys, values, position, seg, key_position,
+                        key_seg, history=history,
+                        window=cfg.sliding_window if self.windowed else None,
+                        dtype=self.dtype, interpret=interpret)
+                else:
+                    attended = self.blockwise(
+                        jax.checkpoint(attend), q, keys, values, position,
+                        seg, key_position, key_seg)
                 ring_k, ring_v = self.ring_after(new_k, new_v, position,
                                                  opened[:, -1], carry)
             gated = attended.reshape(B, T, heads, D) * gate[..., None]
@@ -633,22 +654,40 @@ class _RotaryAttention(nn.Module):
             carry = (ring_k, ring_v, (position[:, -1] + 1).astype(F32))
         return out, carry
 
-    def blockwise(self, attend, q, new_k, new_v, position, seg, carry):
-        """The attended values ``[B, T, KV, G, D]`` of T steps over the
-        ring handed in and their own keys, a block of ``sliding_window``
-        queries at a time. A key is (position, segment, valid); query t
-        sees the valid keys of its segment at positions up to its own and,
-        in a ``W`` layer, less than ``sliding_window`` below it. The ring's
-        slots lie in segment 0 at the positions the counter gives them."""
+    def window_keys(self, new_k, new_v, position, seg, carry):
+        """The keys a learner's window attends over, ``[ring || this
+        call's]``: ``(keys, values [B, history + T, KV, D], position, segment
+        [B, history + T])``. The ring's slots lie in segment 0 at the
+        positions the counter gives them; a slot the episode has not reached
+        has the segment no query has (``pallas_attention.INVALID_KEY``)."""
         old_k, old_v, steps = carry
-        B, T = position.shape
-        history, window = old_k.shape[1], self.cfg.sliding_window
+        history = old_k.shape[1]
         steps = steps.astype(jnp.int32)[:, None]
         slots = jnp.arange(history)
         # slot j holds the last position below ``steps`` that is j mod
         # history, if the episode has come that far
         old_position = steps - 1 - (steps - 1 - slots) % max(history, 1)
-        old_valid = slots < jnp.minimum(steps, history)
+        old_seg = jnp.where(slots < jnp.minimum(steps, history), 0,
+                            pallas_attention.INVALID_KEY)
+        return (jnp.concatenate([old_k, new_k], axis=1),
+                jnp.concatenate([old_v, new_v], axis=1),
+                jnp.concatenate([old_position, position], axis=1),
+                jnp.concatenate([old_seg.astype(seg.dtype), seg], axis=1))
+
+    def blockwise(self, attend, q, keys, values, position, seg,
+                  key_position, key_seg):
+        """The attended values ``[B, T, KV, G, D]`` of T steps over
+        ``window_keys``, a block of ``sliding_window`` queries at a time. A
+        key is (position, segment); query t sees the keys of its segment at
+        positions up to its own and, in a ``W`` layer, less than
+        ``sliding_window`` below it.
+
+        Plain ``jax.numpy``: every block's scores go through HBM. It is the
+        path of every backend but a TPU, where ``ops/pallas_attention.py``
+        computes the same rule in VMEM, and the oracle that kernel is held
+        to (``tests/test_pallas_attention.py``)."""
+        B, T = position.shape
+        history, window = keys.shape[1] - T, self.cfg.sliding_window
         block = min(window, T)
         pad = -T % block
 
@@ -656,15 +695,9 @@ class _RotaryAttention(nn.Module):
             return jnp.pad(v, ((0, 0), (0, pad)) + ((0, 0),) * (v.ndim - 2),
                            constant_values=value) if pad else v
 
-        q, new_k, new_v, position, seg = (
-            padded(v) for v in (q, new_k, new_v, position, seg))
-        live = padded(jnp.ones((B, T), jnp.bool_), False)
-        keys = jnp.concatenate([old_k, new_k], axis=1)
-        values = jnp.concatenate([old_v, new_v], axis=1)
-        key_position = jnp.concatenate([old_position, position], axis=1)
-        key_seg = jnp.concatenate(
-            [jnp.zeros((B, history), seg.dtype), seg], axis=1)
-        key_valid = jnp.concatenate([old_valid, live], axis=1)
+        q, keys, values, position, seg, key_position = (
+            padded(v) for v in (q, keys, values, position, seg, key_position))
+        key_seg = padded(key_seg, pallas_attention.INVALID_KEY)
         out = []
         for lo in range(0, T + pad, block):
             # the keys a block can see at all: a ``W`` block the block
@@ -672,12 +705,10 @@ class _RotaryAttention(nn.Module):
             # block everything up to itself
             first = history + lo - block if self.windowed and lo else 0
             last = history + lo + block
-            at, its_seg = (position[:, lo:lo + block, None],
-                           seg[:, lo:lo + block, None])
-            below = at - key_position[:, None, first:last]
-            see = ((key_valid[:, None, first:last])
-                   & (key_seg[:, None, first:last] == its_seg)
-                   & (below >= 0))
+            below = (position[:, lo:lo + block, None]
+                     - key_position[:, None, first:last])
+            see = ((key_seg[:, None, first:last]
+                    == seg[:, lo:lo + block, None]) & (below >= 0))
             if self.windowed:
                 see = see & (below < window)
             out.append(attend(q[:, lo:lo + block], keys[:, first:last],
@@ -844,6 +875,38 @@ class HybridQNetwork(nn.Module):
             for leaf in jax.tree.leaves(layer):
                 found[names[kind]] = (found.get(names[kind], 0)
                                       + leaf.size * leaf.dtype.itemsize)
+        return found
+
+    def attention_key_blocks(self, windows: int, burn_in: int,
+                             steps: int) -> dict:
+        """``{"window" | "full": (visited, skipped)}``: the key blocks the
+        fused kernels' forward grids (``ops/pallas_attention.py``) read and
+        leave out in ONE forward pass of a learner's batch through the core
+        — the burn-in call from the empty state, then the call over the
+        other ``steps`` — summed over the layers of a kind, their KV heads
+        and the batch's ``windows``. A grad step runs those grids for both
+        networks and once more under ``nn.remat``. Empty where the learner
+        takes ``blockwise`` (no TPU), and for a core without such layers."""
+        cfg = self.core
+        found: dict = {}
+        if not loop_common.pallas_routing(True)[0]:
+            return found
+        for kind in cfg.pattern:
+            if kind not in ROTARY:
+                continue
+            windowed = kind == "W"
+            name = "window" if windowed else "full"
+            visited, skipped = found.get(name, (0, 0))
+            for T in (burn_in, steps):
+                if not T:
+                    continue
+                # the ring either call is handed: ``window_state``'s
+                read, left_out = pallas_attention.key_block_census(
+                    T, cfg.sliding_window if windowed else burn_in,
+                    cfg.sliding_window if windowed else None)
+                visited += windows * cfg.num_key_value_heads * read
+                skipped += windows * cfg.num_key_value_heads * left_out
+            found[name] = (visited, skipped)
         return found
 
     def reset_state(self, carry, done: Array):

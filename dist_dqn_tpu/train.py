@@ -211,6 +211,18 @@ def train(cfg: ExperimentConfig, total_env_steps: int = 0, seed: int = None,
                    "bytes of one lane's acting state, by kind of cache "
                    "(summed over the layers of that kind)",
                    {"cache": cache}).set(nbytes)
+    # What the learner's attention kernels read and leave out, from their
+    # static grids (ops/pallas_attention.py); nothing where none runs.
+    for kind, blocks in getattr(net, "attention_key_blocks",
+                                lambda *_: {})(
+            cfg.learner.batch_size, cfg.replay.burn_in,
+            cfg.replay.unroll_length + cfg.learner.n_step).items():
+        for state, count in zip(("visited", "skipped"), blocks):
+            _reg.gauge("dqn_learner_attention_key_blocks",
+                       "key blocks the fused attention kernels' forward "
+                       "grids read (visited) and leave out (skipped) in one "
+                       "forward pass of a learner's batch, by kind of layer",
+                       {"kind": kind, "state": state}).set(count)
     evaluate = jax.jit(make_evaluator(cfg, env, net,
                                       num_episodes=cfg.eval_episodes))
     # Chip-time attribution (ISSUE 19): the fused chunk is ONE program —

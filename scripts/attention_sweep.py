@@ -1,0 +1,220 @@
+"""Time ``ops/pallas_attention.py``'s two kernels on the chip at the
+``laguna_q`` preset's learner shapes, a tile shape at a time, beside the plain
+blocks they replace, and hold their results to the blocks' on the way:
+
+    chiprun -- python3 scripts/attention_sweep.py
+
+A ``W`` layer (64 heads over 8, window 512) and an ``F`` layer (48 over 8),
+4 windows; the unroll call (1,536 steps behind a ring of 512, forward and
+backward) and the burn-in call (512 steps from an empty ring, forward). One
+JSON line a reading, all of them again in
+``chiprun_out/attention_sweep.json``. ``TILES`` in the kernel's module was
+chosen from this table (``PERF.md`` §6, PR 47). Times only on a TPU: off one
+the script stops (``--smoke``: its own rehearsal, toy shapes interpreted).
+"""
+from __future__ import annotations
+
+import dataclasses
+import functools
+import json
+import os
+import sys
+import time
+from pathlib import Path
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+import jax  # noqa: E402
+import jax.numpy as jnp  # noqa: E402
+import numpy as np  # noqa: E402
+
+from dist_dqn_tpu.config import CONFIGS  # noqa: E402
+from dist_dqn_tpu.models import sequence_core  # noqa: E402
+from dist_dqn_tpu.ops import pallas_attention as pa  # noqa: E402
+
+SMOKE = "--smoke" in sys.argv     # the script's own rehearsal on a CPU
+B, KV, D, HISTORY, WINDOW = (1, 1, 16, 128, 128) if SMOKE else (
+    4, 8, 128, 512, 512)
+UNROLL, BURN_IN = (256, 128) if SMOKE else (1536, 512)
+TILES = ((128, 128),) if SMOKE else (
+    (128, 256), (128, 512), (256, 256), (256, 512), (512, 512), (256, 1024),
+    (512, 1024))
+REPEATS = 1 if SMOKE else 10
+
+
+def seconds(fn, *args):
+    jax.block_until_ready(fn(*args))
+    start = time.perf_counter()
+    for _ in range(REPEATS):
+        out = fn(*args)
+    jax.block_until_ready(out)
+    return (time.perf_counter() - start) / REPEATS
+
+
+def top_ops(fn, *args, top=30):
+    """``[[label, ms], ..]``: the device's time in one traced call of ``fn``
+    by opcode and shape (the benchmark's own reduction), largest first."""
+    import tempfile
+
+    from perf.reduce import trace_reduce, xplane
+
+    with tempfile.TemporaryDirectory() as where:
+        with jax.profiler.trace(where):
+            jax.block_until_ready(fn(*args))
+        planes = xplane.load_newest(Path(where))
+    totals = {}
+    for plane in planes:
+        if "TPU" in plane["name"]:
+            totals = trace_reduce.DeviceTrace(plane).op_totals()
+    ranked = sorted(totals.items(), key=lambda kv: -kv[1])
+    return [[label, round(seconds * 1e3, 4)] for label, seconds in
+            ranked[:top]] + [["all", round(sum(totals.values()) * 1e3, 4)]]
+
+
+def window_of(rng, T, G, steps):
+    """One call's inputs: a reset inside lane 0, lane 1's ring cut short by
+    a reset in its burn-in (``steps`` < history)."""
+    q = jnp.asarray(rng.normal(size=(B, T, KV, G, D)), jnp.float32)
+    new_k, new_v = (jnp.asarray(rng.normal(size=(B, T, KV, D)), jnp.float32)
+                    for _ in range(2))
+    ring = tuple(jnp.asarray(rng.normal(size=(B, HISTORY, KV, D)),
+                             jnp.float32) for _ in range(2))
+    reset = np.zeros((B, T), bool)
+    reset[0, T // 3] = True
+    seg = sequence_core.segments(jnp.asarray(reset))
+    index = jnp.arange(T)
+    opened = jax.lax.cummax(jnp.where(
+        jnp.diff(seg, axis=1, prepend=0) > 0, index, -1), axis=1)
+    steps = jnp.asarray(steps, jnp.float32)
+    position = jnp.where(seg == 0, steps.astype(jnp.int32)[:, None] + index,
+                         index - opened)
+    return q, new_k, new_v, position, seg, ring + (steps,)
+
+
+def main():
+    if jax.default_backend() != "tpu" and not SMOKE:
+        raise SystemExit("attention_sweep times kernels: it needs a TPU")
+    device = jax.devices()[0]
+    print(json.dumps({"device": {"platform": device.platform,
+                                 "kind": device.device_kind,
+                                 "count": jax.device_count()}}), flush=True)
+    core = dataclasses.replace(CONFIGS["laguna_q"].network.core,
+                               sliding_window=WINDOW, head_dim=D,
+                               num_key_value_heads=KV)
+    rng = np.random.default_rng(47)
+    rows = []
+
+    def say(**row):
+        rows.append(row)
+        print(json.dumps(row), flush=True)
+
+    for kind, G in (("W", 8), ("F", 6)):
+        windowed = kind == "W"
+        window = WINDOW if windowed else None
+        layer = sequence_core._RotaryAttention(
+            core, jnp.bfloat16, heads=G * KV, windowed=windowed)
+        # the unroll's rings: full, but every other lane's cut short by a
+        # reset in its burn-in; the burn-in's: empty
+        cut = tuple(HISTORY * 3 // 5 if lane % 2 else HISTORY
+                    for lane in range(B))
+        for call, T, steps in (("unroll", UNROLL, cut),
+                               ("burn_in", BURN_IN, (0,) * B)):
+            q, new_k, new_v, position, seg, carry = window_of(rng, T, G, steps)
+            keys, values, key_position, key_seg = jax.jit(layer.window_keys)(
+                new_k, new_v, position, seg, carry)
+            pull = jnp.asarray(rng.normal(size=q.shape), jnp.float32)
+
+            def plain(q, keys, values):
+                def attend(q, keys, values, see):
+                    scores = jnp.einsum(
+                        "bqkgd,bskd->bkgqs", q.astype(jnp.bfloat16),
+                        keys.astype(jnp.bfloat16),
+                        preferred_element_type=jnp.float32) * D ** -0.5
+                    scores = jnp.where(see[:, None, None], scores, -1e30)
+                    return jnp.einsum(
+                        "bkgqs,bskd->bqkgd",
+                        jax.nn.softmax(scores, axis=-1).astype(jnp.bfloat16),
+                        values.astype(jnp.bfloat16),
+                        preferred_element_type=jnp.float32)
+                return layer.blockwise(jax.checkpoint(attend), q, keys,
+                                       values, position, seg, key_position,
+                                       key_seg)
+
+            def fused(tiles):
+                return lambda q, keys, values: pa.attend(
+                    q, keys, values, position, seg, key_position, key_seg,
+                    history=HISTORY, window=window, dtype=jnp.bfloat16,
+                    tiles=tiles, interpret=SMOKE)
+
+            def with_grads(f):
+                return jax.jit(jax.value_and_grad(
+                    lambda *a: jnp.sum(f(*a) * pull), argnums=(0, 1, 2)))
+
+            # -- the kernels alone, a tile shape at a time -------------------
+            for direction in ("forward", "backward"):
+                if direction == "backward" and call == "burn_in":
+                    continue
+                for bq, bk in TILES:
+                    tiles = pa.fitted(pa.Tiles(bq, bk), T, HISTORY)
+                    spec = pa._Spec(T, HISTORY, window, tiles, SMOKE)
+                    Tp, Sp = -(-T // bq) * bq, -(-(HISTORY + T) // bk) * bk
+                    q16 = jnp.zeros((B, KV, G, Tp, D), jnp.bfloat16) + 0.1
+                    k16 = jnp.zeros((B, KV, Sp, D), jnp.bfloat16) + 0.1
+                    marks = pa._marks(position, seg, key_position, key_seg,
+                                      Tp, Sp)
+                    try:
+                        if direction == "forward":
+                            took = seconds(jax.jit(functools.partial(
+                                pa._forward, spec)), q16, k16, k16, marks)
+                        else:
+                            row = jnp.zeros((B, KV, G, Tp), jnp.float32)
+                            took = seconds(jax.jit(functools.partial(
+                                pa._backward, spec)), q16, k16, k16, marks,
+                                row, row, q16)
+                    except Exception as e:  # noqa: BLE001 - a tile Mosaic refuses
+                        say(kind=kind, call=call, kernel=direction, bq=bq,
+                            bk=bk, refused=str(e)[:300])
+                        continue
+                    visited, skipped = pa.key_block_census(
+                        T, HISTORY, window, tiles)
+                    say(kind=kind, call=call, kernel=direction, bq=bq, bk=bk,
+                        ms=took * 1e3, visited=visited, skipped=skipped)
+
+            # -- the whole path: casts, layout, kernels; against the blocks ---
+            got = jax.jit(fused(None))(q, keys, values)
+            want = jax.jit(plain)(q, keys, values)
+            say(kind=kind, call=call, what="forward", tiles=list(pa.TILES),
+                fused_ms=seconds(jax.jit(fused(None)), q, keys, values) * 1e3,
+                blocks_ms=seconds(jax.jit(plain), q, keys, values) * 1e3,
+                max_gap=float(jnp.max(jnp.abs(got - want))),
+                max_size=float(jnp.max(jnp.abs(want))))
+            if call == "unroll":
+                (_, got), (_, want) = (with_grads(f)(q, keys, values)
+                                       for f in (fused(None), plain))
+                say(kind=kind, call=call, what="forward+backward",
+                    fused_ms=seconds(with_grads(fused(None)), q, keys,
+                                     values) * 1e3,
+                    blocks_ms=seconds(with_grads(plain), q, keys,
+                                      values) * 1e3,
+                    grad_gaps=[float(jnp.max(jnp.abs(a - b)))
+                               for a, b in zip(got, want)],
+                    grad_sizes=[float(jnp.max(jnp.abs(b))) for b in want])
+        # -- one whole sublayer, forward and backward, op by op ---------------
+        hidden = 256 if SMOKE else CONFIGS["laguna_q"].network.hidden
+        u = jnp.asarray(rng.normal(size=(B, UNROLL, hidden)), jnp.float32)
+        seg = jnp.zeros((B, UNROLL), jnp.int32)
+        ring = (jnp.zeros((B, HISTORY, KV, D)),) * 2 + (
+            jnp.full((B,), float(HISTORY)),)
+        params = jax.jit(layer.init)(jax.random.PRNGKey(0), u, seg, ring)
+        step = jax.jit(jax.grad(
+            lambda params, u: jnp.sum(layer.apply(params, u, seg, ring)[0]
+                                      ** 2), argnums=(0, 1)))
+        say(kind=kind, what="sublayer forward+backward",
+            ms=seconds(step, params, u) * 1e3, ops=top_ops(step, params, u))
+    os.makedirs("chiprun_out", exist_ok=True)
+    with open("chiprun_out/attention_sweep.json", "w") as f:
+        json.dump(rows, f, indent=1)
+
+
+if __name__ == "__main__":
+    main()

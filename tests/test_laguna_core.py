@@ -236,19 +236,25 @@ def test_the_32_shares_of_an_expert_sublayer_add_up_to_the_uncut_layer():
     assert float(jnp.max(jnp.abs(uncut - shared))) > 1e-2
 
 
-@pytest.mark.parametrize("kind,heads", [("W", 6), ("F", 4)])
-def test_blockwise_attention_is_masked_attention(kind, heads):
+@pytest.mark.parametrize("kind,heads,before", [("W", 6, 3), ("F", 4, 3),
+                                               ("W", 6, 11)])
+def test_blockwise_attention_is_masked_attention(kind, heads, before):
     """``_RotaryAttention`` by blocks of 4 queries over a window of 19 steps
     (padded; a band of two blocks in ``W``, the causal triangle by blocks in
     ``F``) against the reference's one masked ``[T, S]`` softmax: from a ring
-    that 3 earlier steps have filled, with resets inside a block, at a
-    block's first step and at step 0, a window far longer than the sliding
-    window — outputs, and the gradient to the input and every parameter."""
+    that ``before`` earlier steps have filled — 3: a valid prefix, then
+    empty slots in front of the new keys; 11: the ``W`` ring (4 slots) has
+    wrapped, its slots out of the order of their positions (an ``F`` ring
+    that wraps has forgotten steps the reference still sees) — with
+    resets inside a block, at a block's first step and at step 0, a window
+    far longer than the sliding window — outputs, and the gradient to the
+    input and every parameter. ``blockwise`` is in turn what the TPU's
+    kernels are held to (``tests/test_pallas_attention.py``)."""
     cfg, _, _ = _setup()
     core = laguna_float32.hyper_from_config(cfg).core
     module = sequence_core._MIXERS[kind](cfg.network.core, jnp.float32,
                                          heads=heads)
-    B, T, before = 3, 19, 3
+    B, T = 3, 19
     keys = jax.random.split(jax.random.PRNGKey(3), 4)
     u = jax.random.normal(keys[0], (B, T, HIDDEN))
     earlier = jax.random.normal(keys[1], (B, before, HIDDEN))

@@ -1,0 +1,300 @@
+"""The learner's fused attention kernels (``ops/pallas_attention.py``),
+interpreted on the CPU at toy sizes, against the path they replace on a TPU:
+``_RotaryAttention.blockwise`` over a masked softmax — outputs and the
+gradients to queries, keys and values, for both kinds of layer, both group
+sizes of the ``laguna_q`` preset and every shape a ring and a window's resets
+can give the mask; that the comparison tells a band one step too wide; which
+route a learner takes where; and the static grid the start-up gauge reports.
+"""
+import dataclasses
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from dist_dqn_tpu.config import CONFIGS
+from dist_dqn_tpu.models import sequence_core
+from dist_dqn_tpu.ops import pallas_attention
+from tests.test_laguna_core import SEQS, _setup
+
+B, KV, D = 2, 2, 8
+HISTORY = WINDOW = 16
+# 8 queries x 16 keys a tile: the 40-step window below is five query blocks
+# over four key blocks, the ring in front of them one
+TILES = pallas_attention.Tiles(8, 16)
+
+#: case -> (steps T, the ring's step counter a lane, resets (lane, step)).
+#: A counter below ``HISTORY`` is a ring whose episode opened inside the
+#: burn-in: a valid prefix, then empty slots before the new keys. One above it
+#: is a ring acting has wrapped: slot order is not position order.
+CASES = {
+    "no_reset": (40, (HISTORY, HISTORY), ()),
+    "reset_in_the_call": (40, (HISTORY, HISTORY),
+                          ((0, 9), (0, 10), (0, 27), (1, 0), (1, 16))),
+    "reset_in_the_burn_in": (40, (5, 0), ()),
+    "wrapped_ring": (40, (2 * HISTORY + 3, 7 * HISTORY - 1), ((1, 30),)),
+    "ragged_window": (37, (HISTORY, 11), ((0, 20),)),
+    "shorter_than_the_window": (5, (HISTORY + 2, 3), ((1, 2),)),
+}
+
+
+def _layer(kind, G):
+    core = dataclasses.replace(CONFIGS["laguna_q"].network.core,
+                               sliding_window=WINDOW,
+                               num_key_value_heads=KV, head_dim=D)
+    return sequence_core._MIXERS[kind](core, jnp.float32, heads=G * KV)
+
+
+def _window(kind, G, case, seed=0):
+    """One call's queries, keys with their marks, and a cotangent."""
+    T, steps, resets = CASES[case]
+    keys = jax.random.split(jax.random.PRNGKey(seed), 6)
+    q = jax.random.normal(keys[0], (B, T, KV, G, D))
+    new_k, new_v = (jax.random.normal(k, (B, T, KV, D)) for k in keys[1:3])
+    ring = tuple(jax.random.normal(k, (B, HISTORY, KV, D))
+                 for k in keys[3:5])
+    reset = np.zeros((B, T), bool)
+    for lane, step in resets:
+        reset[lane, step] = True
+    seg = sequence_core.segments(jnp.asarray(reset))
+    index = jnp.arange(T)
+    opened = jax.lax.cummax(jnp.where(
+        jnp.diff(seg, axis=1, prepend=0) > 0, index, -1), axis=1)
+    steps = jnp.asarray(steps, jnp.float32)
+    position = jnp.where(seg == 0, steps.astype(jnp.int32)[:, None] + index,
+                         index - opened)
+    marks = _layer(kind, G).window_keys(new_k, new_v, position, seg,
+                                        ring + (steps,))
+    return q, marks, position, seg, jax.random.normal(keys[5], q.shape)
+
+
+def _masked_softmax(q, keys, values, see):
+    scores = jnp.einsum("bqkgd,bskd->bkgqs", q, keys) * D ** -0.5
+    scores = jnp.where(see[:, None, None], scores, -1e30)
+    return jnp.einsum("bkgqs,bskd->bqkgd", jax.nn.softmax(scores, axis=-1),
+                      values)
+
+
+def _both(kind, G, case, window=WINDOW):
+    """``((out, dq, dk, dv) of the kernels, the same of the blocks)``."""
+    q, (keys, values, key_position, key_seg), position, seg, pull = _window(
+        kind, G, case)
+    layer = _layer(kind, G)
+
+    def fused(q, keys, values):
+        return pallas_attention.attend(
+            q, keys, values, position, seg, key_position, key_seg,
+            history=HISTORY, window=window if kind == "W" else None,
+            dtype=jnp.float32, interpret=True, tiles=TILES)
+
+    def blocks(q, keys, values):
+        return layer.blockwise(_masked_softmax, q, keys, values, position,
+                               seg, key_position, key_seg)
+
+    def with_grads(f):
+        out, grads = jax.jit(jax.value_and_grad(
+            lambda *a: (lambda o: (jnp.sum(o * pull), o))(f(*a)),
+            argnums=(0, 1, 2), has_aux=True))(q, keys, values)
+        return (out[1],) + grads
+
+    return with_grads(fused), with_grads(blocks)
+
+
+def _assert_close(got, want):
+    for name, g, w in zip(("out", "dq", "dk", "dv"), got, want):
+        np.testing.assert_allclose(g, w, rtol=2e-4, atol=2e-5, err_msg=name)
+
+
+@pytest.mark.parametrize("case", CASES)
+@pytest.mark.parametrize("kind,G", [("W", 8), ("W", 6), ("F", 6), ("F", 8)])
+def test_the_kernels_are_blockwise_attention(kind, G, case):
+    """Output and the gradients to ``q``, ``k``, ``v``: the mask built in the
+    kernel from the four int32 vectors is ``blockwise``'s, whatever the ring
+    holds and wherever the episodes open; padding rows and keys add
+    nothing; the static key ranges leave out no key a query sees."""
+    _assert_close(*_both(kind, G, case))
+
+
+@pytest.mark.parametrize("case", ["no_reset", "wrapped_ring"])
+def test_a_band_one_step_too_wide_fails_the_comparison(case):
+    """The kernel told a window of 17 against the blocks' 16: every query
+    past its 16th step sees one key more, and the comparison above says so."""
+    with pytest.raises(AssertionError):
+        _assert_close(*_both("W", 8, case, window=WINDOW + 1))
+
+
+@pytest.mark.parametrize("steps,history,window,tiles", [
+    (1536, 512, 512, pallas_attention.TILES),
+    (1536, 512, None, pallas_attention.TILES),
+    (512, 512, 512, pallas_attention.TILES),
+    (40, 16, 16, TILES), (37, 16, None, TILES), (5, 16, 16, TILES),
+    (1536, 512, 512, pallas_attention.Tiles(128, 256))])
+def test_the_static_grid_visits_the_band_and_the_triangle(steps, history,
+                                                          window, tiles):
+    """``key_block_census``: visited + skipped is the rectangle; visited is
+    the key ranges the module states — ``[0, history + lo + bq)`` in an ``F``
+    layer and while ``lo < window``, ``[history + lo - window, history + lo +
+    bq)`` after — counted here key by key; ``query_ranges`` is the same set
+    of visits read by key block."""
+    tiles = pallas_attention.fitted(tiles, steps, history)
+    bq, bk = tiles.bq, tiles.bk
+    visited, skipped = pallas_attention.key_block_census(
+        steps, history, window, tiles)
+    key_blocks = -(-(history + steps) // bk)
+    assert visited + skipped == -(-steps // bq) * key_blocks
+    reads = np.zeros((-(-steps // bq), key_blocks), bool)
+    for i, lo in enumerate(range(0, steps, bq)):
+        start = history + lo - window if window and lo >= window else 0
+        for key in range(start, min(history + lo + bq, history + steps)):
+            reads[i, key // bk] = True
+    assert visited == reads.sum()
+    first, count = pallas_attention.query_ranges(steps, history, window, bq,
+                                                 bk)
+    for block, (f, c) in enumerate(zip(first, count)):
+        assert (np.flatnonzero(reads[:, block]) == np.arange(f, f + c)).all()
+    # a window layer's share does not grow with the window's length
+    if window and steps >= 3 * window:
+        assert visited <= -(-steps // bq) * (-(-(window + bq) // bk) + 1)
+
+
+def _learner_step(monkeypatch, interpret):
+    """One ``make_r2d2_learner`` step of the toy ``laguna_q`` network on a
+    seeded batch (resets in the burn-in and among the loss positions):
+    ``(lowered text, loss, priorities, params after)``."""
+    from perf.reference import laguna_float32
+
+    if interpret:
+        monkeypatch.setenv("DIST_DQN_PALLAS_INTERPRET", "1")
+    else:
+        monkeypatch.delenv("DIST_DQN_PALLAS_INTERPRET", raising=False)
+    cfg, env, net = _setup()
+    init, train_step, _ = laguna_float32.make_program(cfg, env, net)
+    batch = {k: jnp.asarray(v) for k, v in laguna_float32.seeded_batch(
+        7, 0, SEQS, cfg, env).items() if k != "start_state"}
+    batch["start_state"] = ()
+    state = jax.jit(init)(jax.random.PRNGKey(7))
+    step = jax.jit(train_step)
+    text = step.lower(state, batch).as_text()
+    new, metrics = step(state, batch)
+    return text, metrics["loss"], metrics["priorities"], new.params
+
+
+def test_the_learner_takes_the_kernels_only_where_it_is_told(monkeypatch):
+    """Off a TPU the learner's program holds no kernel (``blockwise`` runs);
+    with ``DIST_DQN_PALLAS_INTERPRET=1`` — ``loop_common.pallas_routing``'s
+    switch for toy tests, the route a TPU takes — the same step goes through
+    the interpreted kernels, forward and backward, and lands where the
+    blocks land within float32 noise."""
+    text, loss, priorities, params = _learner_step(monkeypatch, False)
+    assert "custom_call" not in text and "pallas" not in text.lower()
+    assert pallas_attention.FORWARD_NAME not in text
+    fused_text, fused_loss, fused_priorities, fused_params = _learner_step(
+        monkeypatch, True)
+    assert fused_text != text
+    np.testing.assert_allclose(fused_loss, loss, rtol=1e-5)
+    np.testing.assert_allclose(fused_priorities, priorities, rtol=1e-4,
+                               atol=1e-6)
+    for got, want in zip(jax.tree.leaves(fused_params),
+                         jax.tree.leaves(params)):
+        np.testing.assert_allclose(got, want, rtol=1e-4, atol=2e-6)
+
+
+def test_the_start_up_gauge_is_the_kernels_static_grid(monkeypatch):
+    """``HybridQNetwork.attention_key_blocks`` — what ``train.train`` sets
+    ``dqn_learner_attention_key_blocks`` from — for the ``laguna_q`` preset's
+    batch of 4 windows (512 burn-in + 1,536): each kind's visited + skipped
+    is its layers' rectangles, the window layers (a band) skip a larger
+    share of theirs than the full layers (a triangle), and off a TPU, where
+    no kernel runs, there is nothing to report."""
+    from dist_dqn_tpu import loop_common
+    from dist_dqn_tpu.models import build_network
+
+    cfg = CONFIGS["laguna_q"]
+    net = build_network(cfg.network, 6)
+    shape = (cfg.learner.batch_size, cfg.replay.burn_in,
+             cfg.replay.unroll_length + cfg.learner.n_step)
+    assert shape == (4, 512, 1536)
+    assert net.attention_key_blocks(*shape) == {}
+    monkeypatch.setattr(loop_common, "pallas_routing",
+                        lambda enabled: (enabled, False))
+    found = net.attention_key_blocks(*shape)
+    assert set(found) == {"window", "full"}
+    tiles = pallas_attention.TILES
+    for name, windowed, layers in (("window", True, 3), ("full", False, 2)):
+        rectangle = sum(-(-T // tiles.bq) * -(-(512 + T) // tiles.bk)
+                        for T in (512, 1536))
+        visited, skipped = found[name]
+        assert visited + skipped == layers * 4 * 8 * rectangle
+        each = [pallas_attention.key_block_census(
+            T, 512, 512 if windowed else None) for T in (512, 1536)]
+        assert visited == layers * 4 * 8 * sum(v for v, _ in each)
+    window, full = (found[k][1] / sum(found[k]) for k in ("window", "full"))
+    assert window > full > 0.2
+
+
+# -- under the TPU's compiler -------------------------------------------------
+@pytest.fixture(scope="module")
+def v5e():
+    """One described (not attached) v5e chip: the TPU compiler is installed
+    here and compiles for it (``tests/test_ring_boundary.py``'s fixture).
+    What Mosaic accepts and the program's sizes, never a time."""
+    import os
+
+    from jax.experimental import topologies
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - whatever keeps libtpu away
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return jax.sharding.SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.mark.parametrize("kind,G,steps,backward", [
+    ("W", 8, 1536, True), ("F", 6, 1536, True), ("W", 8, 512, False)])
+def test_the_kernels_compile_for_v5e_at_the_presets_shapes(v5e, kind, G,
+                                                           steps, backward):
+    """The ``laguna_q`` preset's calls (4 windows, 8 KV heads of 128, a ring
+    of 512 in front) through Mosaic at ``TILES``: what the interpreter cannot
+    refuse — a block off the (8, 128) tiling, a transpose or a reshape
+    Mosaic has no rule for, more VMEM than a kernel may have (the backward
+    keeps a KV head's whole ``dq``, 6.3 MB, twice). The compiled program
+    holds the kernels by name and no score-shaped array."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    lanes, kv, d, history = 4, 8, 128, 512
+
+    def attended(q, keys, values, *marks):
+        return pallas_attention.attend(
+            q, keys, values, *marks, history=history,
+            window=512 if kind == "W" else None, dtype=jnp.bfloat16)
+
+    def loss_grads(q, keys, values, *marks):
+        return jax.grad(lambda *a: jnp.sum(attended(*a, *marks) ** 2),
+                        argnums=(0, 1, 2))(q, keys, values)
+
+    def shape(*dims, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=v5e)
+
+    S = history + steps
+    args = (shape(lanes, steps, kv, G, d), shape(lanes, S, kv, d),
+            shape(lanes, S, kv, d), shape(lanes, steps, dtype=jnp.int32),
+            shape(lanes, steps, dtype=jnp.int32),
+            shape(lanes, S, dtype=jnp.int32), shape(lanes, S, dtype=jnp.int32))
+    # a compile for a described chip is written to the persistent cache but
+    # cannot be read back without one: keep it out
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        text = jax.jit(loss_grads if backward else attended).lower(
+            *args).compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        compilation_cache.reset_cache()
+    assert pallas_attention.FORWARD_NAME in text
+    assert (pallas_attention.BACKWARD_NAME in text) == backward
+    # no [.., queries, keys] array in HBM: the largest thing is q's size
+    assert not any(f",{steps},{keys}]" in text or f",{keys},{steps}]" in text
+                   for keys in (S, 1024, 2048))
