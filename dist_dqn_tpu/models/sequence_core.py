@@ -53,7 +53,12 @@ layer's convolution; one letter of ``CoreConfig.pattern`` a layer, as
        one of two paths that hold to one mask rule
        (``_RotaryAttention.window_keys``): on a TPU the fused kernels of
        ``ops/pallas_attention.py``, which keep a tile's scores in VMEM,
-       forward and backward; on every other backend ``blockwise``, plain
+       forward and backward, and take the queries from ``q_proj`` as they
+       lie: a rotary kernel in front rotates them by lane rotations, scales,
+       casts and lays them out in one pass over whole tiles (``rotate``
+       writes its halves as arrays half a tile of lanes wide or less; it
+       stays the keys' path, acting's, every other backend's, and that
+       kernel's oracle); on every other backend ``blockwise``, plain
        ``jax.numpy`` by blocks of ``sliding_window`` queries whose scores
        go through HBM — the kernels' oracle. ``loop_common.pallas_routing``
        chooses (no option does). The acting step (``T == 1``) reads its
@@ -615,8 +620,11 @@ class _RotaryAttention(nn.Module):
                                  steps.astype(jnp.int32)[:, None] + index,
                                  index - opened)            # [B, T]
             tables = rotary_tables(position, rope, D)
-            q = rotate(project(w_q, heads), tables)
-            q = q.reshape(B, T, kv, heads // kv, D)
+            q = project(w_q, heads)                 # not rotated yet
+
+            def grouped(q):
+                return q.reshape(B, T, kv, heads // kv, D)
+
             new_k = rotate(project(w_k, kv), tables)
             new_v = project(w_v, kv)
             gate = jax.nn.sigmoid(jnp.dot(u16, w_g.astype(self.dtype),
@@ -629,23 +637,26 @@ class _RotaryAttention(nn.Module):
                 ring_v = old_v.at[lanes, slot].set(new_v[:, 0])
                 see = (jnp.arange(history)
                        < jnp.minimum(position + 1, history))   # [B, S]
-                attended = attend(q, ring_k, ring_v, see[:, None])
+                attended = attend(grouped(rotate(q, tables)), ring_k, ring_v,
+                                  see[:, None])
             else:
-                # a learner's window: the fused kernels on a TPU, the plain
-                # blocks anywhere else (``loop_common.pallas_routing``)
+                # a learner's window: the fused kernels on a TPU — the
+                # queries rotated on their way into the kernels' layout —
+                # the plain blocks anywhere else
+                # (``loop_common.pallas_routing``)
                 keys, values, key_position, key_seg = self.window_keys(
                     new_k, new_v, position, seg, carry)
                 use_kernel, interpret = loop_common.pallas_routing(True)
                 if use_kernel:
                     attended = pallas_attention.attend(
-                        q, keys, values, position, seg, key_position,
-                        key_seg, history=history,
+                        grouped(q), keys, values, position, seg,
+                        key_position, key_seg, history=history,
                         window=cfg.sliding_window if self.windowed else None,
-                        dtype=self.dtype, interpret=interpret)
+                        dtype=self.dtype, interpret=interpret, rotary=tables)
                 else:
                     attended = self.blockwise(
-                        jax.checkpoint(attend), q, keys, values, position,
-                        seg, key_position, key_seg)
+                        jax.checkpoint(attend), grouped(rotate(q, tables)),
+                        keys, values, position, seg, key_position, key_seg)
                 ring_k, ring_v = self.ring_after(new_k, new_v, position,
                                                  opened[:, -1], carry)
             gated = attended.reshape(B, T, heads, D) * gate[..., None]
@@ -907,6 +918,24 @@ class HybridQNetwork(nn.Module):
                 visited += windows * cfg.num_key_value_heads * read
                 skipped += windows * cfg.num_key_value_heads * left_out
             found[name] = (visited, skipped)
+        return found
+
+    def rotary_head_rows(self, windows: int, burn_in: int,
+                         steps: int) -> dict:
+        """``{"window" | "full": rows}``: the query-head rows (windows x
+        steps x query heads, the burn-in call and the call over the other
+        ``steps``, summed over the layers of a kind) that ONE forward pass
+        of a learner's batch sends through the rotary kernel
+        (``pallas_attention.attend``'s ``rotary``). Empty where the learner
+        takes ``rotate`` (no TPU), and for a core without such layers."""
+        found: dict = {}
+        if not loop_common.pallas_routing(True)[0]:
+            return found
+        for kind, heads in zip(self.core.pattern, rotary_heads(self.core)):
+            if kind in ROTARY:
+                name = "window" if kind == "W" else "full"
+                found[name] = (found.get(name, 0)
+                               + windows * (burn_in + steps) * heads)
         return found
 
     def reset_state(self, carry, done: Array):

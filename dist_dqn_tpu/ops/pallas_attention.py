@@ -1,6 +1,7 @@
 """Pallas TPU kernels: the learner's rotary attention over a long window
 (``models/sequence_core.py _RotaryAttention``, layers ``F`` and ``W``) with
-its scores kept in VMEM, forward and backward.
+its scores kept in VMEM, forward and backward, and its queries rotated on
+their way into the kernels' layout.
 
 The plain path (``_RotaryAttention.blockwise``) makes every block's scores
 in HBM: written in float32, read back for the mask and the softmax, written
@@ -76,10 +77,31 @@ only as the operand of the values product — ``blockwise``'s precision. The
 ``D ** -0.5`` is folded into ``q`` in float32 before its cast.
 
 **Tiles**: one shape for both kinds and both passes; see ``TILES``.
+
+**The queries' road in and out** (``rotary_embed_forward`` /
+``rotary_embed_backward``; ``attend``'s ``rotary``). ``sequence_core.rotate``
+writes its rotated halves as two arrays whose minor dimension is half the
+rotary dims — 64 of a tile's 128 lanes in a ``W`` layer, 32 in an ``F``
+layer, whose other 64 dims become a third array — and this compiler does so
+for every form that slices the minor dimension there, whatever the algebra
+around it: 41 ms of the ``laguna_q`` preset's grad step at a quarter of the
+HBM's rate, with further whole-array passes to join, scale, cast and
+transpose them and to bring ``dq`` back (``PERF.md`` §5, PR 47). The two
+kernels here are that road in ONE pass each way over whole tiles: a block is
+the ``G`` heads of one KV head, which lie side by side along the lanes of
+``q_proj``'s step-major output, so a head is a tile-aligned slice and the
+kernels' ``[B, KV, G, T, D]`` is written (or read) directly; the halves meet
+in VMEM by a lane rotation (``pltpu.roll``) against full-width tables
+(``wide_tables``). Float32 throughout, the one cast where ``kv_major`` casts
+and the backward's rounding where ``_attend_bwd`` rounds; equal to ``rotate``
++ ``kv_major`` to the last bit (``tests/test_pallas_rotary.py``). ``rotate``
+stays: the keys' path (an eighth of the bytes, cached rotated in float32),
+acting's, every other backend's, and these kernels' oracle.
 """
 from __future__ import annotations
 
 import functools
+import math
 from typing import NamedTuple, Optional, Tuple
 
 import jax
@@ -93,6 +115,8 @@ F32 = jnp.float32
 
 FORWARD_NAME = "rotary_attention_forward"    # findable in HLO text, traces
 BACKWARD_NAME = "rotary_attention_backward"
+EMBED_FORWARD_NAME = "rotary_embed_forward"
+EMBED_BACKWARD_NAME = "rotary_embed_backward"
 NEG = -1e30         # a masked score; finite, so that NEG - NEG is 0, not NaN
 INVALID_KEY = -1    # the segment of a key no query may see
 PADDING_QUERY = -2  # the segment of a padding query: it sees no key at all
@@ -387,6 +411,128 @@ def _dq_bytes(G: int, Tp: int, D: int) -> int:
     return 4 * G * Tp * D
 
 
+# -- the queries' road to the kernels: the rotary embedding on the lanes ------
+#: Steps a block, and rows a pass of the loop inside one (``scripts/
+#: attention_sweep.py``; ``PERF.md`` §6 PR 48).
+EMBED_BLOCK = 512
+EMBED_ROWS = 64
+
+
+def wide_tables(tables, D: int) -> Tuple[Array, Tuple[int, ...]]:
+    """``sequence_core.rotary_tables``' ``(cos, sin) [B, T, 1, h]`` as the
+    rotary kernels read them, ``([B, 1 + rolls, T, D] float32, shifts)``:
+    plane 0 is what a head's ``[T, D]`` tile is multiplied by where it lies
+    (``[cos, cos]``, and 1 on the dims past the rotary ones), plane ``1 + k``
+    what the tile rolled by ``shifts[k]`` lanes is multiplied by. Rotary over
+    all of ``D`` (``2 h == D``) is one roll by ``h`` against ``[-sin, sin]``;
+    over fewer dims each half comes from a roll of its own (by ``D - h`` the
+    second half comes under the first, by ``h`` the first under the second)
+    and a plane is zero outside the half it serves, so the dims that pass
+    are never an array of their own."""
+    cos, sin = (t[:, :, 0].astype(F32) for t in tables)        # [B, T, h]
+    h = cos.shape[-1]
+    rest = D - 2 * h
+
+    def plane(first, second, passing):
+        return jnp.concatenate(
+            [first, second, jnp.full(cos.shape[:2] + (rest,), passing, F32)],
+            axis=-1)
+
+    if not rest:
+        planes, shifts = [plane(-sin, sin, 0.0)], (h,)
+    else:
+        zero = jnp.zeros_like(sin)
+        planes = [plane(-sin, zero, 0.0), plane(zero, sin, 0.0)]
+        shifts = (D - h, h)
+    return jnp.stack([plane(cos, cos, 1.0)] + planes, axis=1), shifts
+
+
+def negative_angle(wide: Array) -> Array:
+    """``wide_tables`` of the negative angle: the rolled planes negated. A
+    rotation's transpose is the rotation back."""
+    return wide * jnp.asarray((1.0,) + (-1.0,) * (wide.shape[1] - 1),
+                              F32)[:, None, None]
+
+
+def _rotated(x, row, table_ref, shifts):
+    """A head's rows ``x [rows, D]`` float32, rotated: ``x * c + sum_k
+    roll(x, shifts[k]) * s_k`` — ``sequence_core.rotate`` with its halves
+    met by a lane rotation (and summed in its order, to the last bit)."""
+    turned = functools.reduce(jnp.add, (
+        pltpu.roll(x, shift, 1) * table_ref[1 + k, row, :]
+        for k, shift in enumerate(shifts)))
+    return x * table_ref[0, row, :] + turned
+
+
+def _embed_kernel(from_ref, table_ref, to_ref, *, forward, shifts, rows,
+                  dtype):
+    """There (``forward``): step-major float32 rows, rotated, scaled, cast,
+    into the KV-head-major block. Back: that block's float32 cotangent,
+    rounded to the queries' type where ``_attend_bwd`` rounds it, scaled,
+    rotated by what the tables hold, into the step-major rows. ``rows`` steps
+    a pass of the loop, all ``G`` heads of the block in each."""
+    G, steps, D = (to_ref if forward else from_ref).shape
+
+    def some_rows(i, carry):
+        row = pl.ds(pl.multiple_of(i * rows, rows), rows)
+        for g in range(G):
+            head = slice(g * D, (g + 1) * D)
+            if forward:
+                q = _rotated(from_ref[row, head], row, table_ref, shifts)
+                to_ref[g, row, :] = (q * D ** -0.5).astype(dtype)
+            else:
+                dq = from_ref[g, row, :].astype(dtype).astype(F32) * D ** -0.5
+                to_ref[row, head] = _rotated(dq, row, table_ref, shifts)
+        return carry
+
+    jax.lax.fori_loop(0, steps // rows, some_rows, None)
+
+
+def _embed(x, tables, shifts: Tuple[int, ...], dtype, *,
+           kv: Optional[int] = None, interpret: bool = False):
+    """One pass between the projection's step-major ``[B, Tp, KV * G * D]``
+    float32 and the attention kernels' ``[B, KV, G, Tp, D]``, by
+    ``wide_tables``' ``tables`` and ``shifts``. There (``x`` step-major,
+    ``kv`` its KV heads): ``kv_major(rotate(x) * D ** -0.5)`` in ``dtype``,
+    rotation and scale in float32. Back (``x`` the float32 cotangent of
+    that): rounded to ``dtype``, scaled, rotated by what ``tables`` hold —
+    the caller hands in the negative angle's. A block is
+    the ``G`` heads of one KV head over a block of steps: on the step-major
+    side ``G`` whole tiles of ``D`` lanes side by side, so a head is a slice
+    along the lanes and nothing is transposed. The KV head is the grid's
+    innermost axis: the tables' block stays where it is while it turns."""
+    B, planes, Tp, D = tables.shape
+    forward = kv is not None
+    KV, G = (kv, x.shape[2] // D // kv) if forward else x.shape[1:3]
+    block = math.gcd(Tp, EMBED_BLOCK)
+    step_major = pl.BlockSpec((None, block, G * D), lambda b, t, h: (b, t, h))
+    kv_major = pl.BlockSpec((None, None, G, block, D),
+                            lambda b, t, h: (b, h, 0, t, 0))
+    return pl.pallas_call(
+        functools.partial(_embed_kernel, forward=forward, shifts=shifts,
+                          rows=math.gcd(block, EMBED_ROWS), dtype=dtype),
+        grid=(B, Tp // block, KV),
+        in_specs=[step_major if forward else kv_major,
+                  pl.BlockSpec((None, planes, block, D),
+                               lambda b, t, h: (b, 0, t, 0))],
+        out_specs=kv_major if forward else step_major,
+        out_shape=(jax.ShapeDtypeStruct((B, KV, G, Tp, D), dtype) if forward
+                   else jax.ShapeDtypeStruct((B, Tp, KV * G * D), F32)),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        name=EMBED_FORWARD_NAME if forward else EMBED_BACKWARD_NAME,
+        interpret=interpret,
+    )(x, tables)
+
+
+# -- the two routes into the kernels ------------------------------------------
+def _gradients(spec, kept, d_out):
+    """``(dq, dk, dv)`` float32 of what ``_forward`` kept."""
+    q, k, v, marks, out, lse = kept
+    di = jnp.sum(out * d_out, axis=-1)
+    return _backward(spec, q, k, v, marks, lse, di, d_out.astype(q.dtype))
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
 def _attend(spec: _Spec, q, k, v, marks):
     return _forward(spec, q, k, v, marks)[0]
@@ -398,14 +544,41 @@ def _attend_fwd(spec, q, k, v, marks):
 
 
 def _attend_bwd(spec, kept, d_out):
-    q, k, v, marks, out, lse = kept
-    di = jnp.sum(out * d_out, axis=-1)
-    dq, dk, dv = _backward(spec, q, k, v, marks, lse, di,
-                           d_out.astype(q.dtype))
+    q, k, v = kept[:3]
+    dq, dk, dv = _gradients(spec, kept, d_out)
     return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype), None
 
 
 _attend.defvjp(_attend_fwd, _attend_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def _attend_rotating(spec: _Spec, shifts, x, tables, k, v, marks):
+    """``_attend`` of ``x``, the projection's output before its rotary
+    embedding, by ``wide_tables``' ``tables`` and ``shifts``: ``_embed`` in
+    front of the forward kernel, and behind the backward's ``dq``."""
+    return _attend_rotating_fwd(spec, shifts, x, tables, k, v, marks)[0]
+
+
+def _attend_rotating_fwd(spec, shifts, x, tables, k, v, marks):
+    q = _embed(x, tables, shifts, k.dtype, kv=k.shape[1],
+               interpret=spec.interpret)
+    out, lse = _forward(spec, q, k, v, marks)
+    return out, (tables, q, k, v, marks, out, lse)
+
+
+def _attend_rotating_bwd(spec, shifts, kept, d_out):
+    tables, q, k, v = kept[:4]
+    dq, dk, dv = _gradients(spec, kept[1:], d_out)
+    # the rotation's transpose needs no ``x``; the tables come from integer
+    # positions, nothing flows into them
+    dx = _embed(dq, negative_angle(tables), shifts, q.dtype,
+                interpret=spec.interpret)
+    return (dx, jnp.zeros_like(tables), dk.astype(k.dtype),
+            dv.astype(v.dtype), None)
+
+
+_attend_rotating.defvjp(_attend_rotating_fwd, _attend_rotating_bwd)
 
 
 def _marks(q_position, q_seg, k_position, k_seg, Tp: int, Sp: int):
@@ -426,15 +599,19 @@ def _marks(q_position, q_seg, k_position, k_seg, Tp: int, Sp: int):
 def attend(q: Array, keys: Array, values: Array, q_position: Array,
            q_seg: Array, k_position: Array, k_seg: Array, *, history: int,
            window: Optional[int], dtype, interpret: bool = False,
-           tiles: Optional[Tiles] = None) -> Array:
+           tiles: Optional[Tiles] = None, rotary=None) -> Array:
     """The attended values ``[B, T, KV, G, D]`` float32 of ``q [B, T, KV, G,
     D]`` over ``keys, values [B, S, KV, D]`` (the ring's ``history`` slots,
     then this call's T steps) under the module's mask rule: ``q_position,
     q_seg [B, T]``, ``k_position, k_seg [B, S]`` (``INVALID_KEY`` where a
     key may be seen by nobody); ``window`` None in an ``F`` layer. Products
     take ``dtype`` operands. Differentiable in ``q``, ``keys``, ``values``.
-    ``tiles`` is for tests; ``interpret`` runs the kernels in the Pallas
-    interpreter (CPU tests at toy sizes; never on a TPU backend)."""
+    With ``rotary`` — ``sequence_core.rotary_tables`` of the call's steps —
+    ``q`` is the projection's output BEFORE its rotary embedding, float32,
+    and is rotated on its way into the kernels' layout, as its gradient is
+    on the way back (``_embed``). ``tiles`` is for tests; ``interpret`` runs
+    the kernels in the Pallas interpreter (CPU tests at toy sizes; never on
+    a TPU backend)."""
     if interpret and jax.default_backend() == "tpu":
         raise ValueError(
             "the attention kernels are never interpreted on a TPU backend")
@@ -455,8 +632,15 @@ def attend(q: Array, keys: Array, values: Array, q_position: Array,
         return jnp.pad(x, ((0, 0),) * (x.ndim - 2)
                        + ((0, length - x.shape[-2]), (0, 0)))
 
+    keys, values = kv_major(keys, Sp), kv_major(values, Sp)
+    marks = _marks(q_position, q_seg, k_position, k_seg, Tp, Sp)
     spec = _Spec(T, history, window, tiles, interpret)
-    out = _attend(spec, kv_major(q * D ** -0.5, Tp), kv_major(keys, Sp),
-                  kv_major(values, Sp),
-                  _marks(q_position, q_seg, k_position, k_seg, Tp, Sp))
+    if rotary is None:
+        out = _attend(spec, kv_major(q * D ** -0.5, Tp), keys, values, marks)
+    else:
+        wide, shifts = wide_tables(rotary, D)
+        behind = ((0, 0), (0, Tp - T), (0, 0))      # zeros rotate to zeros
+        out = _attend_rotating(
+            spec, shifts, jnp.pad(q.reshape(B, T, KV * G * D), behind),
+            jnp.pad(wide, ((0, 0),) + behind), keys, values, marks)
     return jnp.moveaxis(out[..., :T, :], -2, 1)

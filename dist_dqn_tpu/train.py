@@ -223,6 +223,14 @@ def train(cfg: ExperimentConfig, total_env_steps: int = 0, seed: int = None,
                        "grids read (visited) and leave out (skipped) in one "
                        "forward pass of a learner's batch, by kind of layer",
                        {"kind": kind, "state": state}).set(count)
+    for kind, rows in getattr(net, "rotary_head_rows", lambda *_: {})(
+            cfg.learner.batch_size, cfg.replay.burn_in,
+            cfg.replay.unroll_length + cfg.learner.n_step).items():
+        _reg.gauge("dqn_learner_rotary_head_rows",
+                   "query-head rows (windows x steps x heads) one forward "
+                   "pass of a learner's batch sends through the rotary "
+                   "kernel in front of the attention kernels, by kind of "
+                   "layer", {"kind": kind}).set(rows)
     evaluate = jax.jit(make_evaluator(cfg, env, net,
                                       num_episodes=cfg.eval_episodes))
     # Chip-time attribution (ISSUE 19): the fused chunk is ONE program —

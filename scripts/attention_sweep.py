@@ -11,6 +11,14 @@ JSON line a reading, all of them again in
 ``chiprun_out/attention_sweep.json``. ``TILES`` in the kernel's module was
 chosen from this table (``PERF.md`` §6, PR 47). Times only on a TPU: off one
 the script stops (``--smoke``: its own rehearsal, toy shapes interpreted).
+
+Four parts, all of them unless some are named after the script: ``tiles`` (the
+attention kernels alone, a tile shape at a time), ``path`` (the whole path
+against the blocks), ``rotary`` (the queries' rotary pass alone — the kernel
+by steps a block and rows a loop pass, beside ``rotate`` + ``kv_major``: ms a
+call, bytes moved, share of the HBM's rate; ``EMBED_BLOCK`` / ``EMBED_ROWS``
+were chosen from it, ``PERF.md`` §6 PR 48) and ``sublayer`` (one sublayer's
+grad pass op by op).
 """
 from __future__ import annotations
 
@@ -33,12 +41,17 @@ from dist_dqn_tpu.models import sequence_core  # noqa: E402
 from dist_dqn_tpu.ops import pallas_attention as pa  # noqa: E402
 
 SMOKE = "--smoke" in sys.argv     # the script's own rehearsal on a CPU
+PARTS = [a for a in sys.argv[1:] if not a.startswith("--")] or [
+    "tiles", "path", "rotary", "sublayer"]
 B, KV, D, HISTORY, WINDOW = (1, 1, 16, 128, 128) if SMOKE else (
     4, 8, 128, 512, 512)
 UNROLL, BURN_IN = (256, 128) if SMOKE else (1536, 512)
 TILES = ((128, 128),) if SMOKE else (
     (128, 256), (128, 512), (256, 256), (256, 512), (512, 512), (256, 1024),
     (512, 1024))
+EMBED_SHAPES = ((128, 128),) if SMOKE else (     # steps a block, rows a pass
+    (512, 32), (512, 8), (512, 16), (512, 64), (512, 512), (256, 32),
+    (128, 32))
 REPEATS = 1 if SMOKE else 10
 
 
@@ -91,6 +104,75 @@ def window_of(rng, T, G, steps):
     return q, new_k, new_v, position, seg, ring + (steps,)
 
 
+def rotary_rows(say, rng, kind, G, rope, T, call):
+    """The queries' rotary pass alone, there and back, a block shape at a
+    time, beside the plain functions it replaces; results compared."""
+    from perf.reduce import peaks
+
+    # the rehearsal's CPU has no peak: its share is of a v5e's, and no time
+    hbm_rate = peaks.peak("TPU v5e" if SMOKE else
+                          jax.devices()[0].device_kind, "hbm_bytes_per_s")
+    heads = KV * G
+    x = jnp.asarray(rng.normal(size=(B, T, heads * D)), jnp.float32)
+    pull = jnp.asarray(rng.normal(size=(B, KV, G, T, D)), jnp.float32)
+    position = jnp.asarray(rng.integers(0, 4096, size=(B, T)), jnp.int32)
+    tables = sequence_core.rotary_tables(position, rope, D)
+    wide, shifts = pa.wide_tables(tables, D)
+    back = pa.negative_angle(wide)
+
+    def plain(x):
+        q = sequence_core.rotate(x.reshape(B, T, heads, D), tables)
+        q = q.reshape(B, T, KV, G, D) * D ** -0.5
+        return jnp.moveaxis(q.astype(jnp.bfloat16), 1, -2)
+
+    def plain_back(x, pull16):
+        return jax.vjp(plain, x)[1](pull16)[0]
+
+    # rounded out here: inside ``plain_back``'s program XLA would fold the
+    # cast and the cast back away, and the kernel keeps its own
+    pull16 = pull.astype(jnp.bfloat16)
+    want = {"forward": jax.jit(plain)(x),
+            "backward": jax.jit(plain_back)(x, pull16)}
+    moved = {   # bytes the pass has to move: x or dq, the tables, the result
+        "forward": (4 + 2) * x.size + 4 * wide.size,
+        "backward": (4 + 4) * x.size + 4 * wide.size}
+    shipped = pa.EMBED_BLOCK, pa.EMBED_ROWS
+    for direction in ("forward", "backward"):
+        if direction == "backward" and call == "burn_in":
+            continue
+        plain_ms = (seconds(jax.jit(plain), x) if direction == "forward"
+                    else seconds(jax.jit(plain_back), x, pull16)) * 1e3
+        for block, rows in EMBED_SHAPES:
+            pa.EMBED_BLOCK, pa.EMBED_ROWS = block, rows
+            fn = jax.jit(
+                (lambda x: pa._embed(x, wide, shifts, jnp.bfloat16, kv=KV,
+                                     interpret=SMOKE))
+                if direction == "forward" else
+                (lambda dq: pa._embed(dq, back, shifts, jnp.bfloat16,
+                                      interpret=SMOKE)))
+            arg = x if direction == "forward" else pull
+            try:
+                start = time.perf_counter()
+                jax.block_until_ready(fn(arg))
+                first = time.perf_counter() - start     # with its compile
+                took = seconds(fn, arg)
+            except Exception as e:  # noqa: BLE001 - a block Mosaic refuses
+                say(kind=kind, call=call, kernel="rotary_" + direction,
+                    block=block, rows=rows, refused=str(e)[:300])
+                continue
+            gap = jnp.max(jnp.abs(fn(arg).astype(jnp.float32)
+                                  - want[direction].astype(jnp.float32)))
+            say(kind=kind, call=call, kernel="rotary_" + direction,
+                block=block, rows=rows, ms=took * 1e3, first_call_s=first,
+                plain_ms=plain_ms,
+                bytes=moved[direction],
+                hbm_share=moved[direction] / took / hbm_rate,
+                max_gap=float(gap),
+                max_size=float(jnp.max(jnp.abs(
+                    want[direction].astype(jnp.float32)))))
+    pa.EMBED_BLOCK, pa.EMBED_ROWS = shipped
+
+
 def main():
     if jax.default_backend() != "tpu" and not SMOKE:
         raise SystemExit("attention_sweep times kernels: it needs a TPU")
@@ -110,6 +192,7 @@ def main():
 
     for kind, G in (("W", 8), ("F", 6)):
         windowed = kind == "W"
+        rope = core.rope_window if windowed else core.rope_full
         window = WINDOW if windowed else None
         layer = sequence_core._RotaryAttention(
             core, jnp.bfloat16, heads=G * KV, windowed=windowed)
@@ -119,6 +202,10 @@ def main():
                     for lane in range(B))
         for call, T, steps in (("unroll", UNROLL, cut),
                                ("burn_in", BURN_IN, (0,) * B)):
+            if "rotary" in PARTS:
+                rotary_rows(say, rng, kind, G, rope, T, call)
+            if not {"tiles", "path"} & set(PARTS):
+                continue
             q, new_k, new_v, position, seg, carry = window_of(rng, T, G, steps)
             keys, values, key_position, key_seg = jax.jit(layer.window_keys)(
                 new_k, new_v, position, seg, carry)
@@ -152,7 +239,8 @@ def main():
 
             # -- the kernels alone, a tile shape at a time -------------------
             for direction in ("forward", "backward"):
-                if direction == "backward" and call == "burn_in":
+                if "tiles" not in PARTS or (
+                        direction == "backward" and call == "burn_in"):
                     continue
                 for bq, bk in TILES:
                     tiles = pa.fitted(pa.Tiles(bq, bk), T, HISTORY)
@@ -181,6 +269,8 @@ def main():
                         ms=took * 1e3, visited=visited, skipped=skipped)
 
             # -- the whole path: casts, layout, kernels; against the blocks ---
+            if "path" not in PARTS:
+                continue
             got = jax.jit(fused(None))(q, keys, values)
             want = jax.jit(plain)(q, keys, values)
             say(kind=kind, call=call, what="forward", tiles=list(pa.TILES),
@@ -200,6 +290,8 @@ def main():
                                for a, b in zip(got, want)],
                     grad_sizes=[float(jnp.max(jnp.abs(b))) for b in want])
         # -- one whole sublayer, forward and backward, op by op ---------------
+        if "sublayer" not in PARTS:
+            continue
         hidden = 256 if SMOKE else CONFIGS["laguna_q"].network.hidden
         u = jnp.asarray(rng.normal(size=(B, UNROLL, hidden)), jnp.float32)
         seg = jnp.zeros((B, UNROLL), jnp.int32)

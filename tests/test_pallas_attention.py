@@ -184,14 +184,27 @@ def test_the_learner_takes_the_kernels_only_where_it_is_told(monkeypatch):
     """Off a TPU the learner's program holds no kernel (``blockwise`` runs);
     with ``DIST_DQN_PALLAS_INTERPRET=1`` — ``loop_common.pallas_routing``'s
     switch for toy tests, the route a TPU takes — the same step goes through
-    the interpreted kernels, forward and backward, and lands where the
-    blocks land within float32 noise."""
+    the interpreted kernels, the queries' rotary pass in front of them,
+    forward and backward, and lands where the blocks land within float32
+    noise."""
+    passes = []
+    embed = pallas_attention._embed
+
+    def counted(*args, kv=None, **more):
+        passes.append("back" if kv is None else "there")
+        return embed(*args, kv=kv, **more)
+
+    monkeypatch.setattr(pallas_attention, "_embed", counted)
     text, loss, priorities, params = _learner_step(monkeypatch, False)
     assert "custom_call" not in text and "pallas" not in text.lower()
     assert pallas_attention.FORWARD_NAME not in text
+    assert not passes       # ``rotate`` and ``kv_major``, as ever
     fused_text, fused_loss, fused_priorities, fused_params = _learner_step(
         monkeypatch, True)
     assert fused_text != text
+    # the queries of every rotary sublayer went through the rotary kernel,
+    # and their gradients came back through its twin
+    assert passes.count("there") >= 2 * passes.count("back") > 0
     np.testing.assert_allclose(fused_loss, loss, rtol=1e-5)
     np.testing.assert_allclose(fused_priorities, priorities, rtol=1e-4,
                                atol=1e-6)
@@ -251,6 +264,22 @@ def v5e():
     return jax.sharding.SingleDeviceSharding(topo.devices[0])
 
 
+def _compiled_for(v5e_args, fn):
+    """``fn`` compiled for the described chip, as text. A compile for a
+    described chip is written to the persistent cache but cannot be read back
+    without one: keep it out."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        return jax.jit(fn).lower(*v5e_args).compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        compilation_cache.reset_cache()
+
+
 @pytest.mark.parametrize("kind,G,steps,backward", [
     ("W", 8, 1536, True), ("F", 6, 1536, True), ("W", 8, 512, False)])
 def test_the_kernels_compile_for_v5e_at_the_presets_shapes(v5e, kind, G,
@@ -261,8 +290,6 @@ def test_the_kernels_compile_for_v5e_at_the_presets_shapes(v5e, kind, G,
     Mosaic has no rule for, more VMEM than a kernel may have (the backward
     keeps a KV head's whole ``dq``, 6.3 MB, twice). The compiled program
     holds the kernels by name and no score-shaped array."""
-    from jax.experimental.compilation_cache import compilation_cache
-
     lanes, kv, d, history = 4, 8, 128, 512
 
     def attended(q, keys, values, *marks):
@@ -282,19 +309,79 @@ def test_the_kernels_compile_for_v5e_at_the_presets_shapes(v5e, kind, G,
             shape(lanes, S, kv, d), shape(lanes, steps, dtype=jnp.int32),
             shape(lanes, steps, dtype=jnp.int32),
             shape(lanes, S, dtype=jnp.int32), shape(lanes, S, dtype=jnp.int32))
-    # a compile for a described chip is written to the persistent cache but
-    # cannot be read back without one: keep it out
-    was = jax.config.jax_enable_compilation_cache
-    jax.config.update("jax_enable_compilation_cache", False)
-    compilation_cache.reset_cache()
-    try:
-        text = jax.jit(loss_grads if backward else attended).lower(
-            *args).compile().as_text()
-    finally:
-        jax.config.update("jax_enable_compilation_cache", was)
-        compilation_cache.reset_cache()
+    text = _compiled_for(args, loss_grads if backward else attended)
     assert pallas_attention.FORWARD_NAME in text
     assert (pallas_attention.BACKWARD_NAME in text) == backward
     # no [.., queries, keys] array in HBM: the largest thing is q's size
     assert not any(f",{steps},{keys}]" in text or f",{keys},{steps}]" in text
                    for keys in (S, 1024, 2048))
+
+
+@pytest.mark.parametrize("kind,G,steps", [
+    ("W", 8, 1536), ("F", 6, 1536), ("W", 8, 512), ("F", 6, 512)])
+def test_the_rotary_kernels_compile_for_v5e_at_the_presets_shapes(
+        v5e, kind, G, steps):
+    """The queries' pass there and back at the ``laguna_q`` preset's calls:
+    a block of ``EMBED_BLOCK`` steps x a KV head's ``G`` tiles of 128 lanes,
+    the lane rotations by 64 (``W``) and by 96 and 32 (``F``) through
+    Mosaic."""
+    core = CONFIGS["laguna_q"].network.core
+    rope = core.rope_window if kind == "W" else core.rope_full
+
+    def there(x, position):
+        wide, shifts = pallas_attention.wide_tables(
+            sequence_core.rotary_tables(position, rope, 128), 128)
+        q = pallas_attention._embed(x, wide, shifts, jnp.bfloat16, kv=8)
+        return q, pallas_attention._embed(q.astype(jnp.float32), wide, shifts,
+                                          jnp.bfloat16)
+
+    text = _compiled_for(
+        (jax.ShapeDtypeStruct((4, steps, 8 * G * 128), jnp.float32,
+                              sharding=v5e),
+         jax.ShapeDtypeStruct((4, steps), jnp.int32, sharding=v5e)), there)
+    assert pallas_attention.EMBED_FORWARD_NAME in text
+    assert pallas_attention.EMBED_BACKWARD_NAME in text
+
+
+@pytest.mark.parametrize("kind,heads", [("W", 64), ("F", 48)])
+def test_a_sublayers_grad_pass_holds_no_half_empty_array(v5e, monkeypatch,
+                                                         kind, heads):
+    """One ``_RotaryAttention`` at the preset's shapes, ``jax.grad`` through
+    ``jax.checkpoint``, the kernel route forced, compiled for v5e: the four
+    kernels by name, and no array whose minor dimension is a rotary half —
+    ``rotate``'s two halves of the queries (``[4,1536,64,64]``,
+    ``[4,1536,48,32]``) and an ``F`` layer's passing dims
+    (``[4,1536,48,64]``) were the cell's largest device operation at a quarter
+    of the HBM's rate (``PERF.md`` §6, PR 48). Any form that slices the minor
+    dimension at 64 or 32 in HLO brings them back."""
+    from dist_dqn_tpu import loop_common
+
+    monkeypatch.setattr(loop_common, "pallas_routing",
+                        lambda enabled: (enabled, False))
+    cfg = CONFIGS["laguna_q"]
+    layer = sequence_core._RotaryAttention(
+        cfg.network.core, jnp.bfloat16, heads=heads, windowed=kind == "W")
+
+    def shape(*dims, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=v5e)
+
+    u, seg = shape(4, 1536, cfg.network.hidden), shape(4, 1536,
+                                                       dtype=jnp.int32)
+    ring = (shape(4, 512, 8, 128), shape(4, 512, 8, 128), shape(4))
+    params = jax.tree.map(
+        lambda a: shape(*a.shape, dtype=a.dtype),
+        jax.eval_shape(layer.init, jax.random.PRNGKey(0), u, seg, ring))
+
+    def loss(params, u, seg, ring):
+        out, carry = jax.checkpoint(
+            lambda params, u: layer.apply(params, u, seg, ring))(params, u)
+        return jnp.sum(out ** 2) + sum(jnp.sum(c) for c in carry)
+
+    text = _compiled_for((params, u, seg, ring),
+                         jax.grad(loss, argnums=(0, 1)))
+    for name in (pallas_attention.FORWARD_NAME, pallas_attention.BACKWARD_NAME,
+                 pallas_attention.EMBED_FORWARD_NAME,
+                 pallas_attention.EMBED_BACKWARD_NAME):
+        assert name in text
+    for half_empty in ("[4,1536,64,64]", "[4,1536,48,32]", "[4,1536,48,64]"):
+        assert half_empty not in text
