@@ -1,16 +1,15 @@
 """Learner grad-steps/sec microbenchmark (north-star metric #2).
 
 BASELINE.json:2 names learner grad-steps/sec alongside env-steps/sec/chip as
-the throughput metrics this framework is judged on. bench.py covers the
-fused actor+learner loop; this script isolates the *learner* train step —
+the throughput metrics this framework is judged on. The benchmark
+(perf/run.py) covers the fused actor+learner loop; this script isolates the *learner* train step —
 what the Ape-X service spends its device time on — for each driver config's
 network/batch shape, on the accelerator (every row names its platform;
 an explicit --platform cpu runs the harness on the CPU instead).
 
 Per config: build the configured Q-net, jit the train step with donated
 state (exactly how both runtimes call it), run a timed chain of steps, and
-fence with a device_get (same discipline as bench.py). Prints one JSON line
-per config.
+fence with a device_get. Prints one JSON line per config.
 
 Usage: python benchmarks/learner_bench.py [--configs atari apex ...]
 """
@@ -93,8 +92,6 @@ def _r2d2_case(cfg):
 
 def bench_config(name: str, iters: int, cfg=None) -> dict:
     from dist_dqn_tpu.config import CONFIGS
-    from dist_dqn_tpu.telemetry import devtime as devtime_mod
-    from dist_dqn_tpu.utils import flops as flops_util
 
     if cfg is None:
         cfg = CONFIGS[name]
@@ -102,32 +99,8 @@ def bench_config(name: str, iters: int, cfg=None) -> dict:
         state, step, args = _r2d2_case(cfg)
     else:
         state, step, args = _feedforward_case(cfg)
-    # AOT-compile so the timed Compiled object also yields the op-census
-    # FLOPs the MFU column is derived from (utils/flops.py). The census
-    # counts a lax.scan body ONCE regardless of trip count, so for the
-    # recurrent configs (scanned time loop) the analytic R2D2 model is
-    # the honest source instead.
+    # AOT-compile so the timed loop holds no compile.
     compiled = step.lower(state, *args).compile()
-    # Chip-time attribution (ISSUE 19): each config leg gets a fresh
-    # process registry so the row's `programs` block tallies this leg
-    # only. The census is `step`'s Compiled — for the recurrent configs
-    # it under-counts by the scan trip (see above); the analytic model
-    # stays the mfu source for those rows.
-    devtime_mod.reset_program_registry()
-    prog = devtime_mod.register_program(  # census of `step`'s Compiled
-        f"learner_bench.{name}", loop="learner_bench", role="train",
-        cost=compiled)
-    if cfg.network.lstm_size:
-        from dist_dqn_tpu import loop_common as _lc
-        T = (cfg.replay.burn_in + cfg.replay.unroll_length
-             + cfg.learner.n_step)
-        flops_per_step = flops_util.r2d2_grad_step_flops(
-            T, _lc.resolve_train_batch(cfg), hidden=cfg.network.hidden,
-            lstm=cfg.network.lstm_size,
-            remat=cfg.network.remat_torso)["total"] \
-            if cfg.network.torso == "nature" else None
-    else:
-        flops_per_step = flops_util.compiled_flops(compiled)
     state, _ = compiled(state, *args)  # one cached-dispatch warmup
     jax.device_get(state.steps)    # fence before timing
     t0 = time.perf_counter()
@@ -135,41 +108,21 @@ def bench_config(name: str, iters: int, cfg=None) -> dict:
         state, metrics = compiled(state, *args)
     jax.device_get(state.steps)    # fence: steps depends on every iteration
     dt = time.perf_counter() - t0
-    prog.count_dispatch(iters)
-    prog.add_device_seconds(dt)
     device = jax.devices()[0]
     from dist_dqn_tpu import loop_common
     train_batch = loop_common.resolve_train_batch(cfg)
-    out = {
+    return {
         "config": name,
         "grad_steps_per_sec": round(iters / dt, 2),
         "batch_size": cfg.learner.batch_size,
         "examples_per_sec": round(iters * train_batch / dt, 1),
         "platform": device.platform,
         # Learner-utilization config provenance (ISSUE 6): every row
-        # names the knobs that shaped it, mirroring bench.py's fields.
+        # names the knobs that shaped it.
         "replay_ratio": loop_common.resolve_replay_ratio(cfg),
         "train_batch": train_batch,
         "actor_dtype": cfg.network.actor_dtype or "float32",
-        # Per-program chip-time census (ISSUE 19).
-        "programs": devtime_mod.programs_snapshot("learner_bench"),
     }
-    out.update(flops_util.mfu_fields(flops_per_step, iters, dt, device))
-    if not cfg.network.lstm_size:
-        # Roofline verdict: bytes census +
-        # which ceiling (compute vs HBM) governs this step, vs the
-        # measured time. Feedforward steps only — the census counts a
-        # scan body once, so the recurrent configs would under-count.
-        out.update(flops_util.roofline_fields(
-            flops_per_step, flops_util.compiled_bytes(compiled), device))
-        if "roofline_s" in out:
-            out["measured_step_s"] = round(dt / iters, 6)
-            # Gap from the UNROUNDED roofline rate: the rounded
-            # roofline_s display field can be 0.0 for sub-microsecond
-            # rooflines (tiny CPU test cases) and must not be divided by.
-            out["roofline_gap_x"] = round(
-                (dt / iters) * out["roofline_grad_steps_per_sec"], 2)
-    return out
 
 
 def r2d2_sweep(iters: int):
@@ -198,9 +151,9 @@ def r2d2_sweep(iters: int):
 
 def batch_sweep(iters: int, config_name: str = "apex"):
     """Learner batch-size scaling (next perf lever after the lane sweep):
-    the feed-forward heads measure 2-5% MFU at their config batch sizes —
-    latency/bandwidth-bound, not MXU-bound — so grad-steps/s should fall
-    sublinearly while examples/s and MFU climb as B doubles. Sizes up to
+    the feed-forward heads are latency/bandwidth-bound, not MXU-bound, at
+    their config batch sizes — so grad-steps/s should fall sublinearly
+    while examples/s climbs as B doubles. Sizes up to
     2048 = 4x the proven B=512 chip run, stepped through 1024 first, so
     each point is <=2x the previously measured size (verify-skill
     incident-#3 rule; run order is smallest-first)."""
@@ -224,12 +177,11 @@ def replay_ratio_sweep(iters: int, ratios=(1, 2, 4, 8),
     WHOLE fused program — collect + N scanned grad sub-steps per train
     event — at each ratio, plus the donation audit of the chunk carry.
 
-    This is the measurement behind the headline MFU move: the
-    standalone-step rows above price one dispatch, but the replay ratio
+    The standalone-step rows above price one dispatch, but the replay ratio
     only pays off inside the chunk scan where the extra sub-steps share
     the collect. ``scaling_vs_ratio1`` is the acceptance column (the
     ISSUE 6 bar: >= 3x from ratio 1 -> 8 on the fused CPU path). On the
-    chip the sweep runs the bench.py-shaped atari program; on CPU a
+    chip the sweep runs a 1024-lane atari program; on CPU a
     cartpole-MLP shrink of the same structure (the pixel program would
     take minutes per point without measuring anything different about
     the scaling).
@@ -283,13 +235,6 @@ def replay_ratio_sweep(iters: int, ratios=(1, 2, 4, 8),
         compiled = jax.jit(run_chunk, static_argnums=1,
                            donate_argnums=0).lower(carry,
                                                    chunk_iters).compile()
-        # Chip-time attribution (ISSUE 19): per-ratio leg registry so
-        # each row's `programs` block tallies that leg's chunk program.
-        from dist_dqn_tpu.telemetry import devtime as devtime_mod
-        devtime_mod.reset_program_registry()
-        _prog = devtime_mod.register_program(
-            "learner_bench.chunk", loop="learner_bench", role="train",
-            cost=compiled, execs_per_dispatch=ratio)
         # Aliasing audit (ISSUE 6): the scan carry must keep updating
         # in place at every ratio — an unintended copy would show here
         # before it shows as an OOM on the chip.
@@ -302,8 +247,6 @@ def replay_ratio_sweep(iters: int, ratios=(1, 2, 4, 8),
             carry, metrics = compiled(carry)
         g = float(jax.device_get(metrics["grad_steps_in_chunk"]))
         dt = time.perf_counter() - t0
-        _prog.count_dispatch(iters)
-        _prog.add_device_seconds(dt)
         rate = g * iters / dt
         row = {
             "replay_ratio": ratio,
@@ -316,8 +259,6 @@ def replay_ratio_sweep(iters: int, ratios=(1, 2, 4, 8),
             "platform": jax.devices()[0].platform,
             "aliased_pairs": audit.get("aliased_pairs"),
             "alias_bytes": audit.get("alias_bytes"),
-            # Per-program chip-time census (ISSUE 19).
-            "programs": devtime_mod.programs_snapshot("learner_bench"),
         }
         if base_rate is None:
             base_rate = rate

@@ -5,9 +5,8 @@ the CPU leg learns fake-ALE Pong end-to-end through the REAL
 AtariPreprocessing path (``ale_learning.py --calibrate-cpu``), and the
 chip leg of that same harness is host-bound (emulator + actors + service
 share the host's cores). This script closes the remaining gap from the
-other side: the FUSED on-device loop — the very program bench.py times
-on this exact env — trained until it is WINNING whole games of the
-device-native Pong (envs/pixel_pong.py: ±1 per point, first-to-5
+other side: the FUSED on-device loop on this exact env, trained until it
+is WINNING whole games of the device-native Pong (envs/pixel_pong.py: ±1 per point, first-to-5
 episodes, tracking opponent, spin). Same production stack as the atari
 config: Nature CNN bf16, uint8 84x84x4 frame stacks, n-step TD, uniform
 replay ring (the atari preset is plain Nature DQN; --head rainbow adds

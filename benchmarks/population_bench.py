@@ -15,12 +15,9 @@ train.py routes it to the plain runtime, so solo IS the honest
 denominator); the M>1 legs run ``population.make_population_train``'s
 stacked entry point. ``scaling_vs_m1`` is the acceptance column — the
 ISSUE 20 bar: aggregate member grad-steps/sec at M=8 >= 3x the M=1
-solo rate on the fused CPU path. Each row's ``programs`` block
-(chip-time census, ISSUE 19) shows dispatches == timed chunks,
-confirming the whole population advances in one stacked dispatch per
-chunk.
+solo rate on the fused CPU path.
 
-On the chip the sweep runs the bench.py-shaped atari program; on CPU a
+On the chip the sweep runs a 1024-lane atari program; on CPU a
 cartpole-MLP shrink of the same structure (the pixel program would
 take minutes per point without measuring anything different about the
 dispatch-amortization scaling).
@@ -84,16 +81,15 @@ def population_sweep(iters: int, sizes=(1, 2, 4, 8),
 
     Row fields: ``population``, aggregate ``grad_steps_per_sec`` (sum
     over members), ``grad_steps_per_sec_member`` (aggregate / M),
-    aggregate ``env_steps_per_sec``, the chunk-carry donation audit,
-    the per-leg ``programs`` census, and ``scaling_vs_m1`` (aggregate
-    rate over the M=1 solo rate — the acceptance column).
+    aggregate ``env_steps_per_sec``, the chunk-carry donation audit, and
+    ``scaling_vs_m1`` (aggregate rate over the M=1 solo rate — the
+    acceptance column).
     """
     from dist_dqn_tpu import loop_common
     from dist_dqn_tpu import population as pop
     from dist_dqn_tpu.config import PopulationConfig
     from dist_dqn_tpu.envs import make_jax_env
     from dist_dqn_tpu.models import build_network
-    from dist_dqn_tpu.telemetry import devtime as devtime_mod
     from dist_dqn_tpu.train_loop import make_fused_train
     from dist_dqn_tpu.utils import donation as donation_util
 
@@ -103,9 +99,6 @@ def population_sweep(iters: int, sizes=(1, 2, 4, 8),
     base_rate = None
     rows = []
     for M in sizes:
-        # Per-leg process registry (ISSUE 19) so each row's `programs`
-        # block tallies that leg's one chunk program only.
-        devtime_mod.reset_program_registry()
         if M == 1:
             # The solo program, exactly as train.py dispatches it when
             # --population is 1/absent — the bar's denominator.
@@ -129,9 +122,6 @@ def population_sweep(iters: int, sizes=(1, 2, 4, 8),
                 donate_argnums=0).lower(carry, hp,
                                         chunk_iters).compile()
             step = (lambda _c, _hp=hp: compiled(_c, _hp))
-        _prog = devtime_mod.register_program(
-            "population_bench.chunk", loop="population_bench",
-            role="train", cost=compiled, execs_per_dispatch=chunk_iters)
         # Aliasing audit (ISSUE 6/20): the [M]-stacked carries must
         # keep donating completely — an unintended copy here is M whole
         # fused working sets doubled on the chip.
@@ -145,8 +135,6 @@ def population_sweep(iters: int, sizes=(1, 2, 4, 8),
         g_members = np.atleast_1d(
             jax.device_get(metrics["grad_steps_in_chunk"]))
         dt = time.perf_counter() - t0
-        _prog.count_dispatch(iters)
-        _prog.add_device_seconds(dt)
         rate = float(np.sum(g_members)) * iters / dt
         row = {
             "population": M,
@@ -162,9 +150,6 @@ def population_sweep(iters: int, sizes=(1, 2, 4, 8),
             "platform": jax.devices()[0].platform,
             "aliased_pairs": audit.get("aliased_pairs"),
             "alias_bytes": audit.get("alias_bytes"),
-            # Per-program chip-time census (ISSUE 19): dispatches ==
-            # `iters` proves one stacked dispatch per chunk at every M.
-            "programs": devtime_mod.programs_snapshot("population_bench"),
         }
         if base_rate is None:
             base_rate = rate

@@ -18,8 +18,7 @@ platform ``--platform`` names; every row names its platform),
 
 and prints one JSON line per (impl, size): median/min seconds per draw.
 
-Fencing discipline matches bench.py: device timings fence with a
-``device_get`` on a kernel output.
+Device timings fence with a ``device_get`` on a kernel output.
 
 Usage:
   python benchmarks/sampler_bench.py                 # the accelerator
@@ -57,11 +56,6 @@ def bench_device(jax, cells: int, batch: int, iters: int,
     import jax.numpy as jnp
 
     from dist_dqn_tpu.ops.pallas_sampler import stratified_sample
-    from dist_dqn_tpu.telemetry import devtime as devtime_mod
-
-    # Chip-time attribution (ISSUE 19): fresh registry per (impl, cells)
-    # point so the row's `programs` block tallies this point only.
-    devtime_mod.reset_program_registry()
 
     T = cells // LANES
     r = np.random.default_rng(0)
@@ -97,10 +91,6 @@ def bench_device(jax, cells: int, batch: int, iters: int,
         draw = make_draw(n_draws)
         keys = [jax.random.PRNGKey(1000 * n_draws + i)
                 for i in range(iters + 2)]
-        prog = devtime_mod.register_program(  # census of `draw` above
-            f"sampler.draw_x{n_draws}", loop="sampler_bench",
-            role="sample", cost=lambda: draw.lower(w, keys[0]),
-            execs_per_dispatch=float(n_draws))
         for k in keys[:2]:  # compile + cached-dispatch warmup
             jax.device_get(draw(w, k))
         it = iter(keys[2:])
@@ -108,18 +98,10 @@ def bench_device(jax, cells: int, batch: int, iters: int,
         def one():
             jax.device_get(draw(w, next(it)))  # fence on an output
 
-        out = _timed(one, iters)
-        # Attribute AFTER timing (no bookkeeping inside the timed
-        # region): median*iters as the measured device-seconds — each
-        # call fences, so the median is the per-dispatch device wall.
-        prog.count_dispatch(iters)
-        prog.add_device_seconds(out["median_s"] * iters)
-        return out
+        return _timed(one, iters)
 
     if amortize <= 1:
-        out = timed_at(1)
-        out["programs"] = devtime_mod.programs_snapshot("sampler_bench")
-        return out
+        return timed_at(1)
 
     # Dividing one K-draw scan's time by K folds the dispatch+fence
     # constant into every draw. Two-point marginal cost subtracts it:
@@ -129,7 +111,6 @@ def bench_device(jax, cells: int, batch: int, iters: int,
         "marginal_s": round((hi["median_s"] - lo["median_s"]) / amortize, 8),
         "dispatch_s": round(2 * lo["median_s"] - hi["median_s"], 6),
         "median_lo_s": lo["median_s"], "median_hi_s": hi["median_s"],
-        "programs": devtime_mod.programs_snapshot("sampler_bench"),
     }
 
 
@@ -162,12 +143,6 @@ def bench_sharded(jax, cells: int, shards: int, batch: int, iters: int,
       wall clock.
     """
     from dist_dqn_tpu.replay.host import DevicePrioritySampler
-    from dist_dqn_tpu.telemetry import devtime as devtime_mod
-
-    # Chip-time attribution (ISSUE 19): fresh registry per grid point —
-    # the samplers self-register `sampler.draw_writeback` in __init__,
-    # so reset BEFORE construction or the row tallies prior points.
-    devtime_mod.reset_program_registry()
 
     devs = jax.devices()
     shard_cells = cells // shards
@@ -227,11 +202,6 @@ def bench_sharded(jax, cells: int, shards: int, batch: int, iters: int,
         "mesh_speedup_vs_host_cpp": round(mesh_agg / host_rate, 3),
         "cpus": os.cpu_count(),
         "devices": len(devs),
-        # Per-program census (ISSUE 19): the shards' shared
-        # `sampler.draw_writeback` record — flops/bytes per fused
-        # write-back+draw, dispatches and device-seconds summed over
-        # every shard event above.
-        "programs": devtime_mod.programs_snapshot("sampler"),
     }
 
 

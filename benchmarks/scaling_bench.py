@@ -1,7 +1,7 @@
 """n-chip scale-out measurement (ISSUE 10): the row that makes the
 trajectory measure SCALE-OUT, not just single-chip rate.
 
-Two legs, one emit-once JSON row (the bench.py ContractEmitter
+Two legs, one emit-once JSON row (the contract.py ContractEmitter
 discipline):
 
 * **host-replay dp leg** — the same tiny run at ``dp=1`` and ``dp=N``
@@ -62,20 +62,13 @@ def _parse_args():
 
 def _host_replay_leg(cfg, total, chunk_iters, dp):
     from dist_dqn_tpu.host_replay_loop import run_host_replay
-    from dist_dqn_tpu.telemetry import devtime as devtime_mod
 
-    # Chip-time attribution (ISSUE 19): fresh registry per leg so the
-    # re-emitted `programs`/`chip_time` blocks tally this leg only
-    # (the dp1 and dpN legs run in the same process).
-    devtime_mod.reset_program_registry()
     out = run_host_replay(cfg, total_env_steps=total,
                           chunk_iters=chunk_iters,
                           log_fn=lambda s: None, mesh_devices=dp)
     return {
-        # Per-program census + busy/idle decomposition from the run's
-        # summary (ISSUE 19): per-chip rows carry WHERE the chip time
-        # went, not just how much of it there was.
-        "programs": out["programs"],
+        # The run's chunk walls by cause (telemetry/devtime.py
+        # UtilizationLedger).
         "chip_time": out["chip_time"],
         "dp_size": out["dp_size"],
         "env_steps_per_sec": out["env_steps_per_sec"],
@@ -149,7 +142,7 @@ def main() -> int:
                 flags + " --xla_force_host_platform_device_count="
                 f"{args.force_host_devices}").strip()
 
-    from bench import ContractEmitter
+    from contract import ContractEmitter
 
     from dist_dqn_tpu.utils.backend import select_platform
 

@@ -15,7 +15,7 @@ JSON row per arm with
     dispatches = sum over responses of 1/fanin_requests),
   * ``requests_shed`` — 429s the bounded queue returned,
 
-plus the run manifest and a registry snapshot (the bench.py pattern).
+plus the run manifest and a registry snapshot.
 ``--ab`` runs the dynamic micro-batcher against the ``--no-batching``
 serialized-dispatch baseline at the same load and reports the speedup —
 the acceptance smoke (tests/test_serving.py) asserts batched >= serial.
@@ -38,7 +38,7 @@ import numpy as np
 sys.path.insert(0, str(Path(__file__).resolve().parent))
 sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
 
-from bench import ContractEmitter  # noqa: E402
+from contract import ContractEmitter  # noqa: E402
 
 METRIC = "serving_acts_per_sec"
 UNIT = ("action rows served/sec (closed-loop HTTP clients, greedy "

@@ -156,7 +156,7 @@ def _check_chunks(rows: List[Dict], lanes: int, chunk_iters: int,
         "grad_steps_last_chunk": last["grad_steps_in_chunk"],
         "loss": last["loss"],
         # Chunk walls from the trainer's own rate column. The compile is
-        # in neither (the census compiles before the first dispatch and
+        # in neither (the trainer compiles before the first dispatch and
         # the meter reports it): the first chunk is mostly pre-fill, the
         # last is steady state.
         "first_chunk_s": round(chunk_iters * lanes
@@ -166,17 +166,23 @@ def _check_chunks(rows: List[Dict], lanes: int, chunk_iters: int,
     }
 
 
-def _check_census() -> Dict:
-    """The chunk program's cost census (telemetry/devtime.py) — harvested
-    off the hot path under a catch-all, so its presence is checked here."""
-    from dist_dqn_tpu.telemetry import devtime
+def _check_stage_table(prioritized: bool, mesh: bool = False) -> Dict:
+    """What the benchmark's per-stage metrics depend on: the executable the
+    trainer kept (telemetry/stages.py) yields a stage table that names every
+    stage the leg's program enters."""
+    from dist_dqn_tpu.telemetry import stages
 
-    rec = devtime.get_program_registry().get("fused.chunk", "fused")
-    check(rec is not None and rec.flops is not None
-          and rec.bytes is not None,
-          "the fused.chunk program has no FLOPs/bytes census")
-    check(rec.dispatches > 0, "fused.chunk recorded no dispatch")
-    return {"census_flops": rec.flops, "census_bytes": rec.bytes}
+    table = stages.table()
+    check(table, "the trainer kept no chunk program: the stage table is "
+                 "empty")
+    entered = set(stages.STAGES)
+    if not prioritized:
+        entered.discard("writeback")
+    if not mesh:
+        entered.discard("allreduce")
+    missing = sorted(entered - set(table.values()))
+    check(not missing, f"the stage table names no instruction of {missing}")
+    return {"stage_instructions": len(table)}
 
 
 def leg_fused(meter: CompileMeter, config: str = "atari",
@@ -185,9 +191,6 @@ def leg_fused(meter: CompileMeter, config: str = "atari",
     """train CLI -> periodic eval -> checkpoint -> evaluate CLI restores."""
     from dist_dqn_tpu import evaluate, train
     from dist_dqn_tpu.config import CONFIGS, apply_overrides
-    from dist_dqn_tpu.telemetry import devtime
-
-    devtime.reset_program_registry()
     cfg = apply_overrides(CONFIGS[config], list(overrides))
     lanes = cfg.actor.num_envs
     total = chunks * chunk_iters * lanes
@@ -210,7 +213,7 @@ def leg_fused(meter: CompileMeter, config: str = "atari",
         check(evals and all(math.isfinite(e) for e in evals),
               f"the periodic evaluator returned {evals}")
         out["train_eval_return"] = evals[-1]
-        out.update(_check_census())
+        out.update(_check_stage_table(cfg.replay.prioritized))
 
         rows = run_cli(evaluate.main, [
             "--config", config, *_set_flags(overrides),
@@ -309,10 +312,8 @@ def leg_kernel(meter: CompileMeter, config: str = "apex",
     from dist_dqn_tpu.envs import make_jax_env
     from dist_dqn_tpu.models import build_network
     from dist_dqn_tpu.ops.pallas_sampler import KERNEL_NAME
-    from dist_dqn_tpu.telemetry import devtime
     from dist_dqn_tpu.train_loop import make_fused_train
 
-    devtime.reset_program_registry()
     cfg = apply_overrides(CONFIGS[config], list(overrides))
     check(cfg.replay.prioritized and cfg.replay.pallas_sampler,
           f"{config} does not select the Pallas sampler")
@@ -325,7 +326,7 @@ def leg_kernel(meter: CompileMeter, config: str = "apex",
     out = {"leg": "kernel", "config": config, **meter.take()}
     out.update(_check_chunks(history, cfg.actor.num_envs, chunk_iters,
                              chunks))
-    out.update(_check_census())
+    out.update(_check_stage_table(prioritized=True))
 
     # The same program the trainer just ran, from the same factory at the
     # same shapes: its compiled text must hold the kernel as a Mosaic
@@ -372,9 +373,6 @@ def leg_mesh(meter: CompileMeter, config: str = "atari",
     import jax
 
     from dist_dqn_tpu.config import CONFIGS, apply_overrides
-    from dist_dqn_tpu.telemetry import devtime
-
-    devtime.reset_program_registry()
     cfg = apply_overrides(CONFIGS[config], list(overrides))
     meter.take()
     carry, history = _train_direct(cfg, chunk_iters, chunks,
@@ -383,7 +381,7 @@ def leg_mesh(meter: CompileMeter, config: str = "atari",
            **meter.take()}
     out.update(_check_chunks(history, cfg.actor.num_envs, chunk_iters,
                              chunks))
-    out.update(_check_census())
+    out.update(_check_stage_table(cfg.replay.prioritized, mesh=True))
 
     ring = carry.replay.ring if cfg.replay.prioritized else carry.replay
     # The acting observation: carried beside the env state, or — an env

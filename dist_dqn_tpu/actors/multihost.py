@@ -27,7 +27,6 @@ from __future__ import annotations
 
 import os
 import threading
-import time
 from typing import Tuple
 
 import numpy as np
@@ -100,13 +99,6 @@ class MultihostLearner:
             return body(state, *data)
 
         jitted = jax.jit(sharded, donate_argnums=0)
-        # Chip-time attribution (ISSUE 19): the collective step is this
-        # host's train program; the priority materialization below is a
-        # fence the wrapper already holds, so the dispatch->materialize
-        # wall is attributable without a new sync.
-        from dist_dqn_tpu.telemetry import devtime as _devtime
-        prog = _devtime.register_program(
-            "multihost.train_step", loop="multihost", role="train")
 
         def to_global(spec, x):
             x = np.asarray(x)
@@ -117,10 +109,6 @@ class MultihostLearner:
             gdata = tuple(
                 jax.tree.map(to_global, spec, d)
                 for spec, d in zip(data_specs, host_data))
-            if not prog.cost_attached:
-                prog.attach_cost(lambda: jitted.lower(state, *gdata))
-            prog.count_dispatch()
-            t0 = time.perf_counter()
             state, metrics = jitted(state, *gdata)
             prios = metrics.pop("priorities")
             # The local slice of the sharded priorities vector, in global
@@ -129,7 +117,6 @@ class MultihostLearner:
                             key=lambda s: s.index[0].start or 0)
             metrics["priorities"] = np.concatenate(
                 [np.asarray(s.data) for s in shards])
-            prog.add_device_seconds(time.perf_counter() - t0)
             return state, metrics
 
         return step
@@ -163,7 +150,7 @@ class MultihostLearner:
                 "psum, so this learner is poisoned — restart the process")
         if self._agree is None:
             # donation: few-element counter psum, nothing worth donating
-            # (caller reuses its input); devtime: out of census scope.
+            # (caller reuses its input).
             self._agree = jax.jit(jax.shard_map(
                 lambda x: jax.lax.psum(x, "dp"), mesh=self.mesh,
                 in_specs=P("dp"), out_specs=P(), check_vma=False))

@@ -573,23 +573,13 @@ class ApexLearnerService:
                    "(multi-host batches shard from learner.batch_size); "
                    "ignored")
             self.train_batch = cfg.learner.batch_size
-        # Chip-time attribution (ISSUE 19): every device-call kind the
-        # service dispatches gets a ProgramRegistry row (created lazily
-        # in _count_device_call, so only the kinds this configuration
-        # actually runs appear). The train program registers eagerly —
-        # it carries role="train" (the registry-derived MFU numerator)
-        # and its cost is harvested at the first dispatch.
         from dist_dqn_tpu.telemetry import devtime as _devtime
         self._devtime = _devtime
-        self._prog_train = _devtime.register_program(
-            "apex.train_scan" if self._train_scan is not None
-            else "apex.train_step", loop="apex", role="train",
-            execs_per_dispatch=(self.replay_ratio
-                                if self._train_scan is not None else 1))
-        self._prog_by_kind: Dict[str, object] = {"train": self._prog_train}
-        # Retirement-interval anchor for the train program's device-
-        # seconds attribution (see _finalize_train).
-        self._devtime_anchor = time.perf_counter()
+        # The ledger's `busy`: the wall train steps occupied the device
+        # queue, summed at their retirement fences (_finalize_train),
+        # from this anchor on.
+        self._train_busy_s = 0.0
+        self._busy_anchor = time.perf_counter()
         self._ledger = _devtime.UtilizationLedger("apex")
         self._ledger_busy_seen = 0.0
         self._ledger_t_last = time.perf_counter()
@@ -905,27 +895,8 @@ class ApexLearnerService:
                 labels={"call": kind})
             self._tm_device_calls[kind] = c
         c.inc()
-        # ProgramRegistry dispatch tally (ISSUE 19): one registry row
-        # per device-call kind (act / fused / bootstrap / ...; "train"
-        # pre-registered with role="train" in __init__).
-        prog = self._prog_by_kind.get(kind)
-        if prog is None:
-            prog = self._devtime.register_program(f"apex.{kind}",
-                                                  loop="apex", role=kind)
-            self._prog_by_kind[kind] = prog
-        prog.count_dispatch()
         if rows is not None:
             self._tm_fanin.observe(float(rows))
-
-    def _attach_train_cost(self, fn, *args) -> None:
-        """One-shot FLOPs/bytes harvest for the train program at its
-        first dispatch (fn.lower, compiled by devtime where a TPU needs
-        the executable for its census — the dispatch reuses it; the
-        wrapped mesh/multi-host steps have no .lower and degrade to
-        cost-absent, exactly once)."""
-        if not self._prog_train.cost_attached:
-            st = self.state
-            self._prog_train.attach_cost(lambda: fn.lower(st, *args))
 
     def _step_specs(self, axis: str):
         """(data_specs, metric_specs) PartitionSpecs for the train step:
@@ -1949,7 +1920,6 @@ class ApexLearnerService:
                     if len(self._stager) == 0:
                         self._stage_scan_batch(batch_size, beta)
                     args, (idx, gen) = self._stager.pop()
-                    self._attach_train_cost(self._train_scan, *args)
                     with self.tracer.span("train_step.dispatch",
                                           substeps=self.replay_ratio):
                         self.state, metrics = self._train_scan(self.state,
@@ -1962,7 +1932,6 @@ class ApexLearnerService:
                     args, (idx, gen) = self._sample_scan_args(batch_size,
                                                               beta)
                     args = self.jax.tree.map(jnp.asarray, args)
-                    self._attach_train_cost(self._train_scan, *args)
                     with self.tracer.span("train_step.dispatch",
                                           substeps=self.replay_ratio):
                         self.state, metrics = self._train_scan(self.state,
@@ -1984,7 +1953,6 @@ class ApexLearnerService:
                 if len(self._stager) == 0:
                     self._stage_batch(batch_size, beta)
                 args, (idx, gen) = self._stager.pop()
-                self._attach_train_cost(self._train_step, *args)
                 with self.tracer.span("train_step.dispatch"):
                     self.state, metrics = self._train_step(self.state,
                                                            *args)
@@ -1998,7 +1966,6 @@ class ApexLearnerService:
                 with self.tracer.span("train_step.dispatch"):
                     if self.recurrent:
                         sample = self._sequence_sample(items, weights)
-                        self._attach_train_cost(self._train_step, sample)
                         self.state, metrics = self._train_step(self.state,
                                                                sample)
                     else:
@@ -2010,8 +1977,6 @@ class ApexLearnerService:
                             discount=jnp.asarray(items["discount"]),
                             next_obs=jnp.asarray(items["next_obs"]))
                         w_dev = jnp.asarray(weights)
-                        self._attach_train_cost(self._train_step,
-                                                batch, w_dev)
                         self.state, metrics = self._train_step(
                             self.state, batch, w_dev)
                 self._count_device_call("train")
@@ -2026,12 +1991,12 @@ class ApexLearnerService:
 
     def _flush_ledger_window(self):
         """Close the current utilization-ledger window: wall since the
-        last flush against the train program's device-seconds delta.
+        last flush against the train steps' queue-occupied delta.
         The apex loop has no chunk boundary, so the log cadence (and a
         final flush before the summary) is its decomposition unit;
         unattributed wall lands in the `other` bucket."""
         now = time.perf_counter()
-        busy_total = self._prog_train.device_seconds
+        busy_total = self._train_busy_s
         self._ledger.observe_chunk(now - self._ledger_t_last,
                                    busy_total - self._ledger_busy_seen)
         self._ledger_t_last = now
@@ -2055,15 +2020,13 @@ class ApexLearnerService:
         # steps — the operationally honest number for the host loop).
         t_retire = time.perf_counter()
         self._tm_grad_latency.observe(t_retire - t_dispatch)
-        # Device-seconds attribution (ISSUE 19), at this fence the loop
-        # already holds: the wall from max(dispatch, previous
-        # retirement) to now is the interval this step occupied the
-        # device queue — overlapping in-flight steps never double-
-        # count. An upper-bound estimate (queue-occupied, not
-        # kernel-active), same spirit as the grad latency above.
-        self._prog_train.add_device_seconds(
-            t_retire - max(t_dispatch, self._devtime_anchor))
-        self._devtime_anchor = t_retire
+        # The wall from max(dispatch, previous retirement) to now is the
+        # interval this step occupied the device queue — overlapping
+        # in-flight steps never double-count. An upper bound (queue-
+        # occupied, not kernel-active), as the grad latency above.
+        self._train_busy_s += max(
+            t_retire - max(t_dispatch, self._busy_anchor), 0.0)
+        self._busy_anchor = t_retire
         if self._profile_tracer.stop():
             print(f"# profile_trace {self.rt.profile_dir}")
         self._last_loss = float(metrics["loss"])
@@ -2457,15 +2420,13 @@ class ApexLearnerService:
                     self._tm_ring_pending.set(self.req_ring.pending_bytes)
                     self._tm_record_age.set(now - self._last_record)
                     self._sweep_dedup_counters()
-                    # Chip-time plane sweep (ISSUE 19), once per log
-                    # period: ledger the window's wall against the
-                    # train program's device-seconds delta (the apex
-                    # loop has no chunk boundary — the log window is
-                    # its decomposition unit; unattributed wall lands
-                    # in the `other` bucket), refresh the registry-
-                    # derived MFU, and sweep device memory stats.
+                    # Once per log period: ledger the window's wall
+                    # against the train steps' queue-occupied delta
+                    # (the apex loop has no chunk boundary — the log
+                    # window is its decomposition unit; unattributed
+                    # wall lands in the `other` bucket), and sweep
+                    # device memory stats.
                     self._flush_ledger_window()
-                    self._devtime.set_learner_mfu("apex")
                     self._devtime.sweep_device_memory()
                     self.tracer.counter("replay_size", len(self.replay))
                     self.tracer.counter("env_steps", self.env_steps)
@@ -2555,10 +2516,8 @@ class ApexLearnerService:
                 # count means the learner is not keeping up with actors.
                 "tcp_backpressure": (self.tcp_server.backpressure_events
                                      if self.tcp_server else 0),
-                # Chip-time attribution plane (ISSUE 19): per-program
-                # cost census + the busy/idle decomposition of wall time.
+                # The ledger's busy/idle decomposition of wall time.
                 "chip_time": self._ledger.snapshot(),
-                "programs": self._devtime.programs_snapshot("apex"),
                 "bad_records": self.bad_records,
                 "actor_restarts": self.actor_restarts}
 

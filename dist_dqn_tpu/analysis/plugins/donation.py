@@ -29,7 +29,7 @@ from typing import List, Tuple
 from dist_dqn_tpu.analysis.core import AnalysisContext, Check, Finding
 from dist_dqn_tpu.analysis.registry import register
 
-SCAN_ROOTS = ("dist_dqn_tpu", "benchmarks", "bench.py")
+SCAN_ROOTS = ("dist_dqn_tpu", "benchmarks")
 
 #: What makes a jitted expression a train/collect entry point.
 #: ``shard`` joined in ISSUE 10: the data-parallel learners wrap their

@@ -18,7 +18,7 @@ from typing import List, Tuple
 from dist_dqn_tpu.analysis.core import AnalysisContext, Check, Finding
 from dist_dqn_tpu.analysis.registry import register
 
-SCAN_ROOTS = ("dist_dqn_tpu", "benchmarks", "bench.py", "__graft_entry__.py")
+SCAN_ROOTS = ("dist_dqn_tpu", "benchmarks", "__graft_entry__.py")
 #: What names the axis inside the call text.
 AXIS_IN_CALL = re.compile(r"""P\(\s*['"]|axis_name|axis\s*=""")
 #: Rationale escape hatch for spec-variable call sites.

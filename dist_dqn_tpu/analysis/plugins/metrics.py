@@ -43,22 +43,21 @@ DOCS_ALLOWLIST = {
 
 #: file (repo-relative, posix) -> call sites grandfathered at ISSUE 1.
 ALLOWLIST = {
-    "bench.py": 1,
     "benchmarks/ale_learning.py": 2,
     "benchmarks/apex_feeder_bench.py": 1,
     "benchmarks/apex_split_bench.py": 2,
+    "benchmarks/contract.py": 1,  # ContractEmitter: the contract line
     "benchmarks/host_replay_bench.py": 1,
     "benchmarks/learner_bench.py": 3,
     "benchmarks/pong_learning.py": 2,
     "benchmarks/r2d2_pixel_learning.py": 1,
-    "benchmarks/roofline_inscan.py": 1,
     # +1 at ISSUE 18: the sharded arm's per-grid BENCH row line — a CLI
     # output contract like the per-impl rows; the device-sampling
     # runtime metrics go through the registry
     # (dqn_replay_device_sample_seconds / _writeback_rows_total).
     "benchmarks/sampler_bench.py": 2,
     # ISSUE 7: the per-arm BENCH row line (the contract line goes
-    # through bench.ContractEmitter, counted under bench.py) — CLI
+    # through contract.ContractEmitter, counted there) — CLI
     # output contracts; the serving metrics themselves go through the
     # registry (dqn_serving_*).
     "benchmarks/serving_bench.py": 1,
@@ -104,7 +103,7 @@ ALLOWLIST = {
     "dist_dqn_tpu/utils/metrics.py": 1,  # MetricLogger.flush itself
 }
 
-SCAN_ROOTS = ("dist_dqn_tpu", "benchmarks", "bench.py", "__graft_entry__.py")
+SCAN_ROOTS = ("dist_dqn_tpu", "benchmarks", "__graft_entry__.py")
 
 
 def scan(repo_root: Path, ctx: AnalysisContext = None) -> Dict[str, int]:

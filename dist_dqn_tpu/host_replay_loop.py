@@ -419,18 +419,7 @@ def run_host_replay(cfg: ExperimentConfig, total_env_steps: int,
     collect_jit = jax.jit(collect, static_argnums=2, donate_argnums=0)
     init_learner, train_step = make_learner(
         net, cfg.learner, axis_name="dp" if mesh_mode else None)
-    # Chip-time attribution (ISSUE 19): both hot programs register in
-    # the process ProgramRegistry; cost is harvested at the first
-    # dispatch (lowering against the live args, compiled by devtime on
-    # a TPU and reused by the dispatch) and device-seconds at the fences
-    # the loop already holds. The collect program is deliberately left without device
-    # time in pipeline mode — it overlaps evac+train by design and
-    # fencing it would be a new hot-path sync.
     from dist_dqn_tpu.telemetry import devtime as _devtime
-    _prog_collect = _devtime.register_program(
-        "host_replay.collect", loop="host_replay", role="collect")
-    _prog_train = _devtime.register_program(
-        "host_replay.train_step", loop="host_replay", role="train")
     mesh = mesh_devs = weights_sharding = None
     if not mesh_mode:
         train_jit = jax.jit(train_step, donate_argnums=0)
@@ -449,18 +438,6 @@ def run_host_replay(cfg: ExperimentConfig, total_env_steps: int,
                                             data_specs, metric_specs)
         weights_sharding = NamedSharding(mesh, P("dp"))
         repl_sharding = NamedSharding(mesh, P())
-
-    def _train_dispatch(state, batch, w):
-        """Every train-step launch goes through here so the registry
-        sees one dispatch count per grad step and the cost analysis is
-        harvested exactly once, at the first launch (when real args
-        exist). The mesh train step is a shard_map wrapper without
-        .lower — attach_cost degrades to flops=None there, one shot."""
-        if not _prog_train.cost_attached:
-            _prog_train.attach_cost(
-                lambda: train_jit.lower(state, batch, w))
-        _prog_train.count_dispatch()
-        return train_jit(state, batch, w)
 
     # Replay-ratio engine (ISSUE 6): multiplies the grad steps each
     # train event runs — the SamplePrefetcher simply draws that many
@@ -498,7 +475,7 @@ def run_host_replay(cfg: ExperimentConfig, total_env_steps: int,
         # donated train step overwrite its state while the async shard
         # collects are still reading the snapshot.
         # donation: the snapshot must COPY (the learner still owns the
-        # params the train step donates); devtime: one cast per chunk.
+        # params the train step donates).
         @jax.jit
         def snapshot_collect_params(params):
             params = _cast_actor(params) if _actor_split else params
@@ -886,15 +863,10 @@ def run_host_replay(cfg: ExperimentConfig, total_env_steps: int,
                             "host_replay.collect", cev.fault)
                     chaos.sleep_for(cev)
                     stalled = True
-                if not _prog_collect.cost_attached:
-                    _c, _v = carries[s], views[s]
-                    _prog_collect.attach_cost(
-                        lambda: collect_jit.lower(_c, _v, chunk_iters))
                 t_d = time.perf_counter()
                 carries[s], r, st = collect_jit(carries[s], views[s],
                                                 chunk_iters)
                 dt = time.perf_counter() - t_d
-                _prog_collect.count_dispatch()
                 h_collect_disp[s].observe(dt)
                 collect_dispatch_s_total += dt
                 hb_collects[s].beat()
@@ -1499,13 +1471,8 @@ def run_host_replay(cfg: ExperimentConfig, total_env_steps: int,
         nonlocal carry
         if mesh_mode:
             return dispatch_collect(state)
-        if not _prog_collect.cost_attached:
-            _c, _p = carry, collect_params(state)
-            _prog_collect.attach_cost(
-                lambda: collect_jit.lower(_c, _p, chunk_iters))
         carry, r, st = collect_jit(carry, collect_params(state),
                                    chunk_iters)
-        _prog_collect.count_dispatch()
         return r, st
 
     # --profile-dir (ISSUE 19 satellite): same contract as the fused
@@ -1693,7 +1660,7 @@ def run_host_replay(cfg: ExperimentConfig, total_env_steps: int,
                             batch = assemble_tree(parts)
                             w = (assemble_tree(w_parts)
                                  if per_samplers is not None else weights)
-                            state, metrics = _train_dispatch(state, batch, w)
+                            state, metrics = train_jit(state, batch, w)
                             _wb_add(auxes, metrics)
                         for s, p in enumerate(prefetchers):
                             ev_sample_s += p.sample_s_total - s0[s][0]
@@ -1721,7 +1688,7 @@ def run_host_replay(cfg: ExperimentConfig, total_env_steps: int,
                             batch = assemble_tree(parts)
                             w = (assemble_tree(w_parts)
                                  if per_samplers is not None else weights)
-                            state, metrics = _train_dispatch(state, batch, w)
+                            state, metrics = train_jit(state, batch, w)
                             _wb_add(auxes, metrics)
                     did = grads_this_chunk
                     grad_steps += did
@@ -1757,7 +1724,7 @@ def run_host_replay(cfg: ExperimentConfig, total_env_steps: int,
                             dev, aux = prefetcher.pop(fence_gen)
                             ev_depth_sum += len(prefetcher)
                             batch, w = _unpack(dev)
-                            state, metrics = _train_dispatch(state, batch, w)
+                            state, metrics = train_jit(state, batch, w)
                             _wb_add(aux, metrics)
                         ev_sample_s = prefetcher.sample_s_total - s0[0]
                         ev_wait_s = prefetcher.wait_s_total - s0[1]
@@ -1776,7 +1743,7 @@ def run_host_replay(cfg: ExperimentConfig, total_env_steps: int,
                         for i in range(grads_this_chunk):
                             dev, aux = stager.pop()
                             batch, w = _unpack(dev)
-                            state, metrics = _train_dispatch(state, batch, w)
+                            state, metrics = train_jit(state, batch, w)
                             _wb_add(aux, metrics)
                             if i + 1 < grads_this_chunk:
                                 t_s = time.perf_counter()
@@ -1795,7 +1762,7 @@ def run_host_replay(cfg: ExperimentConfig, total_env_steps: int,
                         sample_k += 1
                         for i in range(grads_this_chunk):
                             batch, w = _unpack(dev)
-                            state, metrics = _train_dispatch(state, batch, w)
+                            state, metrics = train_jit(state, batch, w)
                             _wb_add(aux, metrics)
                             if i + 1 < grads_this_chunk:
                                 t_s = time.perf_counter()
@@ -1842,28 +1809,20 @@ def run_host_replay(cfg: ExperimentConfig, total_env_steps: int,
             t_stats = time.perf_counter()
             ep = float(cr) / max(float(cc), 1.0)
 
-            # Chip-time attribution (ISSUE 19), all from timestamps the
-            # loop already took. The train section (t_fence -> t_train)
-            # ends at a real fence (block_until_ready above), so minus
-            # its host-blocked share it is the chunk's measured train
-            # device time; `sample` only blocks when no prefetcher runs
-            # (with one, the blocking share is prefetch_wait).
+            # The chunk's wall by cause, all from timestamps the loop
+            # already took. The train section (t_fence -> t_train) ends
+            # at a real fence (block_until_ready above), so minus its
+            # host-blocked share it is the ledger's `busy`; `sample`
+            # only blocks when no prefetcher runs (with one, the
+            # blocking share is prefetch_wait).
             _prefetching = (prefetcher is not None
                             or prefetchers is not None)
             sample_blocked = 0.0 if _prefetching else ev_sample_s
             train_busy = max((t_train - t_fence) - sample_blocked
                              - ev_wait_s, 0.0)
-            if did:
-                _prog_train.add_device_seconds(train_busy)
-            if not pipeline and t_evac_parts is not None:
-                # Serial reference: the monolithic blocking fetch waits
-                # out the collect program — the one place its device
-                # time is fenced and attributable.
-                _prog_collect.add_device_seconds(t_evac_parts[0])
             chip = _ledger.observe_chunk(
                 t_stats - t0, train_busy, sample=sample_blocked,
                 evac_fence=fence_wait_s, prefetch_wait=ev_wait_s)
-            _devtime.set_learner_mfu("host_replay", reg=reg)
             _devtime.sweep_device_memory(reg)
 
             row = {
@@ -2056,10 +2015,7 @@ def run_host_replay(cfg: ExperimentConfig, total_env_steps: int,
         "is_weight_mean": round(is_w_sum / is_w_count, 6)
         if is_w_count else 1.0,
         "is_weight_min": round(is_w_min, 6) if is_w_count else 1.0,
-        # Chip-time attribution (ISSUE 19): cumulative ledger buckets
-        # and the per-program registry rows this run produced — what
-        # scaling_bench re-emits as its `programs` block.
+        # The ledger's cumulative buckets (telemetry/devtime.py).
         "chip_time": _ledger.snapshot(),
-        "programs": _devtime.programs_snapshot("host_replay"),
         "history": history,
     }

@@ -58,8 +58,8 @@ def _mesh_wrap(mesh: Mesh, specs, init_local, run_local):
     """Lift per-device (init, run_chunk) bodies to jit-compiled functions on
     GLOBAL arrays; the carry is donated so replay shards update in place in
     each device's HBM."""
-    # donation: PRNG-key-only init (run() donates the carry); devtime:
-    # one-shot, not hot-path. mesh-axis: dp specs via _carry_specs.
+    # donation: PRNG-key-only init (run() donates the carry).
+    # mesh-axis: dp specs via _carry_specs.
     init = jax.jit(
         jax.shard_map(init_local, mesh=mesh, in_specs=P(),
                          out_specs=specs, check_vma=False))
@@ -90,9 +90,8 @@ def _mesh_wrap(mesh: Mesh, specs, init_local, run_local):
         c_chunks.inc()
         return out
 
-    # The chip-time census (train.py attach_cost) lowers the program it
-    # dispatches; without this the wrapper has no ``lower`` and the mesh
-    # chunk's census silently stays empty.
+    # train.py compiles the chunk program ahead of its first dispatch
+    # (_compile_chunk) through the callable it is handed.
     run_instrumented.lower = run.lower
     return init, run_instrumented
 
@@ -199,8 +198,6 @@ def make_sharded_train_step(train_step, mesh: Mesh, data_specs,
             out_specs=(state_spec, metric_specs), check_vma=False)
         return body(state, *data)
 
-    # devtime: registered by the callers that own the dispatch fence —
-    # apex service `_attach_train_cost` / host-replay `_train_dispatch`.
     return jax.jit(sharded, donate_argnums=0)
 
 

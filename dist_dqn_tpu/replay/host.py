@@ -325,15 +325,6 @@ class DevicePrioritySampler:
             tm.REPLAY_DEVICE_WRITEBACK_ROWS,
             "priority rows scattered into the shard's device plane "
             "(post last-write-wins dedup, pre pow2 padding)", labels)
-        # Chip-time attribution (ISSUE 19): the fused write-back+draw is
-        # the shard's sampler program. ONE record shared by all shards
-        # (equal planes -> equal per-exec cost; dispatches and
-        # device-seconds sum across them), measured at the
-        # dispatch->materialize fence the caller already holds — no new
-        # syncs. Cost attaches lazily at the first fused dispatch.
-        from dist_dqn_tpu.telemetry import devtime as _devtime
-        self._prog_draw = _devtime.register_program(
-            "sampler.draw_writeback", loop="sampler", role="sample")
 
         blk = self._blk
 
@@ -490,17 +481,12 @@ class DevicePrioritySampler:
         dispatch-budget pin's unit of accounting."""
         self._fire_draw_seam()
         self.draw_dispatches += 1
-        self._prog_draw.count_dispatch()
         u = np.asarray(u, np.float32)
         w = self._prep_writes()
         t0 = time.perf_counter()
         if w is None:
             return (t0, self._draw_at_jit(self._plane, self._blk_sums,
                                           u))
-        if not self._prog_draw.cost_attached:
-            self._prog_draw.attach_cost(
-                lambda: self._apply_draw_at.lower(
-                    self._plane, self._blk_sums, *w, u))
         (self._plane, self._blk_sums, idx,
          mass) = self._apply_draw_at(self._plane, self._blk_sums, *w, u)
         return (t0, (idx, mass))
@@ -520,7 +506,6 @@ class DevicePrioritySampler:
             mass = np.where(bad, 0.0, mass)
         dt = time.perf_counter() - t0
         self._h_sample.observe(dt)
-        self._prog_draw.add_device_seconds(dt)
         from dist_dqn_tpu import chaos
         chaos.mark_recovered("replay.device_sample")
         return idx, mass
@@ -535,7 +520,6 @@ class DevicePrioritySampler:
         """-> (flat slot indices [S], IS weights [S])."""
         self._fire_draw_seam()
         self.draw_dispatches += 1
-        self._prog_draw.count_dispatch()
         pend = self._prep_writes()
         t0 = time.perf_counter()
         self._rng, k = self.jax.random.split(self._rng)
@@ -544,12 +528,6 @@ class DevicePrioritySampler:
                                 batch_size, np.float32(beta),
                                 np.float32(size))
         else:
-            if not self._prog_draw.cost_attached:
-                self._prog_draw.attach_cost(
-                    lambda: self._apply_draw.lower(
-                        self._plane, self._blk_sums, *pend, k,
-                        batch_size, np.float32(beta),
-                        np.float32(size)))
             (self._plane, self._blk_sums, idx,
              w) = self._apply_draw(self._plane, self._blk_sums, *pend,
                                    k, batch_size, np.float32(beta),
@@ -558,7 +536,6 @@ class DevicePrioritySampler:
         w = np.asarray(w, np.float32)
         dt = time.perf_counter() - t0
         self._h_sample.observe(dt)
-        self._prog_draw.add_device_seconds(dt)
         # A draw can land past the written region only through fp boundary
         # pathology on a zero-mass cell. Clamping alone would pair slot
         # size-1 with the OUT-OF-RANGE cell's IS weight; zero the weight

@@ -40,7 +40,6 @@ from dist_dqn_tpu.serving.router import Router
 from dist_dqn_tpu.serving.types import (ActResult, QueueFullError,
                                         ServerClosedError, ServingError)
 from dist_dqn_tpu.telemetry import collectors as tmc
-from dist_dqn_tpu.telemetry import devtime as _devtime
 from dist_dqn_tpu.telemetry import get_registry
 from dist_dqn_tpu.telemetry import watchdog as tm_watchdog
 
@@ -197,14 +196,6 @@ class MicroBatcher:
             buckets=tmc.FANIN_BUCKETS)
         self._tm_dispatches = reg.counter(
             tmc.SERVING_DISPATCHES, "act programs dispatched")
-        # Chip-time attribution (ISSUE 19): the coalesced act dispatch
-        # is the serving tier's device program; the np.asarray fence in
-        # _dispatch_inner is one the path already holds, so the
-        # device-seconds sample costs no new sync. Cost attaches at the
-        # first live dispatch — the first-seen pow2 bucket's census
-        # (all buckets share this record).
-        self._prog_act = _devtime.register_program(
-            "serving.act", loop="serving", role="act")
         if slo is not None:
             slo.attach_queue_depth(self.queue_depth)
         self._thread: Optional[threading.Thread] = None
@@ -487,11 +478,6 @@ class MicroBatcher:
             obs_cat, eps, rows, total = pack_act_rows(
                 [p.obs for p in batch], [p.epsilon for p in batch])
             self._rng, k = self._jax.random.split(self._rng)
-            if not self._prog_act.cost_attached:
-                self._prog_act.attach_cost(
-                    lambda: self.act_fn.lower(
-                        snap.params, jnp.asarray(obs_cat), k,
-                        jnp.asarray(eps)))
             actions = self.act_fn(snap.params, jnp.asarray(obs_cat), k,
                                   jnp.asarray(eps))
             acts_np = np.asarray(actions, np.int32)
@@ -504,8 +490,6 @@ class MicroBatcher:
         # failed one (the chaos recovery metric's serving anchor).
         chaos.mark_recovered("serving.dispatch")
         self._tm_dispatches.inc()
-        self._prog_act.count_dispatch()
-        self._prog_act.add_device_seconds(time.perf_counter() - t0)
         # Counted at DISPATCH, not admission: docs derive the mean
         # request fan-in as requests_total / dispatches_total, so a
         # request shed at admission or withdrawn by a client timeout

@@ -24,15 +24,9 @@ watchdog heartbeat (no-op twin until ``install_watchdog`` arms it), and
 sentinel — see telemetry/flight.py and telemetry/watchdog.py.
 """
 from dist_dqn_tpu.telemetry.devtime import (IDLE_CAUSES,  # noqa: F401
-                                            ProgramRecord, ProgramRegistry,
                                             UtilizationLedger,
                                             capture_profile,
-                                            get_program_registry,
                                             maybe_trace_first_chunk,
-                                            programs_snapshot,
-                                            register_program,
-                                            reset_program_registry,
-                                            set_learner_mfu,
                                             sweep_device_memory)
 from dist_dqn_tpu.telemetry.exposition import (CONTENT_TYPE,  # noqa: F401
                                                render_prometheus, snapshot,

@@ -90,32 +90,22 @@ HOST_REPLAY_PRIO_WB_DROPPED = \
 
 # Learner-utilization engine (ISSUE 6): the replay-ratio / batch-width
 # / actor-dtype configuration that produced a process's learner
-# throughput, plus the achieved rate and (where a chip peak is known,
-# bench.py) MFU. Config gauges are labeled {loop=...} like the
-# host-replay families; ACTOR_DTYPE_INFO is a Prometheus info-style
-# gauge — constant 1 with the dtype in the {dtype=...} label.
+# throughput, plus the achieved rate. Config gauges are labeled
+# {loop=...} like the host-replay families; ACTOR_DTYPE_INFO is a
+# Prometheus info-style gauge — constant 1 with the dtype in the
+# {dtype=...} label.
 LEARNER_REPLAY_RATIO = "dqn_learner_replay_ratio"
 LEARNER_TRAIN_BATCH = "dqn_learner_train_batch_size"
 LEARNER_ACTOR_DTYPE_INFO = "dqn_learner_actor_dtype_info"
 LEARNER_GRAD_RATE = "dqn_learner_grad_steps_per_sec"
-LEARNER_MFU = "dqn_learner_mfu"
 
-# Chip-time attribution plane (ISSUE 19): the per-program device-time
-# ledger (telemetry/devtime.py). PROGRAM_* are labeled {program, loop}:
-# FLOPS/BYTES are the XLA cost-analysis totals for ONE execution of the
-# compiled program (a lax.scan body is counted once regardless of trip
-# count); DISPATCHES counts host-side launches; DEVICE_SECONDS
-# accumulates device time sampled at fences the loops already hold —
-# an attribution, not a hardware counter. CHIP_IDLE/CHIP_BUSY decompose
-# chunk wall-time per {loop}: idle is labeled by {cause} from the fixed
-# vocabulary sample|evac_fence|prefetch_wait|h2d|other. DEVICE_MEMORY
+# telemetry/devtime.py: CHIP_IDLE/CHIP_BUSY split a host runtime's
+# chunk wall per {loop} (host walls, not device time), idle labeled by
+# {cause} from the fixed vocabulary
+# sample|evac_fence|prefetch_wait|h2d|other; DEVICE_MEMORY
 # mirrors Device.memory_stats() per {kind, device} (absent on backends
 # that report nothing, e.g. CPU); kind="peak_bytes_in_use_seen" is a
 # host-tracked high-water mark for backends whose native peak resets.
-PROGRAM_FLOPS = "dqn_program_flops"
-PROGRAM_BYTES = "dqn_program_bytes"
-PROGRAM_DISPATCHES = "dqn_program_dispatches_total"
-PROGRAM_DEVICE_SECONDS = "dqn_program_device_seconds_total"
 CHIP_IDLE_SECONDS = "dqn_chip_idle_seconds_total"
 CHIP_BUSY_SECONDS = "dqn_chip_busy_seconds_total"
 DEVICE_MEMORY_BYTES = "dqn_device_memory_bytes"

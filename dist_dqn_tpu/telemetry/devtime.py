@@ -1,43 +1,31 @@
-"""Chip-time attribution plane (ISSUE 19): per-program device-time
-ledger, utilization decomposition, on-demand profiling, HBM telemetry.
+"""What the host can say about the chip without reading a trace: where a
+host runtime's chunk wall went, the allocator's counters, and a profile on
+demand.
 
-Nothing else in the repo can say how busy the chip is or *why* it is
-idle: ``dqn_learner_mfu`` was hand-wired per runtime and no metric
-attributed chunk wall-time to device-busy vs host-blocked causes. This
-module is the shared substrate:
-
-``ProgramRegistry``
-    Process-wide table of every jitted entry point (fused chunk,
-    collect, train/scan-train step, act dispatch, sampler draw,
-    evac split). Each :class:`ProgramRecord` carries FLOPs/bytes from
-    the XLA cost analysis (``utils/flops.py``), dispatch counts, and
-    device-seconds sampled at fences the loops ALREADY hold — no new
-    synchronization on the hot path. Cost is harvested lazily via
-    ``jitted.lower(*args)`` at the first dispatch site: its own
-    ``cost_analysis()`` on the CPU (trace-only), the compiled
-    executable's on a TPU (``_cost_from``; the dispatch reuses that
-    executable, so no program is compiled twice).
+How busy the chip is, and on what, is read from a device trace through the
+program's own names (``telemetry/stages.py``, the ``fused.*`` spans) —
+``perf/`` reduces one, ``--profile-dir`` / ``/debug/profile`` write one.
+Nothing here prices a program's FLOPs or calls a host wall device time.
 
 ``UtilizationLedger``
-    Decomposes each chunk's wall-time into device-busy plus the named
-    host-blocked buckets ``sample | evac_fence | prefetch_wait | h2d |
-    other`` and feeds the ``dqn_chip_idle_seconds_total{cause}`` /
-    ``dqn_chip_busy_seconds_total`` families.
+    For the host runtimes (host_replay_loop.py, actors/service.py), whose
+    loops really wait at named seams: each chunk's wall split into the
+    train section's wall (``busy``) and the stalls ``sample | evac_fence |
+    prefetch_wait | h2d | other``, fed to
+    ``dqn_chip_busy_seconds_total{loop}`` /
+    ``dqn_chip_idle_seconds_total{loop, cause}``. All host walls.
 
-``set_learner_mfu``
-    The registry-derived replacement for the per-loop MFU hand-wirings:
-    FLOPs-per-exec x executions / device-seconds over the chip's bf16
-    peak.
+``sweep_device_memory``
+    ``Device.memory_stats()`` -> ``dqn_device_memory_bytes{kind, device}``
+    gauges with a host-tracked peak; every loop calls it once a chunk.
 
-``sweep_device_memory`` / ``capture_profile``
-    ``Device.memory_stats()`` -> ``dqn_device_memory_bytes{kind,device}``
-    gauges with host-tracked peak, and the ``/debug/profile?seconds=N``
-    backend (jax.profiler trace into the forensics dir).
+``capture_profile`` / ``maybe_trace_first_chunk``
+    The ``/debug/profile?seconds=N`` backend (a jax.profiler trace into
+    the forensics dir) and the host runtimes' ``--profile-dir`` one-shot.
 
-Everything degrades on CPU: cost analysis that fails leaves FLOPs
-``None`` (gauges absent, never a crash), ``memory_stats() is None``
-sweeps to nothing, and jax itself is imported lazily so the module
-stays importable from jax-free actor processes.
+``memory_stats() is None`` (the CPU) sweeps to nothing, and jax itself is
+imported lazily so the module stays importable from jax-free actor
+processes.
 """
 from __future__ import annotations
 
@@ -45,11 +33,10 @@ import os
 import tempfile
 import threading
 import time
-from typing import Any, Callable, Dict, Optional
+from typing import Any, Dict, Optional
 
 from dist_dqn_tpu.telemetry import collectors as tmc
 from dist_dqn_tpu.telemetry.registry import Registry, get_registry
-from dist_dqn_tpu.utils import flops as flops_util
 
 #: Fixed idle-cause vocabulary for dqn_chip_idle_seconds_total. Keep in
 #: lockstep with the docs/observability.md naming table.
@@ -58,253 +45,6 @@ IDLE_CAUSES = ("sample", "evac_fence", "prefetch_wait", "h2d", "other")
 #: Hard ceiling on one /debug/profile capture; xprof windows past this
 #: are better taken as several correlated short ones.
 PROFILE_MAX_SECONDS = 60.0
-
-
-def _cost_from(obj: Any) -> Dict[str, Optional[float]]:
-    """FLOPs/bytes for one execution of ``obj`` — a Compiled, a Lowered,
-    or a zero-arg callable returning either. Any failure (CPU backends
-    without a cost model, interpreter mode, tracing errors) degrades to
-    ``{"flops": None, "bytes": None}``.
-
-    A Lowered has a cost analysis on the CPU backend only; for a TPU it
-    returns None and the census needs the compiled executable. Compiling
-    the Lowered here is the compilation the first dispatch would do:
-    the jit call that follows reuses the executable from JAX's
-    in-memory cache (observed on the chip: a cold first chunk of 0.18 s
-    with no persistent-cache hit), so the compile moves ahead of the
-    first dispatch, it is not paid twice."""
-    try:
-        if callable(obj) and not hasattr(obj, "cost_analysis"):
-            obj = obj()
-        flops = flops_util.compiled_flops(obj)
-        nbytes = flops_util.compiled_bytes(obj)
-        if flops is None and nbytes is None and hasattr(obj, "compile"):
-            obj = obj.compile()
-            flops = flops_util.compiled_flops(obj)
-            nbytes = flops_util.compiled_bytes(obj)
-    except Exception:
-        flops = nbytes = None
-    return {"flops": flops, "bytes": nbytes}
-
-
-class ProgramRecord:
-    """One jitted entry point: static cost + running dispatch tallies.
-
-    ``flops``/``bytes`` are for ONE execution of the compiled program.
-    Caveat inherited from the XLA cost census: a ``lax.scan`` body is
-    counted once regardless of trip count, so scan-shaped programs
-    should register with ``execs_per_dispatch`` = trip count to keep
-    FLOPs x executions honest.
-    """
-
-    def __init__(self, registry: "ProgramRegistry", name: str, loop: str,
-                 role: Optional[str], execs_per_dispatch: float):
-        self._registry = registry
-        self.name = name
-        self.loop = loop
-        self.role = role
-        self.execs_per_dispatch = float(execs_per_dispatch)
-        self.flops: Optional[float] = None
-        self.bytes: Optional[float] = None
-        self._cost_done = False
-        self._lock = threading.Lock()
-        labels = {"program": name, "loop": loop}
-        reg = registry.metrics
-        self._g_flops = reg.gauge(
-            tmc.PROGRAM_FLOPS, "FLOPs per execution (XLA cost analysis)",
-            labels)
-        self._g_bytes = reg.gauge(
-            tmc.PROGRAM_BYTES, "bytes accessed per execution", labels)
-        self._c_dispatch = reg.counter(
-            tmc.PROGRAM_DISPATCHES, "host-side launches", labels)
-        self._c_devsec = reg.counter(
-            tmc.PROGRAM_DEVICE_SECONDS,
-            "device time attributed at existing fences", labels)
-        self.dispatches = 0.0
-        self.device_seconds = 0.0
-
-    def attach_cost(self, source: Any) -> "ProgramRecord":
-        """Harvest FLOPs/bytes once from ``source`` (Compiled / Lowered /
-        zero-arg callable returning either). Idempotent: the first
-        successful harvest wins; repeat calls and failures are free, so
-        dispatch sites can call this unconditionally."""
-        with self._lock:
-            if self._cost_done:
-                return self
-            cost = _cost_from(source)
-            if cost["flops"] is None and cost["bytes"] is None:
-                # Leave _cost_done False only for *callables* that may
-                # succeed later? No: retrying a failing trace every
-                # dispatch is hot-path work. One shot, like the fences.
-                self._cost_done = True
-                return self
-            self.flops, self.bytes = cost["flops"], cost["bytes"]
-            self._cost_done = True
-        if self.flops is not None:
-            self._g_flops.set(self.flops)
-        if self.bytes is not None:
-            self._g_bytes.set(self.bytes)
-        return self
-
-    @property
-    def cost_attached(self) -> bool:
-        return self._cost_done
-
-    def count_dispatch(self, n: float = 1.0) -> None:
-        self.dispatches += n
-        self._c_dispatch.inc(n)
-
-    def add_device_seconds(self, seconds: float) -> None:
-        if seconds <= 0:
-            return
-        self.device_seconds += seconds
-        self._c_devsec.inc(seconds)
-
-    @property
-    def executions(self) -> float:
-        return self.dispatches * self.execs_per_dispatch
-
-    @property
-    def arith_intensity(self) -> Optional[float]:
-        if self.flops is None or not self.bytes:
-            return None
-        return self.flops / self.bytes
-
-    def snapshot(self) -> Dict[str, Any]:
-        out: Dict[str, Any] = {
-            "program": self.name,
-            "loop": self.loop,
-            "flops": self.flops,
-            "bytes": self.bytes,
-            "dispatches": self.dispatches,
-            "execs_per_dispatch": self.execs_per_dispatch,
-            "device_seconds": self.device_seconds,
-        }
-        ai = self.arith_intensity
-        if ai is not None:
-            out["arith_intensity"] = ai
-        return out
-
-
-class ProgramRegistry:
-    """Process-wide (name, loop) -> :class:`ProgramRecord` table."""
-
-    def __init__(self, metrics: Optional[Registry] = None):
-        self.metrics = metrics if metrics is not None else get_registry()
-        self._records: Dict[tuple, ProgramRecord] = {}
-        self._lock = threading.RLock()
-
-    def register(self, name: str, loop: str = "default",
-                 cost: Any = None, role: Optional[str] = None,
-                 execs_per_dispatch: float = 1.0) -> ProgramRecord:
-        """Get-or-create the record for ``(name, loop)``. ``cost`` (a
-        Compiled/Lowered/zero-arg callable) is attached immediately when
-        given; dispatch sites that only have real args later can call
-        ``record.attach_cost`` themselves."""
-        key = (name, loop)
-        with self._lock:
-            rec = self._records.get(key)
-            if rec is None:
-                rec = ProgramRecord(self, name, loop, role,
-                                    execs_per_dispatch)
-                self._records[key] = rec
-            elif role is not None and rec.role is None:
-                rec.role = role
-        if cost is not None:
-            rec.attach_cost(cost)
-        return rec
-
-    def records(self, loop: Optional[str] = None):
-        with self._lock:
-            recs = list(self._records.values())
-        if loop is not None:
-            recs = [r for r in recs if r.loop == loop]
-        return recs
-
-    def get(self, name: str, loop: str = "default"):
-        with self._lock:
-            return self._records.get((name, loop))
-
-    def snapshot(self, loop: Optional[str] = None) -> Dict[str, Dict]:
-        """JSON-able {program: fields} block for BENCH rows."""
-        return {r.name: r.snapshot() for r in self.records(loop)}
-
-    def learner_mfu(self, loop: str,
-                    device: Any = None) -> Optional[float]:
-        """Registry-derived MFU for ``loop``: summed FLOPs x executions
-        over summed device-seconds of every record tagged role="train",
-        against the chip's bf16 peak. None when no train program has
-        both cost and device time, or the chip peak is unknown (CPU)."""
-        if device is None:
-            device = _default_device()
-        if device is None:
-            return None
-        peak = flops_util.chip_peak_flops(device)
-        if not peak:
-            return None
-        total_flops = 0.0
-        total_secs = 0.0
-        for rec in self.records(loop):
-            if rec.role != "train" or rec.flops is None:
-                continue
-            total_flops += rec.flops * rec.executions
-            total_secs += rec.device_seconds
-        if total_secs <= 0 or total_flops <= 0:
-            return None
-        return (total_flops / total_secs) / peak
-
-
-_program_registry = ProgramRegistry()
-
-
-def get_program_registry() -> ProgramRegistry:
-    """The process-global program registry (what every loop uses)."""
-    return _program_registry
-
-
-def reset_program_registry(metrics: Optional[Registry] = None
-                           ) -> ProgramRegistry:
-    """Swap in a fresh registry (tests / multi-leg benchmarks that want
-    per-leg dispatch tallies). Returns the new instance."""
-    global _program_registry
-    _program_registry = ProgramRegistry(metrics)
-    return _program_registry
-
-
-def register_program(name: str, loop: str = "default", cost: Any = None,
-                     role: Optional[str] = None,
-                     execs_per_dispatch: float = 1.0) -> ProgramRecord:
-    """Module-level convenience for the common dispatch-site idiom."""
-    return _program_registry.register(
-        name, loop=loop, cost=cost, role=role,
-        execs_per_dispatch=execs_per_dispatch)
-
-
-def programs_snapshot(loop: Optional[str] = None) -> Dict[str, Dict]:
-    return _program_registry.snapshot(loop)
-
-
-def _default_device():
-    try:
-        import jax
-        return jax.devices()[0]
-    except Exception:
-        return None
-
-
-def set_learner_mfu(loop: str, device: Any = None,
-                    reg: Optional[Registry] = None) -> Optional[float]:
-    """Publish the registry-derived ``dqn_learner_mfu{loop=...}`` gauge.
-    No-op (gauge absent) when the MFU is underivable — unknown chip
-    peak, no cost analysis, no device time yet."""
-    value = _program_registry.learner_mfu(loop, device=device)
-    if value is None:
-        return None
-    if reg is None:
-        reg = get_registry()
-    reg.gauge(tmc.LEARNER_MFU, "model FLOPs utilization vs chip peak "
-              "(registry-derived)", {"loop": loop}).set(value)
-    return value
 
 
 class UtilizationLedger:

@@ -2,7 +2,7 @@
 
 ``render_prometheus`` turns a Registry into the plain-text format every
 Prometheus-compatible scraper parses; ``snapshot`` is the JSON twin for
-offline runs (bench.py's BENCH JSON, the atexit dump). Stdlib only.
+offline runs (the atexit dump). Stdlib only.
 """
 from __future__ import annotations
 
@@ -75,7 +75,7 @@ def render_prometheus(registry: Optional[Registry] = None) -> str:
 
 def snapshot(registry: Optional[Registry] = None) -> Dict:
     """JSON-able snapshot of every instrument (the offline-run twin of
-    the /metrics endpoint; embedded in bench.py's BENCH JSON)."""
+    the /metrics endpoint)."""
     registry = registry if registry is not None else get_registry()
     return registry.snapshot()
 

@@ -4,8 +4,7 @@ ISSUE 4 satellite: BENCH JSON rows, train-CLI log streams and forensics
 bundles all need the same provenance record — git sha, library versions,
 platform, the exact config (and a short hash of it), argv and a schema
 version — so a number found in a file three weeks later self-describes
-how it was produced. One builder here, reused by ``bench.py``
-(``manifest`` block in the contract line extras), ``train.py`` (one
+how it was produced. One builder here, reused by ``train.py`` (one
 ``{"manifest": ...}`` log line at startup), ``/debug/config``
 (telemetry/server.py) and every forensics bundle
 (telemetry/watchdog.py).

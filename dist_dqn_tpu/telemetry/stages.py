@@ -22,8 +22,8 @@ never sees a child, so a child takes no time away from its stage.
 
 A device trace names each op by its HLO instruction (``fusion.584``), so the
 join back to a stage goes through the executable that actually ran:
-``train.train`` hands :func:`keep` the executable it compiles for
-``attach_cost`` and dispatches, and :func:`table` — called by a reader
+``train.train`` hands :func:`keep` the executable it compiles ahead of the
+first dispatch (``_compile_chunk``), and :func:`table` — called by a reader
 AFTER a traced run, never by the trainer — takes that executable's text and
 maps every instruction to its stage. Until somebody calls it nothing is
 printed, walked or written.

@@ -43,6 +43,21 @@ def _pick_mesh_devices(num_devices: int, multiprocess: bool):
     return devs[:num_devices]
 
 
+def _compile_chunk(run, *args) -> None:
+    """The chunk program's ONE compilation, ahead of its first dispatch
+    (which reuses the executable from JAX's in-memory cache, so the first
+    chunk's wall and rate hold no compile), with its stage names in the
+    persistent-cache key so that a cache from before a name existed cannot
+    serve it. The stage table (telemetry/stages.py) is built from this
+    executable on demand, after the run; here only a reference is kept."""
+    from dist_dqn_tpu.telemetry import stages
+    from dist_dqn_tpu.utils import backend
+
+    with backend.names_in_cache_key():
+        compiled = run.lower(*args).compile()
+    stages.keep(compiled)
+
+
 def train(cfg: ExperimentConfig, total_env_steps: int = 0, seed: int = None,
           chunk_iters: int = 2000, log_fn=print,
           checkpoint_dir: str = None, save_every_frames: int = 0,
@@ -233,17 +248,6 @@ def train(cfg: ExperimentConfig, total_env_steps: int = 0, seed: int = None,
                    "layer", {"kind": kind}).set(rows)
     evaluate = jax.jit(make_evaluator(cfg, env, net,
                                       num_episodes=cfg.eval_episodes))
-    # Chip-time attribution (ISSUE 19): the fused chunk is ONE program —
-    # acting, replay and the grad scan fused into a single dispatch — so
-    # it registers with role="train" and execs_per_dispatch=1 (the XLA
-    # cost census already spans the whole chunk body, scan-once caveat
-    # noted in telemetry/devtime.py). Cost is harvested before the first
-    # dispatch below from the executable train compiles there
-    # (_compile_chunk); the dispatch reuses it — one compile either way.
-    _prog_chunk = telemetry.register_program(
-        "fused.chunk", loop="fused", role="train")
-    _ledger = telemetry.UtilizationLedger("fused", _reg)
-
     # Eval-path choice, decided once: multi-process runs eval only on the
     # logging process, from the host copy of the replicated params (the
     # eval program is process-local).
@@ -348,7 +352,6 @@ def train(cfg: ExperimentConfig, total_env_steps: int = 0, seed: int = None,
     # eval_every_steps); otherwise the first chunk gets a baseline eval.
     next_eval = frames if cfg.eval_every_steps else float("inf")
     chunk_index = 0
-    _t_prev_fence = None  # previous chunk's fence, for the ledger wall
     # --profile-dir traces a STEADY chunk: the first one after a chunk that
     # reported the full cadence's grad steps (past min_fill, every train
     # event taken), so the trace shows the stages and spans as they repeat.
@@ -359,33 +362,16 @@ def train(cfg: ExperimentConfig, total_env_steps: int = 0, seed: int = None,
     # --trace-path, the Chrome trace + dqn_host_span_seconds); each span is
     # also a profiler TraceAnnotation, so in any device trace a gap between
     # two chunk programs lies under the host span that caused it.
-    from dist_dqn_tpu.telemetry import stages as _stages
-    from dist_dqn_tpu.utils import backend
     from dist_dqn_tpu.utils.trace import make_tracer
     tracer = make_tracer(trace_path, process_name="fused-learner")
 
-    def _compile_chunk():
-        # The chunk program's ONE compilation, ahead of its first dispatch
-        # (which reuses the executable from JAX's in-memory cache), with
-        # its stage names in the persistent-cache key so that a cache from
-        # before a name existed cannot serve it. The stage table
-        # (telemetry/stages.py) is built from this executable on demand,
-        # after the run; here only a reference is kept.
-        with backend.names_in_cache_key():
-            compiled = run.lower(carry, chunk_iters).compile()
-        _stages.keep(compiled)
-        return compiled
-
     try:
+        if frames < total:
+            _compile_chunk(run, carry, chunk_iters)
         while frames < total:
             profiling = profile_dir is not None and steady and not profiled
             if profiling:
                 jax.profiler.start_trace(profile_dir)
-            if not _prog_chunk.cost_attached:
-                # Compiled against the live args; the dispatch below
-                # reuses the executable, so the first chunk's wall and
-                # rate hold no compile.
-                _prog_chunk.attach_cost(_compile_chunk)
             with jax.profiler.StepTraceAnnotation("fused.chunk",
                                                   step_num=chunk_index):
                 t0 = time.perf_counter()
@@ -397,11 +383,6 @@ def train(cfg: ExperimentConfig, total_env_steps: int = 0, seed: int = None,
                 dt = time.perf_counter() - t0
             # Everything from the fence to the next dispatch.
             with tracer.span("fused.bookkeeping"):
-                _prog_chunk.count_dispatch()
-                # The device_get above IS the chunk fence: dt bounds
-                # the program's device time (one fused program fills
-                # the chunk).
-                _prog_chunk.add_device_seconds(dt)
                 chunk_index += 1
                 prev_frames = frames
                 frames = frame_offset + int(metrics["env_frames"])
@@ -437,17 +418,6 @@ def train(cfg: ExperimentConfig, total_env_steps: int = 0, seed: int = None,
                 # host-replay runtimes observe per sampled record.
                 _lineage.on_chunk(_tm["grad_steps"].value,
                                   max(1, ring_slots // chunk_iters))
-                # Utilization ledger (ISSUE 19): the fused loop's wall is
-                # the dispatch-to-fence dt (device busy, one program) plus
-                # whatever host bookkeeping separated it from the previous
-                # fence — no sample/evac/prefetch seams here, so the host
-                # share lands in the derived `other` bucket.
-                _t_now = time.perf_counter()
-                _ledger.observe_chunk(
-                    _t_now - (_t_prev_fence if _t_prev_fence is not None
-                              else t0), dt)
-                _t_prev_fence = _t_now
-                telemetry.set_learner_mfu("fused", reg=_reg)
                 telemetry.sweep_device_memory(_reg)
                 row = {
                     "env_frames": frames,
@@ -639,14 +609,6 @@ def _train_population(cfg: ExperimentConfig, total_env_steps: int = 0,
     run = jax.jit(run_population_chunk, static_argnums=2, donate_argnums=0)
     evaluate = jax.jit(jax.vmap(make_evaluator(
         cfg, env, net, num_episodes=cfg.eval_episodes)))
-    # Chip-time attribution (ISSUE 19): the population chunk is still
-    # ONE program — M members' acting, replay and grad scans fused into
-    # a single dispatch — so dqn_learner_mfu prices the whole
-    # population's FLOPs against the same chunk wall.
-    _prog_chunk = telemetry.register_program(
-        "population.chunk", loop="fused", role="train")
-    _ledger = telemetry.UtilizationLedger("fused", _reg)
-
     ckpt = None
     frame_offset = 0
     resumed_frames = 0
@@ -707,24 +669,20 @@ def _train_population(cfg: ExperimentConfig, total_env_steps: int = 0,
     frames = resumed_frames   # PER-MEMBER cursor (see docstring)
     next_eval = frames if cfg.eval_every_steps else float("inf")
     chunk_index = 0
-    _t_prev_fence = None
     profile_chunk = 1 if total > frames + chunk_iters * B else 0
     try:
+        if frames < total:
+            _compile_chunk(run, carries, hp, chunk_iters)
         while frames < total:
             profiling = (profile_dir is not None
                          and chunk_index == profile_chunk)
             if profiling:
                 jax.profiler.start_trace(profile_dir)
-            if not _prog_chunk.cost_attached:
-                _c, _hp, _ci = carries, hp, chunk_iters
-                _prog_chunk.attach_cost(lambda: run.lower(_c, _hp, _ci))
             t0 = time.perf_counter()
             carries, metrics = run(carries, hp, chunk_iters)
             # Every metric leaf is [M]; fetch once, fence the chunk.
             metrics = jax.tree.map(np.asarray, jax.device_get(metrics))
             dt = time.perf_counter() - t0
-            _prog_chunk.count_dispatch()
-            _prog_chunk.add_device_seconds(dt)
             if profiling:
                 jax.profiler.stop_trace()
                 log_fn(json.dumps({"profile_trace": profile_dir}))
@@ -760,12 +718,6 @@ def _train_population(cfg: ExperimentConfig, total_env_steps: int = 0,
             if np.any(ep_members):
                 _tm["ep_return"].set(float(np.mean(
                     metrics["episode_return"][ep_members])))
-            _t_now = time.perf_counter()
-            _ledger.observe_chunk(
-                _t_now - (_t_prev_fence if _t_prev_fence is not None
-                          else t0), dt)
-            _t_prev_fence = _t_now
-            telemetry.set_learner_mfu("fused", reg=_reg)
             telemetry.sweep_device_memory(_reg)
             row = {
                 "env_frames": frames,

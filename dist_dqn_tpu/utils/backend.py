@@ -1,8 +1,8 @@
 """The accelerator this process runs on, decided in one place.
 
-Every entry point (train, evaluate, serving, atari57, bench.py,
-chip_smoke.py, the benchmark scripts) goes through here for the three
-things that depend on the machine rather than on the config:
+Every entry point (train, evaluate, serving, atari57, chip_smoke.py,
+the benchmark scripts) goes through here for the three things that depend
+on the machine rather than on the config:
 
 * where JAX keeps its persistent compilation cache, and which programs'
   cache keys hold their stage names;

@@ -2,7 +2,7 @@
 
 env-steps/sec/chip and learner grad-steps/sec are the framework's north-star
 numbers, so they get a dedicated, dependency-free implementation used by the
-train CLI, the Ape-X runtime and bench.py alike.
+train CLI and the Ape-X runtime alike.
 
 Since ISSUE 1 the logger is a registry client: every flush mirrors the
 rates and extras into the process telemetry registry (telemetry/), so the

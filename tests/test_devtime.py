@@ -1,57 +1,27 @@
-"""Chip-time attribution plane (ISSUE 19): unit + integration coverage.
+"""telemetry/devtime.py: unit + integration coverage.
 
-Four layers:
-
-  * ProgramRegistry/ProgramRecord — get-or-create identity, one-shot
-    cost attachment (failures degrade to flops=None, never retrying on
-    the hot path), snapshot shape, registry-derived learner MFU (None
-    on chips without a known peak — the gauge must be ABSENT, not 0);
   * UtilizationLedger — busy + named causes + derived ``other`` residual
     conserve each chunk's wall, with clamping at the estimate edges;
   * sweep_device_memory — ``memory_stats()`` returning None, raising,
     or reporting partial/garbage dicts sweeps to exactly what was
     reported (gauges absent, never a crash) and the host-tracked peak
     is monotone;
-  * the chaos A/B the acceptance pins: an injected ``evac.drain`` stall
-    on a real host-replay run lands in the ledger's ``evac_fence``
-    bucket, the run's programs all show in its summary census, and the
+  * capture_profile — a zero-second window lands a loadable trace;
+  * the chaos A/B: an injected ``evac.drain`` stall on a real
+    host-replay run lands in the ledger's ``evac_fence`` bucket, and the
     per-cause totals conserve against the run wall.
 """
 from __future__ import annotations
 
 import dataclasses
 import os
-import types
 
 import pytest
 
 from dist_dqn_tpu.config import CONFIGS
-from dist_dqn_tpu.telemetry import collectors as tmc
 from dist_dqn_tpu.telemetry import devtime
 from dist_dqn_tpu.telemetry.exposition import render_prometheus
 from dist_dqn_tpu.telemetry.registry import Registry
-
-
-@pytest.fixture(autouse=True)
-def _fresh_program_registry():
-    """Tests below mutate the process-global registry (the loops use
-    it); leave a clean one behind either way."""
-    yield
-    devtime.reset_program_registry()
-
-
-class _Cost:
-    """A stand-in for jax.stages.Compiled: just the cost census."""
-
-    def __init__(self, flops=None, nbytes=None):
-        self._c = {}
-        if flops is not None:
-            self._c["flops"] = flops
-        if nbytes is not None:
-            self._c["bytes accessed"] = nbytes
-
-    def cost_analysis(self):
-        return self._c
 
 
 def _tiny_cfg():
@@ -66,105 +36,6 @@ def _tiny_cfg():
                                    prioritized=False),
         learner=dataclasses.replace(cfg.learner, batch_size=16),
     )
-
-
-# ---------------------------------------------------------------------------
-# ProgramRegistry / ProgramRecord
-# ---------------------------------------------------------------------------
-
-def test_register_is_get_or_create_and_snapshots():
-    reg = devtime.ProgramRegistry(metrics=Registry())
-    rec = reg.register("p", loop="l", cost=_Cost(100.0, 50.0),
-                       role="train")
-    assert reg.register("p", loop="l") is rec
-    assert reg.get("p", "l") is rec
-    assert reg.get("p", "other") is None
-    rec.count_dispatch(3)
-    rec.add_device_seconds(0.5)
-    snap = reg.snapshot("l")["p"]
-    assert snap["flops"] == 100.0 and snap["bytes"] == 50.0
-    assert snap["dispatches"] == 3.0
-    assert snap["device_seconds"] == pytest.approx(0.5)
-    assert snap["arith_intensity"] == pytest.approx(2.0)
-    assert reg.snapshot("other") == {}
-    # add_device_seconds ignores non-positive samples (clock skew at a
-    # fence must not walk the counter backwards).
-    rec.add_device_seconds(-1.0)
-    assert rec.device_seconds == pytest.approx(0.5)
-
-
-def test_attach_cost_is_one_shot_and_failures_degrade():
-    reg = devtime.ProgramRegistry(metrics=Registry())
-    rec = reg.register("p", loop="l")
-    assert not rec.cost_attached
-
-    def boom():
-        raise RuntimeError("no cost model on this backend")
-
-    rec.attach_cost(boom)
-    # A failed harvest still closes the one shot: the hot path must not
-    # retry a failing trace every dispatch.
-    assert rec.cost_attached and rec.flops is None and rec.bytes is None
-    rec.attach_cost(_Cost(1.0))
-    assert rec.flops is None
-    # Zero-arg callables returning a census are unwrapped; the first
-    # SUCCESSFUL harvest wins and later attaches are ignored.
-    rec2 = reg.register("q", loop="l", cost=lambda: _Cost(7.0, 2.0))
-    assert rec2.flops == 7.0
-    rec2.attach_cost(_Cost(999.0))
-    assert rec2.flops == 7.0
-
-
-def test_lowered_without_cost_analysis_is_compiled_for_the_census():
-    """On a TPU ``Lowered.cost_analysis()`` returns None — only the
-    compiled executable has a census there. A source that has none but
-    can be compiled is compiled, so the census is not silently absent
-    on the one platform it exists for."""
-    class _TpuLowered:
-        compiles = 0
-
-        def cost_analysis(self):
-            return None
-
-        def compile(self):
-            _TpuLowered.compiles += 1
-            return _Cost(11.0, 4.0)
-
-    reg = devtime.ProgramRegistry(metrics=Registry())
-    rec = reg.register("p", loop="l", cost=lambda: _TpuLowered())
-    assert (rec.flops, rec.bytes) == (11.0, 4.0)
-    assert _TpuLowered.compiles == 1
-
-
-def test_learner_mfu_registry_derived_and_absent_on_cpu():
-    metrics = Registry()
-    reg = devtime.reset_program_registry(metrics)
-    rec = reg.register("train", loop="l", cost=_Cost(1e12), role="train")
-    other = reg.register("act", loop="l", cost=_Cost(1e30), role="act")
-    other.count_dispatch(5)
-    other.add_device_seconds(3.0)
-    tpu = types.SimpleNamespace(device_kind="TPU v4")
-
-    # No device time on any role="train" record yet -> underivable, and
-    # set_learner_mfu must leave the gauge ABSENT (a 0 would read as a
-    # real 0% utilization on a dashboard).
-    assert devtime.set_learner_mfu("l", device=tpu, reg=metrics) is None
-    assert tmc.LEARNER_MFU not in render_prometheus(metrics)
-
-    rec.count_dispatch(10)
-    rec.add_device_seconds(1.0)
-    # Only the role="train" census counts: 1e12 FLOPs x 10 execs over
-    # 1 s against the v4 peak (275 TFLOP/s); the act program's absurd
-    # FLOPs must not leak into the numerator.
-    want = (1e12 * 10) / 1.0 / 275e12
-    assert reg.learner_mfu("l", device=tpu) == pytest.approx(want)
-    assert devtime.set_learner_mfu("l", device=tpu, reg=metrics) \
-        == pytest.approx(want)
-    assert tmc.LEARNER_MFU in render_prometheus(metrics)
-
-    # CPU (unknown chip peak) -> None, never a made-up denominator.
-    cpu = types.SimpleNamespace(device_kind="cpu")
-    assert reg.learner_mfu("l", device=cpu) is None
 
 
 # ---------------------------------------------------------------------------
@@ -256,19 +127,17 @@ def test_capture_profile_writes_loadable_trace(tmp_path):
 
 
 # ---------------------------------------------------------------------------
-# The acceptance A/B: chaos evac stall -> evac_fence, census complete
+# The acceptance A/B: chaos evac stall -> evac_fence
 # ---------------------------------------------------------------------------
 
 def test_host_replay_chaos_evac_stall_lands_in_evac_fence():
     """An injected ``evac.drain`` stall blocks the loop at the evac
     fence it already holds — the ledger must file that wait under
-    ``evac_fence`` (not ``other``), the run's summary census must name
-    both registered programs with dispatch counts, and the per-cause
-    totals must conserve against the run wall."""
+    ``evac_fence`` (not ``other``), and the per-cause totals must
+    conserve against the run wall."""
     from dist_dqn_tpu import chaos
     from dist_dqn_tpu.host_replay_loop import run_host_replay
 
-    devtime.reset_program_registry()
     plan = chaos.FaultPlan(seed=7, events=(
         chaos.FaultEvent("evac.drain", "stall", at_hit=2,
                          args={"delay_s": 0.8}),))
@@ -287,13 +156,4 @@ def test_host_replay_chaos_evac_stall_lands_in_evac_fence():
     total = chip["busy"] + sum(chip[c] for c in devtime.IDLE_CAUSES)
     assert 0.0 < total <= out["wall_s"] + 1e-6
     assert chip["busy"] <= total
-
-    progs = out["programs"]
-    assert set(progs) >= {"host_replay.collect",
-                          "host_replay.train_step"}
-    assert progs["host_replay.train_step"]["dispatches"] \
-        == out["grad_steps"]
-    # Train device-seconds were attributed at the existing fences and
-    # reconcile with the ledger's busy total exactly (same samples).
-    assert progs["host_replay.train_step"]["device_seconds"] \
-        == pytest.approx(chip["busy"])
+    assert "programs" not in out

@@ -161,11 +161,11 @@ def test_startup_grace_covers_the_first_compile_window():
     hb.close()
 
 
-def test_wedged_evacuation_worker_dumps_bundle_and_flips_healthz(tmp_path):
-    """Acceptance (ISSUE 4): a deliberately wedged EvacuationWorker
-    heartbeat produces a forensics bundle — stacks NAMING the wedged
-    thread, non-empty flight tail, registry snapshot, manifest — within
-    the configured deadline, and /healthz flips to 503."""
+@pytest.fixture()
+def wedged_worker(tmp_path):
+    """A deliberately wedged EvacuationWorker under an armed watchdog
+    (deadline 0.3 s) beside a telemetry server: yields ``(healthz url,
+    release)``; ``release.set()`` un-wedges the drain."""
     import jax.numpy as jnp
 
     from dist_dqn_tpu.replay.staging import (EvacuationWorker,
@@ -175,48 +175,72 @@ def test_wedged_evacuation_worker_dumps_bundle_and_flips_healthz(tmp_path):
     release = threading.Event()
 
     def wedged_on_slice(tree, lo, hi):
-        release.wait(timeout=60)  # the injected hang: append never returns
+        release.wait(timeout=120)  # the injected hang: append never returns
 
     evac = StreamedEvacuator(num_slices=2, name="wedge")
     worker = EvacuationWorker(evac, wedged_on_slice, name="wedge")
     server = telemetry.start_server(0)
-    url = f"http://127.0.0.1:{server.port}/healthz"
     try:
         worker.submit({"obs": jnp.zeros((8, 2, 4)),
                        "action": jnp.zeros((8, 2), jnp.int32)})
-        # bundles rename from "*.writing" only when complete — the poll
-        # must not read a half-written one
-        done = lambda: [b for b in os.listdir(tmp_path)  # noqa: E731
-                        if b.endswith("watchdog_stall")]
-        _wait_for(lambda: done(), timeout_s=10, what="forensics bundle")
-        bundle = tmp_path / done()[0]
-        reason = json.loads((bundle / "reason.json").read_text())
-        assert "evac.wedge" in reason["detail"]["stale"]
-        stacks = (bundle / "stacks.txt").read_text()
-        assert "evac-wedge" in stacks          # the wedged thread BY NAME
-        assert "wedged_on_slice" in stacks     # parked exactly here
-        flight_dump = json.loads((bundle / "flight.json").read_text())
-        names = [e["name"] for e in flight_dump["events"]]
-        assert "evac.wedge.submit" in names    # non-empty, relevant tail
-        registry_dump = json.loads((bundle / "registry.json").read_text())
-        assert any(k.startswith("dqn_") for k in registry_dump)
-        man = json.loads((bundle / "manifest.json").read_text())
-        assert man["schema_version"] == tm_manifest.SCHEMA_VERSION
-        with pytest.raises(urllib.error.HTTPError) as exc_info:
-            urllib.request.urlopen(url)
-        assert exc_info.value.code == 503
-        body = json.loads(exc_info.value.read())
-        assert "evac.wedge" in body["stale_stages_age_s"]
-        # un-wedge: the drain finishes, beats resume, /healthz recovers
-        release.set()
-        _wait_for(lambda: urllib.request.urlopen(url).status == 200,
-                  what="healthz recovery")
+        yield f"http://127.0.0.1:{server.port}/healthz", release
     finally:
         release.set()
         worker.close()
         server.close()
     # a closed worker deregisters its stage: no post-run false stall
     assert "evac.wedge" not in tm_watchdog.get_watchdog().stages()
+
+
+def _healthz(url):
+    """(status, body) of /healthz; a 503 is an answer, not an error."""
+    try:
+        with urllib.request.urlopen(url, timeout=30) as response:
+            return response.status, response.read()
+    except urllib.error.HTTPError as e:
+        return e.code, e.read()
+
+
+# Waits below are sized for six busy workers on eight cores; each is for
+# an EVENT (a bundle renamed into place, a status), never a duration.
+LOADED_S = 60.0
+
+
+def test_wedged_evacuation_worker_dumps_bundle(wedged_worker, tmp_path):
+    """Acceptance (ISSUE 4): a deliberately wedged EvacuationWorker
+    heartbeat produces a forensics bundle — stacks NAMING the wedged
+    thread, non-empty flight tail, registry snapshot, manifest."""
+    # bundles rename from "*.writing" only when complete — the poll
+    # must not read a half-written one
+    done = lambda: [b for b in os.listdir(tmp_path)  # noqa: E731
+                    if b.endswith("watchdog_stall")]
+    _wait_for(done, timeout_s=LOADED_S, what="forensics bundle")
+    bundle = tmp_path / done()[0]
+    reason = json.loads((bundle / "reason.json").read_text())
+    assert "evac.wedge" in reason["detail"]["stale"]
+    stacks = (bundle / "stacks.txt").read_text()
+    assert "evac-wedge" in stacks          # the wedged thread BY NAME
+    assert "wedged_on_slice" in stacks     # parked exactly here
+    flight_dump = json.loads((bundle / "flight.json").read_text())
+    names = [e["name"] for e in flight_dump["events"]]
+    assert "evac.wedge.submit" in names    # non-empty, relevant tail
+    registry_dump = json.loads((bundle / "registry.json").read_text())
+    assert any(k.startswith("dqn_") for k in registry_dump)
+    man = json.loads((bundle / "manifest.json").read_text())
+    assert man["schema_version"] == tm_manifest.SCHEMA_VERSION
+
+
+def test_wedged_evacuation_worker_flips_healthz_and_recovers(wedged_worker):
+    """... and /healthz flips to 503 naming the stage, then back to 200
+    once the drain finishes and beats resume."""
+    url, release = wedged_worker
+    _wait_for(lambda: _healthz(url)[0] == 503, timeout_s=LOADED_S,
+              what="healthz 503")
+    assert "evac.wedge" in json.loads(
+        _healthz(url)[1])["stale_stages_age_s"]
+    release.set()
+    _wait_for(lambda: _healthz(url)[0] == 200, timeout_s=LOADED_S,
+              what="healthz recovery")
 
 
 def test_debug_routes_serve_stacks_flight_config():

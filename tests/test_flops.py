@@ -1,195 +1,169 @@
-"""MFU accounting (utils/flops.py) + bench.py capture contract.
+"""The FLOP counts ``train_mfu`` is made of, held to the compiler.
 
-The MFU number's integrity rests on XLA's cost analysis; the analytic
-cross-check here pins it to the hand-derived Nature-CNN op count so a
-cost-model or network regression can't silently skew the headline MFU.
-bench.py's contract is ONE parseable JSON line on every path, including
-backend failure (VERDICT round 1, weak #2).
+``perf/reduce/flops.py`` and each reference module's ``grad_step_flops``
+count a grad step from shapes. XLA's ``cost_analysis()`` counts what it
+compiled — right for straight-line code, and a ``lax.scan`` / ``while``
+body ONCE whatever its trip count. So every comparison here compiles ONE
+un-scanned step at toy size (scans fully unrolled or one trip long) and
+holds the shape count to that census; the census prices a whole chunk
+program nowhere.
 """
-import json
+import dataclasses
+import importlib
 import os
 import subprocess
 import sys
 
 import jax
 import jax.numpy as jnp
-
-from dist_dqn_tpu.utils import flops as flops_util
-
 import pytest
+
+from perf.reduce import flops, peaks
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
-def _analytic_nature_fwd_flops(batch: int, num_actions: int = 6,
-                               hidden: int = 512) -> float:
-    """2*MACs of the Nature CNN forward (84x84x4, VALID convs 8/4, 4/2, 3/1)."""
-    macs = (20 * 20 * 8 * 8 * 4 * 32        # conv1 -> [20,20,32]
-            + 9 * 9 * 4 * 4 * 32 * 64       # conv2 -> [9,9,64]
-            + 7 * 7 * 3 * 3 * 64 * 64       # conv3 -> [7,7,64]
-            + 3136 * hidden                 # fc
-            + hidden * num_actions)         # head
-    return 2.0 * macs * batch
+def _census_flops(compiled) -> float:
+    """FLOPs of one execution by XLA's own count."""
+    cost = compiled.cost_analysis()
+    if isinstance(cost, (list, tuple)):
+        cost = cost[0]
+    return float(cost["flops"])
 
 
 def test_cost_analysis_matches_analytic_nature_cnn():
+    """``flops.cnn_layer_macs`` — the layer count every pixel
+    configuration's ``grad_step_flops`` starts from — against the compiled
+    forward pass of the atari network."""
     from dist_dqn_tpu.config import CONFIGS
     from dist_dqn_tpu.models import build_network
 
     cfg = CONFIGS["atari"]
     net = build_network(cfg.network, 6)
     obs = jnp.zeros((32, 84, 84, 4), jnp.uint8)
-    params = net.init(jax.random.PRNGKey(0), obs)
+    params = jax.eval_shape(net.init, jax.random.PRNGKey(0), obs)
     compiled = jax.jit(net.apply).lower(params, obs).compile()
-    got = flops_util.compiled_flops(compiled)
-    assert got is not None
-    want = _analytic_nature_fwd_flops(32)
-    assert want / 1.5 < got < want * 1.5, (got, want)
+    want = 2.0 * 32 * sum(flops.cnn_layer_macs(
+        (84, 84, 4), flops.NATURE_CONVS, 512, 6, dueling=False))
+    assert want / 1.1 < _census_flops(compiled) < want * 1.1
 
 
-def test_train_step_flops_exceed_forward():
-    """fwd(online) + fwd(target) + backward must cost well over one fwd."""
+def _dqn_toy():
     from dist_dqn_tpu.config import CONFIGS
-    from benchmarks.learner_bench import _feedforward_case
 
-    state, step, args = _feedforward_case(CONFIGS["atari"])
-    compiled = step.lower(state, *args).compile()
-    got = flops_util.compiled_flops(compiled)
-    assert got is not None
-    fwd = _analytic_nature_fwd_flops(CONFIGS["atari"].learner.batch_size)
-    assert got > 3.0 * fwd, (got, fwd)
+    cfg = CONFIGS["atari"]
+    return dataclasses.replace(
+        cfg, learner=dataclasses.replace(cfg.learner, batch_size=32))
+
+
+def _r2d2_toy(lstm_size: int, seqs: int):
+    """perf/tests/test_perf_reference_r2d2.py's sizes, the LSTM's scan
+    unrolled past the window so that the step is straight-line code."""
+    from dist_dqn_tpu.config import CONFIGS
+
+    cfg = CONFIGS["r2d2"]
+    return dataclasses.replace(
+        cfg,
+        network=dataclasses.replace(
+            cfg.network, torso="small", hidden=32, lstm_size=lstm_size,
+            lstm_unroll=64, compute_dtype="float32", lstm_dtype="float32",
+            remat_torso=False),
+        replay=dataclasses.replace(cfg.replay, burn_in=4, unroll_length=8,
+                                   sequence_stride=4, capacity=256),
+        learner=dataclasses.replace(cfg.learner, n_step=3, batch_size=seqs))
+
+
+def _hybrid_toy(toy_config: dict, wider):
+    """A perf test's toy core, widened until the core is a third of the
+    count (at the toy widths the torso is 99% of it) and with every scan
+    one trip long (``chunk_size`` >= the 12-step window)."""
+    from dist_dqn_tpu.config import CONFIGS, apply_overrides
+
+    return apply_overrides(CONFIGS[toy_config["preset"]],
+                           list(toy_config["overrides"]) + list(wider))
+
+
+def _twotower_toy():
+    from perf.tests.test_perf_run_twotower import TOY_CORE_CONFIG
+
+    return _hybrid_toy(TOY_CORE_CONFIG, (
+        "network.hidden=256", "network.core.mamba_num_heads=8",
+        "network.core.mamba_head_dim=64", "network.core.ssm_state_size=16",
+        "network.core.chunk_size=16",
+        "network.core.moe_intermediate_size=512",
+        "network.core.moe_shared_expert_intermediate_size=512",
+        "network.core.head_dim=64", "network.core.attention_window=16"))
+
+
+def _laguna_toy():
+    from perf.tests.test_perf_laguna import TOY_LAGUNA_CONFIG
+
+    return _hybrid_toy(TOY_LAGUNA_CONFIG, (
+        "network.hidden=256", "network.core.head_dim=64",
+        "network.core.intermediate_size=1024",
+        "network.core.moe_intermediate_size=512",
+        "network.core.moe_shared_expert_intermediate_size=512"))
+
+
+def _step_census_over_shape_count(reference_name: str, cfg) -> float:
+    """XLA's count of the program's own train step (as the reference check
+    builds it) over the module's ``grad_step_flops``."""
+    from dist_dqn_tpu.envs import make_jax_env
+    from dist_dqn_tpu.models import build_network
+
+    reference = importlib.import_module(f"perf.reference.{reference_name}")
+    env = make_jax_env(cfg.env_name)
+    net = build_network(cfg.network, env.num_actions)
+    init, train_step, _ = reference.make_program(cfg, env, net)
+    state = jax.eval_shape(init, jax.random.PRNGKey(0))
+    batch = reference.seeded_batch(1, 0, cfg.learner.batch_size, cfg, env)
+    compiled = jax.jit(train_step).lower(state, batch).compile()
+    return _census_flops(compiled) / reference.grad_step_flops(cfg, env)
+
+
+# The census also counts what the shape count leaves out by its own
+# definition (elementwise work, the loss, the optimizer): ratios read
+# 1.04 / 1.01 / 1.09 / 1.10 when this was written.
+TOYS = {"dqn_float32": _dqn_toy,
+        "r2d2_float32": lambda: _r2d2_toy(lstm_size=16, seqs=8),
+        "twotower_float32": _twotower_toy,
+        "laguna_float32": _laguna_toy}
+
+
+@pytest.mark.parametrize("reference_name", sorted(TOYS))
+def test_grad_step_flops_match_the_compiled_step(reference_name):
+    """Every reference module's ``grad_step_flops`` — the numerator of
+    ``train_mfu`` and ``loss_grad_mfu`` in that configuration's cells —
+    against the census of one un-scanned step of the program's learner."""
+    ratio = _step_census_over_shape_count(reference_name,
+                                          TOYS[reference_name]())
+    assert 1 / 1.2 < ratio < 1.2, ratio
 
 
 def test_r2d2_analytic_cell_flops_match_unrolled_census():
-    """The R2D2 analytic model vs an EXACT census: the op census counts a
-    scan body once regardless of trip count, but lax.scan with
-    unroll >= length emits straight-line code — so a tiny fully-unrolled
-    train step gives a trip-count-correct census to pin the analytic
-    cell accounting (4 passes x T steps x gate matmul) against. Sizes
-    chosen so the cell dominates (tiny MLP torso, big LSTM)."""
-    import dataclasses
-
-    import numpy as np
-
-    from dist_dqn_tpu.agents.r2d2 import make_r2d2_learner
-    from dist_dqn_tpu.config import CONFIGS
-    from dist_dqn_tpu.models import build_network
-    from dist_dqn_tpu.types import SequenceSample
-
-    base = CONFIGS["r2d2"]
-    S, lstm, E = 8, 128, 8
-    cfg = dataclasses.replace(
-        base,
-        network=dataclasses.replace(
-            base.network, torso="mlp", mlp_features=(E,), hidden=E,
-            lstm_size=lstm, compute_dtype="float32", remat_torso=False,
-            lstm_unroll=64),                    # >= T: fully unrolled
-        replay=dataclasses.replace(base.replay, burn_in=4, unroll_length=6,
-                                   sequence_stride=3),
-        learner=dataclasses.replace(base.learner, n_step=2, batch_size=S),
-    )
-    T = cfg.replay.burn_in + cfg.replay.unroll_length + cfg.learner.n_step
-    assert cfg.network.lstm_unroll >= T
-    net = build_network(cfg.network, 2)
-    init, train_step = make_r2d2_learner(net, cfg.learner, cfg.replay)
-    state = init(jax.random.PRNGKey(0), jnp.zeros((4,), jnp.float32))
-    r = np.random.default_rng(0)
-    sample = SequenceSample(
-        obs=jnp.asarray(r.normal(size=(T, S, 4)).astype(np.float32)),
-        action=jnp.asarray(r.integers(0, 2, (T, S), np.int32)),
-        reward=jnp.asarray(r.normal(size=(T, S)).astype(np.float32)),
-        done=jnp.zeros((T, S), bool),
-        reset=jnp.zeros((T, S), bool),
-        start_state=net.initial_state(S),
-        weights=jnp.ones(S, jnp.float32),
-        t_idx=jnp.zeros(S, jnp.int32),
-        b_idx=jnp.zeros(S, jnp.int32),
-    )
-    compiled = jax.jit(train_step).lower(state, sample).compile()
-    census = flops_util.compiled_flops(compiled)
-    assert census is not None
-    analytic_cell = 4.0 * flops_util.lstm_cell_fwd_flops(T * S, E, lstm)
-    # Census adds the (small) torso/head/loss/optimizer terms on top of
-    # the cell; the model approximates backward as 2x forward.
-    assert analytic_cell / 1.6 < census < analytic_cell * 1.9, \
-        (census, analytic_cell)
-
-
-def test_r2d2_time_model_orders_knobs():
-    """Model-level evidence for the knob defaults (VERDICT round 2 next
-    #6): bf16 gates and a deeper unroll must reduce modeled time, and the
-    full-knob point must beat the round-1 measured 47.4 grad-steps/s."""
-    T, B = 125, 64  # the r2d2 config's sequence and batch shape
-    kw = dict(peak_bf16=197e12)
-    f32 = flops_util.r2d2_time_model(T, B, lstm_bf16=False, unroll=1, **kw)
-    bf16 = flops_util.r2d2_time_model(T, B, lstm_bf16=True, unroll=1, **kw)
-    bf16_u8 = flops_util.r2d2_time_model(T, B, lstm_bf16=True, unroll=8,
-                                         **kw)
-    assert bf16["total_s"] < f32["total_s"]
-    assert bf16_u8["total_s"] < bf16["total_s"]
-    assert bf16_u8["modeled_grad_steps_per_sec"] > 47.4
+    """The recurrent count where the CELL carries it: an LSTM of 2,048 on
+    the small torso is four fifths of ``r2d2_float32.grad_step_flops``
+    (four gates a step, forward for both networks over the window, backward
+    over the training positions), the scan unrolled to straight-line
+    code."""
+    ratio = _step_census_over_shape_count(
+        "r2d2_float32", _r2d2_toy(lstm_size=2048, seqs=4))
+    assert 1 / 1.2 < ratio < 1.2, ratio
 
 
 def test_peak_lookup_and_mfu():
-    class FakeDev:
-        device_kind = "TPU v5 lite"
-
-    assert flops_util.chip_peak_flops(FakeDev()) == 197e12
-    assert abs(flops_util.mfu(19.7e12, FakeDev()) - 0.1) < 1e-9
-    cpu = jax.devices()[0]  # conftest forces CPU: no peak -> None
-    assert flops_util.chip_peak_flops(cpu) is None
-    assert flops_util.chip_peak_hbm_bw(cpu) is None
-    assert flops_util.mfu(1e12, cpu) is None
-    assert flops_util.mfu(None, FakeDev()) is None
+    assert peaks.peak("TPU v5 lite", "bf16_flops") == 197e12
+    assert peaks.peak("TPU v5e", "hbm_bytes_per_s") == 819e9
+    assert 19.7e12 / peaks.peak("TPU v5 lite", "bf16_flops") \
+        == pytest.approx(0.1)
 
 
 def test_unknown_accelerator_kind_raises():
-    """An accelerator missing from the peak table is an error naming the
-    kind — never a silently absent mfu (only the CPU returns None)."""
-    class FutureTpu:
-        platform = "tpu"
-        device_kind = "TPU v99"
-
-    for fn in (flops_util.chip_peak_flops, flops_util.chip_peak_hbm_bw):
-        with pytest.raises(KeyError, match="TPU v99"):
-            fn(FutureTpu())
-    with pytest.raises(KeyError, match="TPU v99"):
-        flops_util.mfu(1e12, FutureTpu())
-
-
-def _run_bench(env_overrides, timeout=560):
-    env = {**os.environ, **env_overrides}
-    return subprocess.run(
-        [sys.executable, os.path.join(REPO, "bench.py")], env=env, cwd=REPO,
-        capture_output=True, text=True, timeout=timeout)
-
-
-@pytest.mark.slow
-def test_bench_smoke_emits_contract_json():
-    proc = _run_bench({"BENCH_SMOKE": "1"})
-    assert proc.returncode == 0, proc.stderr[-2000:]
-    lines = [l for l in proc.stdout.splitlines() if l.startswith("{")]
-    assert len(lines) == 1
-    payload = json.loads(lines[0])
-    assert payload["metric"] == "env_steps_per_sec_per_chip"
-    assert payload["value"] > 0
-    assert payload["vs_baseline"] > 0
-    assert "error" not in payload
-
-
-@pytest.mark.parametrize("platforms", ["definitely_not_a_platform", "cpu"])
-def test_bench_backend_failure_emits_error_json(platforms):
-    """No backend, or (without BENCH_SMOKE=1) a CPU backend: one error
-    line and a nonzero code — never a CPU timing under the device metric."""
-    proc = _run_bench({"JAX_PLATFORMS": platforms, "BENCH_SMOKE": ""},
-                      timeout=120)
-    assert proc.returncode != 0
-    lines = [l for l in proc.stdout.splitlines() if l.startswith("{")]
-    assert len(lines) == 1
-    payload = json.loads(lines[0])
-    assert payload["metric"] == "env_steps_per_sec_per_chip"
-    assert payload["value"] is None
-    assert "backend-init" in payload["error"]
+    """A device missing from the peak table is an error naming the kind —
+    the CPU included: no number under a device metric's name."""
+    for kind in ("TPU v99", jax.devices()[0].device_kind):
+        with pytest.raises(KeyError, match=kind):
+            peaks.peak(kind, "bf16_flops")
 
 
 def test_platform_flag_scripts_require_an_accelerator_by_default():
@@ -212,69 +186,3 @@ def test_platform_flag_scripts_require_an_accelerator_by_default():
         text=True, timeout=120, env={**os.environ, "JAX_PLATFORMS": "cpu"})
     assert proc.returncode == 0, proc.stdout[-1000:] + proc.stderr[-2000:]
     assert proc.stdout.strip() == ""            # no row was printed
-
-
-def test_compiled_bytes_census():
-    def f(a, b):
-        return jnp.tanh(a @ b).sum()
-
-    c = jax.jit(f).lower(jnp.zeros((64, 32)), jnp.zeros((32, 16))).compile()
-    nbytes = flops_util.compiled_bytes(c)
-    # At least the operands + output must be accessed once.
-    assert nbytes is not None and nbytes >= (64 * 32 + 32 * 16 + 1) * 4
-
-    class NoCost:
-        def cost_analysis(self):
-            raise RuntimeError("backend without cost analysis")
-
-    assert flops_util.compiled_bytes(NoCost()) is None
-
-
-def test_roofline_fields_math():
-    class FakeDev:
-        device_kind = "TPU v5 lite"  # 197 TFLOP/s bf16, 819 GB/s HBM
-
-    # 0.1 ms of compute, 0.2 ms of memory traffic -> memory-bound.
-    fl = 197e12 * 1e-4
-    by = 819e9 * 2e-4
-    out = flops_util.roofline_fields(fl, by, FakeDev())
-    assert out["roofline_bound"] == "memory"
-    assert out["roofline_s"] == pytest.approx(2e-4, rel=1e-3)
-    assert out["roofline_compute_s"] == pytest.approx(1e-4, rel=1e-3)
-    assert out["arith_intensity"] == pytest.approx(fl / by, rel=1e-2)
-    # Flipped ratio -> compute-bound.
-    out = flops_util.roofline_fields(fl * 4, by, FakeDev())
-    assert out["roofline_bound"] == "compute"
-    # Unknown chip or missing census -> {} (never a fake number).
-    cpu = jax.devices()[0]
-    assert flops_util.roofline_fields(fl, by, cpu) == {}
-    assert flops_util.roofline_fields(None, by, FakeDev()) == {}
-
-
-def test_learner_bench_row_carries_roofline_on_feedforward():
-    """bench_config's row gains the bytes/roofline fields for
-    feedforward configs (the census is scan-free there) — pinned on a
-    tiny MLP cartpole-shaped case so CPU can run it fast."""
-    import dataclasses
-
-    import benchmarks.learner_bench as lb
-    from dist_dqn_tpu.config import CONFIGS
-
-    cfg = CONFIGS["atari"]
-    cfg = dataclasses.replace(
-        cfg,
-        network=dataclasses.replace(cfg.network, torso="mlp",
-                                    mlp_features=(32,), hidden=0,
-                                    compute_dtype="float32"),
-        learner=dataclasses.replace(cfg.learner, batch_size=8))
-    old = lb.OBS_SHAPE
-    lb.OBS_SHAPE = (12,)
-    try:
-        row = lb.bench_config("atari", iters=3, cfg=cfg)
-    finally:
-        lb.OBS_SHAPE = old
-    assert row["grad_steps_per_sec"] > 0
-    # CPU has no roofline peaks, but the census itself must be present
-    # via bytes_per_step only when the device is known — on CPU the
-    # roofline fields are absent and that absence is the contract.
-    assert "roofline_s" not in row or row["roofline_gap_x"] > 0

@@ -28,9 +28,8 @@ The load-bearing assertions:
   a member axis, refuses the --mesh-devices cross outright, and
   validates the spec at the parser boundary;
 * the lint teeth: a jitted ``*population*`` entry point without
-  donate_argnums / registry wiring bites in the donation and
-  program_registry plugins (the TARGET vocabulary covers the new
-  plane).
+  donate_argnums bites in the donation plugin (the TARGET vocabulary
+  covers the new plane).
 """
 from __future__ import annotations
 
@@ -302,25 +301,6 @@ def test_restore_member_on_solo_dir_refused(tmp_path):
     mgr.close()
 
 
-def test_population_devtime_census():
-    """The stacked chunk registers in the chip-time ProgramRegistry
-    (ISSUE 19): one `population.chunk` program under the fused loop,
-    with its dispatches counted and its lowered cost attached — so
-    dqn_learner_mfu prices the population program."""
-    from dist_dqn_tpu.telemetry import devtime
-    from dist_dqn_tpu.train import train
-
-    devtime.reset_program_registry()
-    train(_tiny_cfg(size=2, spec_json=SPEC2), total_env_steps=1600,
-          seed=1, chunk_iters=50, log_fn=lambda s: None)
-    snap = devtime.programs_snapshot("fused")
-    assert "population.chunk" in snap
-    prog = snap["population.chunk"]
-    assert prog["dispatches"] >= 1
-    assert prog["device_seconds"] > 0
-    assert prog.get("flops", 0) > 0
-
-
 def test_train_cli_population_flag_routing(monkeypatch, capsys):
     """ISSUE 20 satellite: --population applies on the fused runtime,
     warns-and-ignores where there is no member axis (apex, recurrent),
@@ -391,19 +371,17 @@ def test_population_sweep_smoke():
     assert rows[0]["mode"] == "solo" and rows[1]["mode"] == "stacked"
     for r in rows:
         for key in ("grad_steps_per_sec", "grad_steps_per_sec_member",
-                    "scaling_vs_m1", "aliased_pairs", "programs"):
+                    "scaling_vs_m1", "aliased_pairs"):
             assert key in r
-        prog = r["programs"]["population_bench.chunk"]
-        assert prog["dispatches"] == 2     # one stacked dispatch/chunk
     assert rows[1]["grad_steps_per_chunk_member"] == \
         rows[0]["grad_steps_per_chunk_member"] > 0
 
 
 def test_population_lint_drift_bite(tmp_path):
-    """The donation + program_registry TARGET vocabulary covers the
-    population entry points: a jitted `*population*` program without
-    donate_argnums / registry wiring bites in both plugins."""
-    from dist_dqn_tpu.analysis.plugins import donation, program_registry
+    """The donation TARGET vocabulary covers the population entry
+    points: a jitted `*population*` program without donate_argnums
+    bites."""
+    from dist_dqn_tpu.analysis.plugins import donation
 
     pkg = tmp_path / "dist_dqn_tpu"
     pkg.mkdir()
@@ -412,17 +390,12 @@ def test_population_lint_drift_bite(tmp_path):
         "run = jax.jit(run_population_chunk, static_argnums=2)\n")
     assert any(rel == "dist_dqn_tpu/rogue.py"
                for rel, _, _ in donation.scan(tmp_path))
-    assert any(rel == "dist_dqn_tpu/rogue.py"
-               for rel, _, _ in program_registry.scan(tmp_path))
-    # Wired correctly, both lints go quiet.
+    # Wired correctly, the lint goes quiet.
     (pkg / "rogue.py").write_text(
         "import jax\n"
         "run = jax.jit(run_population_chunk, static_argnums=2,\n"
-        "              donate_argnums=0)\n"
-        "prog = register_program('population.chunk', loop='fused')\n"
-        "prog.attach_cost(lambda: run.lower(c, hp, 10))\n")
+        "              donate_argnums=0)\n")
     assert not donation.scan(tmp_path)
-    assert not program_registry.scan(tmp_path)
 
 
 def test_sidecar_schema_population_pin():
