@@ -44,7 +44,7 @@ class CoreConfig:
     gated attention over the whole history / the last ``sliding_window``
     steps, ``D`` a dense gated MLP. The defaults are the widths the
     ``twotower_q`` preset runs (``nemotron_h``'s keys, where it has one);
-    ``laguna_q`` states its own.
+    ``laguna_q`` and ``smallthinker_q`` state their own.
     """
 
     kind: str = "lstm"
@@ -72,20 +72,32 @@ class CoreConfig:
     num_key_value_heads: int = 2
     head_dim: int = 128
     attention_window: int = 512
-    # E: the expert's form, "relu2" (``W_down relu(W_up u)^2``) or "silu"
-    # (gated, three matrices: ``W_down (silu(W_gate u) * W_up u)``), and
-    # whether choosing adds a correction bias to the scores.
+    # E: the expert's form, "relu2" (``W_down relu(W_up u)^2``), or gated,
+    # three matrices, ``W_down (act(W_gate u) * W_up u)`` with act "silu"
+    # or "relu"; and whether choosing adds a correction bias to the scores.
+    # A shared expert of width 0 is none.
     expert_act: str = "relu2"
     router_bias: bool = True
+    # E: the gates of the chosen experts. "sigmoid": sigmoid scores, the
+    # chosen scores over their sum, times ``routed_scaling_factor``.
+    # "softmax": the top k of the logits, a softmax over those k.
+    router_scores: str = "sigmoid"
+    # E: the router reads the normed input of the sublayer BEFORE its
+    # experts' (an attention sublayer, which then holds the router's
+    # weights) and its logits are handed on to the experts' sublayer.
+    router_ahead: bool = False
     # D: the dense gated MLP's width ("silu" form).
     intermediate_size: int = 8192
     # F / W: query heads of each such sublayer, in the pattern's order (KV
     # heads and head_dim as ``*``); W sees a lane's last ``sliding_window``
-    # steps, F all of the episode (acting keeps ``attention_window``).
+    # steps, F all of the episode (acting keeps ``attention_window``). A
+    # kind whose ``rotary_factor`` is 0 has no position embedding;
+    # ``attention_gate``: a sigmoid gate a head on the attended values.
     attention_heads_per_layer: Tuple[int, ...] = ()
     sliding_window: int = 512
     rope_full: RopeConfig = RopeConfig()
     rope_window: RopeConfig = RopeConfig()
+    attention_gate: bool = True
 
 
 @dataclasses.dataclass(frozen=True)
@@ -541,9 +553,53 @@ LAGUNA_Q = ExperimentConfig(
     total_env_steps=100_000_000,
 )
 
+SMALLTHINKER_Q = ExperimentConfig(
+    # An R2D2-style agent whose memory is one period of
+    # SmallThinker-21BA3B-Instruct (PowerInfer; layers 0-3 of 52) at its
+    # published widths — eight sublayers: full attention WITHOUT a position
+    # embedding, then window-4096 rotary attention three times (28 heads
+    # over 4 KV heads, no gate), each followed by 8 HELD of 64 ReGLU experts
+    # whose router read the ATTENTION sublayer's normed input (a softmax
+    # over the top 6 logits; no shared expert)
+    # (perf/configs/smallthinker_q.json). Windows of 8,192 steps from the
+    # empty state: 4,096 burn-in (one whole attention window) + 4,091
+    # trained + 5 bootstrap, 2 a grad step = 16,384 tokens, a grad step
+    # every 32nd acting step. Acting keeps 4,096 steps of keys and values
+    # in the window layers and 8,192 in the full one.
+    name="smallthinker_q",
+    env_name="pixel_pong",
+    network=NetworkConfig(
+        torso="nature", hidden=2560, dueling=True,
+        compute_dtype="bfloat16", remat_torso=True,
+        core=CoreConfig(
+            kind="hybrid", pattern="FEWEWEWE", norm_eps=1e-6,
+            n_routed_experts=64, num_experts_per_tok=6,
+            routed_scaling_factor=1.0, moe_intermediate_size=768,
+            moe_shared_expert_intermediate_size=0, expert_act="relu",
+            router_bias=False, router_scores="softmax", router_ahead=True,
+            num_key_value_heads=4, head_dim=128, attention_window=8192,
+            attention_heads_per_layer=(28, 28, 28, 28),
+            sliding_window=4096, attention_gate=False,
+            rope_full=RopeConfig(rotary_factor=0.0),
+            rope_window=RopeConfig(theta=1_500_000.0))),
+    replay=ReplayConfig(capacity=262_144, prioritized=True,
+                        priority_exponent=0.9, importance_exponent=0.6,
+                        burn_in=4096, unroll_length=4091,
+                        sequence_stride=4096, min_fill=131_072,
+                        frame_dedup=True),
+    learner=LearnerConfig(
+        learning_rate=1e-4, adam_eps=1e-3, gamma=0.997, n_step=5,
+        batch_size=2, double_dqn=True, target_update_period=2_500,
+        value_rescale=True,
+    ),
+    actor=ActorConfig(num_envs=16, num_actors=256),
+    train_every=32,
+    total_env_steps=100_000_000,
+)
+
 CONFIGS: Dict[str, ExperimentConfig] = {
     c.name: c for c in (CARTPOLE, ATARI, APEX, R2D2, RAINBOW, QRDQN, IQN,
-                        MDQN, TWOTOWER_Q, LAGUNA_Q)
+                        MDQN, TWOTOWER_Q, LAGUNA_Q, SMALLTHINKER_Q)
 }
 
 

@@ -3,8 +3,10 @@
 heads (``config.CoreConfig`` kind "hybrid").
 
 Every layer is ``x + mixer(RMSNorm(x))`` with no bias but the state-space
-layer's convolution; one letter of ``CoreConfig.pattern`` a layer, as
-``nemotron_h`` writes its ``hybrid_override_pattern``:
+layer's convolution — and nothing but ``x`` and the carry passes from one to
+the next, except the routing under ``router_ahead`` (below); one letter of
+``CoreConfig.pattern`` a layer, as ``nemotron_h`` writes its
+``hybrid_override_pattern``:
 
 ``M``  Mamba-2 (Dao & Gu 2024). ``[z | xBC | dt] = u W_in``; ``xBC =
        silu(causal depthwise conv(xBC) + b)``, split into ``x [H, P]`` and
@@ -46,10 +48,18 @@ layer's convolution; one letter of ``CoreConfig.pattern`` a layer, as
        part of the dims) BEFORE a key is cached, a sigmoid gate a head on
        the attended values, heads a layer (``attention_heads_per_layer``).
        ``F`` sees the whole episode, ``W`` its last ``sliding_window``
-       steps. A learner's window (``T > 1``) is computed by blocks of
-       queries, ``F`` against the keys up to its block (the causal
-       triangle by blocks), ``W`` against the keys a window back and its
-       own (a band whose cost does not grow with the window's length), by
+       steps. Both as ``smallthinker`` writes them too: a kind whose
+       ``rotary_factor`` is 0 has NO position embedding (no tables, nothing
+       rotated, and on a TPU no rotary kernel: the queries reach the fused
+       kernels through ``attend(rotary=None)``), and without
+       ``attention_gate`` there is no ``g_proj`` leaf and no gate. A
+       learner's window (``T > 1``) is computed by blocks of
+       ``QUERY_BLOCK`` queries (the kernels' tile; the window need not be
+       one), ``F`` against the keys up to its block (the causal triangle
+       by blocks), ``W`` the same while the block starts inside the
+       call's first window (any slot of the ring may lie in it) and against
+       the keys a window back and its own after (a band whose cost does not
+       grow with the window's length), by
        one of two paths that hold to one mask rule
        (``_RotaryAttention.window_keys``): on a TPU the fused kernels of
        ``ops/pallas_attention.py``, which keep a tile's scores in VMEM,
@@ -59,14 +69,33 @@ layer's convolution; one letter of ``CoreConfig.pattern`` a layer, as
        writes its halves as arrays half a tile of lanes wide or less; it
        stays the keys' path, acting's, every other backend's, and that
        kernel's oracle); on every other backend ``blockwise``, plain
-       ``jax.numpy`` by blocks of ``sliding_window`` queries whose scores
-       go through HBM — the kernels' oracle. ``loop_common.pallas_routing``
+       ``jax.numpy`` whose blocks' scores go through HBM — the kernels'
+       oracle, which reads the key ranges they read. ``loop_common.pallas_routing``
        chooses (no option does). The acting step (``T == 1``) reads its
        ring with one small masked softmax on either.
 ``D``  A dense gated MLP, ``W_down (silu(W_gate u) * W_up u)``.
 ``E`` takes the expert's form from the configuration (``expert_act``:
-``relu2`` above, or ``silu``, gated as ``D``) and its correction bias where
-``router_bias`` says so.
+``relu2`` above, or gated as ``D`` with ``silu`` or ``relu``, ReGLU), its
+correction bias where ``router_bias`` says so, the rule of its gates
+(``router_scores``: ``sigmoid`` above, or ``softmax``: the top k of the
+logits, a softmax over those k; no bias, no scale), and has no shared expert
+— no ``shared_*`` leaf, no ``moe_shared`` scope — where that expert's width
+is 0.
+
+The router's road across a sublayer (``router_ahead``, ``smallthinker``: the
+router reads the layer's input BEFORE attention). The published layer is ``u =
+RMSNorm_1(x)``, ``h = x + Attn(u)``, ``y = h + MoE(RMSNorm_2(h); routed by u
+W_r)``: two sublayers here, each under its own ``nn.remat``. The sublayer
+BEFORE an ``E`` (``routes_ahead``) holds the router's weights beside its norm
+(``layer_i/router``) and makes the float32 logits ``[B, T, routed]`` from its
+own normed input, under the scope ``moe_router`` (outside the attention's
+scope: the attention readers do not count it); ``_Core`` hands them to the
+``E`` sublayer, which has no ``router`` leaf, chooses and gates from them
+(``moe_router`` again) and hands on none. What crosses the boundary — forward,
+in the backward's recomputation, and as a cotangent on the way back — is that
+one array, not a second ``[B, T, hidden]`` stream; the router's weights get
+their gradient in the attention sublayer's backward, and ``x`` gets the
+gates' gradient through ``u``.
 
 State. A lane's acting state is, for every ``M`` layer, the convolution's
 look-back ``[B, K-1, channels]`` and the state ``h [B, H, P, N]``, and for
@@ -350,19 +379,30 @@ def _inverse_softplus(x: Array) -> Array:
     return x + jnp.log(-jnp.expm1(-x))
 
 
-def silu_gated(u16: Array, w_gate: Array, up: Array) -> Array:
-    """``silu(u W_gate) * up`` for ``up = u W_up``, float32 out: what stands
+def gated(act, u16: Array, w_gate: Array, up: Array) -> Array:
+    """``act(u W_gate) * up`` for ``up = u W_up``, float32 out: what stands
     between the two products of a gated MLP; ``w_gate`` in the operands'
     type, any trailing axes taken as columns."""
-    return jax.nn.silu(jnp.dot(
+    return act(jnp.dot(
         u16, w_gate.reshape(u16.shape[-1], -1),
         preferred_element_type=F32)) * up
 
 
-def route(logits: Array, bias: Array, k: int, scale: float):
-    """``(chosen [.., k] int32, gates [.., k] float32)``: sigmoid scores,
-    the top k of score + bias chosen, the chosen scores normalised to sum
-    to ``scale``. The bias only chooses."""
+#: ``expert_act``: what gates an expert's up-projection; None: no gate
+#: matrix, ``relu(up)^2``.
+GATE_ACTS = {"relu2": None, "silu": jax.nn.silu, "relu": jax.nn.relu}
+
+
+def route(logits: Array, bias: Array, k: int, scale: float, rule: str):
+    """``(chosen [.., k] int32, gates [.., k] float32)`` by
+    ``CoreConfig.router_scores``' ``rule``. "sigmoid": sigmoid scores, the
+    top k of score + bias chosen, the chosen scores normalised to sum to
+    ``scale``; the bias only chooses. "softmax": the top k of the logits, a
+    softmax over those k (what a softmax over all of them gives once the
+    chosen are normalised to sum to 1); it takes neither bias nor scale."""
+    if rule == "softmax":
+        picked, chosen = jax.lax.top_k(logits.astype(F32), k)
+        return chosen, jax.nn.softmax(picked, axis=-1)
     scores = jax.nn.sigmoid(logits.astype(F32))
     _, chosen = jax.lax.top_k(scores + jax.lax.stop_gradient(bias), k)
     picked = jnp.take_along_axis(scores, chosen, axis=-1)
@@ -379,15 +419,19 @@ class _Experts(nn.Module):
     dtype: jnp.dtype
 
     @nn.compact
-    def __call__(self, u: Array, seg: Array, carry):
+    def __call__(self, u: Array, seg: Array, carry, logits=None):
         cfg = self.cfg
         hidden = u.shape[-1]
         held = jnp.asarray(cfg.experts_held, jnp.int32)
         E, width = len(cfg.experts_held), cfg.moe_intermediate_size
         shared = cfg.moe_shared_expert_intermediate_size
-        gated = {"relu2": False, "silu": True}[cfg.expert_act]
-        w_router = self.param("router", _normal(hidden ** -0.5),
-                              (hidden, cfg.n_routed_experts))
+        gate_act = GATE_ACTS[cfg.expert_act]
+        # ``router_ahead``: the sublayer before made the logits (``_Layer``).
+        # Two sites for the one product: moving the leaf out of here would
+        # change the parameter trees of the cores whose router reads ``u``.
+        w_router = (None if cfg.router_ahead else self.param(
+            "router", _normal(hidden ** -0.5),
+            (hidden, cfg.n_routed_experts)))
         bias = (self.param("e_score_correction_bias", nn.initializers.zeros,
                            (cfg.n_routed_experts,))
                 if cfg.router_bias else 0.0)
@@ -397,26 +441,29 @@ class _Experts(nn.Module):
                           (hidden, E, width))
         w_down = self.param("experts_down", _normal(width ** -0.5),
                             (E, width, hidden))
-        s_up = self.param("shared_up", _normal(hidden ** -0.5),
-                          (hidden, shared))
-        s_down = self.param("shared_down", _normal(shared ** -0.5),
-                            (shared, hidden))
+        if shared:
+            s_up = self.param("shared_up", _normal(hidden ** -0.5),
+                              (hidden, shared))
+            s_down = self.param("shared_down", _normal(shared ** -0.5),
+                                (shared, hidden))
         u16 = u.astype(self.dtype)
 
         def activation(up, name, shape):
             """What stands between an expert's two products, over all the
-            columns of ``up = u W_up``: ``relu(up)^2``, or gated ``silu(u
+            columns of ``up = u W_up``: ``relu(up)^2``, or gated ``act(u
             W_gate) * up`` with a gate matrix ``name`` shaped as ``W_up``."""
-            if not gated:
+            if gate_act is None:
                 return jnp.square(jax.nn.relu(up))
             w_gate = self.param(name, _normal(hidden ** -0.5), shape)
-            return silu_gated(u16, w_gate.astype(self.dtype), up)
+            return gated(gate_act, u16, w_gate.astype(self.dtype), up)
 
         with jax.named_scope("moe_router"):
-            logits = jnp.dot(u.astype(F32), w_router,
-                             precision=jax.lax.Precision.HIGHEST)
+            if w_router is not None:
+                logits = jnp.dot(u.astype(F32), w_router,
+                                 precision=jax.lax.Precision.HIGHEST)
             chosen, gates = route(logits, bias, cfg.num_experts_per_tok,
-                                  cfg.routed_scaling_factor)
+                                  cfg.routed_scaling_factor,
+                                  cfg.router_scores)
             on_held = chosen[..., None] == held          # [.., k, E]
             gate_of = jnp.sum(jnp.where(on_held, gates[..., None], 0.0),
                               axis=-2)                   # [.., E]
@@ -443,6 +490,8 @@ class _Experts(nn.Module):
                 (act * gate_wide).astype(self.dtype),
                 w_down.astype(self.dtype).reshape(E * width, hidden),
                 preferred_element_type=F32)
+        if not shared:
+            return routed, carry
         with jax.named_scope("moe_shared"):
             act = activation(jnp.dot(
                 u16, s_up.astype(self.dtype), preferred_element_type=F32),
@@ -583,7 +632,8 @@ class _RotaryAttention(nn.Module):
                          (hidden, heads * D))
         w_k = self.param("k_proj", _normal(hidden ** -0.5), (hidden, kv * D))
         w_v = self.param("v_proj", _normal(hidden ** -0.5), (hidden, kv * D))
-        w_g = self.param("g_proj", _normal(hidden ** -0.5), (hidden, heads))
+        w_g = (self.param("g_proj", _normal(hidden ** -0.5), (hidden, heads))
+               if cfg.attention_gate else None)
         w_o = self.param("o_proj", _normal((heads * D) ** -0.5),
                          (heads * D, hidden))
         old_k, old_v, steps = carry
@@ -619,16 +669,23 @@ class _RotaryAttention(nn.Module):
             position = jnp.where(seg == 0,
                                  steps.astype(jnp.int32)[:, None] + index,
                                  index - opened)            # [B, T]
-            tables = rotary_tables(position, rope, D)
+            # a kind that rotates none of its dims has no position embedding
+            tables = (rotary_tables(position, rope, D)
+                      if rope.rotary_factor else None)
+
+            def turned(x):
+                return x if tables is None else rotate(x, tables)
+
             q = project(w_q, heads)                 # not rotated yet
 
             def grouped(q):
                 return q.reshape(B, T, kv, heads // kv, D)
 
-            new_k = rotate(project(w_k, kv), tables)
+            new_k = turned(project(w_k, kv))
             new_v = project(w_v, kv)
-            gate = jax.nn.sigmoid(jnp.dot(u16, w_g.astype(self.dtype),
-                                          preferred_element_type=F32))
+            if w_g is not None:
+                gate = jax.nn.sigmoid(jnp.dot(u16, w_g.astype(self.dtype),
+                                              preferred_element_type=F32))
             if T == 1 and history:
                 # acting: the new key takes its slot, then the one query
                 # reads the ring; what the ring holds is what it may see
@@ -637,7 +694,7 @@ class _RotaryAttention(nn.Module):
                 ring_v = old_v.at[lanes, slot].set(new_v[:, 0])
                 see = (jnp.arange(history)
                        < jnp.minimum(position + 1, history))   # [B, S]
-                attended = attend(grouped(rotate(q, tables)), ring_k, ring_v,
+                attended = attend(grouped(turned(q)), ring_k, ring_v,
                                   see[:, None])
             else:
                 # a learner's window: the fused kernels on a TPU — the
@@ -655,13 +712,16 @@ class _RotaryAttention(nn.Module):
                         dtype=self.dtype, interpret=interpret, rotary=tables)
                 else:
                     attended = self.blockwise(
-                        jax.checkpoint(attend), grouped(rotate(q, tables)),
+                        jax.checkpoint(attend), grouped(turned(q)),
                         keys, values, position, seg, key_position, key_seg)
                 ring_k, ring_v = self.ring_after(new_k, new_v, position,
                                                  opened[:, -1], carry)
-            gated = attended.reshape(B, T, heads, D) * gate[..., None]
-            out = jnp.dot(gated.reshape(B, T, heads * D).astype(self.dtype),
-                          w_o.astype(self.dtype), preferred_element_type=F32)
+            attended = attended.reshape(B, T, heads, D)
+            if w_g is not None:
+                attended = attended * gate[..., None]
+            out = jnp.dot(
+                attended.reshape(B, T, heads * D).astype(self.dtype),
+                w_o.astype(self.dtype), preferred_element_type=F32)
             carry = (ring_k, ring_v, (position[:, -1] + 1).astype(F32))
         return out, carry
 
@@ -688,10 +748,12 @@ class _RotaryAttention(nn.Module):
     def blockwise(self, attend, q, keys, values, position, seg,
                   key_position, key_seg):
         """The attended values ``[B, T, KV, G, D]`` of T steps over
-        ``window_keys``, a block of ``sliding_window`` queries at a time. A
-        key is (position, segment); query t sees the keys of its segment at
-        positions up to its own and, in a ``W`` layer, less than
-        ``sliding_window`` below it.
+        ``window_keys``, a block of ``QUERY_BLOCK`` queries at a time (fewer
+        where the window or the call is shorter). A key is (position,
+        segment); query t sees the keys of its segment at positions up to
+        its own and, in a ``W`` layer, less than ``sliding_window`` below
+        it. Which keys a block reads at all is the kernels' rule
+        (``pallas_attention.key_ranges``).
 
         Plain ``jax.numpy``: every block's scores go through HBM. It is the
         path of every backend but a TPU, where ``ops/pallas_attention.py``
@@ -699,7 +761,7 @@ class _RotaryAttention(nn.Module):
         to (``tests/test_pallas_attention.py``)."""
         B, T = position.shape
         history, window = keys.shape[1] - T, self.cfg.sliding_window
-        block = min(window, T)
+        block = min(window, T, QUERY_BLOCK)
         pad = -T % block
 
         def padded(v, value=0):
@@ -711,10 +773,12 @@ class _RotaryAttention(nn.Module):
         key_seg = padded(key_seg, pallas_attention.INVALID_KEY)
         out = []
         for lo in range(0, T + pad, block):
-            # the keys a block can see at all: a ``W`` block the block
-            # before it (the ring, for the first) and itself; an ``F``
-            # block everything up to itself
-            first = history + lo - block if self.windowed and lo else 0
+            # the keys a block can see at all: an ``F`` block everything
+            # up to itself, and so a ``W`` block that starts inside the
+            # call's first window (any slot of the ring may lie in it);
+            # after that the steps a window before it and itself
+            first = (history + lo - window
+                     if self.windowed and lo >= window else 0)
             last = history + lo + block
             below = (position[:, lo:lo + block, None]
                      - key_position[:, None, first:last])
@@ -764,7 +828,7 @@ class _DenseMLP(nn.Module):
                             (width, hidden))
         with jax.named_scope("mlp_dense"):
             u16 = u.astype(self.dtype)
-            act = silu_gated(u16, w_gate.astype(self.dtype), jnp.dot(
+            act = gated(jax.nn.silu, u16, w_gate.astype(self.dtype), jnp.dot(
                 u16, w_up.astype(self.dtype), preferred_element_type=F32))
             out = jnp.dot(act.astype(self.dtype), w_down.astype(self.dtype),
                           preferred_element_type=F32)
@@ -777,6 +841,8 @@ _MIXERS = {"M": _Mamba2, "E": _Experts, "*": _Attention, "D": _DenseMLP,
 #: The letters whose sublayer is a ``_RotaryAttention``: each takes its
 #: head count from ``attention_heads_per_layer``, in the pattern's order.
 ROTARY = "FW"
+#: Queries a block of ``_RotaryAttention.blockwise``: the kernels' tile.
+QUERY_BLOCK = pallas_attention.TILES.bq
 
 
 def rotary_heads(cfg: CoreConfig) -> Tuple[int, ...]:
@@ -787,24 +853,51 @@ def rotary_heads(cfg: CoreConfig) -> Tuple[int, ...]:
                  for kind in cfg.pattern)
 
 
+def routes_ahead(cfg: CoreConfig) -> Tuple[bool, ...]:
+    """Which sublayers of the pattern make the routing of the ``E`` sublayer
+    behind them (``router_ahead``): the one before each."""
+    if not cfg.router_ahead:
+        return (False,) * len(cfg.pattern)
+    if cfg.pattern.startswith("E") or "EE" in cfg.pattern:
+        raise ValueError(
+            f"router_ahead: an E sublayer of {cfg.pattern!r} has no "
+            "sublayer of another kind before it to route from")
+    return tuple(later == "E" for later in cfg.pattern[1:]) + (False,)
+
+
 class _Layer(nn.Module):
     """``x + mixer(RMSNorm(x))``; a module of its own so that ``nn.remat``
     can wrap it: a layer's activations are then recomputed in the backward
-    pass, and only its input lives through the loss."""
+    pass, and only its input lives through the loss — and, under
+    ``router_ahead``, the routing that crosses from a sublayer to the
+    experts behind it: ``routes`` says this sublayer's normed input is what
+    the NEXT sublayer's router reads, so the router's weights (``router``,
+    beside ``norm``) and its float32 logits ``[B, T, routed]`` are made
+    here and returned; the experts' sublayer is handed them (``routing``)
+    and hands on none."""
 
     kind: str
     cfg: CoreConfig
     dtype: jnp.dtype
     heads: int = 0      # an ``F`` or ``W`` sublayer's query heads
+    routes: bool = False
 
     @nn.compact
-    def __call__(self, x: Array, seg: Array, carry):
+    def __call__(self, x: Array, seg: Array, carry, routing=None):
         scale = self.param("norm", nn.initializers.ones, (x.shape[-1],))
         its_own = {"heads": self.heads} if self.kind in ROTARY else {}
+        u = rms_norm(x, scale, self.cfg.norm_eps)
         out, carry = _MIXERS[self.kind](
             self.cfg, self.dtype, name="mixer", **its_own)(
-                rms_norm(x, scale, self.cfg.norm_eps), seg, carry)
-        return x + out, carry
+                u, seg, carry, *(() if routing is None else (routing,)))
+        if not self.routes:
+            return x + out, carry, None
+        w_router = self.param("router", _normal(x.shape[-1] ** -0.5),
+                              (x.shape[-1], self.cfg.n_routed_experts))
+        with jax.named_scope("moe_router"):
+            logits = jnp.dot(u, w_router,
+                             precision=jax.lax.Precision.HIGHEST)
+        return x + out, carry, logits
 
 
 class _Core(nn.Module):
@@ -817,11 +910,13 @@ class _Core(nn.Module):
     def __call__(self, x: Array, reset: Array, carry):
         seg = segments(reset)
         new_carry = []
-        for i, (kind, heads) in enumerate(zip(self.cfg.pattern,
-                                              rotary_heads(self.cfg))):
-            x, layer_carry = nn.remat(_Layer)(
-                kind, self.cfg, self.dtype, heads, name=f"layer_{i}")(
-                    x, seg, carry[i])
+        routing = None
+        for i, (kind, heads, routes) in enumerate(zip(
+                self.cfg.pattern, rotary_heads(self.cfg),
+                routes_ahead(self.cfg))):
+            x, layer_carry, routing = nn.remat(_Layer)(
+                kind, self.cfg, self.dtype, heads, routes,
+                name=f"layer_{i}")(x, seg, carry[i], routing)
             new_carry.append(layer_carry)
         scale = self.param("norm_f", nn.initializers.ones, (x.shape[-1],))
         return rms_norm(x, scale, self.cfg.norm_eps), tuple(new_carry)
@@ -926,16 +1021,21 @@ class HybridQNetwork(nn.Module):
         steps x query heads, the burn-in call and the call over the other
         ``steps``, summed over the layers of a kind) that ONE forward pass
         of a learner's batch sends through the rotary kernel
-        (``pallas_attention.attend``'s ``rotary``). Empty where the learner
-        takes ``rotate`` (no TPU), and for a core without such layers."""
+        (``pallas_attention.attend``'s ``rotary``); 0 for a kind without a
+        position embedding, whose queries go to the kernels as they are.
+        Empty where the learner takes ``rotate`` (no TPU), and for a core
+        without such layers."""
+        cfg = self.core
         found: dict = {}
         if not loop_common.pallas_routing(True)[0]:
             return found
-        for kind, heads in zip(self.core.pattern, rotary_heads(self.core)):
+        for kind, heads in zip(cfg.pattern, rotary_heads(cfg)):
             if kind in ROTARY:
-                name = "window" if kind == "W" else "full"
-                found[name] = (found.get(name, 0)
-                               + windows * (burn_in + steps) * heads)
+                name, rope = (("window", cfg.rope_window) if kind == "W"
+                              else ("full", cfg.rope_full))
+                found[name] = found.get(name, 0) + (
+                    windows * (burn_in + steps) * heads
+                    if rope.rotary_factor else 0)
         return found
 
     def reset_state(self, carry, done: Array):
