@@ -71,8 +71,19 @@ the next, except the routing under ``router_ahead`` (below); one letter of
        kernel's oracle); on every other backend ``blockwise``, plain
        ``jax.numpy`` whose blocks' scores go through HBM — the kernels'
        oracle, which reads the key ranges they read. ``loop_common.pallas_routing``
-       chooses (no option does). The acting step (``T == 1``) reads its
-       ring with one small masked softmax on either.
+       chooses (no option does). The acting step (``T == 1``) writes its
+       key and value into their slot and its one query a head reads the
+       ring — float32, whatever the compute type — by the same routing: on
+       a TPU ``pallas_attention.decode`` reads each ring ONCE where it lies
+       in HBM, a block of slots at a time, rounds the block to the compute
+       type in VMEM on its way to the product (the rounding of a whole-ring
+       ``astype``), keeps scores, mask, online softmax and the values
+       product there, and writes nothing the size of a ring; slots past
+       the lane's count are masked, not skipped, so a step costs the same
+       at every position. On every other backend ``attend`` — one masked
+       softmax over the ring cast to the compute type in front of its two
+       products (a second and third pass over the ring) — which is also
+       ``blockwise``'s block function and ``decode``'s oracle.
 ``D``  A dense gated MLP, ``W_down (silu(W_gate u) * W_up u)``.
 ``E`` takes the expert's form from the configuration (``expert_act``:
 ``relu2`` above, or gated as ``D`` with ``silu`` or ``relu``, ReGLU), its
@@ -686,16 +697,26 @@ class _RotaryAttention(nn.Module):
             if w_g is not None:
                 gate = jax.nn.sigmoid(jnp.dot(u16, w_g.astype(self.dtype),
                                               preferred_element_type=F32))
+            use_kernel, interpret = loop_common.pallas_routing(True)
             if T == 1 and history:
                 # acting: the new key takes its slot, then the one query
-                # reads the ring; what the ring holds is what it may see
+                # reads the ring; what the ring holds is what it may see.
+                # On a TPU the float32 ring is read once, where it lies
+                # (``pallas_attention.decode``); ``attend`` casts all of it
+                # in front of its products
                 lanes, slot = jnp.arange(B), position[:, 0] % history
                 ring_k = old_k.at[lanes, slot].set(new_k[:, 0])
                 ring_v = old_v.at[lanes, slot].set(new_v[:, 0])
-                see = (jnp.arange(history)
-                       < jnp.minimum(position + 1, history))   # [B, S]
-                attended = attend(grouped(turned(q)), ring_k, ring_v,
-                                  see[:, None])
+                if use_kernel:
+                    count = jnp.minimum(position[:, 0] + 1, history)  # [B]
+                    attended = pallas_attention.decode(
+                        grouped(turned(q))[:, 0], ring_k, ring_v, count,
+                        self.dtype, interpret=interpret)
+                else:
+                    see = (jnp.arange(history)
+                           < jnp.minimum(position + 1, history))   # [B, S]
+                    attended = attend(grouped(turned(q)), ring_k, ring_v,
+                                      see[:, None])
             else:
                 # a learner's window: the fused kernels on a TPU — the
                 # queries rotated on their way into the kernels' layout —
@@ -703,7 +724,6 @@ class _RotaryAttention(nn.Module):
                 # (``loop_common.pallas_routing``)
                 keys, values, key_position, key_seg = self.window_keys(
                     new_k, new_v, position, seg, carry)
-                use_kernel, interpret = loop_common.pallas_routing(True)
                 if use_kernel:
                     attended = pallas_attention.attend(
                         grouped(q), keys, values, position, seg,
@@ -981,6 +1001,30 @@ class HybridQNetwork(nn.Module):
             for leaf in jax.tree.leaves(layer):
                 found[names[kind]] = (found.get(names[kind], 0)
                                       + leaf.size * leaf.dtype.itemsize)
+        return found
+
+    def attention_ring_bytes(self, lanes: int) -> dict:
+        """``{"window" | "full": (read, copied)}``: the bytes of rings —
+        keys and values, summed over the layers of a kind — that ONE acting
+        step of ``lanes`` lanes reads, and the bytes of ring-sized copies
+        the path it takes writes on the way: none through
+        ``pallas_attention.decode`` (a TPU), the rings once more in the
+        compute type where ``attend`` casts them in front of its products.
+        Empty for a core without such layers."""
+        found: dict = {}
+        width = jnp.dtype(self.compute_dtype).itemsize
+        in_place = loop_common.pallas_routing(True)[0]
+        state = jax.eval_shape(lambda: self.initial_state(lanes))
+        for kind, layer in zip(self.core.pattern, state):
+            if kind not in ROTARY:
+                continue
+            name = "window" if kind == "W" else "full"
+            read, copied = found.get(name, (0, 0))
+            for ring in layer[:2]:
+                read += ring.size * ring.dtype.itemsize
+                if not in_place and width != ring.dtype.itemsize:
+                    copied += ring.size * width
+            found[name] = (read, copied)
         return found
 
     def attention_key_blocks(self, windows: int, burn_in: int,

@@ -1,7 +1,8 @@
 """Pallas TPU kernels: the learner's rotary attention over a long window
 (``models/sequence_core.py _RotaryAttention``, layers ``F`` and ``W``) with
 its scores kept in VMEM, forward and backward, and its queries rotated on
-their way into the kernels' layout.
+their way into the kernels' layout; and acting's one query a head over the
+float32 ring where it lies (``decode``, at the end).
 
 The plain path (``_RotaryAttention.blockwise``) makes every block's scores
 in HBM: written in float32, read back for the mask and the softmax, written
@@ -97,6 +98,26 @@ and the backward's rounding where ``_attend_bwd`` rounds; equal to ``rotate``
 + ``kv_major`` to the last bit (``tests/test_pallas_rotary.py``). ``rotate``
 stays: the keys' path (an eighth of the bytes, cached rotated in float32),
 acting's, every other backend's, and these kernels' oracle.
+
+**Acting** (``rotary_attention_decode``; ``decode``). A lane's acting step
+has ONE query a head and a ring of float32 keys and values ``[B, S, KV, D]``
+(1.34 GB for the ``smallthinker_q`` preset's 16 lanes). The plain ``attend``
+casts every ring to the compute type in front of its two products: the ring
+read, a copy half its size written and read again, every step (``PERF.md``
+§6, PR 51: 2.65 of 5.45 ms). Here the ring is read once: grid ``(lane, block
+of slots)``, the blocks of a lane in sequence. A block is ``[slots * KV, D]``
+of the ring's own bytes (``[B, S, KV, D]`` seen as ``[B, S * KV, D]``: a
+bitcast, row ``s * KV + h``), so all KV heads ride in one instance and
+nothing is transposed or copied in front. The queries of all heads, a KV
+head's ``G`` padded to the 8 sublanes of a tile (``[KV * 8, D]``), meet all
+the block's rows in one product ``[KV * 8, slots * KV]``; a score counts
+where its row's KV head is its column's and its slot lies below the lane's
+``count``; the others are masked, their probabilities are exactly 0 and add
+nothing. The block is rounded to the operands' type in VMEM — the same
+round-to-nearest ``astype`` — scores, running maximum, sum and the ``[KV *
+8, D]`` accumulator are float32 (the forward kernel's scheme at one query a
+head), and the last block divides. Slots at or past ``count`` are masked,
+NOT skipped: every block is fetched whatever the lane's position.
 """
 from __future__ import annotations
 
@@ -117,6 +138,7 @@ FORWARD_NAME = "rotary_attention_forward"    # findable in HLO text, traces
 BACKWARD_NAME = "rotary_attention_backward"
 EMBED_FORWARD_NAME = "rotary_embed_forward"
 EMBED_BACKWARD_NAME = "rotary_embed_backward"
+DECODE_NAME = "rotary_attention_decode"
 NEG = -1e30         # a masked score; finite, so that NEG - NEG is 0, not NaN
 INVALID_KEY = -1    # the segment of a key no query may see
 PADDING_QUERY = -2  # the segment of a padding query: it sees no key at all
@@ -126,6 +148,7 @@ _LANES = 128
 # plus the tiles and the temporaries of one step.
 _VMEM_STEP = 24 << 20
 _VMEM_MOST = 100 << 20
+_VMEM_LEAST = 16 << 20     # Mosaic's default scope
 
 
 class Tiles(NamedTuple):
@@ -523,6 +546,117 @@ def _embed(x, tables, shifts: Tuple[int, ...], dtype, *,
         name=EMBED_FORWARD_NAME if forward else EMBED_BACKWARD_NAME,
         interpret=interpret,
     )(x, tables)
+
+
+# -- acting: one query a lane over the float32 ring, where it lies -------------
+#: Ring slots a block of the decode kernel (``scripts/attention_sweep.py
+#: acting``; ``PERF.md`` §6 PR 51).
+DECODE_BLOCK = 512
+_SUBLANES = 8       # rows a float32 tile: a KV head's queries are padded to it
+
+
+def _decode_kernel(count_ref, q_ref, k_ref, v_ref, out_ref, m_ref, l_ref,
+                   acc_ref, *, kv, slots, history, dtype):
+    """One lane's block of ring slots. The block is ``[slots * kv, D]``
+    float32 as the ring lies: row ``s * kv + h`` is slot s of KV head h. The
+    queries of ALL heads (``[kv * 8, D]``, a KV head's G padded to 8 rows)
+    meet all rows in one product; a score counts where its row's KV head is
+    its column's and the slot lies below the lane's count — the others are
+    masked and their probabilities are exactly 0, so they add nothing to the
+    sums or to the values product."""
+    b, j = pl.program_id(0), pl.program_id(1)
+    rows, D = q_ref.shape
+    cols = k_ref.shape[0]
+
+    @pl.when(j == 0)
+    def _():
+        m_ref[...] = jnp.full_like(m_ref, NEG)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    row = jax.lax.broadcasted_iota(jnp.int32, (rows, cols), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (rows, cols), 1)
+    slot = j * slots + jax.lax.div(col, kv)
+    see = jnp.logical_and(jax.lax.rem(col, kv) == jax.lax.div(row, _SUBLANES),
+                          slot < count_ref[b])
+    # the ring's values rounded to the operands' type HERE, on their way to
+    # the product: what the whole-ring ``astype`` in front of it did
+    scores = jax.lax.dot_general(
+        q_ref[...], k_ref[...].astype(dtype), _NT,
+        preferred_element_type=F32) * D ** -0.5           # [rows, cols]
+    scores = jnp.where(see, scores, NEG)
+    m_old = m_ref[...]
+    m_new = jnp.maximum(m_old, jnp.max(scores, axis=1, keepdims=True))
+    keep = jnp.exp(m_old - m_new)
+    # slot 0 lies in every lane's count: after the first block every row's
+    # maximum is a score, and a masked column reads exp(NEG - score) = 0
+    p = jnp.exp(scores - m_new)
+    l_ref[...] = keep * l_ref[...] + jnp.sum(p, axis=1, keepdims=True)
+    m_ref[...] = m_new
+    v = v_ref[...]
+    if history % slots:
+        # the last block reaches past the ring: what lies there is not a
+        # number to multiply by 0
+        at = jax.lax.broadcasted_iota(jnp.int32, (cols, 1), 0)
+        v = jnp.where(j * slots + jax.lax.div(at, kv) < history, v, 0.0)
+    acc_ref[...] = keep * acc_ref[...] + jnp.dot(
+        p.astype(dtype), v.astype(dtype), preferred_element_type=F32)
+
+    @pl.when(j == pl.num_programs(1) - 1)
+    def _():
+        out_ref[...] = acc_ref[...] / l_ref[...]
+
+
+def decode(q: Array, ring_k: Array, ring_v: Array, count: Array, dtype, *,
+           interpret: bool = False, block: Optional[int] = None) -> Array:
+    """The attended values ``[B, KV, G, D]`` float32 of ONE query a head,
+    ``q [B, KV, G, D]``, over a lane's ring ``ring_k, ring_v [B, S, KV, D]``
+    (float32; read once, a block of slots at a time, as they lie in HBM: no
+    array the size of a ring is written) where slot s is seen while ``s <
+    count [B]``. Operands of both products are the ``dtype`` roundings of
+    ``q``, of what the ring holds and of the probabilities; accumulation
+    and the online softmax are float32 — ``_RotaryAttention``'s ``attend``
+    at one query. Slots at or past ``count`` are masked, not skipped: every
+    block is fetched whatever the lane's position. ``block`` (slots) is for
+    tests and sweeps."""
+    if interpret and jax.default_backend() == "tpu":
+        raise ValueError(
+            "the attention kernels are never interpreted on a TPU backend")
+    B, KV, G, D = q.shape
+    S = ring_k.shape[1]
+    slots = min(block or DECODE_BLOCK, S)
+    rows = KV * _SUBLANES
+    if G > _SUBLANES:
+        raise ValueError(f"{G} query heads a KV head: the kernel holds 8")
+    q = jnp.pad(q.astype(dtype), ((0, 0), (0, 0), (0, _SUBLANES - G), (0, 0)))
+    # [B, S, KV, D] -> [B, S * KV, D]: the same bytes in the same order
+    flat = (B, S * KV, D)
+    tile = pl.BlockSpec((None, slots * KV, D), lambda b, j, count: (b, j, 0))
+    whole = pl.BlockSpec((None, rows, D), lambda b, j, count: (b, 0, 0))
+    out = pl.pallas_call(
+        functools.partial(_decode_kernel, kv=KV, slots=slots, history=S,
+                          dtype=dtype),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(B, _cdiv(S, slots)),
+            in_specs=[whole, tile, tile],
+            out_specs=whole,
+            scratch_shapes=[pltpu.VMEM((rows, 1), F32),
+                            pltpu.VMEM((rows, 1), F32),
+                            pltpu.VMEM((rows, D), F32)]),
+        out_shape=jax.ShapeDtypeStruct((B, rows, D), F32),
+        # VMEM for the two rings' blocks, double-buffered, their roundings
+        # and the scores, and no more: what a kernel reserves the compiler
+        # cannot fill with the acting step's weights ahead of their
+        # products while the kernel runs (``PERF.md`` §6 PR 51)
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=max(
+                _VMEM_LEAST, 8 * slots * KV * D * ring_k.dtype.itemsize)),
+        name=DECODE_NAME, interpret=interpret,
+    )(count.astype(jnp.int32), q.reshape(B, rows, D), ring_k.reshape(flat),
+      ring_v.reshape(flat))
+    return out.reshape(B, KV, _SUBLANES, D)[:, :, :G]
 
 
 # -- the two routes into the kernels ------------------------------------------

@@ -226,6 +226,16 @@ def train(cfg: ExperimentConfig, total_env_steps: int = 0, seed: int = None,
                    "bytes of one lane's acting state, by kind of cache "
                    "(summed over the layers of that kind)",
                    {"cache": cache}).set(nbytes)
+    # What one acting step of all lanes reads of those rings, and what the
+    # path it takes copies of them on the way (nothing on a TPU).
+    for kind, sizes in getattr(net, "attention_ring_bytes", lambda _: {})(
+            cfg.actor.num_envs).items():
+        for state, nbytes in zip(("read", "copied"), sizes):
+            _reg.gauge("dqn_actor_attention_ring_bytes",
+                       "bytes of attention rings (keys and values) one "
+                       "acting step of all lanes reads, and bytes of "
+                       "ring-sized copies it writes on the way, by kind of "
+                       "layer", {"kind": kind, "state": state}).set(nbytes)
     # What the learner's attention kernels read and leave out, from their
     # static grids (ops/pallas_attention.py); nothing where none runs.
     for kind, blocks in getattr(net, "attention_key_blocks",

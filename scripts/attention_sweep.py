@@ -18,7 +18,13 @@ against the blocks), ``rotary`` (the queries' rotary pass alone — the kernel
 by steps a block and rows a loop pass, beside ``rotate`` + ``kv_major``: ms a
 call, bytes moved, share of the HBM's rate; ``EMBED_BLOCK`` / ``EMBED_ROWS``
 were chosen from it, ``PERF.md`` §6 PR 48) and ``sublayer`` (one sublayer's
-grad pass op by op).
+grad pass op by op). A fifth runs only when named, ``acting``: ONE acting
+step's attention alone — the one query a head over the float32 ring of the
+``smallthinker_q`` and ``laguna_q`` presets' own shapes and lanes, a kind of
+layer at a time: the plain path (the whole-ring cast in front of two
+products) and the decode kernel by slots a block, ms a call, the ops by name,
+the share of the HBM's rate the ring's bytes moved at, results compared
+(``DECODE_BLOCK`` was chosen from it, ``PERF.md`` §6 PR 51).
 """
 from __future__ import annotations
 
@@ -43,6 +49,7 @@ from dist_dqn_tpu.ops import pallas_attention as pa  # noqa: E402
 SMOKE = "--smoke" in sys.argv     # the script's own rehearsal on a CPU
 PARTS = [a for a in sys.argv[1:] if not a.startswith("--")] or [
     "tiles", "path", "rotary", "sublayer"]
+DECODE_BLOCKS = (16, 512) if SMOKE else (256, 512, 1024, 2048)
 B, KV, D, HISTORY, WINDOW = (1, 1, 16, 128, 128) if SMOKE else (
     4, 8, 128, 512, 512)
 UNROLL, BURN_IN = (256, 128) if SMOKE else (1536, 512)
@@ -173,6 +180,79 @@ def rotary_rows(say, rng, kind, G, rope, T, call):
     pa.EMBED_BLOCK, pa.EMBED_ROWS = shipped
 
 
+def acting_rows(say, rng, preset):
+    """One acting step's attention alone at ``preset``'s ring shapes and
+    lanes, a kind at a time: the plain path and the decode kernel."""
+    from perf.reduce import peaks
+
+    hbm_rate = peaks.peak("TPU v5e" if SMOKE else
+                          jax.devices()[0].device_kind, "hbm_bytes_per_s")
+    cfg = CONFIGS[preset]
+    core = cfg.network.core
+    lanes, KV, D = cfg.actor.num_envs, core.num_key_value_heads, core.head_dim
+    rings = {kind: (core.sliding_window if kind == "W"
+                    else core.attention_window, heads // KV)
+             for kind, heads in zip(core.pattern,
+                                    sequence_core.rotary_heads(core))
+             if kind in sequence_core.ROTARY}
+    for kind, (S, G) in rings.items():
+        if SMOKE:
+            lanes, S, D = 4, 40, 16
+        q = jnp.asarray(rng.normal(size=(lanes, KV, G, D)), jnp.float32)
+        ring_k, ring_v = (jnp.asarray(rng.normal(size=(lanes, S, KV, D)),
+                                      jnp.float32) for _ in range(2))
+        # lanes just reset, part full, exactly full and wrapped
+        count = jnp.asarray(
+            [(1, S // 3, S, S)[lane % 4] for lane in range(lanes)], jnp.int32)
+
+        def plain(q, ring_k, ring_v, count):
+            see = jnp.arange(S) < count[:, None]
+            scores = jnp.einsum(
+                "bkgd,bskd->bkgs", q.astype(jnp.bfloat16),
+                ring_k.astype(jnp.bfloat16),
+                preferred_element_type=jnp.float32) * D ** -0.5
+            scores = jnp.where(see[:, None, None], scores, -1e30)
+            return jnp.einsum(
+                "bkgs,bskd->bkgd",
+                jax.nn.softmax(scores, axis=-1).astype(jnp.bfloat16),
+                ring_v.astype(jnp.bfloat16),
+                preferred_element_type=jnp.float32)
+
+        args = (q, ring_k, ring_v, count)
+        read = 2 * ring_k.size * 4
+        plain = jax.jit(plain)
+        want = plain(*args)
+        took = seconds(plain, *args)
+        say(preset=preset, kind=kind, what="acting", path="plain",
+            ring=list(ring_k.shape), G=G, ms=took * 1e3, ring_bytes=read,
+            hbm_share=read / took / hbm_rate,
+            ops=None if SMOKE else top_ops(plain, *args, top=8))
+        # the ring rounded OUTSIDE the program: the kernel must round as that
+        rounded = tuple(r.astype(jnp.bfloat16).astype(jnp.float32)
+                        for r in (ring_k, ring_v))
+        for block in sorted({min(block, S) for block in DECODE_BLOCKS}):
+            fn = jax.jit(lambda *a: pa.decode(
+                *a, jnp.bfloat16, interpret=SMOKE, block=block))
+            shipped = block == min(pa.DECODE_BLOCK, S)
+            try:
+                got = fn(*args)
+                took = seconds(fn, *args)
+            except Exception as e:  # noqa: BLE001 - a block Mosaic refuses
+                say(preset=preset, kind=kind, what="acting", path="kernel",
+                    block=block, refused=str(e)[:300])
+                continue
+            say(preset=preset, kind=kind, what="acting", path="kernel",
+                block=block, shipped=shipped, ring=list(ring_k.shape), G=G,
+                ms=took * 1e3, ring_bytes=read,
+                hbm_share=read / took / hbm_rate,
+                max_gap=float(jnp.max(jnp.abs(got - want))),
+                max_size=float(jnp.max(jnp.abs(want))),
+                rounds_as_the_cast=bool(jnp.array_equal(
+                    got, fn(q, *rounded, count))),
+                ops=(top_ops(fn, *args, top=8)
+                     if shipped and not SMOKE else None))
+
+
 def main():
     if jax.default_backend() != "tpu" and not SMOKE:
         raise SystemExit("attention_sweep times kernels: it needs a TPU")
@@ -190,6 +270,9 @@ def main():
         rows.append(row)
         print(json.dumps(row), flush=True)
 
+    if "acting" in PARTS:
+        for preset in ("smallthinker_q", "laguna_q"):
+            acting_rows(say, rng, preset)
     for kind, G in (("W", 8), ("F", 6)):
         windowed = kind == "W"
         rope = core.rope_window if windowed else core.rope_full

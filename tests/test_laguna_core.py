@@ -297,7 +297,9 @@ def test_blockwise_attention_is_masked_attention(kind, heads, before):
         np.testing.assert_allclose(got, want, rtol=1e-3, atol=1e-4)
 
 
-def test_acting_step_by_step_through_both_rings_is_the_unroll():
+@pytest.mark.parametrize("route", ["plain", "kernels"])
+def test_acting_step_by_step_through_both_rings_is_the_unroll(
+        monkeypatch, route):
     """29 steps — seven sliding windows, so every ``W`` ring wraps many
     times; the ``F`` rings (32 slots) do not — with an episode boundary a
     lane: the network stepped through its carry (one slot of each ring
@@ -306,7 +308,19 @@ def test_acting_step_by_step_through_both_rings_is_the_unroll():
     the reset flags; so does an unroll split in two, the way the learner
     splits burn-in from loss; and the three carries go on alike."""
     from dist_dqn_tpu.agents import make_agent
+    from dist_dqn_tpu.ops import pallas_attention as kernels
 
+    # "kernels": the route a TPU takes (``loop_common.pallas_routing``),
+    # interpreted — acting's one query a head through ``decode`` over the
+    # float32 rings, through a reset and past a wrap, the unroll through the
+    # learner's kernels
+    decoded, decode = [], kernels.decode
+    monkeypatch.setattr(kernels, "decode", lambda *a, **k: (
+        decoded.append(a[1].shape), decode(*a, **k))[1])
+    if route == "kernels":
+        monkeypatch.setenv("DIST_DQN_PALLAS_INTERPRET", "1")
+    else:
+        monkeypatch.delenv("DIST_DQN_PALLAS_INTERPRET", raising=False)
     cfg, env, net = _setup()
     T, B = 29, 2
     obs = jax.random.normal(jax.random.PRNGKey(0),
@@ -346,6 +360,8 @@ def test_acting_step_by_step_through_both_rings_is_the_unroll():
     for layer in stepped:
         if layer:
             np.testing.assert_array_equal(layer[2], [27.0, 13.0])
+    rings = {layer[0].shape for layer in carry if layer}
+    assert set(decoded) == (rings if route == "kernels" else set())
     assert agent.stored_state(carry) == ()
     # emptying a lane leaves its rings where they lie
     emptied = agent.reset_state(stepped, jnp.asarray([True, False]))

@@ -7,6 +7,7 @@ can give the mask; that the comparison tells a band one step too wide; which
 route a learner takes where; and the static grid the start-up gauge reports.
 """
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -385,3 +386,71 @@ def test_a_sublayers_grad_pass_holds_no_half_empty_array(v5e, monkeypatch,
         assert name in text
     for half_empty in ("[4,1536,64,64]", "[4,1536,48,32]", "[4,1536,48,64]"):
         assert half_empty not in text
+
+
+@pytest.mark.parametrize("history,kv,G", [
+    (4096, 4, 7), (8192, 4, 7), (2048, 8, 6), (512, 8, 8)])
+def test_the_decode_kernel_compiles_for_v5e_at_the_presets_rings(v5e, history,
+                                                                 kv, G):
+    """One acting step of 16 lanes over a ring of the ``smallthinker_q`` and
+    ``laguna_q`` presets — the new key and value into their slot, then
+    ``decode`` — through Mosaic at ``DECODE_BLOCK``: the kernel by name, the
+    ring handed to it where it lies (its ``[B, S * KV, D]`` view is the same
+    bytes: no copy), and no bfloat16 array of a ring's size, in any layout."""
+    lanes, d = 16, 128
+
+    def step(q, old_k, old_v, new_k, new_v, position):
+        at = (jnp.arange(lanes), position % history)
+        ring_k, ring_v = old_k.at[at].set(new_k), old_v.at[at].set(new_v)
+        return pallas_attention.decode(
+            q, ring_k, ring_v, jnp.minimum(position + 1, history),
+            jnp.bfloat16), ring_k, ring_v
+
+    def shape(*dims, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=v5e)
+
+    text = _compiled_for(
+        (shape(lanes, kv, G, d), shape(lanes, history, kv, d),
+         shape(lanes, history, kv, d), shape(lanes, kv, d),
+         shape(lanes, kv, d), shape(lanes, dtype=jnp.int32)), step)
+    assert pallas_attention.DECODE_NAME in text
+    assert f"bf16[{lanes},{history}," not in text
+    assert f"bf16[{lanes},{kv},{history}," not in text
+    flat = f"f32[{lanes},{history * kv},{d}]"
+    made = [line for line in text.splitlines() if f" = {flat}" in line]
+    assert made and all(" bitcast(" in line for line in made), made
+
+
+def test_an_acting_program_holds_no_bfloat16_ring(v5e, monkeypatch):
+    """A toy ``FEWE`` network's acting step (bfloat16 compute;
+    ``tests/test_smallthinker_core.py``'s toy of the ``smallthinker_q``
+    preset) lowered for v5e the way ``scripts/chunk_program_hash.py`` lowers a chunk
+    program: on the route a TPU takes its text holds the decode kernel and no
+    bfloat16 tensor of a ring's shape; on the plain route, which casts the
+    rings in front of its products, it holds one a ring."""
+    from dist_dqn_tpu import loop_common
+    from tests.test_smallthinker_core import _setup as toy_smallthinker
+
+    cfg, env, net = toy_smallthinker(compute_dtype="bfloat16")
+    assert cfg.network.core.pattern == "FEWE"
+    lanes = cfg.actor.num_envs
+    obs = jax.ShapeDtypeStruct((lanes,) + tuple(env.observation_shape),
+                               jnp.float32, sharding=v5e)
+    carry = jax.eval_shape(lambda: net.initial_state(lanes))
+    params = jax.eval_shape(net.init, jax.random.PRNGKey(0), carry, obs)
+    on_chip = functools.partial(jax.tree.map, lambda s: jax.ShapeDtypeStruct(
+        s.shape, s.dtype, sharding=v5e))
+    rings = {"x".join(map(str, layer[0].shape)) for layer in carry if layer}
+    assert len(rings) == 2      # the full layer's and the window layer's
+
+    def lowered(route):
+        monkeypatch.setattr(loop_common, "pallas_routing", route)
+        return jax.jit(net.apply, donate_argnums=1).lower(
+            on_chip(params), on_chip(carry), obs).as_text()
+
+    text = lowered(lambda enabled: (enabled, False))
+    assert pallas_attention.DECODE_NAME in text
+    assert not any(f"tensor<{ring}xbf16>" in text for ring in rings)
+    plain = lowered(lambda enabled: (False, False))
+    assert pallas_attention.DECODE_NAME not in plain
+    assert all(f"tensor<{ring}xbf16>" in plain for ring in rings)

@@ -364,7 +364,9 @@ def test_a_full_layer_launches_no_rotary_kernel(monkeypatch):
     assert seen == [False, False, True, True]     # init and apply, each
 
 
-def test_acting_step_by_step_through_both_rings_is_the_unroll():
+@pytest.mark.parametrize("route", ["plain", "kernels"])
+def test_acting_step_by_step_through_both_rings_is_the_unroll(
+        monkeypatch, route):
     """29 steps — nearly five sliding windows, so every ``W`` ring wraps;
     the ``F`` ring (32 slots) does not — with an episode boundary a lane: the
     network stepped through its carry (one slot of each ring written a step,
@@ -373,7 +375,19 @@ def test_acting_step_by_step_through_both_rings_is_the_unroll():
     Q-values of ``unroll`` over the same steps with the reset flags; so does
     an unroll split in two, the way the learner splits burn-in from loss."""
     from dist_dqn_tpu.agents import make_agent
+    from dist_dqn_tpu.ops import pallas_attention as kernels
 
+    # "kernels": the route a TPU takes (``loop_common.pallas_routing``),
+    # interpreted — acting's one query a head through ``decode`` over the
+    # float32 rings, through a reset and past a wrap, the unroll through the
+    # learner's kernels
+    decoded, decode = [], kernels.decode
+    monkeypatch.setattr(kernels, "decode", lambda *a, **k: (
+        decoded.append(a[1].shape), decode(*a, **k))[1])
+    if route == "kernels":
+        monkeypatch.setenv("DIST_DQN_PALLAS_INTERPRET", "1")
+    else:
+        monkeypatch.delenv("DIST_DQN_PALLAS_INTERPRET", raising=False)
     cfg, env, net = _setup()
     T, B = 29, 2
     obs = jax.random.normal(jax.random.PRNGKey(0),
@@ -410,6 +424,8 @@ def test_acting_step_by_step_through_both_rings_is_the_unroll():
     for layer in stepped:
         if layer:
             np.testing.assert_array_equal(layer[2], [27.0, 13.0])
+    rings = {layer[0].shape for layer in carry if layer}
+    assert set(decoded) == (rings if route == "kernels" else set())
     assert agent.stored_state(carry) == ()
 
 
