@@ -56,10 +56,10 @@ the next, except the routing under ``router_ahead`` (below); one letter of
        learner's window (``T > 1``) is computed by blocks of
        ``QUERY_BLOCK`` queries (the kernels' tile; the window need not be
        one), ``F`` against the keys up to its block (the causal triangle
-       by blocks), ``W`` the same while the block starts inside the
-       call's first window (any slot of the ring may lie in it) and against
-       the keys a window back and its own after (a band whose cost does not
-       grow with the window's length), by
+       by blocks), ``W`` against the keys a window back and its own (a
+       band whose cost does not grow with the window's length: its keys are
+       handed over in the order of time, so the band is a range of indices
+       whatever the ring's state), by
        one of two paths that hold to one mask rule
        (``_RotaryAttention.window_keys``): on a TPU the fused kernels of
        ``ops/pallas_attention.py``, which keep a tile's scores in VMEM,
@@ -625,7 +625,9 @@ class _RotaryAttention(nn.Module):
     """``F`` / ``W``: see the module's docstring. ``carry`` is ``(keys,
     values [B, history, KV, D], steps [B])``: a ring of the lane's rotated
     keys and its values, step p of the episode in slot ``p mod history``,
-    and how many steps the episode has had."""
+    and how many steps the episode has had. A learner's window reads an
+    ``F`` layer's ring as it lies and a ``W`` layer's turned into the order
+    of time (``window_keys``)."""
 
     cfg: CoreConfig
     dtype: jnp.dtype
@@ -748,18 +750,39 @@ class _RotaryAttention(nn.Module):
     def window_keys(self, new_k, new_v, position, seg, carry):
         """The keys a learner's window attends over, ``[ring || this
         call's]``: ``(keys, values [B, history + T, KV, D], position, segment
-        [B, history + T])``. The ring's slots lie in segment 0 at the
-        positions the counter gives them; a slot the episode has not reached
-        has the segment no query has (``pallas_attention.INVALID_KEY``)."""
+        [B, history + T])``. The ring's keys lie in segment 0 at the
+        positions the counter gives them; one the episode has not reached
+        has the segment no query has (``pallas_attention.INVALID_KEY``).
+
+        An ``F`` layer's ring comes as it lies: slot j holds the last
+        position below ``steps`` that is j mod history. A ``W`` layer's comes
+        in the ORDER OF TIME, right-aligned: index i holds position ``steps -
+        history + i`` (slot ``(steps + i) mod history``), invalid where that
+        is negative. A key of segment 0 then lies at index ``position +
+        history - steps`` whether the ring or this call holds it, and a
+        later segment's keys are this call's, a step an index: between a
+        query and a key of its segment the distance of the indices is the
+        distance of the positions — whether the ring is full, wrapped by
+        acting, cut by a reset in the burn-in or empty — and the band a
+        window layer reads is a range of indices
+        (``pallas_attention.key_ranges``)."""
         old_k, old_v, steps = carry
         history = old_k.shape[1]
         steps = steps.astype(jnp.int32)[:, None]
         slots = jnp.arange(history)
-        # slot j holds the last position below ``steps`` that is j mod
-        # history, if the episode has come that far
-        old_position = steps - 1 - (steps - 1 - slots) % max(history, 1)
-        old_seg = jnp.where(slots < jnp.minimum(steps, history), 0,
-                            pallas_attention.INVALID_KEY)
+        if self.windowed:
+            old_position = steps - history + slots              # [B, history]
+            old_seg = jnp.where(old_position >= 0, 0,
+                                pallas_attention.INVALID_KEY)
+            slot = ((steps + slots) % max(history, 1))[..., None, None]
+            old_k, old_v = (
+                jnp.take_along_axis(ring, slot, axis=1,
+                                    mode="promise_in_bounds")
+                for ring in (old_k, old_v))
+        else:
+            old_position = steps - 1 - (steps - 1 - slots) % max(history, 1)
+            old_seg = jnp.where(slots < jnp.minimum(steps, history), 0,
+                                pallas_attention.INVALID_KEY)
         return (jnp.concatenate([old_k, new_k], axis=1),
                 jnp.concatenate([old_v, new_v], axis=1),
                 jnp.concatenate([old_position, position], axis=1),
@@ -773,7 +796,8 @@ class _RotaryAttention(nn.Module):
         segment); query t sees the keys of its segment at positions up to
         its own and, in a ``W`` layer, less than ``sliding_window`` below
         it. Which keys a block reads at all is the kernels' rule
-        (``pallas_attention.key_ranges``).
+        (``pallas_attention.key_ranges``): a ``W`` layer's keys lie in the
+        order of time, so its band is a range of indices.
 
         Plain ``jax.numpy``: every block's scores go through HBM. It is the
         path of every backend but a TPU, where ``ops/pallas_attention.py``
@@ -794,11 +818,11 @@ class _RotaryAttention(nn.Module):
         out = []
         for lo in range(0, T + pad, block):
             # the keys a block can see at all: an ``F`` block everything
-            # up to itself, and so a ``W`` block that starts inside the
-            # call's first window (any slot of the ring may lie in it);
-            # after that the steps a window before it and itself
-            first = (history + lo - window
-                     if self.windowed and lo >= window else 0)
+            # up to itself, a ``W`` block those less than a window in front
+            # of its first query (``window_keys``: index distance is
+            # position distance)
+            first = (max(0, history + lo - window + 1) if self.windowed
+                     else 0)
             last = history + lo + block
             below = (position[:, lo:lo + block, None]
                      - key_position[:, None, first:last])
@@ -1034,9 +1058,12 @@ class HybridQNetwork(nn.Module):
         leave out in ONE forward pass of a learner's batch through the core
         — the burn-in call from the empty state, then the call over the
         other ``steps`` — summed over the layers of a kind, their KV heads
-        and the batch's ``windows``. A grad step runs those grids for both
-        networks and once more under ``nn.remat``. Empty where the learner
-        takes ``blockwise`` (no TPU), and for a core without such layers."""
+        and the batch's ``windows``: an ``F`` layer's grid is the causal
+        triangle, a ``W`` layer's the band a window wide (``key_ranges``;
+        its keys come in the order of time). A grad step runs those grids
+        for both networks and once more under ``nn.remat``. Empty where the
+        learner takes ``blockwise`` (no TPU), and for a core without such
+        layers."""
         cfg = self.core
         found: dict = {}
         if not loop_common.pallas_routing(True)[0]:

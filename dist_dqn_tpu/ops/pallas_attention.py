@@ -22,19 +22,22 @@ opened so far in this call; the ring lies in segment 0; an invalid key —
 an empty ring slot, padding — has a segment no query has, -1). Query t sees
 key s where ``k_seg[s] == q_seg[t]`` and ``0 <= q_position[t] -
 k_position[s]``, in a ``W`` layer also ``< window``. A reset inside a window
-restarts positions; one inside the burn-in leaves invalid slots between the
-ring's valid prefix and the new keys; a ring handed over from acting may
-have wrapped, so its slots are not in the order of their positions. The
-kernels build the mask of a tile from those four int32 vectors.
+restarts positions; one inside the burn-in leaves invalid keys in front of
+the ring's valid ones; an ``F`` layer's ring lies slot by slot, so one that
+acting has wrapped is not in the order of its positions. The kernels build
+the mask of a tile from those four int32 vectors.
 
 **What is static** is which key blocks a query block visits (``key_ranges``):
 a query block starting at new step ``lo`` reads keys ``[0, history + lo +
-bq)`` in an ``F`` layer, and in a ``W`` layer the same while ``lo < window``
-(any ring slot may lie inside the window) and ``[history + lo - window,
-history + lo + bq)`` after — ``blockwise``'s own rule at its block of 512.
-The ranges ride in as scalar-prefetch tables: the grid's innermost axis is
-as long as the longest range, a step past a block's range does nothing and
-re-reads nothing (its index map stays on the last block it read).
+bq)`` in an ``F`` layer and ``[history + lo - window + 1, history + lo + bq)``
+in a ``W`` layer — ``blockwise``'s own rule at its block of 512. The ``W``
+range counts on what ``_RotaryAttention.window_keys`` hands over: a ``W``
+layer's keys in the order of time, so that between a query and a key of its
+segment the distance of the indices IS the distance of the positions,
+whatever the ring's state. The ranges ride in as scalar-prefetch tables: the
+grid's innermost axis is as long as the longest range, a step past a block's
+range does nothing and re-reads nothing (its index map stays on the last
+block it read).
 
 **Both kernels work on the TRANSPOSED tile of scores, ``[bk, bq]``, one
 query head at a time** (a Python loop over the ``G = heads / KV`` heads of a
@@ -189,11 +192,12 @@ def key_ranges(steps: int, history: int, window: Optional[int], bq: int,
                bk: int) -> Tuple[np.ndarray, np.ndarray]:
     """``(first, count) [query blocks]`` int32: the key blocks of ``bk`` keys
     a block of ``bq`` queries visits, ``first .. first + count - 1``, among
-    the ``ceil((history + steps) / bk)`` there are."""
+    the ``ceil((history + steps) / bk)`` there are. A ``W`` layer's keys lie
+    in the order of time (``_RotaryAttention.window_keys``): the query at new
+    step t sees no key in front of index ``history + t - window + 1``."""
     first, count = [], []
     for lo in range(0, steps, bq):
-        start = (history + lo - window
-                 if window is not None and lo >= window else 0)
+        start = 0 if window is None else max(0, history + lo - window + 1)
         stop = min(history + lo + bq, history + steps)
         first.append(start // bk)
         count.append(_cdiv(stop, bk) - start // bk)

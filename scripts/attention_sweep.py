@@ -24,7 +24,13 @@ step's attention alone — the one query a head over the float32 ring of the
 layer at a time: the plain path (the whole-ring cast in front of two
 products) and the decode kernel by slots a block, ms a call, the ops by name,
 the share of the HBM's rate the ring's bytes moved at, results compared
-(``DECODE_BLOCK`` was chosen from it, ``PERF.md`` §6 PR 51).
+(``DECODE_BLOCK`` was chosen from it, ``PERF.md`` §6 PR 51). A sixth likewise,
+``band``: the ``smallthinker_q`` preset's ``W`` call alone (2 windows, 28
+heads over 4, 4,096 steps behind a ring of 4,096, window 4,096 — eight tiles)
+— the two kernels at ``TILES`` with the static grid they walk, ``window_keys``
+alone (what handing the ring over costs), and the whole path forward and
+backward op by op against the blocks (``PERF.md`` §6 PR 52;
+``docs/records/pr52/``).
 """
 from __future__ import annotations
 
@@ -91,15 +97,48 @@ def top_ops(fn, *args, top=30):
             ranked[:top]] + [["all", round(sum(totals.values()) * 1e3, 4)]]
 
 
-def window_of(rng, T, G, steps):
+def block_attend(q, keys, values, see):
+    """``blockwise``'s block function at the presets' precision: bfloat16
+    operands, float32 scores and softmax."""
+    scores = jnp.einsum(
+        "bqkgd,bskd->bkgqs", q.astype(jnp.bfloat16),
+        keys.astype(jnp.bfloat16),
+        preferred_element_type=jnp.float32) * q.shape[-1] ** -0.5
+    scores = jnp.where(see[:, None, None], scores, -1e30)
+    return jnp.einsum(
+        "bkgqs,bskd->bqkgd",
+        jax.nn.softmax(scores, axis=-1).astype(jnp.bfloat16),
+        values.astype(jnp.bfloat16), preferred_element_type=jnp.float32)
+
+
+def kernel_ms(direction, spec, kv, G, d, position, seg, key_position,
+              key_seg):
+    """One attention kernel alone at ``spec`` (``forward`` or ``backward``),
+    ms a call: constant operands padded to whole tiles, the call's marks."""
+    lanes = position.shape[0]
+    bq, bk = spec.tiles
+    Tp = -(-spec.steps // bq) * bq
+    Sp = -(-(spec.history + spec.steps) // bk) * bk
+    q16 = jnp.zeros((lanes, kv, G, Tp, d), jnp.bfloat16) + 0.1
+    k16 = jnp.zeros((lanes, kv, Sp, d), jnp.bfloat16) + 0.1
+    marks = pa._marks(position, seg, key_position, key_seg, Tp, Sp)
+    if direction == "forward":
+        return seconds(jax.jit(functools.partial(pa._forward, spec)),
+                       q16, k16, k16, marks) * 1e3
+    row = jnp.zeros((lanes, kv, G, Tp), jnp.float32)
+    return seconds(jax.jit(functools.partial(pa._backward, spec)),
+                   q16, k16, k16, marks, row, row, q16) * 1e3
+
+
+def window_of(rng, T, G, steps, lanes=B, kv=KV, d=D, history=HISTORY):
     """One call's inputs: a reset inside lane 0, lane 1's ring cut short by
     a reset in its burn-in (``steps`` < history)."""
-    q = jnp.asarray(rng.normal(size=(B, T, KV, G, D)), jnp.float32)
-    new_k, new_v = (jnp.asarray(rng.normal(size=(B, T, KV, D)), jnp.float32)
-                    for _ in range(2))
-    ring = tuple(jnp.asarray(rng.normal(size=(B, HISTORY, KV, D)),
+    q = jnp.asarray(rng.normal(size=(lanes, T, kv, G, d)), jnp.float32)
+    new_k, new_v = (jnp.asarray(rng.normal(size=(lanes, T, kv, d)),
+                                jnp.float32) for _ in range(2))
+    ring = tuple(jnp.asarray(rng.normal(size=(lanes, history, kv, d)),
                              jnp.float32) for _ in range(2))
-    reset = np.zeros((B, T), bool)
+    reset = np.zeros((lanes, T), bool)
     reset[0, T // 3] = True
     seg = sequence_core.segments(jnp.asarray(reset))
     index = jnp.arange(T)
@@ -253,6 +292,85 @@ def acting_rows(say, rng, preset):
                      if shipped and not SMOKE else None))
 
 
+def band_rows(say, rng):
+    """The ``smallthinker_q`` preset's ``W`` call alone: a window of eight
+    tiles, every query block of the call inside the first window."""
+    cfg = CONFIGS["smallthinker_q"]
+    core = cfg.network.core
+    lanes, kv, d = (cfg.learner.batch_size, core.num_key_value_heads,
+                    core.head_dim)
+    window, burn_in = core.sliding_window, cfg.replay.burn_in
+    unroll = cfg.replay.unroll_length + cfg.learner.n_step
+    G = max(sequence_core.rotary_heads(core)) // kv
+    if SMOKE:
+        lanes, kv, d, window, burn_in, unroll = 1, 1, 16, 256, 256, 256
+        core = dataclasses.replace(core, sliding_window=window, head_dim=d,
+                                   num_key_value_heads=kv)
+    layer = sequence_core._RotaryAttention(core, jnp.bfloat16, heads=G * kv,
+                                           windowed=True)
+    tiles = pa.fitted(pa.TILES, unroll, window)
+    # the unroll's rings: full, the last lane's cut short by a reset in its
+    # burn-in; the burn-in's: empty
+    cut = (window,) * (lanes - 1) + (window * 3 // 5,)
+    for call, T, steps in (("unroll", unroll, cut),
+                           ("burn_in", burn_in, (0,) * lanes)):
+        q, new_k, new_v, position, seg, carry = window_of(
+            rng, T, G, steps, lanes, kv, d, window)
+        order = jax.jit(layer.window_keys)
+        keys, values, key_position, key_seg = order(new_k, new_v, position,
+                                                    seg, carry)
+        pull = jnp.asarray(rng.normal(size=q.shape), jnp.float32)
+        visited, skipped = pa.key_block_census(T, window, window, tiles)
+        shape = dict(preset="smallthinker_q", kind="W", call=call,
+                     lanes=lanes, kv=kv, G=G, steps=T, history=window,
+                     window=window, tiles=list(tiles))
+        # -- the kernels alone --------------------------------------------
+        spec = pa._Spec(T, window, window, tiles, SMOKE)
+        for direction in ("forward", "backward")[:1 + (call == "unroll")]:
+            say(**shape, kernel=direction, visited=visited, skipped=skipped,
+                ms=kernel_ms(direction, spec, kv, G, d, position, seg,
+                             key_position, key_seg))
+        # -- handing the keys over: the ring's order, the concatenation ----
+        say(**shape, what="window_keys",
+            ms=seconds(order, new_k, new_v, position, seg, carry) * 1e3,
+            ring_bytes=2 * carry[0].size * 4,
+            ops=None if SMOKE else top_ops(order, new_k, new_v, position,
+                                           seg, carry, top=8))
+
+        # -- the whole path from the ring as it lies, against the blocks ---
+        def fused(q, new_k, new_v):
+            return pa.attend(
+                q, *layer.window_keys(new_k, new_v, position, seg, carry)[:2],
+                position, seg, key_position, key_seg, history=window,
+                window=window, dtype=jnp.bfloat16, interpret=SMOKE)
+
+        def plain(q, new_k, new_v):
+            return layer.blockwise(
+                jax.checkpoint(block_attend), q,
+                *layer.window_keys(new_k, new_v, position, seg, carry)[:2],
+                position, seg, key_position, key_seg)
+
+        def with_grads(f):
+            return jax.jit(jax.value_and_grad(
+                lambda *a: jnp.sum(f(*a) * pull), argnums=(0, 1, 2)))
+
+        # one jit a program: each is compiled once, on the chip's time
+        args = (q, new_k, new_v)
+        for what, both, top in (
+                ("forward", (jax.jit(fused), jax.jit(plain)), 12),
+                ("forward+backward", (with_grads(fused), with_grads(plain)),
+                 16))[:1 + (call == "unroll")]:
+            # the output; with gradients: the pulled sum, dq, dk, dv
+            got, want = (jax.tree.leaves(f(*args)) for f in both)
+            say(**shape, what=what,
+                fused_ms=seconds(both[0], *args) * 1e3,
+                blocks_ms=seconds(both[1], *args) * 1e3,
+                max_gaps=[float(jnp.max(jnp.abs(a - b)))
+                          for a, b in zip(got, want)],
+                max_sizes=[float(jnp.max(jnp.abs(b))) for b in want],
+                ops=None if SMOKE else top_ops(both[0], *args, top=top))
+
+
 def main():
     if jax.default_backend() != "tpu" and not SMOKE:
         raise SystemExit("attention_sweep times kernels: it needs a TPU")
@@ -273,6 +391,8 @@ def main():
     if "acting" in PARTS:
         for preset in ("smallthinker_q", "laguna_q"):
             acting_rows(say, rng, preset)
+    if "band" in PARTS:
+        band_rows(say, rng)
     for kind, G in (("W", 8), ("F", 6)):
         windowed = kind == "W"
         rope = core.rope_window if windowed else core.rope_full
@@ -295,18 +415,7 @@ def main():
             pull = jnp.asarray(rng.normal(size=q.shape), jnp.float32)
 
             def plain(q, keys, values):
-                def attend(q, keys, values, see):
-                    scores = jnp.einsum(
-                        "bqkgd,bskd->bkgqs", q.astype(jnp.bfloat16),
-                        keys.astype(jnp.bfloat16),
-                        preferred_element_type=jnp.float32) * D ** -0.5
-                    scores = jnp.where(see[:, None, None], scores, -1e30)
-                    return jnp.einsum(
-                        "bkgqs,bskd->bqkgd",
-                        jax.nn.softmax(scores, axis=-1).astype(jnp.bfloat16),
-                        values.astype(jnp.bfloat16),
-                        preferred_element_type=jnp.float32)
-                return layer.blockwise(jax.checkpoint(attend), q, keys,
+                return layer.blockwise(jax.checkpoint(block_attend), q, keys,
                                        values, position, seg, key_position,
                                        key_seg)
 
@@ -328,20 +437,9 @@ def main():
                 for bq, bk in TILES:
                     tiles = pa.fitted(pa.Tiles(bq, bk), T, HISTORY)
                     spec = pa._Spec(T, HISTORY, window, tiles, SMOKE)
-                    Tp, Sp = -(-T // bq) * bq, -(-(HISTORY + T) // bk) * bk
-                    q16 = jnp.zeros((B, KV, G, Tp, D), jnp.bfloat16) + 0.1
-                    k16 = jnp.zeros((B, KV, Sp, D), jnp.bfloat16) + 0.1
-                    marks = pa._marks(position, seg, key_position, key_seg,
-                                      Tp, Sp)
                     try:
-                        if direction == "forward":
-                            took = seconds(jax.jit(functools.partial(
-                                pa._forward, spec)), q16, k16, k16, marks)
-                        else:
-                            row = jnp.zeros((B, KV, G, Tp), jnp.float32)
-                            took = seconds(jax.jit(functools.partial(
-                                pa._backward, spec)), q16, k16, k16, marks,
-                                row, row, q16)
+                        took = kernel_ms(direction, spec, KV, G, D, position,
+                                         seg, key_position, key_seg)
                     except Exception as e:  # noqa: BLE001 - a tile Mosaic refuses
                         say(kind=kind, call=call, kernel=direction, bq=bq,
                             bk=bk, refused=str(e)[:300])
@@ -349,7 +447,7 @@ def main():
                     visited, skipped = pa.key_block_census(
                         T, HISTORY, window, tiles)
                     say(kind=kind, call=call, kernel=direction, bq=bq, bk=bk,
-                        ms=took * 1e3, visited=visited, skipped=skipped)
+                        ms=took, visited=visited, skipped=skipped)
 
             # -- the whole path: casts, layout, kernels; against the blocks ---
             if "path" not in PARTS:

@@ -3,8 +3,11 @@ interpreted on the CPU at toy sizes, against the path they replace on a TPU:
 ``_RotaryAttention.blockwise`` over a masked softmax — outputs and the
 gradients to queries, keys and values, for both kinds of layer, both group
 sizes of the ``laguna_q`` preset and every shape a ring and a window's resets
-can give the mask; that the comparison tells a band one step too wide; which
-route a learner takes where; and the static grid the start-up gauge reports.
+can give the mask; both of them against one masked softmax over ALL keys,
+which shares no key range with either; that the comparison tells a band one
+step too wide; the order ``window_keys`` hands a window layer's keys over in;
+which route a learner takes where; and the static grid the start-up gauge
+reports.
 """
 import dataclasses
 import functools
@@ -27,8 +30,9 @@ TILES = pallas_attention.Tiles(8, 16)
 
 #: case -> (steps T, the ring's step counter a lane, resets (lane, step)).
 #: A counter below ``HISTORY`` is a ring whose episode opened inside the
-#: burn-in: a valid prefix, then empty slots before the new keys. One above it
-#: is a ring acting has wrapped: slot order is not position order.
+#: burn-in: fewer valid keys than slots (a ``W`` layer's right-aligned, an
+#: ``F`` layer's a prefix). One above it is a ring acting has wrapped: slot
+#: order is not position order.
 CASES = {
     "no_reset": (40, (HISTORY, HISTORY), ()),
     "reset_in_the_call": (40, (HISTORY, HISTORY),
@@ -77,13 +81,16 @@ def _masked_softmax(q, keys, values, see):
                       values)
 
 
-def _both(kind, G, case, window=WINDOW):
-    """``((out, dq, dk, dv) of the kernels, the same of the blocks)``."""
+def _paths(kind, G, case, window=WINDOW, which=("kernels", "blocks")):
+    """``(out, dq, dk, dv)`` of each path of ``which``: the ``kernels``
+    (interpreted; told ``window``), the ``blocks``, and ``every_key`` — one
+    masked softmax of every query over ALL keys under the mask rule, which
+    reads no key range."""
     q, (keys, values, key_position, key_seg), position, seg, pull = _window(
         kind, G, case)
     layer = _layer(kind, G)
 
-    def fused(q, keys, values):
+    def kernels(q, keys, values):
         return pallas_attention.attend(
             q, keys, values, position, seg, key_position, key_seg,
             history=HISTORY, window=window if kind == "W" else None,
@@ -93,13 +100,21 @@ def _both(kind, G, case, window=WINDOW):
         return layer.blockwise(_masked_softmax, q, keys, values, position,
                                seg, key_position, key_seg)
 
+    def every_key(q, keys, values):
+        below = position[:, :, None] - key_position[:, None, :]
+        see = (key_seg[:, None, :] == seg[:, :, None]) & (below >= 0)
+        if kind == "W":
+            see = see & (below < WINDOW)
+        return _masked_softmax(q, keys, values, see)
+
     def with_grads(f):
         out, grads = jax.jit(jax.value_and_grad(
             lambda *a: (lambda o: (jnp.sum(o * pull), o))(f(*a)),
             argnums=(0, 1, 2), has_aux=True))(q, keys, values)
         return (out[1],) + grads
 
-    return with_grads(fused), with_grads(blocks)
+    paths = {"kernels": kernels, "blocks": blocks, "every_key": every_key}
+    return tuple(with_grads(paths[name]) for name in which)
 
 
 def _assert_close(got, want):
@@ -114,7 +129,20 @@ def test_the_kernels_are_blockwise_attention(kind, G, case):
     kernel from the four int32 vectors is ``blockwise``'s, whatever the ring
     holds and wherever the episodes open; padding rows and keys add
     nothing; the static key ranges leave out no key a query sees."""
-    _assert_close(*_both(kind, G, case))
+    _assert_close(*_paths(kind, G, case))
+
+
+@pytest.mark.parametrize("case", CASES)
+@pytest.mark.parametrize("path", ["kernels", "blocks"])
+@pytest.mark.parametrize("kind,G", [("W", 8), ("F", 6)])
+def test_no_key_range_drops_a_key_a_query_sees(kind, G, path, case):
+    """The kernels and the blocks share ``key_ranges``, so the comparison
+    above cannot see a range that leaves a visible key out of both. Here each
+    is held to a masked softmax of every query over ALL keys, the mask from
+    the marks alone: whatever the ring holds — wrapped by acting, cut short
+    by a reset in the burn-in — a ``W`` layer's band by index is its band by
+    position, output and the three gradients."""
+    _assert_close(*_paths(kind, G, case, which=(path, "every_key")))
 
 
 @pytest.mark.parametrize("case", ["no_reset", "wrapped_ring"])
@@ -122,22 +150,66 @@ def test_a_band_one_step_too_wide_fails_the_comparison(case):
     """The kernel told a window of 17 against the blocks' 16: every query
     past its 16th step sees one key more, and the comparison above says so."""
     with pytest.raises(AssertionError):
-        _assert_close(*_both("W", 8, case, window=WINDOW + 1))
+        _assert_close(*_paths("W", 8, case, window=WINDOW + 1))
 
 
-@pytest.mark.parametrize("steps,history,window,tiles", [
-    (1536, 512, 512, pallas_attention.TILES),
-    (1536, 512, None, pallas_attention.TILES),
-    (512, 512, 512, pallas_attention.TILES),
-    (40, 16, 16, TILES), (37, 16, None, TILES), (5, 16, 16, TILES),
-    (1536, 512, 512, pallas_attention.Tiles(128, 256))])
-def test_the_static_grid_visits_the_band_and_the_triangle(steps, history,
-                                                          window, tiles):
+@pytest.mark.parametrize("kind", ["W", "F"])
+def test_window_keys_hands_a_window_layer_its_keys_in_the_order_of_time(kind):
+    """Rings whose counters lie below, at and above ``HISTORY`` (no multiple
+    of it among those above): a key of the ring is what the slot of its
+    position holds, the valid ones are the last ``min(steps, HISTORY)``
+    positions, and in a ``W`` layer ``index - position`` is one number a
+    lane, the ring's keys' and the new keys' alike — the order ``key_ranges``
+    counts on. An ``F`` layer's ring stays as it lies."""
+    rng = np.random.default_rng(52)
+    steps = np.concatenate([[0, 1, 5, HISTORY - 1, HISTORY, HISTORY + 1],
+                            rng.integers(HISTORY + 2, 9 * HISTORY, size=10)])
+    steps = steps[(steps <= HISTORY) | (steps % HISTORY != 0)]
+    lanes, T = len(steps), 6
+    slot = jnp.broadcast_to(jnp.arange(HISTORY, dtype=jnp.float32)[
+        None, :, None, None], (lanes, HISTORY, KV, D))
+    new = jnp.full((lanes, T, KV, D), -1.0)
+    position = jnp.asarray(steps)[:, None] + jnp.arange(T)
+    keys, values, key_position, key_seg = (
+        np.asarray(x) for x in _layer(kind, 8).window_keys(
+            new, new, position, jnp.zeros((lanes, T), jnp.int32),
+            (slot, 2 * slot, jnp.asarray(steps, jnp.float32))))
+    index = np.arange(HISTORY + T)
+    for lane, count in enumerate(steps):
+        valid = key_seg[lane] == 0
+        assert (np.sort(key_position[lane, valid])
+                == np.arange(max(count - HISTORY, 0), count + T)).all()
+        ring = valid[:HISTORY]
+        at = key_position[lane, :HISTORY][ring] % HISTORY
+        assert (keys[lane, :HISTORY, 0, 0][ring] == at).all()
+        assert (values[lane, :HISTORY, -1, -1][ring] == 2 * at).all()
+        assert (key_seg[lane, :HISTORY][~ring]
+                == pallas_attention.INVALID_KEY).all()
+        if kind == "W":
+            assert set(index[valid] - key_position[lane, valid]) == {
+                HISTORY - count}
+        else:
+            assert (keys[lane, :HISTORY, 0, 0] == np.arange(HISTORY)).all()
+
+
+@pytest.mark.parametrize("steps,history,window,tiles,blocks", [
+    (1536, 512, 512, pallas_attention.TILES, None),
+    (1536, 512, None, pallas_attention.TILES, None),
+    (512, 512, 512, pallas_attention.TILES, None),
+    (40, 16, 16, TILES, None), (37, 16, None, TILES, None),
+    (5, 16, 16, TILES, None),
+    (1536, 512, 512, pallas_attention.Tiles(128, 256), None),
+    # the ``smallthinker_q`` preset's calls: the window is eight tiles
+    (4096, 4096, 4096, pallas_attention.TILES, 72),
+    (4096, 4096, None, pallas_attention.TILES, 100)])
+def test_the_static_grid_visits_the_band_and_the_triangle(
+        steps, history, window, tiles, blocks):
     """``key_block_census``: visited + skipped is the rectangle; visited is
-    the key ranges the module states — ``[0, history + lo + bq)`` in an ``F``
-    layer and while ``lo < window``, ``[history + lo - window, history + lo +
-    bq)`` after — counted here key by key; ``query_ranges`` is the same set
-    of visits read by key block."""
+    the blocks the MASK RULE needs and no more — for every query (index
+    ``history + t``) the keys at index distance ``0 .. window - 1`` in front
+    of it (a ``W`` layer's keys lie in the order of time), all of them in an
+    ``F`` layer — counted here query by query; ``query_ranges`` is the same
+    set of visits read by key block."""
     tiles = pallas_attention.fitted(tiles, steps, history)
     bq, bk = tiles.bq, tiles.bk
     visited, skipped = pallas_attention.key_block_census(
@@ -145,11 +217,13 @@ def test_the_static_grid_visits_the_band_and_the_triangle(steps, history,
     key_blocks = -(-(history + steps) // bk)
     assert visited + skipped == -(-steps // bq) * key_blocks
     reads = np.zeros((-(-steps // bq), key_blocks), bool)
-    for i, lo in enumerate(range(0, steps, bq)):
-        start = history + lo - window if window and lo >= window else 0
-        for key in range(start, min(history + lo + bq, history + steps)):
-            reads[i, key // bk] = True
+    for t in range(steps):
+        seen = np.arange(history + t + 1)
+        if window:
+            seen = seen[history + t - seen < window]
+        reads[t // bq, seen // bk] = True
     assert visited == reads.sum()
+    assert blocks is None or visited == blocks
     first, count = pallas_attention.query_ranges(steps, history, window, bq,
                                                  bk)
     for block, (f, c) in enumerate(zip(first, count)):

@@ -78,8 +78,8 @@ def test_the_preset_is_one_published_period(monkeypatch):
     the eight sublayers by name and shape — no ``g_proj``, no ``shared_*``,
     the router beside the ATTENTION sublayer's norm — 281.4 M parameters,
     the acting state a lane by kind of cache, and the start-up gauges'
-    readings: 100 of 128 key blocks visited in either kind of layer, rotary
-    rows in the window layers only."""
+    readings: of 128 key blocks a call the full layer's triangle visits 100
+    and a window layer's band 72, rotary rows in the window layers only."""
     from dist_dqn_tpu import loop_common
     from dist_dqn_tpu.envs import make_jax_env
     from dist_dqn_tpu.models import build_network
@@ -118,10 +118,11 @@ def test_the_preset_is_one_published_period(monkeypatch):
                         lambda enabled: (enabled, False))
     blocks = net.attention_key_blocks(2, 4096, 4096)
     rows = net.rotary_head_rows(2, 4096, 4096)
-    # one (lane, KV head) of one call: 8 query blocks x 16 key blocks, of
-    # which 9 + 10 + ... + 16 = 100 are read; the band needs 72
+    # one (lane, KV head) of one call: 8 query blocks x 16 key blocks; the
+    # full layer's triangle reads 9 + 10 + ... + 16 = 100 of them, a window
+    # layer's band (its keys in the order of time) 9 a query block = 72
     assert blocks == {"full": (2 * 4 * 2 * 100, 2 * 4 * 2 * 28),
-                      "window": (3 * 2 * 4 * 2 * 100, 3 * 2 * 4 * 2 * 28)}
+                      "window": (3 * 2 * 4 * 2 * 72, 3 * 2 * 4 * 2 * 56)}
     assert rows == {"full": 0, "window": 3 * 2 * 8192 * 28}
 
 
