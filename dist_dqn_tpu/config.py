@@ -44,12 +44,19 @@ class CoreConfig:
     gated attention over the whole history / the last ``sliding_window``
     steps, ``D`` a dense gated MLP. The defaults are the widths the
     ``twotower_q`` preset runs (``nemotron_h``'s keys, where it has one);
-    ``laguna_q`` and ``smallthinker_q`` state their own.
+    ``laguna_q``, ``smallthinker_q`` and ``ouro_q`` state their own.
     """
 
     kind: str = "lstm"
     pattern: str = "MEMEM*EME"
     norm_eps: float = 1e-5
+    # The stack (every sublayer of ``pattern``, then the final norm) runs
+    # ``loops`` times over the SAME parameters, each turn on the turn
+    # before's output and with a state of its own (``ouro``'s
+    # ``total_ut_steps``); ``sandwich_norm``: a sublayer norms its mixer's
+    # OUTPUT too, ``x + RMSNorm'(mixer(RMSNorm(x)))``.
+    loops: int = 1
+    sandwich_norm: bool = False
     # M: heads x head_dim channels, B and C shared by the heads of a group.
     mamba_num_heads: int = 64
     mamba_head_dim: int = 64
@@ -597,9 +604,48 @@ SMALLTHINKER_Q = ExperimentConfig(
     total_env_steps=100_000_000,
 )
 
+OURO_Q = ExperimentConfig(
+    # An R2D2-style agent whose memory is one pipeline stage of Ouro-2.6B
+    # (ByteDance; ``ouro``) at its published widths: four layers — eight
+    # sublayers, full rotary attention (16 heads over 16 KV heads of 128,
+    # theta 1e6 over all dims, no gate) and a SwiGLU MLP of 5,632, each with
+    # a norm on its input AND on its output — run FOUR TIMES over the same
+    # parameters (``total_ut_steps``), the final norm after every turn, a
+    # ring of keys and values a turn a layer (perf/configs/ouro_q.json).
+    # Windows of 2,048 steps from a zero state: 512 burn-in + 1,531 trained
+    # + 5 bootstrap, 2 a grad step = 4,096 tokens, a grad step every 16th
+    # acting step. Acting keeps 2,048 steps of keys and values in each of
+    # the sixteen rings: 537 MB a lane.
+    name="ouro_q",
+    env_name="pixel_pong",
+    network=NetworkConfig(
+        torso="nature", hidden=2048, dueling=True,
+        compute_dtype="bfloat16", remat_torso=True,
+        core=CoreConfig(
+            kind="hybrid", pattern="FDFDFDFD", norm_eps=1e-6, loops=4,
+            sandwich_norm=True, intermediate_size=5632,
+            num_key_value_heads=16, head_dim=128, attention_window=2048,
+            attention_heads_per_layer=(16, 16, 16, 16),
+            attention_gate=False,
+            rope_full=RopeConfig(theta=1_000_000.0))),
+    replay=ReplayConfig(capacity=131_072, prioritized=True,
+                        priority_exponent=0.9, importance_exponent=0.6,
+                        burn_in=512, unroll_length=1531,
+                        sequence_stride=512, min_fill=20_480,
+                        frame_dedup=True),
+    learner=LearnerConfig(
+        learning_rate=1e-4, adam_eps=1e-3, gamma=0.997, n_step=5,
+        batch_size=2, double_dqn=True, target_update_period=2_500,
+        value_rescale=True,
+    ),
+    actor=ActorConfig(num_envs=8, num_actors=256),
+    train_every=16,
+    total_env_steps=100_000_000,
+)
+
 CONFIGS: Dict[str, ExperimentConfig] = {
     c.name: c for c in (CARTPOLE, ATARI, APEX, R2D2, RAINBOW, QRDQN, IQN,
-                        MDQN, TWOTOWER_Q, LAGUNA_Q, SMALLTHINKER_Q)
+                        MDQN, TWOTOWER_Q, LAGUNA_Q, SMALLTHINKER_Q, OURO_Q)
 }
 
 

@@ -1,10 +1,13 @@
 """Recurrent Q-network over a hybrid sequence core: torso -> Dense
 ``hidden`` -> a stack of pre-norm residual layers -> RMSNorm -> dueling
-heads (``config.CoreConfig`` kind "hybrid").
+heads (``config.CoreConfig`` kind "hybrid"); the stack and its norm run once,
+or ``loops`` times over the same parameters (below).
 
-Every layer is ``x + mixer(RMSNorm(x))`` with no bias but the state-space
-layer's convolution — and nothing but ``x`` and the carry passes from one to
-the next, except the routing under ``router_ahead`` (below); one letter of
+Every layer is ``x + mixer(RMSNorm(x))`` — ``x + RMSNorm'(mixer(RMSNorm(x)))``
+under ``sandwich_norm``, as ``ouro`` writes its layers — with no bias but the
+state-space layer's convolution, and nothing but ``x`` and the carry passes
+from one to the next, except the routing under ``router_ahead`` (below); one
+letter of
 ``CoreConfig.pattern`` a layer, as ``nemotron_h`` writes its
 ``hybrid_override_pattern``:
 
@@ -107,6 +110,19 @@ in the backward's recomputation, and as a cotangent on the way back — is that
 one array, not a second ``[B, T, hidden]`` stream; the router's weights get
 their gradient in the attention sublayer's backward, and ``x`` gets the
 gates' gradient through ``u``.
+
+Loops (``loops`` > 1, ``ouro``: a looped language model's ``total_ut_steps``).
+The whole stack — every sublayer of the pattern, then the final norm — is
+applied ``loops`` times over ONE set of parameters (``layer_i`` and
+``norm_f`` exist once, whatever ``loops`` says): the norm's output after turn
+r is turn r + 1's input, and the heads read the last turn's. A weight's
+gradient is the sum over its turns. The turns share NO state: turn r's keys
+and values are projections of turn r's hidden state, so the carry has one
+entry a sublayer A TURN (``applied``; entry ``r * len(pattern) + l``), and
+everything that walks it — the empty state, a reset, the bytes a lane
+carries, the key blocks and rotary rows a learner's pass runs — counts every
+turn. ``HybridQNetwork.turns`` writes the turns out, one after the other and
+each ring an array of its own, under one scope, ``loops``.
 
 State. A lane's acting state is, for every ``M`` layer, the convolution's
 look-back ``[B, K-1, channels]`` and the state ``h [B, H, P, N]``, and for
@@ -889,6 +905,13 @@ ROTARY = "FW"
 QUERY_BLOCK = pallas_attention.TILES.bq
 
 
+def applied(cfg: CoreConfig) -> str:
+    """The kind of every sublayer APPLICATION of one forward step, in the
+    carry's order: the pattern once a turn (the module's docstring, "Loops");
+    entry ``r * len(pattern) + l`` is the state of sublayer l in turn r."""
+    return cfg.pattern * cfg.loops
+
+
 def rotary_heads(cfg: CoreConfig) -> Tuple[int, ...]:
     """Query heads of every sublayer of the pattern; 0 where the sublayer
     is no ``F`` or ``W``."""
@@ -910,7 +933,9 @@ def routes_ahead(cfg: CoreConfig) -> Tuple[bool, ...]:
 
 
 class _Layer(nn.Module):
-    """``x + mixer(RMSNorm(x))``; a module of its own so that ``nn.remat``
+    """``x + mixer(RMSNorm(x))`` — under ``sandwich_norm`` ``x +
+    RMSNorm'(mixer(RMSNorm(x)))``, the second norm's weights the leaf
+    ``norm_out`` beside ``norm`` — a module of its own so that ``nn.remat``
     can wrap it: a layer's activations are then recomputed in the backward
     pass, and only its input lives through the loss — and, under
     ``router_ahead``, the routing that crosses from a sublayer to the
@@ -934,6 +959,10 @@ class _Layer(nn.Module):
         out, carry = _MIXERS[self.kind](
             self.cfg, self.dtype, name="mixer", **its_own)(
                 u, seg, carry, *(() if routing is None else (routing,)))
+        if self.cfg.sandwich_norm:
+            out = rms_norm(out, self.param(
+                "norm_out", nn.initializers.ones, (x.shape[-1],)),
+                self.cfg.norm_eps)
         if not self.routes:
             return x + out, carry, None
         w_router = self.param("router", _normal(x.shape[-1] ** -0.5),
@@ -945,7 +974,9 @@ class _Layer(nn.Module):
 
 
 class _Core(nn.Module):
-    """The stack of layers and the final norm over ``[B, T, hidden]``."""
+    """The stack of layers and the final norm over ``[B, T, hidden]``: ONE
+    turn of a looped core (``HybridQNetwork.turns`` applies it ``loops``
+    times), ``carry`` that turn's entries, one a sublayer."""
 
     cfg: CoreConfig
     dtype: jnp.dtype
@@ -986,7 +1017,8 @@ class HybridQNetwork(nn.Module):
         return "E" in self.core.pattern
 
     def initial_state(self, batch_size: int, history: Optional[int] = None):
-        """The empty state of ``batch_size`` lanes, one entry a layer;
+        """The empty state of ``batch_size`` lanes, one entry a sublayer a
+        turn (``applied``);
         ``history``: steps of keys and values a ``*`` or ``F`` layer keeps
         (default ``attention_window``, what acting carries); a ``W`` layer
         keeps ``sliding_window``, whoever asks."""
@@ -1011,17 +1043,18 @@ class HybridQNetwork(nn.Module):
                           zeros(batch_size, history)),
             "F": lambda: (zeros(*cache), zeros(*cache), zeros(batch_size)),
             "W": lambda: (zeros(*band), zeros(*band), zeros(batch_size)),
-        }[kind]() for kind in cfg.pattern)
+        }[kind]() for kind in applied(cfg))
 
     def state_bytes_a_lane(self) -> dict:
         """Bytes of one lane's acting state by kind of cache (the scope a
-        kind's mixer enters): the sum over the pattern's sublayers of that
-        kind; kinds that keep nothing are left out."""
+        kind's mixer enters): the sum over the sublayers of that kind, every
+        turn of a looped core counted; kinds that keep nothing are left
+        out."""
         names = {"M": "ssm", "*": "attention", "F": "attention_full",
                  "W": "attention_window"}
         found: dict = {}
         lane = jax.eval_shape(lambda: self.initial_state(1))
-        for kind, layer in zip(self.core.pattern, lane):
+        for kind, layer in zip(applied(self.core), lane):
             for leaf in jax.tree.leaves(layer):
                 found[names[kind]] = (found.get(names[kind], 0)
                                       + leaf.size * leaf.dtype.itemsize)
@@ -1029,7 +1062,8 @@ class HybridQNetwork(nn.Module):
 
     def attention_ring_bytes(self, lanes: int) -> dict:
         """``{"window" | "full": (read, copied)}``: the bytes of rings —
-        keys and values, summed over the layers of a kind — that ONE acting
+        keys and values, summed over the layers of a kind and the turns of a
+        looped core — that ONE acting
         step of ``lanes`` lanes reads, and the bytes of ring-sized copies
         the path it takes writes on the way: none through
         ``pallas_attention.decode`` (a TPU), the rings once more in the
@@ -1039,7 +1073,7 @@ class HybridQNetwork(nn.Module):
         width = jnp.dtype(self.compute_dtype).itemsize
         in_place = loop_common.pallas_routing(True)[0]
         state = jax.eval_shape(lambda: self.initial_state(lanes))
-        for kind, layer in zip(self.core.pattern, state):
+        for kind, layer in zip(applied(self.core), state):
             if kind not in ROTARY:
                 continue
             name = "window" if kind == "W" else "full"
@@ -1057,8 +1091,9 @@ class HybridQNetwork(nn.Module):
         fused kernels' forward grids (``ops/pallas_attention.py``) read and
         leave out in ONE forward pass of a learner's batch through the core
         — the burn-in call from the empty state, then the call over the
-        other ``steps`` — summed over the layers of a kind, their KV heads
-        and the batch's ``windows``: an ``F`` layer's grid is the causal
+        other ``steps`` — summed over the layers of a kind (every turn of a
+        looped core), their KV heads and the batch's ``windows``: an ``F``
+        layer's grid is the causal
         triangle, a ``W`` layer's the band a window wide (``key_ranges``;
         its keys come in the order of time). A grad step runs those grids
         for both networks and once more under ``nn.remat``. Empty where the
@@ -1068,7 +1103,7 @@ class HybridQNetwork(nn.Module):
         found: dict = {}
         if not loop_common.pallas_routing(True)[0]:
             return found
-        for kind in cfg.pattern:
+        for kind in applied(cfg):
             if kind not in ROTARY:
                 continue
             windowed = kind == "W"
@@ -1090,7 +1125,8 @@ class HybridQNetwork(nn.Module):
                          steps: int) -> dict:
         """``{"window" | "full": rows}``: the query-head rows (windows x
         steps x query heads, the burn-in call and the call over the other
-        ``steps``, summed over the layers of a kind) that ONE forward pass
+        ``steps``, summed over the layers of a kind and the turns of a
+        looped core) that ONE forward pass
         of a learner's batch sends through the rotary kernel
         (``pallas_attention.attend``'s ``rotary``); 0 for a kind without a
         position embedding, whose queries go to the kernels as they are.
@@ -1100,7 +1136,7 @@ class HybridQNetwork(nn.Module):
         found: dict = {}
         if not loop_common.pallas_routing(True)[0]:
             return found
-        for kind, heads in zip(cfg.pattern, rotary_heads(cfg)):
+        for kind, heads in zip(applied(cfg), rotary_heads(cfg) * cfg.loops):
             if kind in ROTARY:
                 name, rope = (("window", cfg.rope_window) if kind == "W"
                               else ("full", cfg.rope_full))
@@ -1122,7 +1158,7 @@ class HybridQNetwork(nn.Module):
         return tuple(
             layer[:2] + (emptied(layer[2]),) if kind in ROTARY
             else jax.tree.map(emptied, layer)
-            for kind, layer in zip(self.core.pattern, carry))
+            for kind, layer in zip(applied(self.core), carry))
 
     def stored_state(self, carry):
         """What the replay ring keeps of a lane's state with each step:
@@ -1133,6 +1169,31 @@ class HybridQNetwork(nn.Module):
         """The state a learner's window starts from: empty, the history of
         its ``*`` and ``F`` layers as long as the burn-in that fills it."""
         return self.initial_state(batch_size, history=burn_in)
+
+    def turns(self, x: Array, reset: Array, carry):
+        """The core over ``x [B, T, hidden]``: ``_Core`` — one set of
+        parameters, ``core`` — applied ``loops`` times, each turn on the turn
+        before's normed output and with its own entries of ``carry``
+        (``applied``), under the scope ``loops`` where there is more than one.
+
+        The turns are written out, acting's and a learner's window's alike:
+        every ring is an array of its own, so an acting step's key goes into
+        its slot in place and ``decode`` reads that ring where it lies (a
+        scan over a stacked carry would slice a ring out of the stack and
+        write it back, 537 MB a lane a step in ``ouro_q``; what one scanned
+        body cost a learner's window is ``PERF.md`` §6, PR 53)."""
+        cfg = self.core
+        core = _Core(cfg, self.compute_dtype, name="core")
+        if cfg.loops == 1:
+            return core(x, reset, carry)
+        per_turn = len(cfg.pattern)
+        after = ()
+        with jax.named_scope("loops"):
+            for r in range(cfg.loops):
+                x, its_own = core(
+                    x, reset, carry[r * per_turn:(r + 1) * per_turn])
+                after += tuple(its_own)
+        return x, after
 
     def __call__(self, carry, obs: Array, reset: Optional[Array] = None):
         """One step: obs [B, ...], reset [B] bool (None = no resets)."""
@@ -1153,8 +1214,7 @@ class HybridQNetwork(nn.Module):
                   self.compute_dtype, name="torso")(
                       obs.reshape((T * B,) + obs.shape[2:]))
         x = jnp.swapaxes(x.reshape(T, B, -1), 0, 1)
-        x, carry = _Core(self.core, self.compute_dtype, name="core")(
-            x, reset.T, carry)
+        x, carry = self.turns(x, reset.T, carry)
         h = jnp.swapaxes(x, 0, 1).reshape(T * B, -1)
         adv = nn.Dense(self.num_actions, name="advantage")(h)
         q = adv

@@ -7,13 +7,16 @@ replay/prioritized_device.py, replay/sequence_device.py, agents/dqn.py,
 agents/r2d2.py). A scope is metadata: it lands in every HLO instruction's
 ``op_name`` and leaves the optimized program as it was.
 
-Stage ``loss_grad`` has child names, in three groups that each split it
+Stage ``loss_grad`` has child names, in four groups that each split it
 another way: :data:`PASSES`, scopes the recurrent learner enters
 (agents/r2d2.py ``_unrolled_q``); :data:`PARTS`, which nobody enters —
 they are the names the recurrent networks give their two sub-modules
 (parameter keys, so they cannot drift), and Flax puts a module's name on the
-op path; and :data:`CORE_PARTS`, which splits ``core`` by the scopes the
-hybrid core's mixers enter (models/sequence_core.py).
+op path; :data:`CORE_PARTS`, which splits ``core`` by the scopes the
+hybrid core's mixers enter (models/sequence_core.py); and :data:`LOOPS`,
+the one scope a looped core enters around its turns, so that what the loop
+costs outside every mixer can be read (under it, under no name of
+:data:`CORE_PARTS`).
 A child is read (:func:`child_of`) through the wrappers a transform puts
 around the outermost name inside it (``transpose(jvp(online_unroll))``),
 and counts only on an instruction whose STAGE is ``loss_grad``
@@ -56,6 +59,15 @@ PARTS = ("torso", "core")
 CORE_PARTS = ("torso", "ssm", "attention", "moe_router", "moe_routed",
               "moe_shared", "attention_window", "attention_full",
               "mlp_dense")
+#: Child of stage ``loss_grad``, entered: the scope a core that runs its
+#: stack several times enters around all of its turns
+#: (``HybridQNetwork.turns``; one name whatever the loop's form), the
+#: mixers' scopes inside it. What lies under it and under no name of
+#: :data:`CORE_PARTS` is the looped stack OUTSIDE every mixer: the
+#: sublayers' norms on input and output, the residual adds, the norm after
+#: each turn, and what the loop's form adds (the turns are written out: the
+#: sum of a weight's gradients over the turns).
+LOOPS = ("loops",)
 #: The stage whose instructions the child names split.
 PARENT = "loss_grad"
 #: A fusion whose instructions come from more than one stage (or child).

@@ -389,7 +389,7 @@ def main():
         print(json.dumps(row), flush=True)
 
     if "acting" in PARTS:
-        for preset in ("smallthinker_q", "laguna_q"):
+        for preset in ("smallthinker_q", "laguna_q", "ouro_q"):
             acting_rows(say, rng, preset)
     if "band" in PARTS:
         band_rows(say, rng)

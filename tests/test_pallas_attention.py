@@ -44,20 +44,20 @@ CASES = {
 }
 
 
-def _layer(kind, G):
+def _layer(kind, G, kv=KV):
     core = dataclasses.replace(CONFIGS["laguna_q"].network.core,
                                sliding_window=WINDOW,
-                               num_key_value_heads=KV, head_dim=D)
-    return sequence_core._MIXERS[kind](core, jnp.float32, heads=G * KV)
+                               num_key_value_heads=kv, head_dim=D)
+    return sequence_core._MIXERS[kind](core, jnp.float32, heads=G * kv)
 
 
-def _window(kind, G, case, seed=0):
+def _window(kind, G, case, seed=0, kv=KV):
     """One call's queries, keys with their marks, and a cotangent."""
     T, steps, resets = CASES[case]
     keys = jax.random.split(jax.random.PRNGKey(seed), 6)
-    q = jax.random.normal(keys[0], (B, T, KV, G, D))
-    new_k, new_v = (jax.random.normal(k, (B, T, KV, D)) for k in keys[1:3])
-    ring = tuple(jax.random.normal(k, (B, HISTORY, KV, D))
+    q = jax.random.normal(keys[0], (B, T, kv, G, D))
+    new_k, new_v = (jax.random.normal(k, (B, T, kv, D)) for k in keys[1:3])
+    ring = tuple(jax.random.normal(k, (B, HISTORY, kv, D))
                  for k in keys[3:5])
     reset = np.zeros((B, T), bool)
     for lane, step in resets:
@@ -69,8 +69,8 @@ def _window(kind, G, case, seed=0):
     steps = jnp.asarray(steps, jnp.float32)
     position = jnp.where(seg == 0, steps.astype(jnp.int32)[:, None] + index,
                          index - opened)
-    marks = _layer(kind, G).window_keys(new_k, new_v, position, seg,
-                                        ring + (steps,))
+    marks = _layer(kind, G, kv).window_keys(new_k, new_v, position, seg,
+                                            ring + (steps,))
     return q, marks, position, seg, jax.random.normal(keys[5], q.shape)
 
 
@@ -81,14 +81,15 @@ def _masked_softmax(q, keys, values, see):
                       values)
 
 
-def _paths(kind, G, case, window=WINDOW, which=("kernels", "blocks")):
+def _paths(kind, G, case, window=WINDOW, which=("kernels", "blocks"),
+           kv=KV):
     """``(out, dq, dk, dv)`` of each path of ``which``: the ``kernels``
     (interpreted; told ``window``), the ``blocks``, and ``every_key`` — one
     masked softmax of every query over ALL keys under the mask rule, which
     reads no key range."""
     q, (keys, values, key_position, key_seg), position, seg, pull = _window(
-        kind, G, case)
-    layer = _layer(kind, G)
+        kind, G, case, kv=kv)
+    layer = _layer(kind, G, kv)
 
     def kernels(q, keys, values):
         return pallas_attention.attend(
@@ -130,6 +131,16 @@ def test_the_kernels_are_blockwise_attention(kind, G, case):
     holds and wherever the episodes open; padding rows and keys add
     nothing; the static key ranges leave out no key a query sees."""
     _assert_close(*_paths(kind, G, case))
+
+
+@pytest.mark.parametrize("case", CASES)
+def test_the_kernels_hold_at_one_query_head_a_kv_head(case):
+    """The ``ouro_q`` preset's grouping — G = 1 over 16 KV heads, where the
+    presets before it ran 6, 7 and 8 query heads over 2 to 8: the forward's
+    ``[1, bq]`` statistics and ``[1, D, bq]`` accumulator, the backward's
+    ``dq [1, Tp, D]`` block, output and the gradients to ``q``, ``k``, ``v``
+    against ``blockwise``."""
+    _assert_close(*_paths("F", 1, case, kv=16))
 
 
 @pytest.mark.parametrize("case", CASES)
@@ -355,17 +366,21 @@ def _compiled_for(v5e_args, fn):
         compilation_cache.reset_cache()
 
 
-@pytest.mark.parametrize("kind,G,steps,backward", [
-    ("W", 8, 1536, True), ("F", 6, 1536, True), ("W", 8, 512, False)])
+@pytest.mark.parametrize("kind,G,steps,backward,lanes,kv", [
+    ("W", 8, 1536, True, 4, 8), ("F", 6, 1536, True, 4, 8),
+    ("W", 8, 512, False, 4, 8), ("F", 1, 1536, True, 2, 16),
+    ("F", 1, 512, False, 2, 16)])
 def test_the_kernels_compile_for_v5e_at_the_presets_shapes(v5e, kind, G,
-                                                           steps, backward):
+                                                           steps, backward,
+                                                           lanes, kv):
     """The ``laguna_q`` preset's calls (4 windows, 8 KV heads of 128, a ring
-    of 512 in front) through Mosaic at ``TILES``: what the interpreter cannot
+    of 512 in front) and the ``ouro_q`` preset's (2 windows, ONE query head
+    over each of 16 KV heads) through Mosaic at ``TILES``: what the interpreter cannot
     refuse — a block off the (8, 128) tiling, a transpose or a reshape
     Mosaic has no rule for, more VMEM than a kernel may have (the backward
     keeps a KV head's whole ``dq``, 6.3 MB, twice). The compiled program
     holds the kernels by name and no score-shaped array."""
-    lanes, kv, d, history = 4, 8, 128, 512
+    d, history = 128, 512
 
     def attended(q, keys, values, *marks):
         return pallas_attention.attend(
@@ -392,26 +407,30 @@ def test_the_kernels_compile_for_v5e_at_the_presets_shapes(v5e, kind, G,
                    for keys in (S, 1024, 2048))
 
 
-@pytest.mark.parametrize("kind,G,steps", [
-    ("W", 8, 1536), ("F", 6, 1536), ("W", 8, 512), ("F", 6, 512)])
+@pytest.mark.parametrize("kind,G,steps,preset", [
+    ("W", 8, 1536, "laguna_q"), ("F", 6, 1536, "laguna_q"),
+    ("W", 8, 512, "laguna_q"), ("F", 6, 512, "laguna_q"),
+    ("F", 1, 1536, "ouro_q")])
 def test_the_rotary_kernels_compile_for_v5e_at_the_presets_shapes(
-        v5e, kind, G, steps):
+        v5e, kind, G, steps, preset):
     """The queries' pass there and back at the ``laguna_q`` preset's calls:
     a block of ``EMBED_BLOCK`` steps x a KV head's ``G`` tiles of 128 lanes,
     the lane rotations by 64 (``W``) and by 96 and 32 (``F``) through
-    Mosaic."""
-    core = CONFIGS["laguna_q"].network.core
+    Mosaic; and at the ``ouro_q`` preset's, a block ONE tile wide (G = 1, 16
+    KV heads, one roll by 64 over all dims)."""
+    core = CONFIGS[preset].network.core
     rope = core.rope_window if kind == "W" else core.rope_full
+    kv = core.num_key_value_heads
 
     def there(x, position):
         wide, shifts = pallas_attention.wide_tables(
             sequence_core.rotary_tables(position, rope, 128), 128)
-        q = pallas_attention._embed(x, wide, shifts, jnp.bfloat16, kv=8)
+        q = pallas_attention._embed(x, wide, shifts, jnp.bfloat16, kv=kv)
         return q, pallas_attention._embed(q.astype(jnp.float32), wide, shifts,
                                           jnp.bfloat16)
 
     text = _compiled_for(
-        (jax.ShapeDtypeStruct((4, steps, 8 * G * 128), jnp.float32,
+        (jax.ShapeDtypeStruct((4, steps, kv * G * 128), jnp.float32,
                               sharding=v5e),
          jax.ShapeDtypeStruct((4, steps), jnp.int32, sharding=v5e)), there)
     assert pallas_attention.EMBED_FORWARD_NAME in text
@@ -463,11 +482,11 @@ def test_a_sublayers_grad_pass_holds_no_half_empty_array(v5e, monkeypatch,
 
 
 @pytest.mark.parametrize("history,kv,G", [
-    (4096, 4, 7), (8192, 4, 7), (2048, 8, 6), (512, 8, 8)])
+    (4096, 4, 7), (8192, 4, 7), (2048, 8, 6), (512, 8, 8), (2048, 16, 1)])
 def test_the_decode_kernel_compiles_for_v5e_at_the_presets_rings(v5e, history,
                                                                  kv, G):
-    """One acting step of 16 lanes over a ring of the ``smallthinker_q`` and
-    ``laguna_q`` presets — the new key and value into their slot, then
+    """One acting step of 16 lanes over a ring of the ``smallthinker_q``,
+    ``laguna_q`` and ``ouro_q`` (16 KV heads, one query head each) presets — the new key and value into their slot, then
     ``decode`` — through Mosaic at ``DECODE_BLOCK``: the kernel by name, the
     ring handed to it where it lies (its ``[B, S * KV, D]`` view is the same
     bytes: no copy), and no bfloat16 array of a ring's size, in any layout."""

@@ -47,12 +47,14 @@ def _step(G, KV, history, seed=0):
 @pytest.mark.parametrize("history", [48, 37, 16, 5],
                          ids=["whole_blocks", "ragged", "one_block",
                               "less_than_a_block"])
-@pytest.mark.parametrize("G,KV", [(7, 4), (6, 8), (8, 8)])
+@pytest.mark.parametrize("G,KV", [(7, 4), (6, 8), (8, 8), (1, 16), (1, 4),
+                                  (3, 2)])
 def test_the_kernel_is_attend_over_the_float32_ring(G, KV, history):
     """The mask built in the kernel from a lane's count is ``attend``'s; the
-    rows that pad a KV head's queries to eight, the scores between a head's
-    queries and another head's keys, and what lies past a ragged ring's last
-    slot add nothing."""
+    rows that pad a KV head's queries to eight (7 of 8 at the ``ouro_q``
+    preset's ONE query head a KV head over 16 KV heads), the scores between a
+    head's queries and another head's keys, and what lies past a ragged
+    ring's last slot add nothing."""
     args = _step(G, KV, history)
     got = pallas_attention.decode(*args, jnp.float32, interpret=True,
                                   block=BLOCK)
@@ -74,7 +76,7 @@ def test_a_lane_just_reset_sees_its_one_key():
         rtol=1e-6)
 
 
-@pytest.mark.parametrize("G,KV", [(7, 4), (8, 8)])
+@pytest.mark.parametrize("G,KV", [(7, 4), (8, 8), (1, 16)])
 def test_the_ring_is_rounded_in_the_kernel_as_the_cast_rounds_it(G, KV):
     """A ring whose values bfloat16 cannot hold, read by the kernel with
     bfloat16 operands, gives BIT FOR BIT what the same kernel gives over the

@@ -83,11 +83,12 @@ def test_every_scope_in_the_package_is_a_stage_and_every_stage_is_entered():
         entered.update(re.findall(r'named_scope\("([^"]+)"\)',
                                   path.read_text()))
     # ... and the hybrid core's mixers enter their part names (``torso`` is
-    # a module name there too)
+    # a module name there too), a looped core ``loops`` around its turns
     assert entered == (set(stages.STAGES) | set(stages.PASSES)
+                       | set(stages.LOOPS)
                        | set(stages.CORE_PARTS) - {"torso"})
     assert not set(stages.STAGES) & set(
-        stages.PASSES + stages.PARTS + stages.CORE_PARTS)
+        stages.PASSES + stages.PARTS + stages.CORE_PARTS + stages.LOOPS)
 
 
 def test_the_parts_are_the_recurrent_networks_module_names():
